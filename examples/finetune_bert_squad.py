@@ -1,4 +1,4 @@
-"""BERT-base SQuAD-style fine-tune, DP over 8 chips — BASELINE.md config #3.
+"""BERT-base SQuAD-style fine-tune, DP over 8 chips — ladder config #3.
 
 The capability-ladder rung the reference covers with PaddleNLP's
 ``run_squad.py``: BertForQuestionAnswering span head, AdamW with linear

@@ -1,5 +1,5 @@
 """Llama pretraining driver (PaddleNLP ``llm/run_pretrain.py`` analog) —
-BASELINE.md config #4: TP+PP+sharding hybrid parallel.
+capability-ladder config #4: TP+PP+sharding hybrid parallel.
 
 Run (CPU simulation, 8 virtual devices):
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \

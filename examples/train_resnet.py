@@ -1,4 +1,4 @@
-"""ResNet-50 training driver (PaddleClas analog) — BASELINE.md config #2.
+"""ResNet-50 training driver (PaddleClas analog) — ladder config #2.
 
 Run: python examples/train_resnet.py --cpu --arch resnet18 --steps 10
 """

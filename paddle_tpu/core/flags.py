@@ -146,10 +146,6 @@ define_flag("disable_pallas_kernels", False,
             "Force the XLA composite path for all Pallas kernels "
             "(mirrors to PADDLE_TPU_DISABLE_PALLAS for subprocesses).",
             on_set=_bool_env_mirror("PADDLE_TPU_DISABLE_PALLAS"))
-define_flag("strict_pallas", False,
-            "Raise (instead of warn) when a Pallas kernel falls back to XLA "
-            "(mirrors to PADDLE_TPU_STRICT_PALLAS for subprocesses).",
-            on_set=_bool_env_mirror("PADDLE_TPU_STRICT_PALLAS"))
 define_flag("pallas_autotune", False,
             "Measured block-size sweep for Pallas flash attention, memoized "
             "per shape/dtype/device (the N11 autotune-cache analog).")

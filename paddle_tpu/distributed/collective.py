@@ -20,7 +20,6 @@ import jax.numpy as jnp
 
 from ..core.dispatch import run_op
 from ..core.tensor import Tensor
-from ..parallel._compat import lax_axis_size
 
 
 class ReduceOp:
@@ -42,7 +41,7 @@ def _in_spmd() -> bool:
 
 def _axis_bound(axis: str) -> bool:
     try:
-        lax_axis_size(axis)
+        jax.lax.axis_size(axis)
         return True
     except Exception:
         return False
@@ -83,7 +82,7 @@ def all_gather(tensor_list, tensor: Tensor, group=None, sync_op=True):
             lambda v: jax.lax.all_gather(v, axis, tiled=False),
             tensor,
         )
-        n = lax_axis_size(axis)
+        n = jax.lax.axis_size(axis)
         for i in range(n):
             tensor_list.append(out[i])
         return None

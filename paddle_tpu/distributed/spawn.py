@@ -29,9 +29,7 @@ def _worker(func, rank: int, nprocs: int, master: str, args: Tuple,
         "PADDLE_MASTER": master,
         "MASTER_ADDR": master.split(":")[0],
         "MASTER_PORT": master.split(":")[1],
-        "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
-        # consumed by init_parallel_env: CPU platform pin (via jax.config,
-        # the env var alone is not honored) + virtual device count
+        # consumed by init_parallel_env: virtual device count
         "PADDLE_TPU_CPU_SIM": str(sim_devices),
     })
     func(*args)
@@ -45,6 +43,11 @@ def spawn(func, args=(), nprocs: int = 1, join: bool = True,
     CPU-simulation path (default 1 — the reference's per-GPU fork shape)."""
     master = options.get("master") or f"127.0.0.1:{_free_port()}"
     sim_devices = int(options.get("sim_devices", 1))
+    # jax reads JAX_PLATFORMS when it is imported, which in a child happens
+    # while _worker is being unpickled: the variable has to be in the
+    # environment the children start with (this process has imported jax
+    # already and is not affected)
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     ctx = mp.get_context("spawn")
     procs = []
     for rank in range(nprocs):

@@ -15,9 +15,9 @@ from typing import Any, List, Optional
 
 import jax
 import numpy as np
+from jax import export as jax_export
 
 from ..core.tensor import Tensor
-from ..parallel._compat import get_jax_export  # the ONE jax.export
                                                # binding (ISSUE 15)
 from .api import StaticFunction, in_to_static_trace, not_to_static, to_static  # noqa: F401
 
@@ -87,7 +87,7 @@ def save(layer, path: str, input_spec=None, **configs):
                 return tuple(o._value for o in out)
             return out._value
 
-        exported = get_jax_export().export(jax.jit(fwd))(
+        exported = jax_export.export(jax.jit(fwd))(
             [jax.ShapeDtypeStruct(np.shape(v), v.dtype) for v in vals], *examples
         )
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
@@ -101,7 +101,7 @@ def save(layer, path: str, input_spec=None, **configs):
 
 def load(path: str, **configs) -> TranslatedLayer:
     with open(path + ".stablehlo", "rb") as f:
-        exported = get_jax_export().deserialize(f.read())
+        exported = jax_export.deserialize(f.read())
     with open(path + ".pdiparams", "rb") as f:
         vals = [jax.numpy.asarray(v) for v in pickle.load(f)]
     return TranslatedLayer(exported, vals)

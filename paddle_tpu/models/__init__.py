@@ -1,4 +1,4 @@
-"""Flagship model families (the capability ladder of BASELINE.md).
+"""Flagship model families (the capability ladder).
 
 Analog of the PaddleNLP/PaddleClas model zoos the reference's configs target
 (`llm/` Llama pretrain, BERT finetune, ResNet-50) — built here as first-class

@@ -1,4 +1,4 @@
-"""BERT model family (BASELINE.md config #3: BERT-base SQuAD finetune, DP×8).
+"""BERT model family (ladder config #3: BERT-base SQuAD finetune, DP×8).
 
 Capability analog of PaddleNLP's BERT stack targeted by the reference's
 capability ladder.  TPU-first: plain dense layers (the DP-over-8 config needs

@@ -617,24 +617,22 @@ def shard_kv_pool(pool):
         pool, NamedSharding(mesh, PartitionSpec(*KV_POOL_SPEC)))
 
 
-# Which path the most recent dispatch took: "pallas" | "xla" (same loud
-# fallback contract as ops/flash_attention.py).
+# Which path the most recent dispatch took: "pallas" | "xla".
 last_path: Optional[str] = None
 
 
-def pallas_dispatch(kernel_fn, oracle_fn, use_pallas, tileable,
-                    name: str):
+def pallas_dispatch(kernel_fn, oracle_fn, use_pallas, tileable):
     """ONE home for the kernel-vs-oracle dispatch policy shared by the
     decode kernel (:func:`paged_attention`) and the unified ragged
     kernel (``ops.ragged_paged.ragged_paged_attention``): the operator
     kill switch (``PADDLE_TPU_DISABLE_PALLAS`` / the
     ``disable_pallas_kernels`` flag) always wins, ``use_pallas=True``
     forces the kernel past the tileability heuristic (interpret mode
-    off-TPU), ``False`` pins the oracle, and a kernel failure falls back
-    loudly (or re-raises under ``PADDLE_TPU_STRICT_PALLAS`` /
-    ``strict_pallas``).  Returns ``(out, path)`` with ``path`` in
-    ``{"pallas", "xla"}`` — callers publish it as their module's
-    ``last_path``."""
+    off-TPU), ``False`` pins the oracle.  The choice is made from what
+    the code can see BEFORE the launch; a kernel that then fails raises —
+    it is never retried on the gather path.  Returns ``(out, path)``
+    with ``path`` in ``{"pallas", "xla"}`` — callers publish it as their
+    module's ``last_path``."""
     import os
 
     from ..core import flags
@@ -644,18 +642,7 @@ def pallas_dispatch(kernel_fn, oracle_fn, use_pallas, tileable,
     if use_pallas is False:
         tileable = False          # pin the XLA gather path
     if not disable and (tileable or use_pallas is True):
-        try:
-            return kernel_fn(), "pallas"
-        except Exception as e:
-            import warnings
-
-            if (os.environ.get("PADDLE_TPU_STRICT_PALLAS") == "1"
-                    or flags.flag("strict_pallas")):
-                raise
-            warnings.warn(
-                f"{name} failed, falling back to the XLA gather path: "
-                f"{type(e).__name__}: {e}",
-                RuntimeWarning, stacklevel=3)
+        return kernel_fn(), "pallas"
     return oracle_fn(), "xla"
 
 
@@ -790,8 +777,8 @@ def paged_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     seq_lens: [B] int32.  Returns [B, H, D].
 
     Dispatches to the Pallas kernel (``pallas_paged.py`` — scalar-prefetch
-    page DMA, no dense context copy) when shapes are TPU-tileable; falls
-    back to the XLA gather path with a loud warning otherwise.
+    page DMA, no dense context copy) when shapes are TPU-tileable, to
+    the XLA gather path otherwise; a kernel failure raises.
 
     ``use_pallas`` overrides the auto dispatch (``EngineConfig.
     use_pallas_paged``, ISSUE 5): ``True`` routes through the Pallas
@@ -819,5 +806,5 @@ def paged_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         kernel,
         lambda: _xla_paged_attention(q, k_cache, v_cache, block_tables,
                                      seq_lens),
-        use_pallas, tileable, "pallas paged attention")
+        use_pallas, tileable)
     return out

@@ -38,8 +38,36 @@ from .pallas_x32 import no_x64
 _NEG_INF = np.float32(-1e30)
 
 
+# Scalar-prefetch operands live in SMEM for the whole launch, their
+# minor dimension padded to 128 words: the v5e compiler reports "Used
+# 1.02M of 1.00M smem" for a [2048, 128] int32 table AND for a [2048, 64]
+# one (tools/pallas_mosaic_check.py).  A little is kept for its own use.
+SMEM_BYTES = (1 << 20) - 4096
+SMEM_LANES = 128
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def check_scalar_prefetch(kernel: str, *operands) -> None:
+    """Refuse a compiled launch whose scalar-prefetch operands cannot fit
+    scalar memory, naming the limit — instead of the compiler's
+    RESOURCE_EXHAUSTED dump.  Interpret mode has no such limit."""
+    if _interpret():
+        return
+    need = sum(int(np.prod(o.shape[:-1])) * 4
+               * -(-o.shape[-1] // SMEM_LANES) * SMEM_LANES
+               for o in operands)
+    if need > SMEM_BYTES:
+        shapes = ", ".join(str(tuple(o.shape)) for o in operands)
+        raise ValueError(
+            f"{kernel}: scalar-prefetch operands {shapes} need {need} "
+            f"bytes of TPU scalar memory (rows padded to {SMEM_LANES} "
+            f"words), limit {SMEM_BYTES} — shrink "
+            "the token bucket or the block-table width (a longer "
+            "context needs the kernel to walk pages without a "
+            "per-token table, ROADMAP S4)")
 
 
 def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -127,6 +155,7 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens):
     # Mosaic has no i64: scalar-prefetch operands must be 32-bit
     block_tables = block_tables.astype(jnp.int32)
     seq_lens = seq_lens.astype(jnp.int32)
+    check_scalar_prefetch("paged_attention_decode", block_tables, seq_lens)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,   # block_tables, seq_lens

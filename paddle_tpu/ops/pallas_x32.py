@@ -12,13 +12,8 @@ touching the global config.
 
 from __future__ import annotations
 
-import contextlib
+import jax
 
 
 def no_x64():
-    try:
-        from jax._src import config as _jcfg
-
-        return _jcfg.enable_x64(False)
-    except Exception:  # pragma: no cover - jax internals moved
-        return contextlib.nullcontext()
+    return jax.enable_x64(False)

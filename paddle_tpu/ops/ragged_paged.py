@@ -60,6 +60,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pallas_paged import SMEM_BYTES, SMEM_LANES, check_scalar_prefetch
 from .pallas_x32 import no_x64
 
 # np.float32 scalar, not a Python float: inside an OUTER jit the
@@ -68,13 +69,21 @@ from .pallas_x32 import no_x64
 # pallas_paged / pallas_flash)
 _NEG_INF = np.float32(-1e30)
 
-# Which path the most recent dispatch took: "pallas" | "xla" (the same
-# loud-fallback contract as ops/paged_attention.py).
+# Which path the most recent dispatch took: "pallas" | "xla".
 last_path = None
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def max_table_width(tokens: int) -> int:
+    """Widest power-of-two block table a compiled launch packing
+    ``tokens`` tokens can prefetch into scalar memory (0: none fits):
+    ``tables[tokens, width]`` — each row padded to ``SMEM_LANES`` words —
+    plus ``seg_ids``/``q_pos``/``kv_lens`` ``[tokens]``, all int32."""
+    width = SMEM_BYTES // (4 * tokens) - 3
+    return 1 << (width.bit_length() - 1) if width >= SMEM_LANES else 0
 
 
 def ragged_oracle(q, k_cache, v_cache, block_tables, kv_lens, seg_ids,
@@ -189,6 +198,8 @@ def _ragged_attention_kernel(q, k_cache, v_cache, block_tables, kv_lens,
     q_pos = q_pos.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
     kv_lens = kv_lens.astype(jnp.int32)
+    check_scalar_prefetch("ragged_paged_attention", seg_ids, q_pos,
+                          block_tables, kv_lens)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,   # seg_ids, q_pos, block_tables, kv_lens
@@ -242,11 +253,10 @@ def _mesh_kernel(q, k_cache, v_cache, block_tables, kv_lens, seg_ids,
                                         kv_lens, seg_ids, q_pos)
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel._compat import shard_map
     from ..parallel.utils import manual_sharding_mode
     from .paged_attention import KV_POOL_SPEC
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         _ragged_attention_kernel, mesh=mesh,
         in_specs=(P(None, "mp", None), P(*KV_POOL_SPEC), P(*KV_POOL_SPEC),
                   P(), P(), P(), P()),
@@ -262,8 +272,8 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, kv_lens,
 
     Dispatches to the Pallas kernel (``shard_map`` over ``mp`` when a
     mesh is live — the fast path spans the mesh instead of being pinned
-    off at mp>1) when shapes are TPU-tileable; falls back to the XLA
-    gather reference with a loud warning otherwise.  ``use_pallas``
+    off at mp>1) when shapes are TPU-tileable, to the XLA gather
+    reference otherwise; a kernel failure raises.  ``use_pallas``
     overrides the auto dispatch exactly like
     :func:`~paddle_tpu.ops.paged_attention.paged_attention`: ``True``
     forces the kernel (interpret mode off-TPU — the CPU parity path),
@@ -282,5 +292,5 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, kv_lens,
                              seg_ids, q_pos),
         lambda: ragged_oracle(q, k_cache, v_cache, block_tables, kv_lens,
                               seg_ids, q_pos),
-        use_pallas, tileable, "pallas ragged paged attention")
+        use_pallas, tileable)
     return out

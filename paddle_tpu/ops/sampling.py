@@ -41,8 +41,12 @@ Design constraints the serving layer relies on:
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
-_NEG = jnp.float32(-1e30)  # mask value: finite, so argmax ties stay sane
+# mask value: finite, so argmax ties stay sane.  A numpy scalar: a jnp
+# scalar here would dispatch at import and initialise a JAX backend in
+# every process that imports the package (one process per chip)
+_NEG = np.float32(-1e30)
 
 
 def _fmix32(z):
@@ -75,7 +79,6 @@ def make_keys(seed_draws, out=None):
     """Pack ``[(seed, draw_index), ...]`` into the raw ``[n, 2]`` u32 key
     array :func:`sample_tokens` consumes (host-side helper, numpy-free of
     jax so schedulers can call it without touching the device)."""
-    import numpy as np
     n = len(seed_draws)
     keys = np.zeros((n, 2), dtype=np.uint32) if out is None else out
     for i, (seed, draw) in enumerate(seed_draws):

@@ -25,7 +25,7 @@ from typing import Any, Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from ._compat import lax_axis_size, shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.dispatch import mark_derived, mark_inputs, run_op
@@ -178,7 +178,7 @@ def pipeline_spmd(stage_fn: Callable, stage_params: Any, x: jnp.ndarray,
         # params_local leaves: [1, ...] (this stage's slice)
         params_here = jax.tree.map(lambda p: p[0], params_local)
         idx = jax.lax.axis_index(axis)
-        n = lax_axis_size(axis)
+        n = jax.lax.axis_size(axis)
         perm = [(j, (j + 1) % n) for j in range(n)]
         T = n_microbatch + n - 1
 

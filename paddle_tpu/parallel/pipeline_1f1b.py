@@ -41,7 +41,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from ._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.dispatch import mark_derived, mark_inputs, run_op

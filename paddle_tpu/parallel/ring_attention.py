@@ -20,7 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ._compat import lax_axis_size, shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.dispatch import run_op
@@ -59,7 +59,7 @@ def _block_attn(q, k, v, bias_mask, scale):
 def ring_attention_local(q, k, v, axis: str = SEP_AXIS, causal: bool = True):
     """Per-shard body (call inside shard_map): q/k/v are the local sequence
     shard [B, S/n, H, D]."""
-    n = lax_axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     B, Sl, H, D = q.shape
     scale = 1.0 / math.sqrt(D)

@@ -9,8 +9,8 @@ can ever dispatch is a point in a small power-of-two lattice derived
 from the deployment config (pool capacity, scheduler caps, chunk
 budgets).  This module closes the loop the ROADMAP names — MPK's
 compile-once artifact (PAPERS.md #5), the deployment shape the
-Julia-to-TPU work (#4) and the repo's own 8B proof (AOT_8B.md) already
-validated:
+Julia-to-TPU work (#4) and the repo's own 8B proof
+(``tools/aot_lower_8b.py``) already validated:
 
 * :func:`enumerate_buckets` walks that closed universe — the legacy
   three program families (one-shot ``prefill`` / ``chunk``\\ ed prefill /
@@ -62,8 +62,8 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax import export as jax_export
 
-from ..parallel._compat import get_jax_export
 from .scheduler import bucket_size
 
 # v2 (ISSUE 18): every program takes the per-row sampling quartet
@@ -374,7 +374,6 @@ class AotArtifact:
         :func:`enumerate_buckets` lattice — :meth:`validate` requires
         exactly that coverage at load, so a pruned save could never
         bind."""
-        ex = get_jax_export()
         t0 = time.perf_counter()
         sched = engine.scheduler.config
         max_seq = _max_seq_cap(engine, max_seq_len)
@@ -402,7 +401,7 @@ class AotArtifact:
         try:
             for program, bucket in buckets:
                 bucket = tuple(int(b) for b in bucket)
-                exported = ex.export(_jit_for(engine, program))(
+                exported = jax_export.export(_jit_for(engine, program))(
                     *_arg_specs(engine, program, bucket))
                 blob = exported.serialize()
                 key = _key_str(program, bucket)
@@ -483,7 +482,6 @@ class AotArtifact:
         Environment mismatches (artifact version, jax version, platform)
         fail here; deployment-shape mismatches fail in
         :meth:`validate` once an engine exists to compare against."""
-        ex = get_jax_export()
         t0 = time.perf_counter()
         mpath = os.path.join(path, MANIFEST_NAME)
         if not os.path.exists(mpath):
@@ -517,7 +515,7 @@ class AotArtifact:
             try:
                 with open(fpath, "rb") as f:
                     programs[(meta["program"],)
-                             + tuple(meta["bucket"])] = ex.deserialize(
+                             + tuple(meta["bucket"])] = jax_export.deserialize(
                                  f.read())
             except Exception as e:
                 raise AotError(

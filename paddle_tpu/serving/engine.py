@@ -63,6 +63,8 @@ from ..observability.audit import AuditConfig, NumericsAuditor, logit_stats
 from ..observability.cachestat import CacheStatTracker
 from ..observability.lifecycle import LifecycleTracker
 from ..observability.stepprof import StepProfiler
+from ..ops import paged_attention as _paged_ops
+from ..ops import ragged_paged as _ragged_ops
 from ..ops.paged_attention import (
     KV_POOL_SPEC,
     PagedCache,
@@ -386,6 +388,12 @@ class EngineCore:
         self.prefill_trace_count = 0
         self.ragged_trace_count = 0
         self.burst_trace_count = 0
+        # which attention path each program family was traced through
+        # ("pallas" | "xla"), read off the ops modules' ``last_path``
+        # while THIS engine traces — the modules' globals are overwritten
+        # by any later trace (the auditor's gather reference, another
+        # replica), this record is not
+        self.attention_paths: Dict[str, str] = {}
         self.decode_buckets = set()
         self.prefill_buckets = set()
         self.ragged_buckets = set()
@@ -418,6 +426,9 @@ class EngineCore:
         self._jit_burst = jax.jit(self._burst_fn, donate_argnums=donate,
                                   **jit_kw["burst"])
         self._profile_ops = config.profile_ops
+        if self._unified and jax.default_backend() == "tpu" \
+                and config.use_pallas_paged is not False:
+            self._cap_ragged_context()
         model.eval()
         # --- speculative decoding (ISSUE 18) --------------------------------
         # host-side n-gram proposer + verify-row bookkeeping; packs draft
@@ -479,7 +490,11 @@ class EngineCore:
         # AotArtifact.call): a request whose target length outgrows the
         # saved universe is rejected honestly at admission instead of
         # raising AotBucketMissing from the engine thread mid-stream
-        self.scheduler.seq_len_cap = int(artifact.manifest["max_seq_len"])
+        self._cap_seq_len(
+            int(artifact.manifest["max_seq_len"]),
+            "the AOT artifact was saved for max_seq_len="
+            f"{artifact.manifest['max_seq_len']}; re-save with a larger "
+            "bound")
         # burst programs (ISSUE 19) were exported with the table width
         # derived from the artifact's max_seq_len; the seq_len_cap set
         # above guarantees no admitted sequence can outgrow it, so the
@@ -499,6 +514,43 @@ class EngineCore:
             observe = artifact.mark_load_observed(sp.registry)
         sp.record_aot_load(artifact.load_seconds,
                            artifact.program_count, observe=observe)
+
+    def _cap_seq_len(self, cap: int, why: str) -> None:
+        """Lower the admission cap on prompt + max_new_tokens (never
+        raise it: the tightest limit and its reason win)."""
+        sched = self.scheduler
+        if sched.seq_len_cap is None or cap < sched.seq_len_cap:
+            sched.seq_len_cap, sched.seq_len_cap_why = cap, why
+
+    def _cap_ragged_context(self) -> None:
+        """Unified step on a TPU: the ragged kernel prefetches one
+        block-table row per packed token into scalar memory
+        (``ops/ragged_paged.py``), so token bucket x table width is
+        bounded by it.  Refuse at build what no launch could take, and
+        reject at admission any request whose context outgrows the
+        widest table that fits — never at launch, where the error would
+        take the engine thread with it."""
+        sched = self.scheduler.config
+        limit = ("the ragged kernel keeps one block-table row per packed "
+                 "token in TPU scalar memory (ROADMAP S4 regrids it)")
+        if sched.max_tokens_per_step is None:
+            raise ValueError(
+                "unified_step=True on a TPU needs SchedulerConfig."
+                f"max_tokens_per_step: {limit}, so the packed token "
+                "bucket must be bounded")
+        tokens = bucket_size(max(sched.max_tokens_per_step,
+                                 sched.max_num_seqs))
+        width = _ragged_ops.max_table_width(tokens)
+        if not width:
+            raise ValueError(
+                f"unified_step=True on a TPU: max_tokens_per_step="
+                f"{sched.max_tokens_per_step} packs {tokens} tokens a "
+                f"step, more than fit — {limit}")
+        self._cap_seq_len(
+            width * self.block_size,
+            f"at {tokens} packed tokens a step this engine serves "
+            f"contexts up to {width * self.block_size} tokens "
+            f"({width} blocks of {self.block_size}): {limit}")
 
     def _step_call(self, program: str, bucket, jit_fn, *args):
         """THE aot-vs-jit dispatch choice, shared by all five step
@@ -611,6 +663,7 @@ class EngineCore:
             c.use_pallas = self._use_pallas  # EngineConfig.use_pallas_paged
             caches.append(c)
         logits = self._call_model(ids, caches, pos, param_vals)
+        self.attention_paths["decode"] = _paged_ops.last_path
         last = logits[:, -1, :].astype(jnp.float32)
         # in-trace sampling epilogue (ISSUE 18): greedy rows (temp 0,
         # padding included) reduce to argmax inside the same program —
@@ -649,6 +702,7 @@ class EngineCore:
                 c.use_pallas = self._use_pallas
                 caches.append(c)
             logits = self._call_model(ids_j, caches, pos_j, param_vals)
+            self.attention_paths["burst"] = _paged_ops.last_path
             return (logits[:, -1, :].astype(jnp.float32),
                     tuple(c.k_pool._value for c in caches),
                     tuple(c.v_pool._value for c in caches))
@@ -744,6 +798,7 @@ class EngineCore:
             # the mp>1 auto-pin does NOT apply to the ragged program
             caches.append(c)
         logits = self._call_model(ids, caches, pos, param_vals)
+        self.attention_paths["ragged"] = _ragged_ops.last_path
         last = jnp.take(logits[0], last_idx, axis=0).astype(jnp.float32)
         # sample at EVERY packed token position (ISSUE 18): the sampling
         # quartet is per-TOKEN here, so a spec-decode verify row gets its

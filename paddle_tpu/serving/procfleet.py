@@ -201,8 +201,6 @@ class ProcessFleetConfig:
     seed: int = 0
     aot_path: Optional[str] = None     # shared artifact every worker
                                        # boots from (zero-trace, PR 14)
-    compile_cache: Optional[str] = None  # JAX persistent compilation
-    # cache dir: N sibling workers compile each program once machine-wide
     warm_boot: bool = False            # workers execute every AOT
     # program once at boot (first request wave pays zero lazy compiles)
     heartbeat_interval_s: float = 0.25
@@ -252,11 +250,10 @@ class WorkerHandle:
     def spawn(cls, cfg: ProcessFleetConfig, index: int,
               spec: Dict) -> "WorkerHandle":
         cmd = [cfg.python, "-m", "paddle_tpu.serving.worker",
-               "--replica", str(index), "--spec", json.dumps(spec)]
+               "--replica", str(index), "--fleet-size", str(cfg.dp),
+               "--spec", json.dumps(spec)]
         if cfg.aot_path:
             cmd += ["--aot-path", cfg.aot_path]
-        if cfg.compile_cache:
-            cmd += ["--compile-cache", cfg.compile_cache]
         if cfg.warm_boot:
             cmd += ["--warm"]
         env = dict(os.environ)
@@ -268,8 +265,10 @@ class WorkerHandle:
             # mp>1 on the forced-host-device CPU backend: the CHILD
             # process must see >= mp devices before jax initializes —
             # injecting here (not in the worker) keeps the worker module
-            # backend-agnostic.  Real TPU workers already have their
-            # chips; the guard leaves an operator's explicit flag alone.
+            # backend-agnostic.  The guard leaves an operator's explicit
+            # flag alone.  On a TPU host nothing here pins a worker to
+            # its chips: a worker that finds itself one of several there
+            # refuses to start (serving/worker.py main).
             env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                                 " --xla_force_host_platform_device_count"
                                 f"={cfg.mp}").strip()

@@ -175,9 +175,13 @@ class ContinuousBatchingScheduler:
         # manifest's max_seq_len HONESTLY (finish_reason=abort + error)
         # instead of letting AotBucketMissing kill the engine thread
         # mid-stream — in a supervised fleet a re-dispatched oversize
-        # request would otherwise cascade replica deaths.  None = no cap
-        # (traced engines bucket anything the pool holds).
+        # request would otherwise cascade replica deaths.  The unified
+        # program on a TPU caps it too: its kernel's block table must
+        # fit scalar memory (EngineCore._cap_ragged_context).  None = no
+        # cap (traced engines bucket anything the pool holds).
+        # ``seq_len_cap_why`` names the limit in the rejection.
         self.seq_len_cap: Optional[int] = None
+        self.seq_len_cap_why = ""
 
     # --- queue ops ----------------------------------------------------------
     def add(self, req: Request) -> None:
@@ -278,19 +282,19 @@ class ContinuousBatchingScheduler:
             target_len = len(req.prompt_ids) + req.sampling.max_new_tokens
             if self.seq_len_cap is not None \
                     and target_len > self.seq_len_cap:
-                # outside the AOT artifact's saved bucket universe: the
-                # zero-trace contract can never serve this sequence, so
-                # fail it honestly AT ADMISSION instead of raising
-                # AotBucketMissing from the engine thread mid-stream
+                # a sequence no program of this engine can take (outside
+                # the AOT artifact's saved buckets, or a block table the
+                # ragged kernel cannot prefetch): fail it honestly AT
+                # ADMISSION instead of raising from the engine thread
+                # mid-stream
                 self.waiting.popleft()
                 req.state = RequestState.FINISHED
                 req.finish_reason = FinishReason.ABORT
                 req.error = (
                     f"request targets {target_len} tokens (prompt "
                     f"{len(req.prompt_ids)} + max_new_tokens "
-                    f"{req.sampling.max_new_tokens}) but the AOT "
-                    f"artifact was saved for max_seq_len="
-                    f"{self.seq_len_cap}; re-save with a larger bound")
+                    f"{req.sampling.max_new_tokens}) but "
+                    f"{self.seq_len_cap_why}")
                 out.aborted.append(req)
                 continue
             if prompt_blocks > self._usable_blocks():

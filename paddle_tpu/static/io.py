@@ -16,9 +16,9 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
+from jax import export as jax_export
 
 from ..core.tensor import Parameter, Tensor
-from ..parallel._compat import get_jax_export  # the ONE jax.export
                                                # binding (ISSUE 15)
 
 _MODEL_SUFFIX = ".pdmodel"
@@ -142,7 +142,7 @@ def _export_bytes(program, feed_vars, fetch_vars) -> bytes:
 
     specs = [jax.ShapeDtypeStruct(tuple(v.shape), v._value.dtype)
              for v in feed_vars]
-    exported = get_jax_export().export(jax.jit(pure))(*specs)
+    exported = jax_export.export(jax.jit(pure))(*specs)
     return exported.serialize()
 
 
@@ -152,7 +152,7 @@ def serialize_program(feed_vars, fetch_vars, program=None, **kwargs) -> bytes:
 
 
 def deserialize_program(data: bytes):
-    exported = get_jax_export().deserialize(data)
+    exported = jax_export.deserialize(data)
     n_in = len(exported.in_avals)
     return _LoadedProgram(exported, [f"feed_{i}" for i in range(n_in)],
                           [f"fetch_{i}" for i in range(len(exported.out_avals))])
@@ -217,7 +217,7 @@ def load_inference_model(path_prefix: str, executor=None, **kwargs):
     """Returns ``[loaded_program, feed_names, fetch_names]`` — run it with
     ``Executor.run(program=loaded_program, feed=..., fetch_list=...)``."""
     raw = pickle.loads(load_from_file(path_prefix + _MODEL_SUFFIX))
-    exported = get_jax_export().deserialize(raw["stablehlo"])
+    exported = jax_export.deserialize(raw["stablehlo"])
     lp = _LoadedProgram(exported, raw["meta"]["feed_names"],
                         raw["meta"]["fetch_names"])
     return [lp, lp.feed_names, lp.fetch_names]

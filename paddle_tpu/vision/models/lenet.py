@@ -1,5 +1,5 @@
 """LeNet (``python/paddle/vision/models/lenet.py`` capability) — the PR1
-end-to-end model (BASELINE.md config 1)."""
+end-to-end model (capability-ladder config 1)."""
 
 from __future__ import annotations
 

@@ -1,5 +1,5 @@
 """ResNet family (``python/paddle/vision/models/resnet.py`` capability) —
-BASELINE.md config 2 (conv/BN path on the MXU)."""
+capability-ladder config 2 (conv/BN path on the MXU)."""
 
 from __future__ import annotations
 

@@ -92,7 +92,8 @@ class TestMfuFormula:
         assert _peak_flops("TPU v5p") == 459e12
         assert _peak_flops("TPU v4") == 275e12
         assert _peak_flops("TPU v6e (Trillium)") == 918e12
-        assert _peak_flops("cpu") == 0.0
+        with pytest.raises(KeyError):  # unknown device: error, not 0
+            _peak_flops("cpu")
 
     def test_end_to_end_mfu(self):
         """The exact arithmetic bench.py reports as vs_baseline."""

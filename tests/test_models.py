@@ -1,4 +1,4 @@
-"""BERT + MoE-Llama model family tests (BASELINE.md capability rungs #3/#5)."""
+"""BERT + MoE-Llama model family tests (capability rungs #3/#5)."""
 
 import numpy as np
 import pytest

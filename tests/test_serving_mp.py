@@ -309,7 +309,6 @@ class TestBoundedMetricsLint:
         covered = {os.path.relpath(p, repo) for p in lint.SCAN_FILES}
         for need in ("paddle_tpu/parallel/mp_layers.py",
                      "paddle_tpu/parallel/utils.py",
-                     "paddle_tpu/parallel/_compat.py",
                      "paddle_tpu/distributed/topology.py",
                      "paddle_tpu/ops/pallas_paged.py",
                      # ISSUE 11: the unified ragged kernel is hot-path

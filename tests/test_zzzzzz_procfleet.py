@@ -652,21 +652,26 @@ class TestAutoscaler:
 # --- cross-process compile reuse (satellite 3) ------------------------------
 
 class TestCompileCacheReuse:
-    def test_second_worker_boots_on_sibling_cache_entries(self, aot_dir,
-                                                          tmp_path):
-        """Two sequential workers share --compile-cache: the first
-        warm-boot compiles every AOT program into the persistent cache;
-        the second's boot log shows those entries pre-existing and adds
-        NONE — every warm compile was a cache hit."""
+    def test_second_worker_boots_on_sibling_cache_entries(
+            self, aot_dir, tmp_path, monkeypatch):
+        """Two sequential workers inherit one JAX_COMPILATION_CACHE_DIR:
+        the first warm-boot compiles every AOT program into the
+        persistent cache; the second's boot log shows those entries
+        pre-existing and adds NONE — every warm compile was a cache hit.
+        (The floors are dropped through the environment too: the toy
+        programs compile in milliseconds.)"""
         cache = str(tmp_path / "jaxcache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
+        monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+        monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
 
         def boot():
-            pf = ProcessFleet(_cfg(aot_dir, dp=1, compile_cache=cache,
-                                   warm_boot=True))
+            pf = ProcessFleet(_cfg(aot_dir, dp=1, warm_boot=True))
             try:
                 wh = pf.proxy(0).worker
                 assert wh.compile_cache is not None, \
                     "worker printed no compile-cache boot line"
+                assert wh.compile_cache["dir"] == cache
                 return dict(wh.compile_cache), wh.boot_s
             finally:
                 pf.stop()

@@ -6,7 +6,7 @@ Reads the newest ``plugins/profile/*/ *.trace.json.gz`` under ``trace_dir``
 (default ``prof_trace``, as written by ``tools/profile_train.py``), buckets
 device-lane op time into coarse categories (MXU matmul/fusion, pallas
 custom calls, copies/transposes, collectives, host gaps) and prints the
-step-time breakdown the BASELINE.md gap analysis needs.  Pure stdlib — the
+step-time breakdown the MFU gap analysis needs.  Pure stdlib — the
 tensorboard_plugin_profile converter in this image has a protobuf version
 conflict, and the chrome trace carries everything we need.
 """

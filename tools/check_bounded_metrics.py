@@ -98,7 +98,6 @@ SCAN_FILES = (
     os.path.join(_REPO, "paddle_tpu", "ops", "decode_burst.py"),
     os.path.join(_REPO, "paddle_tpu", "parallel", "mp_layers.py"),
     os.path.join(_REPO, "paddle_tpu", "parallel", "utils.py"),
-    os.path.join(_REPO, "paddle_tpu", "parallel", "_compat.py"),
     os.path.join(_REPO, "paddle_tpu", "distributed", "topology.py"),
     # ISSUE 16: the cross-process fleet's wire connections, worker-side
     # live-request mirror, proxy request mirrors / worker log tails and

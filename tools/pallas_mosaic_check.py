@@ -1,12 +1,17 @@
-"""First-contact Pallas verdict: do the kernels survive the Mosaic compiler?
+"""Mosaic verdict: does each Pallas kernel compile for the chip, and right?
 
-Off-TPU the kernels only ever ran in interpret mode; Mosaic routinely
-rejects kernels that interpret fine (VERDICT r3 weak/missing #2).  This
-tool compiles each kernel with the REAL backend, checks numerics against
-the XLA reference path, and micro-benchmarks pallas vs XLA attention.
+Off the chip the kernels only ever run in interpret mode, and Mosaic
+rejects kernels that interpret fine.  This tool compiles every kernel of
+``paddle_tpu/ops`` (and the fused sampling epilogue, plain XLA) with the
+real backend at the shapes the Llama-3-8B serving path launches, compares
+each against its XLA reference, and records the compiler's own words where
+it refuses.  Times are host-clock information, not a benchmark.
 
-Writes one JSON line per check to stdout and a summary to
-``PALLAS_VERDICT.json``.  Run on a quiet chip (after bench.py finishes).
+    chiprun -- python tools/pallas_mosaic_check.py
+
+One JSON line per check on stdout; the table lands in
+``chiprun_out/PALLAS_VERDICT.json`` (copy it over the committed record).
+Exits non-zero without a TPU, and when any check fails.
 """
 
 from __future__ import annotations
@@ -16,223 +21,258 @@ import os
 import sys
 import time
 
+_HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _HERE)
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-_HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, _HERE)
+from paddle_tpu.utils.compile_cache import configure_compile_cache
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(_HERE, ".jax_compile_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
-os.environ["PADDLE_TPU_STRICT_PALLAS"] = "1"  # raise, don't fall back
+OUT_DIR = os.path.join(_HERE, "chiprun_out")
+D = 128           # head dim
+BS = 16           # KV block size
+NB = 2048         # pool blocks (32k tokens, the one-chip smoke's pool)
 
 
-def _bench(fn, *args, iters=20):
-    out = fn(*args)
-    jax.block_until_ready(out)
+def _bench(fn, *args, iters=10):
+    jax.block_until_ready(fn(*args))
     t0 = time.perf_counter()
     for _ in range(iters):
         out = fn(*args)
     jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / iters
+    return round((time.perf_counter() - t0) / iters * 1e3, 3)
 
 
-def main() -> None:
+def _err(a, b):
+    return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                 - b.astype(jnp.float32))))
+
+
+def _rand(rng, shape, dtype=jnp.bfloat16):
+    return jnp.asarray(rng.standard_normal(shape), dtype)
+
+
+# --- flash attention (training / one-shot prefill shapes) -------------------
+
+def _xla_attn(q, k, v, causal):
+    rep = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) / np.sqrt(q.shape[-1])
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v)
+
+
+def flash_check(causal, hkv=8, d=D, grad=False):
+    def run():
+        from paddle_tpu.ops import pallas_flash
+
+        rng = np.random.default_rng(0)
+        q = _rand(rng, (4, 2048, 8, d))
+        k = _rand(rng, (4, 2048, hkv, d))
+        v = _rand(rng, (4, 2048, hkv, d))
+
+        def wrap(attn):
+            if not grad:
+                return jax.jit(lambda q, k, v: attn(q, k, v, causal))
+            return jax.jit(jax.grad(
+                lambda q, k, v: attn(q, k, v, causal)
+                .astype(jnp.float32).sum(), argnums=(0, 1, 2)))
+
+        f = wrap(pallas_flash.flash_attention)
+        out, ref = f(q, k, v), wrap(_xla_attn)(q, k, v)
+        err = (max(_err(a, b) for a, b in zip(out, ref)) if grad
+               else _err(out, ref))
+        # bf16 attention tolerance; gradients accumulate more error
+        return {"ok": err < (0.5 if grad else 0.15), "max_err": err,
+                "pallas_ms": _bench(f, q, k, v)}
+    return run
+
+
+# --- paged decode (one token per row over the block pool) -------------------
+
+def paged_decode_check(h, hkv, pool_dtype=jnp.bfloat16, batch=8, width=64):
+    def run():
+        from paddle_tpu.ops import pallas_paged
+
+        rng = np.random.default_rng(1)
+        kc = _rand(rng, (NB, BS, hkv, D), pool_dtype)
+        vc = _rand(rng, (NB, BS, hkv, D), pool_dtype)
+        q = _rand(rng, (batch, h, D))
+        bt = jnp.asarray(rng.integers(1, NB, (batch, width)), jnp.int32)
+        sl = jnp.asarray(rng.integers(1, width * BS, (batch,)), jnp.int32)
+        f = jax.jit(pallas_paged.paged_attention_decode)
+        out = f(q, kc, vc, bt, sl)
+        ref = jax.jit(pallas_paged.decode_oracle)(q, kc, vc, bt, sl)
+        err = _err(out, ref)
+        return {"ok": err < 0.05, "max_err": err,
+                "pallas_ms": _bench(f, q, kc, vc, bt, sl)}
+    return run
+
+
+# --- ragged packed step (prefill chunks + decode rows in one launch) --------
+
+def _ragged_inputs(rng, tokens, width, h):
+    """One prefill chunk filling half the bucket plus decode rows, packed;
+    per-ROW tables are padded to one row per token as the engine does."""
+    chunk = tokens // 2
+    n_dec = min(8, tokens - chunk)
+    tables = np.zeros((tokens, width), np.int32)
+    lens = np.ones((tokens,), np.int32)
+    seg = np.full((tokens,), min(n_dec + 1, tokens - 1), np.int32)
+    pos = np.zeros((tokens,), np.int32)
+    start = min(width * BS - chunk, 300)
+    tables[0] = rng.integers(1, NB, width)
+    lens[0] = start + chunk
+    seg[:chunk] = 0
+    pos[:chunk] = np.arange(start, start + chunk)
+    for r in range(1, n_dec + 1):
+        tables[r] = rng.integers(1, NB, width)
+        lens[r] = int(rng.integers(1, width * BS))
+        seg[chunk + r - 1] = r
+        pos[chunk + r - 1] = lens[r] - 1
+    q = _rand(rng, (tokens, h, D))
+    return q, tuple(jnp.asarray(a) for a in (tables, lens, seg, pos))
+
+
+def ragged_check(tokens, width, h=32, hkv=8, oracle=True):
+    def run():
+        from paddle_tpu.ops import ragged_paged
+
+        rng = np.random.default_rng(2)
+        kc, vc = _rand(rng, (NB, BS, hkv, D)), _rand(rng, (NB, BS, hkv, D))
+        q, meta = _ragged_inputs(rng, tokens, width, h)
+        f = jax.jit(ragged_paged._ragged_attention_kernel)
+        out = f(q, kc, vc, *meta)
+        info = {"finite": bool(jnp.isfinite(out.astype(jnp.float32)).all()),
+                "smem_bytes": 4 * tokens * (max(width, 128) + 3),
+                "pallas_ms": _bench(f, q, kc, vc, *meta, iters=3)}
+        if oracle:   # the gather reference is [T, W*bs, Hkv, D] float32
+            ref = jax.jit(ragged_paged.ragged_oracle)(q, kc, vc, *meta)
+            info["max_err"] = _err(out, ref)
+        info["ok"] = info["finite"] and info.get("max_err", 0.0) < 0.05
+        return info
+    return run
+
+
+def prefetch_limit_check(tokens, width):
+    """Past scalar memory the launch is refused by name before it reaches
+    the compiler, which said on libtpu 0.0.34, for [2048, 128] and for
+    [2048, 64] tables alike (SMEM pads a row to 128 words): "Ran out of
+    memory in memory space smem. Used 1.02M of 1.00M smem"."""
+    def run():
+        try:
+            ragged_check(tokens, width, oracle=False)()
+        except ValueError as e:
+            return {"ok": "scalar memory" in str(e), "refused": str(e)[:160]}
+        return {"ok": False, "refused": None}
+    return run
+
+
+# --- fused sampling epilogue (XLA: full-vocabulary sort per row) ------------
+
+def sampler_check(rows, vocab=128256):
+    def run():
+        from paddle_tpu.ops.sampling import sample_tokens
+
+        rng = np.random.default_rng(3)
+        logits = _rand(rng, (rows, vocab), jnp.float32)
+        temps = jnp.asarray(np.where(np.arange(rows) % 4 == 3, 0.8, 0.0),
+                            jnp.float32)
+        top_ks = jnp.full((rows,), 40, jnp.int32)
+        top_ps = jnp.full((rows,), 0.9, jnp.float32)
+        keys = jnp.asarray(rng.integers(0, 2**31, (rows, 2)), jnp.uint32)
+        f = jax.jit(sample_tokens)
+        toks = np.asarray(f(logits, temps, top_ks, top_ps, keys))
+        greedy = np.asarray(jnp.argmax(logits, axis=-1))
+        g = np.asarray(temps) <= 0
+        peak = jax.devices()[0].memory_stats().get("peak_bytes_in_use", 0)
+        return {"ok": bool((toks[g] == greedy[g]).all()
+                           and (toks >= 0).all() and (toks < vocab).all()),
+                "xla_ms": _bench(f, logits, temps, top_ks, top_ps, keys,
+                                 iters=3),
+                "peak_hbm_gb_so_far": round(peak / 1e9, 2)}
+    return run
+
+
+CHECKS = [
+    ("flash_fwd_causal=False", flash_check(False)),
+    ("flash_fwd_causal=True", flash_check(True)),
+    ("flash_bwd_causal=False", flash_check(False, grad=True)),
+    ("flash_bwd_causal=True", flash_check(True, grad=True)),
+    ("flash_fwd_gqa4", flash_check(True, hkv=2)),
+    ("flash_bwd_gqa4", flash_check(True, hkv=2, grad=True)),
+    ("flash_fwd_d64", flash_check(True, d=64)),
+    # Llama-3-8B is GQA 32 query / 8 KV heads; under mp=4 a shard sees 8 / 2
+    ("paged_decode_gqa_32q8kv", paged_decode_check(32, 8)),
+    ("paged_decode_gqa_8q2kv_mp4_shard", paged_decode_check(8, 2)),
+    ("paged_decode_mha_8q8kv", paged_decode_check(8, 8)),
+    # EngineConfig.dtype=None: float32 pools under bf16 queries
+    ("paged_decode_f32_pool_bf16_q",
+     paged_decode_check(32, 8, pool_dtype=jnp.float32)),
+    ("ragged_T64_W64_32q8kv", ragged_check(64, 64)),
+    ("ragged_T64_W64_8q2kv_mp4_shard", ragged_check(64, 64, h=8, hkv=2)),
+    ("ragged_T256_W64_32q8kv", ragged_check(256, 64)),
+    # scalar prefetch: tables[T, W] int32, one row per packed token, each
+    # row padded to 128 words of a 1 MB SMEM
+    ("ragged_T1024_W128_prefetch_512KB", ragged_check(1024, 128,
+                                                      oracle=False)),
+    ("ragged_T2048_W64_prefetch_refused", prefetch_limit_check(2048, 64)),
+    ("ragged_T2048_W128_prefetch_refused", prefetch_limit_check(2048, 128)),
+    ("sampler_rows8_vocab128256", sampler_check(8)),
+    ("sampler_rows256_vocab128256", sampler_check(256)),
+    ("sampler_rows2048_vocab128256", sampler_check(2048)),
+]
+
+
+def main(argv) -> int:
+    if jax.default_backend() != "tpu":
+        print(f"pallas_mosaic_check: needs a TPU, found backend "
+              f"{jax.default_backend()!r}", file=sys.stderr)
+        return 1
+    cache = configure_compile_cache()
+    import importlib.metadata as md
+
     dev = jax.devices()[0]
-    print(f"# device: {dev.device_kind} backend={jax.default_backend()}",
-          file=sys.stderr)
     results = {"device": dev.device_kind, "backend": jax.default_backend(),
-               "checks": []}
-
-    from paddle_tpu.ops import pallas_flash, pallas_paged
-
-    rng = np.random.default_rng(0)
-    B, S, H, D = 4, 2048, 8, 128  # [B, S, H, D] — pallas_flash layout
-    q = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.bfloat16)
-
-    def xla_attn(q, k, v, causal):
-        scale = 1.0 / np.sqrt(D)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                       preferred_element_type=jnp.float32) * scale
-        if causal:
-            mask = jnp.tril(jnp.ones((S, S), bool))
-            s = jnp.where(mask, s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v)
-
-    for causal in (False, True):
-        name = f"flash_fwd_causal={causal}"
+               "versions": {p: md.version(p)
+                            for p in ("jax", "jaxlib", "libtpu")},
+               "compile_cache": cache, "checks": []}
+    print(json.dumps({k: results[k] for k in ("device", "versions")}))
+    only = set(argv[1:])
+    errors = []
+    for name, run in CHECKS:
+        if only and name not in only:
+            continue
+        t0 = time.perf_counter()
         try:
-            f_pallas = jax.jit(
-                lambda q, k, v: pallas_flash.flash_attention(
-                    q, k, v, causal=causal))
-            out = f_pallas(q, k, v)
-            jax.block_until_ready(out)
-            ref = jax.jit(lambda q, k, v: xla_attn(q, k, v, causal))(q, k, v)
-            err = float(jnp.max(jnp.abs(out.astype(jnp.float32) -
-                                        ref.astype(jnp.float32))))
-            t_p = _bench(f_pallas, q, k, v)
-            t_x = _bench(jax.jit(lambda q, k, v: xla_attn(q, k, v, causal)),
-                         q, k, v)
-            ok = err < 0.15  # bf16 attention tolerance
-            results["checks"].append(
-                {"name": name, "status": "pass" if ok else "numerics",
-                 "max_err": err, "pallas_ms": round(t_p * 1e3, 3),
-                 "xla_ms": round(t_x * 1e3, 3),
-                 "speedup": round(t_x / t_p, 3)})
-        except Exception as e:  # Mosaic rejection lands here
-            results["checks"].append(
-                {"name": name, "status": "mosaic_fail",
-                 "error": str(e)[-800:]})
-        print(json.dumps(results["checks"][-1]))
-
-    # backward: grad of sum(flash(q,k,v)) vs grad of reference
-    for causal in (False, True):
-        name = f"flash_bwd_causal={causal}"
-        try:
-            g_pallas = jax.jit(jax.grad(
-                lambda q, k, v: pallas_flash.flash_attention(
-                    q, k, v, causal=causal).astype(jnp.float32).sum(),
-                argnums=(0, 1, 2)))
-            gp = g_pallas(q, k, v)
-            jax.block_until_ready(gp)
-            g_ref = jax.jit(jax.grad(
-                lambda q, k, v: xla_attn(
-                    q, k, v, causal).astype(jnp.float32).sum(),
-                argnums=(0, 1, 2)))(q, k, v)
-            err = max(float(jnp.max(jnp.abs(a.astype(jnp.float32) -
-                                            b.astype(jnp.float32))))
-                      for a, b in zip(gp, g_ref))
-            t_p = _bench(g_pallas, q, k, v, iters=10)
-            ok = err < 0.5  # bf16 grads accumulate more error
-            results["checks"].append(
-                {"name": name, "status": "pass" if ok else "numerics",
-                 "max_err": err, "pallas_ms": round(t_p * 1e3, 3)})
-        except Exception as e:
-            results["checks"].append(
-                {"name": name, "status": "mosaic_fail",
-                 "error": str(e)[-800:]})
-        print(json.dumps(results["checks"][-1]))
-
-    # GQA 4:1 (the flagship Llama-3 pattern): fwd + bwd numerics vs the
-    # repeat-KV XLA reference
-    def xla_attn_gqa(q, k, v, causal=True):
-        rep = q.shape[2] // k.shape[2]
-        kr = jnp.repeat(k, rep, axis=2)
-        vr = jnp.repeat(v, rep, axis=2)
-        return xla_attn(q, kr, vr, causal)
-
-    kg = jnp.asarray(rng.standard_normal((B, S, 2, D)), jnp.bfloat16)
-    vg = jnp.asarray(rng.standard_normal((B, S, 2, D)), jnp.bfloat16)
-    try:
-        f = jax.jit(lambda q, k, v: pallas_flash.flash_attention(
-            q, k, v, causal=True))
-        out = f(q, kg, vg)
-        jax.block_until_ready(out)
-        ref = jax.jit(xla_attn_gqa)(q, kg, vg)
-        err = float(jnp.max(jnp.abs(out.astype(jnp.float32) -
-                                    ref.astype(jnp.float32))))
-        results["checks"].append(
-            {"name": "flash_fwd_gqa4",
-             "status": "pass" if err < 0.15 else "numerics", "max_err": err,
-             "pallas_ms": round(_bench(f, q, kg, vg) * 1e3, 3)})
-    except Exception as e:
-        results["checks"].append({"name": "flash_fwd_gqa4",
-                                  "status": "mosaic_fail",
-                                  "error": str(e)[-800:]})
-    print(json.dumps(results["checks"][-1]))
-
-    try:
-        g_pallas = jax.jit(jax.grad(
-            lambda q, k, v: pallas_flash.flash_attention(
-                q, k, v, causal=True).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2)))
-        gp = g_pallas(q, kg, vg)
-        jax.block_until_ready(gp)
-        g_ref = jax.jit(jax.grad(
-            lambda q, k, v: xla_attn_gqa(
-                q, k, v).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2)))(q, kg, vg)
-        err = max(float(jnp.max(jnp.abs(a.astype(jnp.float32) -
-                                        b.astype(jnp.float32))))
-                  for a, b in zip(gp, g_ref))
-        results["checks"].append(
-            {"name": "flash_bwd_gqa4",
-             "status": "pass" if err < 0.5 else "numerics", "max_err": err,
-             "pallas_ms": round(_bench(g_pallas, q, kg, vg, iters=10) * 1e3,
-                                3)})
-    except Exception as e:
-        results["checks"].append({"name": "flash_bwd_gqa4",
-                                  "status": "mosaic_fail",
-                                  "error": str(e)[-800:]})
-    print(json.dumps(results["checks"][-1]))
-
-    # head_dim 64 (BERT/GPT-2 size; D block == full dim — the other legal
-    # tiling arm)
-    try:
-        q64 = jnp.asarray(rng.standard_normal((B, S, H, 64)), jnp.bfloat16)
-        k64 = jnp.asarray(rng.standard_normal((B, S, H, 64)), jnp.bfloat16)
-        v64 = jnp.asarray(rng.standard_normal((B, S, H, 64)), jnp.bfloat16)
-
-        def xla64(q, k, v):
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                           preferred_element_type=jnp.float32) / 8.0
-            mask = jnp.tril(jnp.ones((S, S), bool))
-            p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
-            return jnp.einsum("bhqk,bkhd->bqhd", p.astype(q.dtype), v)
-
-        f = jax.jit(lambda q, k, v: pallas_flash.flash_attention(
-            q, k, v, causal=True))
-        out = f(q64, k64, v64)
-        jax.block_until_ready(out)
-        ref = jax.jit(xla64)(q64, k64, v64)
-        err = float(jnp.max(jnp.abs(out.astype(jnp.float32) -
-                                    ref.astype(jnp.float32))))
-        results["checks"].append(
-            {"name": "flash_fwd_d64",
-             "status": "pass" if err < 0.15 else "numerics", "max_err": err,
-             "pallas_ms": round(_bench(f, q64, k64, v64) * 1e3, 3)})
-    except Exception as e:
-        results["checks"].append({"name": "flash_fwd_d64",
-                                  "status": "mosaic_fail",
-                                  "error": str(e)[-800:]})
-    print(json.dumps(results["checks"][-1]))
-
-    # paged decode
-    try:
-        n_blocks, blk, max_blocks = 64, 16, 8
-        kc = jnp.asarray(rng.standard_normal((n_blocks, blk, 8, D)),
-                         jnp.bfloat16)
-        vc = jnp.asarray(rng.standard_normal((n_blocks, blk, 8, D)),
-                         jnp.bfloat16)
-        qd = jnp.asarray(rng.standard_normal((B, 8, D)), jnp.bfloat16)
-        bt = jnp.asarray(
-            rng.integers(0, n_blocks, (B, max_blocks)), jnp.int32)
-        sl = jnp.asarray([100, 128, 37, 64], jnp.int32)
-        f = jax.jit(lambda q, kc, vc, bt, sl:
-                    pallas_paged.paged_attention_decode(q, kc, vc, bt, sl))
-        out = f(qd, kc, vc, bt, sl)
-        jax.block_until_ready(out)
-        results["checks"].append(
-            {"name": "paged_decode", "status": "pass",
-             "pallas_ms": round(_bench(f, qd, kc, vc, bt, sl) * 1e3, 3)})
-    except Exception as e:
-        results["checks"].append({"name": "paged_decode",
-                                  "status": "mosaic_fail",
-                                  "error": str(e)[-800:]})
-    print(json.dumps(results["checks"][-1]))
-
-    n_fail = sum(1 for c in results["checks"] if c["status"] != "pass")
-    results["verdict"] = "pass" if n_fail == 0 else f"{n_fail} failing"
-    with open(os.path.join(_HERE, "PALLAS_VERDICT.json"), "w") as f:
+            rec = run()
+            rec["status"] = "pass" if rec.pop("ok") else "numerics"
+        except Exception as e:  # the compiler's refusal IS the result
+            text = f"{type(e).__name__}: {e}"
+            errors.append(f"=== {name}\n{text}\n")
+            rec = {"status": "refused",
+                   "error": text if len(text) < 1600
+                   else text[:600] + " … " + text[-900:]}
+        rec = {"name": name, **rec,
+               "seconds": round(time.perf_counter() - t0, 1)}
+        results["checks"].append(rec)
+        print(json.dumps(rec), flush=True)
+    bad = [c["name"] for c in results["checks"] if c["status"] != "pass"]
+    results["verdict"] = "pass" if not bad else f"{len(bad)} failing"
+    os.makedirs(OUT_DIR, exist_ok=True)
+    with open(os.path.join(OUT_DIR, "PALLAS_VERDICT.json"), "w") as f:
         json.dump(results, f, indent=1)
-    print(json.dumps({"verdict": results["verdict"]}))
+    if errors:
+        with open(os.path.join(OUT_DIR, "mosaic_errors.txt"), "w") as f:
+            f.write("\n".join(errors))
+    print(json.dumps({"verdict": results["verdict"], "failing": bad}))
+    return 1 if bad else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main(sys.argv))

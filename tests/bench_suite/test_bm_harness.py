@@ -1,0 +1,321 @@
+"""The harness is driven by data: every name in BENCHMARK.json resolves to
+a file of its own, a later PR adds a cell by adding files only, the
+reference agrees with the model, and the command line measures on a TPU or
+not at all."""
+
+import io
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from benchmarks import harness
+
+BENCH = harness.load_json(harness.ROOT, "BENCHMARK.json")
+TINY = {"source": "test", "hidden_size": 64, "intermediate_size": 128,
+        "num_hidden_layers": 2, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 256,
+        "max_position_embeddings": 256, "rms_norm_eps": 1e-5,
+        "rope_theta": 10000.0, "tie_word_embeddings": False,
+        "builder": "llama_dense", "reference": "dense_decoder",
+        "engine": {"num_blocks": 64, "block_size": 16,
+                   "pool_dtype": "bfloat16", "max_num_seqs": 8,
+                   "max_queue": 64, "prefix_cache": True},
+        "check": {"prompt_lens": [40, 25], "decode_steps": 4,
+                  "atol": 0.05, "rms_rel": 0.05}}
+
+
+@pytest.mark.parametrize("m", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_metric_is_a_file_that_agrees_with_its_entry(m):
+    mod = harness.load_reader(m["name"])
+    assert (mod.UNIT, mod.LAYER, mod.SOURCE) == \
+        (m["unit"], m["layer"], m["source"])
+    moved = [e for e in BENCH["end_to_end"] if e["name"] == m["moves"]][0]
+    cells = set(m.get("workloads") or [w["name"] for w in BENCH["workloads"]])
+    assert cells <= set(moved.get("workloads")
+                        or [w["name"] for w in BENCH["workloads"]])
+    assert callable(mod.read)
+
+
+@pytest.mark.parametrize("m", BENCH["end_to_end"], ids=lambda m: m["name"])
+def test_end_to_end_metric_is_a_file(m):
+    assert callable(harness.load_module("e2e_metrics", m["name"]).compute)
+    assert 0.01 <= m["bound"] <= 0.1 and m["source"] == "host_clock"
+
+
+@pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
+def test_cell_resolves_every_piece(w):
+    cell = harness.Cell(w["name"])
+    assert cell.config["reduced"].keys() == set(
+        [c for c in BENCH["configs"] if c["name"] == w["config"]][0]["reduced"])
+    for kind, name in (("traffic_kinds", cell.traffic["kind"]),
+                       ("models", cell.config["builder"]),
+                       ("reference", cell.config["reference"])):
+        assert cell.module(kind, name)
+    names = {m["name"] for m in cell.end_to_end}
+    assert "setup_s" in names and len(names) >= 2 and cell.per_layer
+    lim = harness.traffic_limits(cell.traffic)
+    pool = (cell.config["engine"]["num_blocks"] - 1) * 16
+    assert lim["max_total"] <= min(pool, cell.config["max_position_embeddings"])
+
+
+def test_unknown_names_are_errors():
+    with pytest.raises(KeyError):
+        harness.Cell("no-such.cell")
+    with pytest.raises(FileNotFoundError):
+        harness.load_reader("no.such.metric")
+    # a group of cells is a suffix on the entry's name, not a file
+    assert callable(harness.load_reader("device.idle_share.chat").read)
+    assert not os.path.exists(os.path.join(
+        harness.HERE, "layer_metrics", "device.idle_share.chat.py"))
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    from benchmarks.models import llama_dense
+
+    return llama_dense.build(TINY, 3_000_000_019)
+
+
+def test_reference_agrees_with_the_model_at_tiny_size(tiny_model):
+    import jax.numpy as jnp
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.core.tensor import Tensor
+    from benchmarks.models import llama_dense
+    from benchmarks.reference import dense_decoder
+
+    ids = np.random.default_rng(0).integers(1, 256, 48).tolist()
+    with paddle.no_grad():
+        got = tiny_model(Tensor(jnp.asarray([ids])))._value[0]
+    w = llama_dense.reference_weights(tiny_model)
+    res = dense_decoder.compare(got, dense_decoder.reference_logits(w, TINY, ids),
+                                atol=0.05, rms_rel=0.05)
+    assert res["ok"] and res["rms_rel"] < 0.02, res
+    # and it is tight enough to tell a wrong model: drop a layer, or scale
+    # the final norm
+    wrong = dict(w, layers=w["layers"][:1])
+    bad = dense_decoder.compare(got, dense_decoder.reference_logits(wrong, TINY, ids),
+                                atol=0.05, rms_rel=0.05)
+    assert not bad["ok"]
+    bad = dense_decoder.compare(
+        got, dense_decoder.reference_logits(dict(w, norm=w["norm"] * 1.25), TINY, ids),
+        atol=0.05, rms_rel=0.05)
+    assert not bad["ok"]
+
+
+def test_seed_decides_the_weights(tiny_model):
+    import numpy as np
+
+    from benchmarks.models import llama_dense
+
+    a = llama_dense.build(TINY, 3_000_000_019)
+    b = llama_dense.build(TINY, 5)
+    pick = lambda m: np.asarray(
+        dict(m.named_parameters())["lm_head.weight"]._value, np.float32)
+    assert (pick(a) == pick(tiny_model)).all() and (pick(a) != pick(b)).any()
+    assert str(dict(a.named_parameters())["lm_head.weight"].dtype).endswith("bfloat16")
+
+
+# --- the probe reads the program's step call by name, and says so when it cannot
+
+def _step(order, vocab=256, n_out=5):
+    """A stand-in for a jitted step program taking ``order``'s arguments."""
+    import numpy as np
+
+    src = "def fn(%s):\n    return out" % ", ".join(order)
+    env = {"out": (None, np.zeros((4, vocab), np.float32), None, (), ())[:n_out]}
+    exec(src, env)
+    return env["fn"]
+
+
+class _Engine:
+    class kv:
+        occupancy = staticmethod(lambda: 0.25)
+
+    def _step_call(self, program, bucket, fn, *args):
+        return fn(*args)
+
+
+DECODE_ARGS = ("param_vals", "k_pools", "v_pools", "ids", "pos", "tables",
+               "lens", "slot_blocks", "slot_offsets")
+
+
+def _decode_values(lens_dtype="int32"):
+    import numpy as np
+
+    return {"ids": np.zeros((4, 1), np.int64), "tables": np.zeros((4, 8), np.int32),
+            "lens": np.array([9, 1, 30, 1]).astype(lens_dtype)}
+
+
+@pytest.mark.parametrize("order", [DECODE_ARGS, DECODE_ARGS[::-1]],
+                         ids=["as-today", "reordered"])
+@pytest.mark.parametrize("program", ["decode", "ragged"])
+def test_probe_counts_rows_by_argument_name(order, program):
+    from benchmarks import launcher
+
+    eng = _Engine()
+    probe = launcher.Probe(eng, vocab=256)
+    vals = _decode_values()
+    eng._step_call(program, (4, 8), _step(order), *(vals.get(n) for n in order))
+    assert probe.n[f"{program}_launches"] == 1
+    assert probe.n[f"{program}_rows"] == 2          # two rows are padding
+    assert probe.n[f"{program}_kv_tokens"] == 39
+    assert probe.pool_peak == 0.25
+    assert probe.rows_hist == ({2: 1} if program == "decode" else {})
+
+
+@pytest.mark.parametrize("case", ["lens-renamed", "lens-float", "wrong-rows",
+                                  "logits-vocab", "return-tuple"])
+def test_probe_fails_loudly_when_the_step_call_changed(case):
+    from benchmarks import launcher
+
+    eng = _Engine()
+    launcher.Probe(eng, vocab=256)
+    order, vals, fn_kw, bucket = DECODE_ARGS, _decode_values(), {}, (4, 8)
+    if case == "lens-renamed":
+        order = tuple("kv_lens" if n == "lens" else n for n in order)
+        vals["kv_lens"] = vals.pop("lens")
+    elif case == "lens-float":
+        vals = _decode_values("float32")
+    elif case == "wrong-rows":
+        bucket = (8, 8)
+    elif case == "logits-vocab":
+        fn_kw = {"vocab": 255}
+    elif case == "return-tuple":
+        fn_kw = {"n_out": 4}
+    with pytest.raises(launcher.ProbeMismatch):
+        eng._step_call("decode", bucket, _step(order, **fn_kw),
+                       *(vals.get(n) for n in order))
+
+
+def test_probe_counts_prompt_tokens_and_checks_the_position():
+    import numpy as np
+    from benchmarks import launcher
+
+    eng = _Engine()
+    probe = launcher.Probe(eng, vocab=256)
+    order = ("param_vals", "k_pools", "v_pools", "ids", "last_pos", "blocks", "offs")
+    vals = {"ids": np.zeros((1, 64), np.int64), "last_pos": np.int32(39)}
+    eng._step_call("prefill", (64,), _step(order), *(vals.get(n) for n in order))
+    assert (probe.n["prefill_tokens"], probe.n["prefill_tokens_sq"]) == (40, 1600)
+    vals["last_pos"] = np.int32(64)
+    with pytest.raises(launcher.ProbeMismatch):
+        eng._step_call("prefill", (64,), _step(order), *(vals.get(n) for n in order))
+
+
+def test_the_knee_in_the_traffic_file_is_the_sweeps():
+    from benchmarks import sweep
+
+    kept = harness.load_json(harness.HERE, "sweeps", "chat-steady_knee.json")
+    mix = harness.load_json(harness.HERE, "traffic", "chat-steady.json")
+    found = sweep.knee(kept["rows"])
+    assert found == {k: mix[k] for k in ("knee_rps", "knee_criterion")}
+    assert found == {k: kept["knee"][k] for k in ("knee_rps", "knee_criterion")}
+    assert mix["rate_rps"] / mix["knee_rps"] == pytest.approx(
+        mix["rate_share_of_knee"], abs=0.005)
+    # every rate up to the knee ran on the plateau; a failure ends it
+    rows = [dict(r, failed=int(r["rate_rps"] == 2.75)) for r in kept["rows"]]
+    assert sweep.knee(rows)["knee_rps"] == 2.5
+    with pytest.raises(ValueError):
+        sweep.knee([dict(kept["rows"][0], compiles=1)])
+
+
+def _env(**kw):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **kw)
+    env.pop("XLA_FLAGS", None)
+    return env
+
+
+def test_client_never_imports_jax():
+    code = ("import sys; sys.path.insert(0, %r); import benchmarks.run, "
+            "benchmarks.sweep, benchmarks.traffic_kinds.open_loop, "
+            "benchmarks.traffic_kinds.backlog, benchmarks.layer_lib; "
+            "bad = [m for m in sys.modules if m.split('.')[0] in "
+            "('jax', 'jaxlib', 'numpy', 'paddle_tpu')]; assert not bad, bad"
+            % harness.ROOT)
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+def test_command_line_exits_non_zero_off_tpu_and_prints_no_result():
+    r = subprocess.run(
+        [sys.executable, os.path.join(harness.HERE, "run.py"), "--workload",
+         BENCH["workloads"][0]["name"], "--seed", "3000000019",
+         "--seconds", "1", "--trace", "0"],
+        env=_env(), capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0 and r.stdout.strip() == ""
+    assert "needs 1 tpu chip" in r.stderr
+
+
+def test_a_later_pr_adds_a_cell_by_adding_files_only(tmp_path):
+    """A dummy configuration, mix, metric and cell as NEW files in a copy;
+    nothing that was there is edited (BENCHMARK.json gains entries)."""
+    from benchmarks import run
+
+    root = str(tmp_path)
+    shutil.copytree(harness.HERE, os.path.join(root, "benchmarks"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: open(os.path.join(dp, p), "rb").read()
+              for dp, _, fs in os.walk(os.path.join(root, "benchmarks"))
+              for p in fs}
+    bdir = os.path.join(root, "benchmarks")
+    with open(os.path.join(bdir, "configs", "tiny.json"), "w") as f:
+        json.dump(TINY, f)
+    mix = harness.load_json(harness.HERE, "traffic", "chat-steady.json")
+    mix.update(rate_rps=6.0, lead_in_s=1, lead_out_s=1, trace_s=0.5,
+               prompt_len={"dist": "lognormal", "median": 24, "sigma": 0.5,
+                           "min": 8, "max": 64},
+               output_len={"dist": "lognormal", "median": 8, "sigma": 0.5,
+                           "min": 4, "max": 16})
+    with open(os.path.join(bdir, "traffic", "tiny-chat.json"), "w") as f:
+        json.dump(mix, f)
+    with open(os.path.join(bdir, "layer_metrics", "dummy.prompt_tokens.py"), "w") as f:
+        f.write('UNIT, LAYER, SOURCE = "tokens", "scheduler", '
+                '"program_counter"\n\n\ndef read(counters, trace):\n'
+                '    return counters["window"]["probe"]["prefill_tokens"]\n')
+    bench = json.loads(json.dumps(BENCH))
+    name = "tiny.tiny-chat"
+    bench["configs"].append({"name": "tiny", "source": "test", "reduced": [],
+                             "file": "benchmarks/configs/tiny.json", "why": "t"})
+    bench["workloads"].append({"name": name, "config": "tiny", "chips": 1,
+                               "traffic": "tiny-chat", "why": "t"})
+    chat = BENCH["workloads"][0]["name"]
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if chat in m.get("workloads", ()):
+            m["workloads"].append(name)
+    bench["per_layer"].append({"name": "dummy.prompt_tokens", "unit": "tokens",
+                               "better": "higher", "source": "program_counter",
+                               "layer": "scheduler", "moves": "ttft_p90_ms",
+                               "workloads": [name]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    lines = {}
+    for trace in (False, True):
+        out = io.StringIO()
+        rc = run.run_cell(name, 3_000_000_019, 4.0, trace, root=root,
+                          platform="cpu", out=out)
+        assert rc == 0
+        lines[trace] = json.loads(out.getvalue().strip().splitlines()[-1])
+    e2e, layer = lines[False], lines[True]
+    assert e2e["correct"] and e2e["failed"] == 0 and e2e["attempted"] == 24
+    assert set(e2e["metrics"]) == {"ttft_p90_ms", "tpot_p50_ms", "itl_p995_ms",
+                                   "setup_s"}
+    assert all(v["value"] > 0 for v in e2e["metrics"].values())
+    assert e2e["device"]["platform"] == "cpu"      # never a device metric
+    assert layer["metrics"]["dummy.prompt_tokens"]["value"] > 0
+    assert layer["metrics"]["programs.compiles_in_window.chat"]["value"] == 0
+    assert 0 < layer["metrics"]["cache.pool_peak_share.chat"]["value"] <= 100
+    assert layer["metrics"]["programs.warm_s_per_program"]["value"] > 0
+    assert "device.idle_share.chat" not in layer["metrics"]    # no trace: left out
+    assert layer["detail"]["check"]["ok"]
+    after = {p: open(os.path.join(dp, p), "rb").read()
+             for dp, _, fs in os.walk(bdir) for p in fs if "__pycache__" not in dp}
+    assert all(after[p] == before[p] for p in before)
+    # the compilation cache sits at a fixed path inside the checkout
+    assert run.child_env(root)["JAX_COMPILATION_CACHE_DIR"] == \
+        os.path.join(root, ".jax_compile_cache")

@@ -1,0 +1,12 @@
+"""Device idle time between step programs while the engine was in ``engine.trackers`` (the end-of-step trackers and ``stepprof.end_step``), per launch.
+With the other ``gap_*`` metrics, the idle time under ``engine.wait`` and the
+unattributed rest it sums to ``engine.host_ms_per_step`` of the same trace."""
+from benchmarks import host_spans
+
+UNIT = "ms"
+LAYER = "engine host loop"
+SOURCE = "program_span"
+
+
+def read(counters, trace):
+    return host_spans.gap_ms(trace, "engine.trackers")

@@ -493,10 +493,16 @@ class LlamaDecoderLayer(Layer):
         return sharding_constraint(x, "dp", "sep", None)
 
     def forward(self, x, cache=None, pos=None):
+        # ``attn`` / ``mlp`` (and ``embed`` / ``lm_head`` below) are the
+        # names a device trace knows the parts of a step program by:
+        # metadata on the operations, nothing computed differently
         x = self._sp(x)
-        h = x + self.self_attn(self.input_layernorm(x), cache=cache, pos=pos)
-        out = h + self.mlp(self.post_attention_layernorm(h))
-        return self._sp(out)
+        with jax.named_scope("attn"):   # projections, RoPE, cache write, kernel
+            a = self.self_attn(self.input_layernorm(x), cache=cache, pos=pos)
+        h = x + a
+        with jax.named_scope("mlp"):
+            m = self.mlp(self.post_attention_layernorm(h))
+        return self._sp(h + m)
 
 
 class LlamaModel(Layer):
@@ -526,7 +532,8 @@ class LlamaModel(Layer):
 
     def forward(self, input_ids, pp_microbatches: Optional[int] = None,
                 caches=None, pos=None):
-        h = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            h = self.embed_tokens(input_ids)
         if caches is not None:
             for layer, cache in zip(self.layers, caches):
                 h = layer(h, cache=cache, pos=pos)
@@ -680,10 +687,11 @@ class LlamaForCausalLM(Layer):
                 caches=None, pos=None):
         h = self.llama(input_ids, pp_microbatches=pp_microbatches,
                        caches=caches, pos=pos)
-        if self.lm_head is None:
-            w = self.llama.embed_tokens.weight
-            return run_op("tied_head", lambda a, wv: a @ wv.T, h, w)
-        return self.lm_head(h)
+        with jax.named_scope("lm_head"):
+            if self.lm_head is None:
+                w = self.llama.embed_tokens.weight
+                return run_op("tied_head", lambda a, wv: a @ wv.T, h, w)
+            return self.lm_head(h)
 
     def train_batch_1f1b(self, input_ids, labels, n_microbatch: int,
                          criterion=None, recompute: bool = False):

@@ -107,16 +107,21 @@ def logit_stats(logits):
     import jax
     import jax.numpy as jnp
 
-    l = logits.astype(jnp.float32)
-    if l.ndim == 1:
-        l = l[None, :]
-    finite = jnp.isfinite(l)
-    nonfinite = jnp.sum(~finite, axis=-1).astype(jnp.float32)
-    safe = jnp.where(finite, l, 0.0)
-    absmax = jnp.max(jnp.abs(safe), axis=-1)
-    top2 = jax.lax.top_k(safe, 2)[0]
-    margin = top2[:, 0] - top2[:, 1]
-    return jnp.stack([nonfinite, absmax, margin], axis=-1)
+    # ``logit_stats`` is the name a device trace knows these operations
+    # by (metadata only).  It is worth a name: on the TPU the top-2
+    # lowers to a full-vocabulary sort, in every step program, audit on
+    # or off — as long as the sampler's own (PERF.md, PR 25)
+    with jax.named_scope("logit_stats"):
+        l = logits.astype(jnp.float32)
+        if l.ndim == 1:
+            l = l[None, :]
+        finite = jnp.isfinite(l)
+        nonfinite = jnp.sum(~finite, axis=-1).astype(jnp.float32)
+        safe = jnp.where(finite, l, 0.0)
+        absmax = jnp.max(jnp.abs(safe), axis=-1)
+        top2 = jax.lax.top_k(safe, 2)[0]
+        margin = top2[:, 0] - top2[:, 1]
+        return jnp.stack([nonfinite, absmax, margin], axis=-1)
 
 
 @dataclass(frozen=True)

@@ -24,6 +24,10 @@ _PID = 0  # single-process host trace
 def _jsonable(v):
     if isinstance(v, (str, int, float, bool)) or v is None:
         return v
+    if isinstance(v, (tuple, list)):
+        # a sequence of ids (the request ids of a step's rows): the
+        # step path hands over the sequence, the text is made here
+        return ",".join(str(x) for x in v)
     return str(v)
 
 

@@ -29,18 +29,25 @@ module finally measures:
   (``GET /v1/debug/profile?steps=N``) arms a bounded window that
   records the next N engine steps as tracer :class:`Span` objects —
   each step span annotated with program/bucket/utilization, each
-  program launch a child span — exported through the existing
-  ``observability.export`` chrome machinery.  When a real accelerator
-  is present the window is wrapped in ``jax.profiler.start_trace`` /
-  ``stop_trace`` (the ``paddle_tpu.profiler`` XPlane path), so host
-  step spans and the device XPlane dump correlate on one timeline —
-  the carried-over ROADMAP thread.
+  program launch and each phase of the step
+  (:data:`~paddle_tpu.observability.tracer.STEP_PHASES`) a child span —
+  exported through the existing ``observability.export`` chrome
+  machinery.  When a real accelerator is present the window is wrapped
+  in ``jax.profiler.start_trace`` / ``stop_trace`` (the
+  ``paddle_tpu.profiler`` XPlane path); the same phases are then
+  ``TraceAnnotation`` events in the XPlane dump's host plane, on the
+  clock its device planes are aligned to.  Both profiler calls are made
+  OUTSIDE the lock the engine's step takes: they take seconds on a real
+  device, and serving goes on meanwhile.
 
 Overhead contract: gated by ``EngineConfig.step_profile`` (default on).
 Everything outside an armed capture window is O(1) per program launch —
 counter/histogram increments and a bounded last-K record ring (the
 flight recorder embeds it in post-mortem bundles).  Span objects are
-built only while a capture window is armed.  Nothing here runs inside a
+built only while a capture window is armed, and request ids ride the
+records as the tuple the engine already holds: they are joined into
+text where a record is read (:meth:`StepProfiler.records`, the chrome
+export), never on the step path.  Nothing here runs inside a
 traced function, so the profiler adds **zero** jit traces (tested).
 
 Boundedness (``tools/check_bounded_metrics.py`` lints this module):
@@ -59,6 +66,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
+from .export import _jsonable
 from .metrics import MetricsRegistry
 from .tracer import Span
 
@@ -105,6 +113,13 @@ _MAX_BUCKET_KEYS = 64
 
 def _bucket_str(bucket: Tuple[int, ...]) -> str:
     return "x".join(str(int(b)) for b in bucket)
+
+
+def _readable(rec: Dict) -> Dict:
+    """A step record as its readers get it: a copy whose program rows
+    hold text where the engine handed over a tuple of request ids."""
+    return dict(rec, programs=[{k: _jsonable(v) for k, v in p.items()}
+                               for p in rec["programs"]])
 
 
 class CaptureWindow:
@@ -177,6 +192,14 @@ class StepProfiler:
         self._cur: Optional[List[Dict]] = None
         self._cur_t0 = 0.0
         self._capture: Optional[CaptureWindow] = None
+        # one window at a time, from arm_capture's first check until
+        # the window's device trace has stopped (both profiler calls
+        # run outside ``_lock``, so ``_capture`` alone cannot say it)
+        self._capture_busy = False
+        # where SpanTracer.phase records a step's phases: a list while
+        # a capture window is armed, None otherwise — the one attribute
+        # a phase site reads on the step path
+        self.phase_sink: Optional[List[List]] = None
         self.last_capture: Optional[CaptureWindow] = None
         # AOT attribution (ISSUE 15): set once an artifact is bound —
         # loaded programs count serving_aot_hits_total instead of fake
@@ -332,6 +355,22 @@ class StepProfiler:
                                   if k != "t"})
                     child.duration = max(p["wall_s"], 1e-9)
                     capw.spans.append(child)
+                # the step's phases (SpanTracer.phase), in the order
+                # they ran: children of the step, except what the
+                # engine thread did between two steps (wait, intake,
+                # the stream hand-off), which has no parent.  A phase
+                # still open — ``engine.trackers`` wraps this very
+                # call — ends here.
+                sink = self.phase_sink
+                phases, sink[:] = list(sink), ()
+                for name, start, end, attrs in phases:
+                    child = Span(name, "phase", start, sp.tid,
+                                 capw.next_id(),
+                                 sp.span_id if start >= self._cur_t0
+                                 else None, dict(attrs))
+                    child.duration = max(
+                        (now if end is None else end) - start, 1e-9)
+                    capw.spans.append(child)
                 capw.remaining -= 1
                 if capw.remaining <= 0:
                     finalize = capw
@@ -446,7 +485,7 @@ class StepProfiler:
         """Last-K per-step records, oldest first (the flight recorder
         embeds these in post-mortem bundles)."""
         with self._lock:
-            return [dict(r) for r in self._records]
+            return [_readable(r) for r in self._records]
 
     def last_record(self) -> Optional[Dict]:
         """Newest per-step record (``None`` before the first step) —
@@ -454,7 +493,7 @@ class StepProfiler:
         reply so the router can attribute wire latency per-program
         (``observability.distrib.WireStats``)."""
         with self._lock:
-            return dict(self._records[-1]) if self._records else None
+            return _readable(self._records[-1]) if self._records else None
 
     def bucket_set(self, program: str) -> set:
         """Distinct bucket strings observed for ``program`` — tests
@@ -551,23 +590,25 @@ class StepProfiler:
                                      "/tmp/paddle_tpu_profile")
         window = CaptureWindow(steps, device_trace, log_dir)
         with self._lock:
-            if self._capture is not None:
+            if self._capture_busy:
                 raise CaptureBusy("a capture window is already armed")
-            if device_trace:
-                # host spans + device XPlane on one timeline (the
-                # ROADMAP's carried-over correlation thread): both are
-                # wall-clock-anchored, so the exported chrome trace and
-                # the XPlane dump under log_dir line up in one viewer.
-                # Started BEFORE the window is published (and under the
-                # lock the engine's finalize path claims), so a fast
-                # engine can never stop_trace a trace that has not
-                # started yet and orphan it
-                try:
-                    import jax
+            self._capture_busy = True
+        if device_trace:
+            # the same phases land in the XPlane dump's host plane as
+            # TraceAnnotation events, beside the device planes.
+            # start_trace takes seconds on a real device: it runs here,
+            # outside the lock every engine step takes, so serving goes
+            # on meanwhile.  It still runs BEFORE the window is
+            # published, so a fast engine can never stop a trace that
+            # has not started yet and orphan it.
+            try:
+                import jax
 
-                    jax.profiler.start_trace(window.log_dir)
-                except Exception:
-                    window.device_trace = False  # swallow-ok: already tracing; the response's deviceTraceDir field reports the downgrade
+                jax.profiler.start_trace(window.log_dir)
+            except Exception:
+                window.device_trace = False  # swallow-ok: already tracing; the response's deviceTraceDir field reports the downgrade
+        with self._lock:
+            self.phase_sink = []
             self._capture = window
         return window
 
@@ -585,25 +626,32 @@ class StepProfiler:
             if self._capture is not window:
                 return  # already finalized (engine/cancel race)
             self._capture = None
-            if window.device_trace:
-                # stopped under the SAME lock arm_capture starts under:
-                # a deferred stop outside it could kill a concurrently
-                # armed new window's device trace at step 0
-                try:
-                    import jax
-
-                    jax.profiler.stop_trace()
-                except Exception:
-                    pass  # swallow-ok: no device trace was running (the start raced/failed); nothing to stop is the expected idempotent case
-        window.complete = complete
-        result = chrome_trace_dict(window.spans,
-                                   epoch_offset=self.epoch_offset)
-        # chrome viewers ignore unknown top-level keys; waiters read them
-        result["captureSteps"] = window.steps - window.remaining
-        result["requestedSteps"] = window.steps
-        result["complete"] = complete
+            self.phase_sink = None
         if window.device_trace:
-            result["deviceTraceDir"] = window.log_dir
-        window.result = result
-        self.last_capture = window
-        window.done.set()
+            # outside the step's lock, like start_trace: the dump takes
+            # seconds.  ``_capture_busy`` stays set until it is done, so
+            # this can never stop a newly armed window's trace.
+            try:
+                import jax
+
+                jax.profiler.stop_trace()
+            except Exception:
+                pass  # swallow-ok: no device trace was running (the start raced/failed); nothing to stop is the expected idempotent case
+        try:
+            window.complete = complete
+            result = chrome_trace_dict(window.spans,
+                                       epoch_offset=self.epoch_offset)
+            # chrome viewers ignore unknown top-level keys; waiters read
+            # them
+            result["captureSteps"] = window.steps - window.remaining
+            result["requestedSteps"] = window.steps
+            result["complete"] = complete
+            if window.device_trace:
+                result["deviceTraceDir"] = window.log_dir
+            window.result = result
+            self.last_capture = window
+        finally:
+            # whatever happened above, the next window can be armed and
+            # no waiter hangs (a missing result answers 503)
+            self._capture_busy = False
+            window.done.set()

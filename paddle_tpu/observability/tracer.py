@@ -16,6 +16,13 @@ collectives, watchdog timeouts.  Design constraints:
   JSON (``ph:"X"`` complete events with explicit ``id``/``parent`` args,
   so nesting round-trips exactly through
   :func:`~paddle_tpu.observability.load_profiler_result`).
+* **phases on the device trace's clock** — :meth:`SpanTracer.phase`
+  marks one phase of the serving engine's step with a
+  ``jax.profiler.TraceAnnotation``: a no-op in C++ while no profiler
+  session runs, and otherwise an event in the profiler's own host plane,
+  beside the device planes.  That annotation is ALL a phase costs on the
+  step path; a :class:`Span` is recorded too only while the step
+  profiler's capture window is armed (``GET /v1/debug/profile``).
 """
 
 from __future__ import annotations
@@ -25,6 +32,24 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+# the phases of one serving-engine step, in the order a step runs them.
+# The names are a contract: the benchmark's readers
+# (benchmarks/host_spans.py) and PERF.md key on them.
+STEP_PHASES = (
+    "engine.wait",          # engine thread blocked with no work
+    "engine.intake",        # server queue -> scheduler (submits, aborts)
+    "sched.plan",           # scheduler.schedule()
+    "engine.admit",         # per-request admission bookkeeping
+    "engine.build",         # numpy routing arrays + the SamplingPack
+    "engine.dispatch",      # the step call, until the jit call returns
+    "engine.device_wait",   # blocked until the program has ended
+    "engine.fetch",         # logits (bytes=), audit stats, tokens to the host
+    "engine.emit",          # commit, emission, retire, stream hand-off
+    "engine.trackers",      # end-of-step trackers and stepprof.end_step
+)
 
 
 class Span:
@@ -74,6 +99,29 @@ class _SpanContext:
             self._span.attrs.setdefault("error", exc_type.__name__)
         self._tracer._pop(self._span)
         return False
+
+
+class _RecordedPhase:
+    """A phase inside an armed capture window: the profiler annotation
+    plus one ``[name, start, end, attrs]`` record handed to the step
+    profiler, which turns the records of a step into child spans."""
+
+    __slots__ = ("_ann", "_rec", "_sink")
+
+    def __init__(self, ann, sink, name: str, attrs: Dict[str, int]):
+        self._ann = ann
+        self._sink = sink
+        self._rec = [name, 0.0, None, attrs]
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._rec[1] = time.perf_counter()
+        self._sink.append(self._rec)
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._rec[2] = time.perf_counter()
+        return self._ann.__exit__(exc_type, exc, tb)
 
 
 class SpanTracer:
@@ -129,6 +177,24 @@ class SpanTracer:
                   threading.get_ident(), next(self._ids),
                   parent.span_id if parent else None, dict(attrs))
         return _SpanContext(self, sp)
+
+    @staticmethod
+    def phase(name: str, recorder=None, **ints):
+        """``with tracer.phase("engine.build", prof, rows=B): ...`` —
+        one phase of an engine step (:data:`STEP_PHASES`).
+
+        Always, and only, a ``jax.profiler.TraceAnnotation`` with a
+        constant name and integer attributes the caller already holds:
+        JAX has no public "is a profiler session running", so the
+        annotation is what is always made — it costs a fraction of a
+        microsecond while no session runs.  ``recorder`` is the
+        engine's :class:`~paddle_tpu.observability.StepProfiler`; while
+        its capture window is armed the phase is recorded for it too,
+        and becomes a child span of the captured step."""
+        ann = TraceAnnotation(name, **ints)
+        if recorder is None or recorder.phase_sink is None:
+            return ann
+        return _RecordedPhase(ann, recorder.phase_sink, name, ints)
 
     def instant(self, name: str, cat: str = "event", **attrs) -> Span:
         """Zero-duration marker (chrome ``ph:"i"``), e.g. a watchdog

@@ -183,4 +183,5 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
             interpret=_interpret(),
+            name="paged_decode_attention",   # its name in a device trace
         )(block_tables, seq_lens, q, k_cache, v_cache)

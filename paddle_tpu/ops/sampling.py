@@ -40,6 +40,7 @@ Design constraints the serving layer relies on:
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -87,8 +88,11 @@ def make_keys(seed_draws, out=None):
     return keys
 
 
+@jax.named_scope("sampler")
 def sample_tokens(logits, temps, top_ks, top_ps, keys):
-    """Sample one token per row, in-trace.
+    """Sample one token per row, in-trace.  Everything here runs under
+    the ``sampler`` scope: the name the device trace knows the whole
+    epilogue by (its sort, its masks, its draw), metadata only.
 
     Args:
       logits: ``[R, V]`` float (any float dtype; upcast to f32).

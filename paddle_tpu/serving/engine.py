@@ -96,6 +96,16 @@ from .scheduler import (
 _EVICT_EVENTS_PER_STEP = 8
 
 
+# StepTimer series and collective-phase label of each program family
+_STEP_TIMERS = {
+    "prefill": ("prefill_step", "prefill"),
+    "chunk": ("prefill_step", "prefill"),
+    "decode": ("decode_step", "decode"),
+    "ragged": ("unified_step", "ragged"),
+    "burst": ("burst_step", "burst"),
+}
+
+
 @dataclass
 class EngineConfig:
     """Engine-level deployment knobs (the config plumb-through of ISSUE 5).
@@ -565,6 +575,56 @@ class EngineCore:
         out = self._aot.call(program, bucket, *args)
         self.stepprof.record_aot_hit(program)
         return out
+
+    def _launch(self, program: str, bucket, jit_fn, args, rows: int,
+                fetch_logits: bool = True):
+        """One step-program launch, shared by all five families, cut
+        into the phases the device trace can tell apart
+        (``observability.tracer.STEP_PHASES``): ``engine.dispatch`` is
+        the step call alone, until the jit call returns;
+        ``engine.device_wait`` waits for the program to end and copies
+        nothing; ``engine.fetch`` brings back what the step reads — the
+        ``[rows, vocab]`` float32 logits (and the audit's logit stats
+        when the audit is on), then the int32 tokens.  The copies run in
+        the order and from the moment they always did (the logits' copy
+        is asked for as soon as the program is dispatched, the tokens'
+        after it): only the waiting is cut in two, so that the wait for
+        the device and the copy are separate spans.  A burst fetches its
+        token buffer alone, so its ``engine.device_wait`` is that fetch.
+        Returns ``(tokens, logits, stats, wall seconds)``, numpy where
+        fetched (``stats`` stays a device array with the audit off:
+        nothing reads it).  A launch during which a trace counter moved
+        IS that bucket's trace+compile, so its wall time goes to the
+        compile table."""
+        phase, prof = self.tracer.phase, self.stepprof
+        timer, collective = _STEP_TIMERS[program]
+        traces0 = (self.prefill_trace_count + self.decode_trace_count
+                   + self.ragged_trace_count + self.burst_trace_count)
+        with StepTimer(self.metrics, timer,
+                       self._collective_phase(collective)) as st:
+            with phase("engine.dispatch", prof, rows=rows,
+                       bucket=bucket[0]):
+                toks, logits, stats, self._k_pools, self._v_pools = \
+                    self._step_call(program, bucket, jit_fn,
+                                    self._param_vals(), self._k_pools,
+                                    self._v_pools, *args)
+                if fetch_logits:
+                    logits.copy_to_host_async()
+            if fetch_logits:
+                with phase("engine.device_wait", prof):
+                    logits.block_until_ready()
+                with phase("engine.fetch", prof, bytes=logits.nbytes):
+                    logits = np.asarray(logits, np.float32)
+                    if self.audit.enabled:
+                        stats = np.asarray(stats, np.float32)
+                    toks = np.asarray(toks, np.int32)
+            else:
+                with phase("engine.device_wait", prof):
+                    toks = np.asarray(toks, np.int32)
+        if (self.prefill_trace_count + self.decode_trace_count
+                + self.ragged_trace_count + self.burst_trace_count) > traces0:
+            self.stepprof.record_compile(program, bucket, st.dt)
+        return toks, logits, stats, st.dt
 
     def _mesh_jit_shardings(self, mesh, cfg) -> Dict[str, dict]:
         """Explicit in/out shardings for the three mesh-spanning jitted
@@ -1067,199 +1127,175 @@ class EngineCore:
         the request's next token only when the prefill completes (the
         final chunk's last-position logits ARE that token)."""
         rid = req.request_id
+        phase, prof = self.tracer.phase, self.stepprof
         t_chunk0 = time.perf_counter()
-        ids, target, start, n, recompute = \
-            self._begin_prefill_chunk(req, t_chunk0)
-        table = self.kv.table(rid)
-        pos = np.arange(start, start + n)
-        # one sampling quartet row: the final chunk's last-position draw
-        # (output position len(output_tokens) — on recompute the replayed
-        # positions are already in output_tokens and never re-drawn)
-        pack = SamplingPack(1)
-        pack.set_request(0, req)
-        if start == 0 and n == target:
-            # cold one-shot: dense-cache forward + scatter (the cheapest
-            # program when nothing is cached and no budget splits it)
-            Tb = bucket_size(target)
-            ids_arr = np.zeros((1, Tb), np.int64)
-            ids_arr[0, :target] = ids
-            blocks = np.zeros((Tb,), np.int32)  # pads -> null page
-            blocks[:target] = [table[p // self.block_size] for p in pos]
-            offs = (np.arange(Tb) % self.block_size).astype(np.int32)
-            self.prefill_buckets.add(("prefill", Tb))
-            traces0 = self.prefill_trace_count
+        one_shot = False
+        with phase("engine.build", prof):
+            ids, target, start, n, recompute = \
+                self._begin_prefill_chunk(req, t_chunk0)
+            table = self.kv.table(rid)
+            pos = np.arange(start, start + n)
+            # one sampling quartet row: the final chunk's last-position
+            # draw (output position len(output_tokens) — on recompute the
+            # replayed positions are already in output_tokens and never
+            # re-drawn)
+            pack = SamplingPack(1)
+            pack.set_request(0, req)
+            if start == 0 and n == target:
+                # cold one-shot: dense-cache forward + scatter (the
+                # cheapest program when nothing is cached and no budget
+                # splits it)
+                one_shot = True
+                Tb = bucket_size(target)
+                ids_arr = np.zeros((1, Tb), np.int64)
+                ids_arr[0, :target] = ids
+                blocks = np.zeros((Tb,), np.int32)  # pads -> null page
+                blocks[:target] = [table[p // self.block_size] for p in pos]
+                offs = (np.arange(Tb) % self.block_size).astype(np.int32)
+                self.prefill_buckets.add(("prefill", Tb))
+            else:
+                # chunk / resume: the chunk scatters into its pages and
+                # attends over the paged prefix, so earlier chunks and
+                # prefix-cache forks need no recompute.  Two buckets
+                # bound the trace count: chunk width and block-table
+                # width.
+                Wb = bucket_size(n)
+                TWb = bucket_size(len(table))
+                ids_arr = np.zeros((1, Wb), np.int64)
+                ids_arr[0, :n] = ids[start:start + n]
+                blocks = np.zeros((1, Wb), np.int32)  # pads -> null page
+                blocks[0, :n] = [table[p // self.block_size] for p in pos]
+                offs = np.zeros((1, Wb), np.int32)
+                offs[0, :n] = pos % self.block_size
+                tables = np.zeros((1, TWb), np.int32)
+                tables[0, :len(table)] = table
+                lens = np.array([start + n], np.int32)
+                self.prefill_buckets.add(("chunk", Wb, TWb))
+                self.metrics.count("chunked_prefill_steps")
+        if one_shot:
             with self.tracer.span("prefill_step", cat="serving",
                                   request=str(rid), trace=req.trace_id,
                                   tokens=target, bucket=Tb,
                                   recompute=bool(req.output_tokens)):
-                with StepTimer(self.metrics, "prefill_step",
-                               self._collective_phase("prefill")) as st:
-                    toks, last, stats, self._k_pools, self._v_pools = \
-                        self._step_call(
-                            "prefill", (Tb,), self._jit_prefill,
-                            self._param_vals(), self._k_pools,
-                            self._v_pools, ids_arr, np.int32(target - 1),
-                            blocks, offs, *pack.arrays())
-                    logits = np.asarray(last, np.float32)
-                    tok = int(np.asarray(toks, np.int32)[0])
-            if self.prefill_trace_count > traces0:
-                # the in-trace counter advanced during THIS launch, so
-                # its wall time is the trace+compile of this bucket
-                self.stepprof.record_compile("prefill", (Tb,), st.dt)
-            self.stepprof.record_program(
-                "prefill", (Tb,), scheduled=n, capacity=Tb, wall_s=st.dt,
-                request=str(rid))
-            if self.audit.enabled:
-                self.audit.observe_program(
-                    "prefill", np.asarray(stats, np.float32), (Tb,),
-                    logits=logits[None, :],
-                    inputs={"ids": ids_arr, "blocks": blocks,
-                            "offs": offs},
-                    requests=[{"id": str(rid),
-                               "greedy": req.sampling.temperature == 0.0}])
+                toks, logits, stats, dt = self._launch(
+                    "prefill", (Tb,), self._jit_prefill,
+                    (ids_arr, np.int32(target - 1), blocks, offs,
+                     *pack.arrays()), rows=1)
+            program, bucket, capacity, attrs = "prefill", (Tb,), Tb, {}
+            inputs = {"ids": ids_arr, "blocks": blocks, "offs": offs}
         else:
-            # chunk / resume: the chunk scatters into its pages and
-            # attends over the paged prefix, so earlier chunks and
-            # prefix-cache forks need no recompute.  Two buckets bound
-            # the trace count: chunk width and block-table width.
-            Wb = bucket_size(n)
-            TWb = bucket_size(len(table))
-            ids_arr = np.zeros((1, Wb), np.int64)
-            ids_arr[0, :n] = ids[start:start + n]
-            blocks = np.zeros((1, Wb), np.int32)  # pads -> null page
-            blocks[0, :n] = [table[p // self.block_size] for p in pos]
-            offs = np.zeros((1, Wb), np.int32)
-            offs[0, :n] = pos % self.block_size
-            tables = np.zeros((1, TWb), np.int32)
-            tables[0, :len(table)] = table
-            lens = np.array([start + n], np.int32)
-            self.prefill_buckets.add(("chunk", Wb, TWb))
-            self.metrics.count("chunked_prefill_steps")
-            traces0 = self.prefill_trace_count
             with self.tracer.span("prefill_step", cat="serving",
                                   request=str(rid), trace=req.trace_id,
                                   tokens=n, bucket=Wb, chunk=True,
                                   start=start,
                                   cached=req.num_cached_tokens,
                                   recompute=bool(req.output_tokens)):
-                with StepTimer(self.metrics, "prefill_step",
-                               self._collective_phase("prefill")) as st:
-                    toks, last, stats, self._k_pools, self._v_pools = \
-                        self._step_call(
-                            "chunk", (Wb, TWb), self._jit_chunk_prefill,
-                            self._param_vals(), self._k_pools,
-                            self._v_pools, ids_arr, np.int32(start),
-                            np.int32(n - 1), tables, lens, blocks, offs,
-                            *pack.arrays())
-                    logits = np.asarray(last, np.float32)
-                    tok = int(np.asarray(toks, np.int32)[0])
-            if self.prefill_trace_count > traces0:
-                self.stepprof.record_compile("chunk", (Wb, TWb), st.dt)
+                toks, logits, stats, dt = self._launch(
+                    "chunk", (Wb, TWb), self._jit_chunk_prefill,
+                    (ids_arr, np.int32(start), np.int32(n - 1), tables,
+                     lens, blocks, offs, *pack.arrays()), rows=1)
+            program, bucket, capacity = "chunk", (Wb, TWb), Wb
+            attrs = {"start": start, "table_width": len(table)}
+            inputs = {"ids": ids_arr, "start": np.int32(start),
+                      "tables": tables, "lens": lens,
+                      "slot_blocks": blocks, "slot_offsets": offs}
+        with phase("engine.emit", prof):
             self.stepprof.record_program(
-                "chunk", (Wb, TWb), scheduled=n, capacity=Wb,
-                wall_s=st.dt, request=str(rid), start=start,
-                table_width=len(table))
+                program, bucket, scheduled=n, capacity=capacity,
+                wall_s=dt, request=str(rid), **attrs)
             if self.audit.enabled:
                 self.audit.observe_program(
-                    "chunk", np.asarray(stats, np.float32), (Wb, TWb),
-                    logits=logits[None, :],
-                    inputs={"ids": ids_arr, "start": np.int32(start),
-                            "tables": tables, "lens": lens,
-                            "slot_blocks": blocks, "slot_offsets": offs},
+                    program, stats, bucket, logits=logits[None, :],
+                    inputs=inputs,
                     requests=[{"id": str(rid),
                                "greedy": req.sampling.temperature == 0.0}])
-        self._finish_prefill_chunk(req, ids, target, start, n, recompute,
-                                   t_chunk0, tok)
+            self._finish_prefill_chunk(req, ids, target, start, n,
+                                       recompute, t_chunk0, int(toks[0]))
 
     def _decode(self, reqs: List[Request]) -> Dict[object, int]:
         """One bucketed decode step for ``reqs`` (slots already reserved
         by the scheduler on ``req._slot``)."""
+        phase, prof = self.tracer.phase, self.stepprof
         B = len(reqs)
-        Bb = bucket_size(B)
-        width = max(len(self.kv.table(r.request_id)) for r in reqs)
-        Wb = bucket_size(width)
-        ids = np.zeros((Bb, 1), np.int64)
-        poss = np.zeros((Bb,), np.int32)
-        tables = np.zeros((Bb, Wb), np.int32)
-        lens = np.ones((Bb,), np.int32)   # pad rows: 1 token of null page
-        slot_blocks = np.zeros((Bb,), np.int32)
-        slot_offsets = np.zeros((Bb,), np.int32)
-        pack = SamplingPack(Bb)  # pad rows stay temp=0 → argmax, ignored
-        for i, r in enumerate(reqs):
-            rid = r.request_id
-            t = self.kv.table(rid)
-            p = self.kv.seq_len(rid)
-            ids[i, 0] = r.last_token
-            poss[i] = p
-            tables[i, :len(t)] = t
-            lens[i] = p + 1               # cache length AFTER this token
-            slot_blocks[i], slot_offsets[i] = r._slot
-            pack.set_request(i, r)
-        self.decode_buckets.add(("decode", Bb, Wb))
-        traces0 = self.decode_trace_count
-        # shadow-oracle capture (ISSUE 10): on sampled audit steps the
-        # PRE-step pools are snapshotted so the auditor can re-execute
-        # this exact step through the XLA gather reference program
-        pre_pools = self.audit.snapshot_pools(self._k_pools,
-                                              self._v_pools)
+        with phase("engine.build", prof, rows=B):
+            Bb = bucket_size(B)
+            width = max(len(self.kv.table(r.request_id)) for r in reqs)
+            Wb = bucket_size(width)
+            ids = np.zeros((Bb, 1), np.int64)
+            poss = np.zeros((Bb,), np.int32)
+            tables = np.zeros((Bb, Wb), np.int32)
+            lens = np.ones((Bb,), np.int32)  # pad rows: 1 token of null page
+            slot_blocks = np.zeros((Bb,), np.int32)
+            slot_offsets = np.zeros((Bb,), np.int32)
+            pack = SamplingPack(Bb)  # pad rows stay temp=0 → argmax, ignored
+            for i, r in enumerate(reqs):
+                rid = r.request_id
+                t = self.kv.table(rid)
+                p = self.kv.seq_len(rid)
+                ids[i, 0] = r.last_token
+                poss[i] = p
+                tables[i, :len(t)] = t
+                lens[i] = p + 1           # cache length AFTER this token
+                slot_blocks[i], slot_offsets[i] = r._slot
+                pack.set_request(i, r)
+            self.decode_buckets.add(("decode", Bb, Wb))
+            # shadow-oracle capture (ISSUE 10): on sampled audit steps the
+            # PRE-step pools are snapshotted so the auditor can re-execute
+            # this exact step through the XLA gather reference program
+            pre_pools = self.audit.snapshot_pools(self._k_pools,
+                                                  self._v_pools)
+            # the rows' ids ride the span and the step record as the
+            # tuple; whoever reads them joins them (export, records())
+            rids = tuple(r.request_id for r in reqs)
         with self.tracer.span("decode_step", cat="serving", batch=B,
                               batch_bucket=Bb, width_bucket=Wb,
-                              requests=",".join(str(r.request_id)
-                                                for r in reqs),
-                              traces=",".join(str(r.trace_id)
-                                              for r in reqs)):
-            with StepTimer(self.metrics, "decode_step",
-                           self._collective_phase("decode")) as st:
-                toks, out, stats, self._k_pools, self._v_pools = \
-                    self._step_call(
-                        "decode", (Bb, Wb), self._jit_decode,
-                        self._param_vals(), self._k_pools, self._v_pools,
-                        ids, poss, tables, lens, slot_blocks,
-                        slot_offsets, *pack.arrays())
-                out = np.asarray(out, np.float32)
-                toks = np.asarray(toks, np.int32)
-        if self.decode_trace_count > traces0:
-            self.stepprof.record_compile("decode", (Bb, Wb), st.dt)
-        # token/row accounting only: scheduled = B real rows (one token
-        # each) vs the Bb row bucket — this is the axis the scheduler's
-        # tokens_planned ledger counts, so the invariant stays exact.
-        # Width-bucket padding (tables padded `width` -> Wb with null
-        # pages) is NOT in these counters; it rides the record as the
-        # table_width attr next to the bucket shape.
-        self.stepprof.record_program(
-            "decode", (Bb, Wb), scheduled=B, capacity=Bb, wall_s=st.dt,
-            table_width=width,
-            requests=",".join(str(r.request_id) for r in reqs))
-        if self.audit.enabled:
-            # sentinel over the REAL rows (pad rows attend the null page
-            # — their logits are not part of the serving contract), plus
-            # the shadow re-execution when this step is sampled.
-            # kernel_corrupt (ISSUE 12) corrupts ONLY this audit copy —
-            # the sampler below reads the untouched `out`, so served
-            # tokens stay correct while the divergence net trips.  Only
-            # SAMPLED steps run the shadow compare, so the exactly-once
-            # plan entry must not be consumed by a launch the oracle
-            # never checks.
-            audit_logits = out[:B]
-            if self._fault is not None and self.audit.sampled:
-                audit_logits = self._fault.corrupt_logits(
-                    self.step_seq, audit_logits)
-            self.audit.observe_program(
-                "decode", np.asarray(stats, np.float32)[:B], (Bb, Wb),
-                logits=audit_logits,
-                inputs={"ids": ids, "pos": poss, "tables": tables,
-                        "lens": lens, "slot_blocks": slot_blocks,
-                        "slot_offsets": slot_offsets},
-                pre_pools=pre_pools,
-                requests=[{"id": str(r.request_id),
-                           "greedy": r.sampling.temperature == 0.0}
-                          for r in reqs])
-        result = {}
-        for i, r in enumerate(reqs):
-            self.kv.commit(r.request_id, 1)
-            tok = int(toks[i])
-            self._emit_device(r, tok)
-            result[r.request_id] = tok
+                              requests=rids,
+                              traces=tuple(r.trace_id for r in reqs)):
+            toks, out, stats, dt = self._launch(
+                "decode", (Bb, Wb), self._jit_decode,
+                (ids, poss, tables, lens, slot_blocks, slot_offsets,
+                 *pack.arrays()), rows=B)
+        with phase("engine.emit", prof, rows=B):
+            # token/row accounting only: scheduled = B real rows (one
+            # token each) vs the Bb row bucket — this is the axis the
+            # scheduler's tokens_planned ledger counts, so the invariant
+            # stays exact.  Width-bucket padding (tables padded `width`
+            # -> Wb with null pages) is NOT in these counters; it rides
+            # the record as the table_width attr next to the bucket shape.
+            self.stepprof.record_program(
+                "decode", (Bb, Wb), scheduled=B, capacity=Bb, wall_s=dt,
+                table_width=width, requests=rids)
+            if self.audit.enabled:
+                # sentinel over the REAL rows (pad rows attend the null
+                # page — their logits are not part of the serving
+                # contract), plus the shadow re-execution when this step
+                # is sampled.  kernel_corrupt (ISSUE 12) corrupts ONLY
+                # this audit copy — the emitted tokens were sampled on
+                # the device from the untouched logits, so served tokens
+                # stay correct while the divergence net trips.  Only
+                # SAMPLED steps run the shadow compare, so the
+                # exactly-once plan entry must not be consumed by a
+                # launch the oracle never checks.
+                audit_logits = out[:B]
+                if self._fault is not None and self.audit.sampled:
+                    audit_logits = self._fault.corrupt_logits(
+                        self.step_seq, audit_logits)
+                self.audit.observe_program(
+                    "decode", stats[:B], (Bb, Wb),
+                    logits=audit_logits,
+                    inputs={"ids": ids, "pos": poss, "tables": tables,
+                            "lens": lens, "slot_blocks": slot_blocks,
+                            "slot_offsets": slot_offsets},
+                    pre_pools=pre_pools,
+                    requests=[{"id": str(r.request_id),
+                               "greedy": r.sampling.temperature == 0.0}
+                              for r in reqs])
+            result = {}
+            for i, r in enumerate(reqs):
+                self.kv.commit(r.request_id, 1)
+                tok = int(toks[i])
+                self._emit_device(r, tok)
+                result[r.request_id] = tok
         return result
 
     def _burst_exec(self, reqs: List[Request],
@@ -1273,106 +1309,103 @@ class EngineCore:
         (stream cursor, lifecycle decode_token events, ITL aggregates),
         KV commit of what was actually written, and truncation of the
         unused pre-allocated tail."""
+        phase, prof = self.tracer.phase, self.stepprof
         B = len(reqs)
-        Bb = bucket_size(B)
-        Nb = bucket_size(n_steps)
-        W = self._burst_width
-        starts: Dict[object, int] = {}
-        for r in reqs:
-            rid = r.request_id
-            starts[rid] = self.kv.seq_len(rid)
-            # positions p..p+n-1 all get slots up front (the decode slot
-            # reservation already covers p); exact need is <= the
-            # conservative per-row bound burst_capacity promised, so
-            # failure here means the shared accessor broke — fail loudly
-            if not self.kv.allocate(rid, n_steps, cause="burst"):
-                raise PoolExhausted(
-                    f"burst pre-allocation failed for {rid!r}: "
-                    f"burst_capacity promised {n_steps} steps "
-                    f"x {B} rows")
-        ids = np.zeros((Bb, 1), np.int64)
-        poss = np.zeros((Bb,), np.int32)
-        tables = np.zeros((Bb, W), np.int32)
-        lens = np.ones((Bb,), np.int32)   # pad rows: 1 token of null page
-        slot_blocks = np.zeros((Bb, Nb), np.int32)
-        slot_offsets = np.zeros((Bb, Nb), np.int32)
-        active = np.zeros((Bb,), np.bool_)
-        eos_ids = np.full((Bb,), -1, np.int32)
-        pack = SamplingPack(Bb)
-        bs = self.block_size
-        for i, r in enumerate(reqs):
-            rid = r.request_id
-            t = self.kv.table(rid)
-            p = starts[rid]
-            ids[i, 0] = r.last_token
-            poss[i] = p
-            tables[i, :len(t)] = t
-            lens[i] = p + 1
-            for j in range(n_steps):
-                q = p + j
-                slot_blocks[i, j] = t[q // bs]
-                slot_offsets[i, j] = q % bs
-            active[i] = True
-            if r.sampling.eos_token_id is not None:
-                eos_ids[i] = int(r.sampling.eos_token_id)
-            pack.set_request(i, r)
-        self.burst_buckets.add(("burst", Bb, Nb))
-        traces0 = self.burst_trace_count
+        with phase("engine.build", prof, rows=B):
+            Bb = bucket_size(B)
+            Nb = bucket_size(n_steps)
+            W = self._burst_width
+            starts: Dict[object, int] = {}
+            for r in reqs:
+                rid = r.request_id
+                starts[rid] = self.kv.seq_len(rid)
+                # positions p..p+n-1 all get slots up front (the decode
+                # slot reservation already covers p); exact need is <=
+                # the conservative per-row bound burst_capacity promised,
+                # so failure here means the shared accessor broke — fail
+                # loudly
+                if not self.kv.allocate(rid, n_steps, cause="burst"):
+                    raise PoolExhausted(
+                        f"burst pre-allocation failed for {rid!r}: "
+                        f"burst_capacity promised {n_steps} steps "
+                        f"x {B} rows")
+            ids = np.zeros((Bb, 1), np.int64)
+            poss = np.zeros((Bb,), np.int32)
+            tables = np.zeros((Bb, W), np.int32)
+            lens = np.ones((Bb,), np.int32)  # pad rows: 1 token of null page
+            slot_blocks = np.zeros((Bb, Nb), np.int32)
+            slot_offsets = np.zeros((Bb, Nb), np.int32)
+            active = np.zeros((Bb,), np.bool_)
+            eos_ids = np.full((Bb,), -1, np.int32)
+            pack = SamplingPack(Bb)
+            bs = self.block_size
+            for i, r in enumerate(reqs):
+                rid = r.request_id
+                t = self.kv.table(rid)
+                p = starts[rid]
+                ids[i, 0] = r.last_token
+                poss[i] = p
+                tables[i, :len(t)] = t
+                lens[i] = p + 1
+                for j in range(n_steps):
+                    q = p + j
+                    slot_blocks[i, j] = t[q // bs]
+                    slot_offsets[i, j] = q % bs
+                active[i] = True
+                if r.sampling.eos_token_id is not None:
+                    eos_ids[i] = int(r.sampling.eos_token_id)
+                pack.set_request(i, r)
+            self.burst_buckets.add(("burst", Bb, Nb))
+            rids = tuple(r.request_id for r in reqs)
         with self.tracer.span("burst_step", cat="serving", batch=B,
                               batch_bucket=Bb, burst_len=n_steps,
-                              burst_bucket=Nb,
-                              requests=",".join(str(r.request_id)
-                                                for r in reqs),
-                              traces=",".join(str(r.trace_id)
-                                              for r in reqs)):
-            with StepTimer(self.metrics, "burst_step",
-                           self._collective_phase("burst")) as st:
-                buf, _out, _stats, self._k_pools, self._v_pools = \
-                    self._step_call(
-                        "burst", (Bb, Nb), self._jit_burst,
-                        self._param_vals(), self._k_pools, self._v_pools,
-                        ids, poss, tables, lens, slot_blocks,
-                        slot_offsets, np.int32(n_steps), active,
-                        eos_ids, *pack.arrays())
-                buf = np.asarray(buf, np.int32)
-        if self.burst_trace_count > traces0:
-            self.stepprof.record_compile("burst", (Bb, Nb), st.dt)
-        result = {}
-        emitted_total = 0
-        for i, r in enumerate(reqs):
-            rid = r.request_id
-            e = 0
-            for j in range(n_steps):
-                tok = int(buf[i, j])
-                if tok < 0:   # -1 sentinel: row went inactive (EOS)
-                    break
-                self._emit_device(r, tok)
-                result[rid] = tok
-                e += 1
-                if r.finished:
-                    break
-            emitted_total += e
-            # iteration j wrote the KV of its input token at p+j, so e
-            # emissions committed e positions — identical to e per-step
-            # decode commits; unfinished rows hand back the unused
-            # pre-allocated tail (finished rows free wholesale in retire)
-            self.kv.commit(rid, e)
-            if not r.finished:
-                self.kv.truncate(rid, starts[rid] + e)
-        # scheduled-token ledger (ISSUE 9): the scheduler planned one
-        # decode token per row; the burst's extra emissions are decode
-        # work the ENGINE added — mirror them into the ledger so the
-        # EXACT invariant (profiler scheduled == scheduler planned)
-        # holds when one launch covers N steps
-        self.scheduler.tokens_planned_decode += emitted_total - B
-        self.stepprof.record_program(
-            "burst", (Bb, Nb), scheduled=emitted_total, capacity=Bb * Nb,
-            wall_s=st.dt, burst_len=n_steps,
-            requests=",".join(str(r.request_id) for r in reqs))
-        c = self._burst_counters
-        c["launches"].inc()
-        c["tokens"].inc(emitted_total)
-        c["length"].observe(float(n_steps))
+                              burst_bucket=Nb, requests=rids,
+                              traces=tuple(r.trace_id for r in reqs)):
+            # only the [Bb, Nb] token buffer crosses to the host: a
+            # burst never fetched its logits, and still does not
+            buf, _out, _stats, dt = self._launch(
+                "burst", (Bb, Nb), self._jit_burst,
+                (ids, poss, tables, lens, slot_blocks, slot_offsets,
+                 np.int32(n_steps), active, eos_ids, *pack.arrays()),
+                rows=B, fetch_logits=False)
+        with phase("engine.emit", prof, rows=B):
+            result = {}
+            emitted_total = 0
+            for i, r in enumerate(reqs):
+                rid = r.request_id
+                e = 0
+                for j in range(n_steps):
+                    tok = int(buf[i, j])
+                    if tok < 0:   # -1 sentinel: row went inactive (EOS)
+                        break
+                    self._emit_device(r, tok)
+                    result[rid] = tok
+                    e += 1
+                    if r.finished:
+                        break
+                emitted_total += e
+                # iteration j wrote the KV of its input token at p+j, so
+                # e emissions committed e positions — identical to e
+                # per-step decode commits; unfinished rows hand back the
+                # unused pre-allocated tail (finished rows free wholesale
+                # in retire)
+                self.kv.commit(rid, e)
+                if not r.finished:
+                    self.kv.truncate(rid, starts[rid] + e)
+            # scheduled-token ledger (ISSUE 9): the scheduler planned one
+            # decode token per row; the burst's extra emissions are
+            # decode work the ENGINE added — mirror them into the ledger
+            # so the EXACT invariant (profiler scheduled == scheduler
+            # planned) holds when one launch covers N steps
+            self.scheduler.tokens_planned_decode += emitted_total - B
+            self.stepprof.record_program(
+                "burst", (Bb, Nb), scheduled=emitted_total,
+                capacity=Bb * Nb, wall_s=dt, burst_len=n_steps,
+                requests=rids)
+            c = self._burst_counters
+            c["launches"].inc()
+            c["tokens"].inc(emitted_total)
+            c["length"].observe(float(n_steps))
         return result
 
     def _unified_exec(self, prefills: List[Request],
@@ -1399,195 +1432,188 @@ class EngineCore:
         accepted position rolls back via :meth:`KVCacheManager.truncate`
         (the preemption-recompute slot discipline, pointed at a length
         instead of zero)."""
-        rows: List[Dict] = []
-        t0 = time.perf_counter()
-        for r in decodes:
-            p = self.kv.seq_len(r.request_id)
-            rows.append({"req": r, "kind": "decode", "start": p, "n": 1,
-                         "tokens": [r.last_token], "slot": r._slot})
-        drafts_packed = 0
-        if self.spec is not None and draft_budget > 0:
-            # upgrade decode rows to verify rows in-place (proposer +
-            # draft-slot allocation; a row whose slots cannot be covered
-            # stays a plain decode row — pool pressure, not an error)
-            drafts_packed = self.spec.plan_drafts(self.kv, rows,
-                                                  draft_budget)
-            if drafts_packed:
-                # keep the scheduled-token ledger exact (ISSUE 9): the
-                # scheduler planned 1 token per decode row; the drafts
-                # the engine packs on top are decode-side work too
-                self.scheduler.tokens_planned_decode += drafts_packed
-        for req in prefills:
-            # the SAME pre-launch bookkeeping the legacy programs run
-            # (queue-wait, recompute accounting, all-or-nothing allocate)
-            ids_full, target, start, n, recompute = \
-                self._begin_prefill_chunk(req, t0)
-            rows.append({"req": req, "kind": "chunk", "start": start,
-                         "n": n, "tokens": ids_full[start:start + n],
-                         "target": target, "recompute": recompute,
-                         "ids_full": ids_full})
-        R = len(rows)
-        T = sum(row["n"] for row in rows)
-        Tb = bucket_size(T)
-        width = max(len(self.kv.table(row["req"].request_id))
-                    for row in rows)
-        TWb = bucket_size(width)
-        ids = np.zeros((1, Tb), np.int64)
-        pos = np.zeros((1, Tb), np.int32)
-        # pad tokens route to a pad row (all-null table, kv_len 1); when
-        # R == Tb every row is real and no pad token exists
-        seg = np.full((Tb,), min(R, Tb - 1), np.int32)
-        last_idx = np.zeros((Tb,), np.int32)
-        tables = np.zeros((Tb, TWb), np.int32)
-        lens = np.ones((Tb,), np.int32)   # pad rows: 1 token of null page
-        slot_blocks = np.zeros((Tb,), np.int32)  # pad tokens -> null page
-        slot_offsets = np.zeros((Tb,), np.int32)
-        # per-TOKEN sampling quartet (ISSUE 18): pad positions stay
-        # temp=0 (argmax over the null page, discarded); a verify row's
-        # k+1 positions each carry their own output-position draw index
-        pack = SamplingPack(Tb)
-        cursor = 0
-        for i, row in enumerate(rows):
-            req = row["req"]
-            table = self.kv.table(req.request_id)
-            n, start = row["n"], row["start"]
-            row["cursor"] = cursor
-            ids[0, cursor:cursor + n] = row["tokens"]
-            pp = np.arange(start, start + n)
-            pos[0, cursor:cursor + n] = pp
-            seg[cursor:cursor + n] = i
-            tables[i, :len(table)] = table
-            lens[i] = start + n           # cache length AFTER this step
-            if row["kind"] == "decode":
-                slot_blocks[cursor], slot_offsets[cursor] = row["slot"]
-                pack.set_request(cursor, req)
-            else:
-                # chunk AND verify rows: every position scatters into its
-                # own table-derived slot (a verify row's draft slots were
-                # just allocated by spec.plan_drafts, so its table covers
-                # start+n like any mid-prefill chunk's does)
-                slot_blocks[cursor:cursor + n] = [
-                    table[x // self.block_size] for x in pp]
-                slot_offsets[cursor:cursor + n] = pp % self.block_size
-                if row["kind"] == "verify":
-                    for j in range(n):
-                        pack.set_request(cursor + j, req, offset=j)
+        phase, prof = self.tracer.phase, self.stepprof
+        with phase("engine.build", prof,
+                   rows=len(prefills) + len(decodes)):
+            rows: List[Dict] = []
+            t0 = time.perf_counter()
+            for r in decodes:
+                p = self.kv.seq_len(r.request_id)
+                rows.append({"req": r, "kind": "decode", "start": p, "n": 1,
+                             "tokens": [r.last_token], "slot": r._slot})
+            drafts_packed = 0
+            if self.spec is not None and draft_budget > 0:
+                # upgrade decode rows to verify rows in-place (proposer +
+                # draft-slot allocation; a row whose slots cannot be covered
+                # stays a plain decode row — pool pressure, not an error)
+                drafts_packed = self.spec.plan_drafts(self.kv, rows,
+                                                      draft_budget)
+                if drafts_packed:
+                    # keep the scheduled-token ledger exact (ISSUE 9): the
+                    # scheduler planned 1 token per decode row; the drafts
+                    # the engine packs on top are decode-side work too
+                    self.scheduler.tokens_planned_decode += drafts_packed
+            for req in prefills:
+                # the SAME pre-launch bookkeeping the legacy programs run
+                # (queue-wait, recompute accounting, all-or-nothing allocate)
+                ids_full, target, start, n, recompute = \
+                    self._begin_prefill_chunk(req, t0)
+                rows.append({"req": req, "kind": "chunk", "start": start,
+                             "n": n, "tokens": ids_full[start:start + n],
+                             "target": target, "recompute": recompute,
+                             "ids_full": ids_full})
+            R = len(rows)
+            T = sum(row["n"] for row in rows)
+            Tb = bucket_size(T)
+            width = max(len(self.kv.table(row["req"].request_id))
+                        for row in rows)
+            TWb = bucket_size(width)
+            ids = np.zeros((1, Tb), np.int64)
+            pos = np.zeros((1, Tb), np.int32)
+            # pad tokens route to a pad row (all-null table, kv_len 1); when
+            # R == Tb every row is real and no pad token exists
+            seg = np.full((Tb,), min(R, Tb - 1), np.int32)
+            last_idx = np.zeros((Tb,), np.int32)
+            tables = np.zeros((Tb, TWb), np.int32)
+            lens = np.ones((Tb,), np.int32)   # pad rows: 1 token of null page
+            slot_blocks = np.zeros((Tb,), np.int32)  # pad tokens -> null page
+            slot_offsets = np.zeros((Tb,), np.int32)
+            # per-TOKEN sampling quartet (ISSUE 18): pad positions stay
+            # temp=0 (argmax over the null page, discarded); a verify row's
+            # k+1 positions each carry their own output-position draw index
+            pack = SamplingPack(Tb)
+            cursor = 0
+            for i, row in enumerate(rows):
+                req = row["req"]
+                table = self.kv.table(req.request_id)
+                n, start = row["n"], row["start"]
+                row["cursor"] = cursor
+                ids[0, cursor:cursor + n] = row["tokens"]
+                pp = np.arange(start, start + n)
+                pos[0, cursor:cursor + n] = pp
+                seg[cursor:cursor + n] = i
+                tables[i, :len(table)] = table
+                lens[i] = start + n           # cache length AFTER this step
+                if row["kind"] == "decode":
+                    slot_blocks[cursor], slot_offsets[cursor] = row["slot"]
+                    pack.set_request(cursor, req)
                 else:
-                    # only the final chunk's last position is ever read
-                    pack.set_request(cursor + n - 1, req)
-            cursor += n
-            last_idx[i] = cursor - 1
-        self.ragged_buckets.add(("ragged", Tb, TWb))
-        self.metrics.count("unified_steps")
-        traces0 = self.ragged_trace_count
-        pre_pools = self.audit.snapshot_pools(self._k_pools,
-                                              self._v_pools)
+                    # chunk AND verify rows: every position scatters into its
+                    # own table-derived slot (a verify row's draft slots were
+                    # just allocated by spec.plan_drafts, so its table covers
+                    # start+n like any mid-prefill chunk's does)
+                    slot_blocks[cursor:cursor + n] = [
+                        table[x // self.block_size] for x in pp]
+                    slot_offsets[cursor:cursor + n] = pp % self.block_size
+                    if row["kind"] == "verify":
+                        for j in range(n):
+                            pack.set_request(cursor + j, req, offset=j)
+                    else:
+                        # only the final chunk's last position is ever read
+                        pack.set_request(cursor + n - 1, req)
+                cursor += n
+                last_idx[i] = cursor - 1
+            self.ragged_buckets.add(("ragged", Tb, TWb))
+            self.metrics.count("unified_steps")
+            pre_pools = self.audit.snapshot_pools(self._k_pools,
+                                                  self._v_pools)
+            rids = tuple(row["req"].request_id for row in rows)
         with self.tracer.span("unified_step", cat="serving", tokens=T,
                               rows=R, token_bucket=Tb, table_bucket=TWb,
-                              requests=",".join(
-                                  str(row["req"].request_id)
-                                  for row in rows)):
-            with StepTimer(self.metrics, "unified_step",
-                           self._collective_phase("ragged")) as st:
-                toks, out, stats, self._k_pools, self._v_pools = \
-                    self._step_call(
-                        "ragged", (Tb, TWb), self._jit_unified,
-                        self._param_vals(), self._k_pools, self._v_pools,
-                        ids, pos, seg, last_idx, tables, lens,
-                        slot_blocks, slot_offsets, *pack.arrays())
-                out = np.asarray(out, np.float32)
-                toks = np.asarray(toks, np.int32)
-        if self.ragged_trace_count > traces0:
-            self.stepprof.record_compile("ragged", (Tb, TWb), st.dt)
-        # scheduled = T real tokens (decode rows count 1 each) vs the Tb
-        # token bucket — the same axis the scheduler's tokens_planned
-        # ledger counts, so the PR 8 invariant stays exact in unified
-        # mode.  Table-width padding rides the record as attrs.
-        self.stepprof.record_program(
-            "ragged", (Tb, TWb), scheduled=T, capacity=Tb, wall_s=st.dt,
-            rows=R, table_width=width,
-            requests=",".join(str(row["req"].request_id) for row in rows))
-        if self.audit.enabled:
-            # sentinel over the REAL rows; the shadow oracle re-executes
-            # sampled packed steps through the independently jitted XLA
-            # ragged reference (audit._reference_ragged).  kernel_corrupt
-            # corrupts only this audit copy, on sampled steps only — see
-            # _decode.
-            audit_logits = out[:R]
-            if self._fault is not None and self.audit.sampled:
-                audit_logits = self._fault.corrupt_logits(
-                    self.step_seq, audit_logits)
-            self.audit.observe_program(
-                "ragged", np.asarray(stats, np.float32)[:R], (Tb, TWb),
-                logits=audit_logits,
-                inputs={"ids": ids, "pos": pos, "seg_ids": seg,
-                        "last_idx": last_idx, "tables": tables,
-                        "lens": lens, "slot_blocks": slot_blocks,
-                        "slot_offsets": slot_offsets},
-                pre_pools=pre_pools,
-                requests=[{"id": str(row["req"].request_id),
-                           "greedy":
-                           row["req"].sampling.temperature == 0.0}
-                          for row in rows])
-        emitted: Dict[object, int] = {}
-        for i, row in enumerate(rows):
-            req = row["req"]
-            rid = req.request_id
-            n, start = row["n"], row["start"]
-            c0 = row["cursor"]
-            if row["kind"] == "decode":
-                self.kv.commit(rid, 1)
-                tok = int(toks[c0])
-                self._emit_device(req, tok)
-                emitted[rid] = tok
-                continue
-            if row["kind"] == "verify":
-                # spec accept/rollback (ISSUE 18): position j's target
-                # T_j = toks[c0+j] is exactly the token the plain decode
-                # path would have sampled at that output position (same
-                # logits prefix, same (seed, draw) key) — so exact-match
-                # acceptance keeps spec-on token-identical to spec-off
-                # for greedy AND seeded sampling
-                drafts = row["drafts"]
-                accepted = 0
-                for j, d in enumerate(drafts):
-                    if int(toks[c0 + j]) == int(d):
-                        accepted += 1
-                    else:
-                        break
-                emitted_n = 0
-                for j in range(accepted + 1):
-                    self._emit_device(req, int(toks[c0 + j]))
-                    emitted[rid] = int(toks[c0 + j])
-                    emitted_n += 1
-                    if req.finished:
-                        break  # eos/length mid-run: later targets are
-                        # tokens the plain path would never have drawn
-                # KV valid prefix: the emitted tokens' consumed inputs
-                # (last_token + the accepted drafts actually consumed) —
-                # the newest emitted token's KV is, as ever, written by
-                # the step that consumes it
-                self.kv.commit(rid, emitted_n)
-                if not req.finished:
-                    # roll back the rejected/unconsumed draft tail (the
-                    # preemption-recompute slot discipline, aimed at a
-                    # length): surplus freshly-allocated blocks go back
-                    # to the free list
-                    self.kv.truncate(rid, start + emitted_n)
-                self.spec.record(len(drafts), accepted)
-                self._lc(rid, "spec_verify", drafted=len(drafts),
-                         accepted=accepted, emitted=emitted_n)
-                continue
-            # the SAME post-launch bookkeeping the legacy programs run
-            # (commit, lifecycle event, counters, hash registration,
-            # completion emission)
-            before = len(req.output_tokens)
-            self._finish_prefill_chunk(req, row["ids_full"],
-                                       row["target"], start, n,
-                                       row["recompute"], t0,
-                                       int(toks[c0 + n - 1]))
-            if len(req.output_tokens) > before:  # prefill completed
-                emitted[rid] = req.output_tokens[-1]
+                              requests=rids):
+            toks, out, stats, dt = self._launch(
+                "ragged", (Tb, TWb), self._jit_unified,
+                (ids, pos, seg, last_idx, tables, lens, slot_blocks,
+                 slot_offsets, *pack.arrays()), rows=R)
+        with phase("engine.emit", prof, rows=R):
+            # scheduled = T real tokens (decode rows count 1 each) vs the Tb
+            # token bucket — the same axis the scheduler's tokens_planned
+            # ledger counts, so the PR 8 invariant stays exact in unified
+            # mode.  Table-width padding rides the record as attrs.
+            self.stepprof.record_program(
+                "ragged", (Tb, TWb), scheduled=T, capacity=Tb, wall_s=dt,
+                rows=R, table_width=width, requests=rids)
+            if self.audit.enabled:
+                # sentinel over the REAL rows; the shadow oracle re-executes
+                # sampled packed steps through the independently jitted XLA
+                # ragged reference (audit._reference_ragged).  kernel_corrupt
+                # corrupts only this audit copy, on sampled steps only — see
+                # _decode.
+                audit_logits = out[:R]
+                if self._fault is not None and self.audit.sampled:
+                    audit_logits = self._fault.corrupt_logits(
+                        self.step_seq, audit_logits)
+                self.audit.observe_program(
+                    "ragged", stats[:R], (Tb, TWb),
+                    logits=audit_logits,
+                    inputs={"ids": ids, "pos": pos, "seg_ids": seg,
+                            "last_idx": last_idx, "tables": tables,
+                            "lens": lens, "slot_blocks": slot_blocks,
+                            "slot_offsets": slot_offsets},
+                    pre_pools=pre_pools,
+                    requests=[{"id": str(row["req"].request_id),
+                               "greedy":
+                               row["req"].sampling.temperature == 0.0}
+                              for row in rows])
+            emitted: Dict[object, int] = {}
+            for i, row in enumerate(rows):
+                req = row["req"]
+                rid = req.request_id
+                n, start = row["n"], row["start"]
+                c0 = row["cursor"]
+                if row["kind"] == "decode":
+                    self.kv.commit(rid, 1)
+                    tok = int(toks[c0])
+                    self._emit_device(req, tok)
+                    emitted[rid] = tok
+                    continue
+                if row["kind"] == "verify":
+                    # spec accept/rollback (ISSUE 18): position j's target
+                    # T_j = toks[c0+j] is exactly the token the plain decode
+                    # path would have sampled at that output position (same
+                    # logits prefix, same (seed, draw) key) — so exact-match
+                    # acceptance keeps spec-on token-identical to spec-off
+                    # for greedy AND seeded sampling
+                    drafts = row["drafts"]
+                    accepted = 0
+                    for j, d in enumerate(drafts):
+                        if int(toks[c0 + j]) == int(d):
+                            accepted += 1
+                        else:
+                            break
+                    emitted_n = 0
+                    for j in range(accepted + 1):
+                        self._emit_device(req, int(toks[c0 + j]))
+                        emitted[rid] = int(toks[c0 + j])
+                        emitted_n += 1
+                        if req.finished:
+                            break  # eos/length mid-run: later targets are
+                            # tokens the plain path would never have drawn
+                    # KV valid prefix: the emitted tokens' consumed inputs
+                    # (last_token + the accepted drafts actually consumed) —
+                    # the newest emitted token's KV is, as ever, written by
+                    # the step that consumes it
+                    self.kv.commit(rid, emitted_n)
+                    if not req.finished:
+                        # roll back the rejected/unconsumed draft tail (the
+                        # preemption-recompute slot discipline, aimed at a
+                        # length): surplus freshly-allocated blocks go back
+                        # to the free list
+                        self.kv.truncate(rid, start + emitted_n)
+                    self.spec.record(len(drafts), accepted)
+                    self._lc(rid, "spec_verify", drafted=len(drafts),
+                             accepted=accepted, emitted=emitted_n)
+                    continue
+                # the SAME post-launch bookkeeping the legacy programs run
+                # (commit, lifecycle event, counters, hash registration,
+                # completion emission)
+                before = len(req.output_tokens)
+                self._finish_prefill_chunk(req, row["ids_full"],
+                                           row["target"], start, n,
+                                           row["recompute"], t0,
+                                           int(toks[c0 + n - 1]))
+                if len(req.output_tokens) > before:  # prefill completed
+                    emitted[rid] = req.output_tokens[-1]
         return emitted
 
     def step(self) -> Dict[object, int]:
@@ -1600,6 +1626,8 @@ class EngineCore:
         self.stepprof.begin_step()
         self.audit.begin_step()
         fi = self._fault
+        phase, prof = self.tracer.phase, self.stepprof
+        trackers = None
         try:
             if fi is not None:
                 # named injection points (ISSUE 12): slow_step sleeps
@@ -1614,68 +1642,70 @@ class EngineCore:
                 if fi is not None and fi.pool_exhausted:
                     self.kv.refuse_allocations = True
                 try:
-                    plan = self.scheduler.schedule()
+                    with phase("sched.plan", prof):
+                        plan = self.scheduler.schedule()
                 finally:
                     # refusal applies to PLANNING only: the launches
                     # below must still allocate the chunks the (starved)
                     # plan actually contains
                     self.kv.refuse_allocations = False
-                self.metrics.count("engine_steps")
-                self.metrics.count("preemptions", len(plan.preempted))
-                for req in plan.preempted:
-                    self.tracer.instant(
-                        "preemption", cat="serving",
-                        request=str(req.request_id), trace=req.trace_id,
-                        generated=len(req.output_tokens))
-                    self._lc(req.request_id, _lc.EV_PREEMPTED,
-                             generated=len(req.output_tokens))
-                for req in plan.aborted:
-                    # unservable at admission: scheduler set state/reason,
-                    # the engine owns finish bookkeeping (timestamp +
-                    # counter)
-                    self._lc(req.request_id, _lc.EV_ADMISSION_REJECTED,
-                             reason="abort", error=req.error)
-                    self._finish(req, FinishReason.ABORT)
-                    self.requests.pop(req.request_id, None)
-                for req in plan.admitted:
-                    cached = req.num_cached_tokens
-                    total = len(req.prompt_ids) + len(req.output_tokens)
-                    self.metrics.count("prefix_cache_hit_tokens", cached)
-                    self.metrics.count("prefix_cache_miss_tokens",
-                                       total - cached)
-                    if req.prompt_cached_tokens is None:
-                        # FIRST admission (output empty, so cached <=
-                        # prompt): the client-facing usage attribution
-                        req.prompt_cached_tokens = cached
-                    # per-request attribution (ISSUE 13): accumulated at
-                    # the SAME points as the counters above, so
-                    # sum(per-request cached) == prefix_cache_hit_tokens
-                    # exactly (asserted in tests and bench)
-                    self.cachestat.record_admission(
-                        req.request_id, cached, total - cached,
-                        len(req.prompt_ids),
-                        recompute=bool(req.output_tokens))
-                    self._lc(req.request_id, _lc.EV_ADMITTED,
-                             cached_tokens=cached,
-                             computed_tokens=total - cached,
-                             recompute=bool(req.output_tokens))
-                    if cached:
+                with phase("engine.admit", prof):
+                    self.metrics.count("engine_steps")
+                    self.metrics.count("preemptions", len(plan.preempted))
+                    for req in plan.preempted:
                         self.tracer.instant(
-                            "prefix_cache_hit", cat="serving",
-                            request=str(req.request_id),
-                            trace=req.trace_id, cached_tokens=cached)
-                    if cached and self.cachestat.enabled:
-                        # prefix-heat (ISSUE 13): keyed by the DEEPEST
-                        # matched block's chain hash — it commits to the
-                        # whole cached prefix.  Guarded: the table copy
-                        # + hash lookup must cost nothing when the
-                        # tracker is disabled.
-                        depth = cached // self.block_size
-                        table = self.kv.table(req.request_id)
-                        self.cachestat.record_prefix_hit(
-                            self.kv.block_chain_hash(table[depth - 1])
-                            if 0 < depth <= len(table) else None,
-                            depth, cached, self.step_seq)
+                            "preemption", cat="serving",
+                            request=str(req.request_id), trace=req.trace_id,
+                            generated=len(req.output_tokens))
+                        self._lc(req.request_id, _lc.EV_PREEMPTED,
+                                 generated=len(req.output_tokens))
+                    for req in plan.aborted:
+                        # unservable at admission: scheduler set state/reason,
+                        # the engine owns finish bookkeeping (timestamp +
+                        # counter)
+                        self._lc(req.request_id, _lc.EV_ADMISSION_REJECTED,
+                                 reason="abort", error=req.error)
+                        self._finish(req, FinishReason.ABORT)
+                        self.requests.pop(req.request_id, None)
+                    for req in plan.admitted:
+                        cached = req.num_cached_tokens
+                        total = len(req.prompt_ids) + len(req.output_tokens)
+                        self.metrics.count("prefix_cache_hit_tokens", cached)
+                        self.metrics.count("prefix_cache_miss_tokens",
+                                           total - cached)
+                        if req.prompt_cached_tokens is None:
+                            # FIRST admission (output empty, so cached <=
+                            # prompt): the client-facing usage attribution
+                            req.prompt_cached_tokens = cached
+                        # per-request attribution (ISSUE 13): accumulated at
+                        # the SAME points as the counters above, so
+                        # sum(per-request cached) == prefix_cache_hit_tokens
+                        # exactly (asserted in tests and bench)
+                        self.cachestat.record_admission(
+                            req.request_id, cached, total - cached,
+                            len(req.prompt_ids),
+                            recompute=bool(req.output_tokens))
+                        self._lc(req.request_id, _lc.EV_ADMITTED,
+                                 cached_tokens=cached,
+                                 computed_tokens=total - cached,
+                                 recompute=bool(req.output_tokens))
+                        if cached:
+                            self.tracer.instant(
+                                "prefix_cache_hit", cat="serving",
+                                request=str(req.request_id),
+                                trace=req.trace_id, cached_tokens=cached)
+                        if cached and self.cachestat.enabled:
+                            # prefix-heat (ISSUE 13): keyed by the DEEPEST
+                            # matched block's chain hash — it commits to the
+                            # whole cached prefix.  Guarded: the table copy
+                            # + hash lookup must cost nothing when the
+                            # tracker is disabled.
+                            depth = cached // self.block_size
+                            table = self.kv.table(req.request_id)
+                            self.cachestat.record_prefix_hit(
+                                self.kv.block_chain_hash(table[depth - 1])
+                                if 0 < depth <= len(table) else None,
+                                depth, cached, self.step_seq)
                 emitted: Dict[object, int] = {}
                 decodes = [r for r in plan.decodes
                            if r.state is RequestState.RUNNING]
@@ -1710,9 +1740,14 @@ class EngineCore:
                             emitted[req.request_id] = req.output_tokens[-1]
                     if decodes:
                         emitted.update(self._decode(decodes))
-                for req in list(self.scheduler.running):
-                    if req.finished:
-                        self._retire(req)
+                with phase("engine.emit", prof):
+                    for req in list(self.scheduler.running):
+                        if req.finished:
+                            self._retire(req)
+                # the end-of-step trackers, one phase from here to the
+                # end of ``stepprof.end_step`` in the ``finally`` below
+                trackers = phase("engine.trackers", prof)
+                trackers.__enter__()
                 # (prefix-cache evictions are event-driven now: the
                 # pool's on_evict hook fires the counter, the lifecycle
                 # event and the cause/depth series at the eviction;
@@ -1742,6 +1777,8 @@ class EngineCore:
             # runs on the death path too: the partial step record still
             # reaches the last-K ring the flight bundle embeds
             self.stepprof.end_step()
+            if trackers is not None:
+                trackers.__exit__(None, None, None)
             remove_timer()
 
     def run(self, max_steps: Optional[int] = None) -> None:

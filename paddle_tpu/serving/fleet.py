@@ -487,12 +487,17 @@ class EngineReplica:
     # --- engine thread ------------------------------------------------------
     def _loop(self) -> None:
         eng = self.engine
+        # the step's phases that happen on this thread OUTSIDE
+        # ``eng.step()`` (observability.tracer.STEP_PHASES): taking
+        # requests in, handing tokens to the streams, waiting for work
+        phase, prof = eng.tracer.phase, eng.stepprof
         try:
             while True:
-                self._drain_submissions()
-                self._drain_aborts()
-                self._drain_tasks()
-                self._evict_finished()
+                with phase("engine.intake", prof):
+                    self._drain_submissions()
+                    self._drain_aborts()
+                    self._drain_tasks()
+                    self._evict_finished()
                 if self._stop and not eng.scheduler.has_work():
                     break
                 if eng.scheduler.has_work():
@@ -508,10 +513,12 @@ class EngineReplica:
                     else:
                         eng.step()
                     self.steps_done += 1
-                    self._notify()
+                    with phase("engine.emit", prof):
+                        self._notify()
                 else:
-                    self.wake.wait(timeout=0.02)
-                    self.wake.clear()
+                    with phase("engine.wait", prof):
+                        self.wake.wait(timeout=0.02)
+                        self.wake.clear()
         except Exception:
             # fail loudly but leave no handler hanging and no block held
             err = traceback.format_exc()

@@ -445,6 +445,9 @@ class _StepProfProxy:
         self._p = proxy
         self.enabled = bool(proxy.engine_config.step_profile)
         self.max_capture_steps = 512  # advertised bound; arm refuses
+        # no capture window on this side of the wire: the replica
+        # loop's phases are profiler annotations and nothing else
+        self.phase_sink = None
 
     def records(self) -> List[Dict]:
         data = self._p.debug_fetch("records", [])

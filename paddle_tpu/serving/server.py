@@ -910,7 +910,13 @@ class CompletionServer:
             # owned by arm_capture, never duplicated here
             steps = self._debug_int(params, "steps", 32, 1,
                                     sp.max_capture_steps)
-            window = sp.arm_capture(steps)
+            # in an executor: on a real device arming starts the JAX
+            # profiler, which takes seconds — the event loop (every
+            # stream, every new request) must not wait for it, nor
+            # does the engine (arm_capture starts it outside the lock
+            # the step takes)
+            window = await self._loop.run_in_executor(
+                None, sp.arm_capture, steps)
         except CaptureBusy as e:
             await self._respond(writer, 409, error_body(
                 str(e), "conflict"), keep_alive=keep_alive)

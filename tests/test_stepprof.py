@@ -312,9 +312,14 @@ class TestCaptureWindow:
         loaded = load_profiler_result(str(path))
         assert len(loaded.find("engine_step")) == 5
         roots = [r for r in loaded.roots if r.name == "engine_step"]
+        # a step's children: its program launches, and since ISSUE 25
+        # its phases (tests/test_zzzzzzzzzzzz_step_phases.py orders them)
+        from paddle_tpu.observability.tracer import STEP_PHASES
         assert roots and all(
-            c.name in ("prefill", "chunk", "decode")
+            c.name in ("prefill", "chunk", "decode") + STEP_PHASES
             for r in roots for c in r.children)
+        assert all(any(c.name in ("prefill", "chunk", "decode")
+                       for c in r.children) for r in roots)
 
     def test_capture_excludes_steps_outside_window(self):
         eng = _engine()

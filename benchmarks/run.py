@@ -31,7 +31,7 @@ import threading    # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
-from benchmarks import harness, roofline, stats     # noqa: E402
+from benchmarks import harness, records, roofline, stats   # noqa: E402
 
 READY_TIMEOUT_S = 1150.0    # a first run compiles; the contract allows 1200
 LATE_WARN_MS = 250.0        # every run but one sent within 22 ms (PERF.md)
@@ -116,7 +116,7 @@ class RunEnv:
 def client_counters(run: dict) -> dict:
     win = stats.counted(run["timelines"])
     late = [(t["sent"] - t["due"]) * 1e3 for t in win]
-    ttft = [v for v in (stats.ttft_ms(t) for t in win) if v is not None]
+    ttft = stats.ttfts_ms(win)
     gaps = [g for t in win for g in stats.token_gaps_ms(t)]
     tpot = [v for v in (stats.tpot_ms(t) for t in win) if v is not None]
     shape = {f"itl_p{q}_ms": stats.percentile(gaps, q)
@@ -133,7 +133,7 @@ def client_counters(run: dict) -> dict:
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              root: str = harness.ROOT, platform: str = "tpu",
-             out=sys.stdout) -> int:
+             out=sys.stdout, records_path: str = "") -> int:
     cell = harness.Cell(workload, root)
     mix = cell.traffic
     trace_s = float(mix["trace_s"]) if trace else 0.0
@@ -155,6 +155,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     finally:
         child.stop()
 
+    if records_path:
+        records.write(records_path, run, {"workload": workload, "seed": seed,
+                                          "seconds": seconds})
     ended = [t for t in run["timelines"] if t["end"] is not None
              and (mix["kind"] != "open_loop" or t["section"] == "window")]
     failed = [t for t in ended if not t["ok"]]
@@ -219,10 +222,14 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, required=True)
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    p.add_argument("--records", default="", metavar="FILE",
+                   help="also write the window's per-request records "
+                        "(benchmarks/records.py) to FILE; off by default")
     args = p.parse_args(argv)
     # the command line measures on the TPU and nowhere else: there is no
     # option that makes it fall back to another platform
-    return run_cell(args.workload, args.seed, args.seconds, bool(args.trace))
+    return run_cell(args.workload, args.seed, args.seconds, bool(args.trace),
+                    records_path=args.records)
 
 
 if __name__ == "__main__":

@@ -13,6 +13,7 @@ one entry per streamed event that carried tokens.
 from __future__ import annotations
 
 import math
+import statistics
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 
@@ -40,6 +41,12 @@ def ttft_ms(t: Dict) -> Optional[float]:
     if not t["chunks"]:
         return None
     return (t["chunks"][0][0] - t["due"]) * 1e3
+
+
+def ttfts_ms(timelines: Iterable[Dict]) -> List[float]:
+    """TTFTs of the requests due in the window that came back whole."""
+    vals = (ttft_ms(t) for t in counted(timelines))
+    return [v for v in vals if v is not None]
 
 
 def tpot_ms(t: Dict) -> Optional[float]:
@@ -87,10 +94,30 @@ def tokens_in_window(timelines: Iterable[Dict], t_open: float,
 def iqr_share(values: Sequence[float]) -> Optional[float]:
     """Distance between first and third quartile over the median, as the
     driver takes it (``statistics.quantiles(values, n=4)``)."""
-    import statistics
-
     if len(values) < 2:
         return None
     q1, _, q3 = statistics.quantiles(values, n=4)
     med = statistics.median(values)
     return (q3 - q1) / med if med else None
+
+
+def trimmed_range_share(values: Sequence[float]) -> Optional[float]:
+    """The range of the runs less the one run farthest from their median,
+    over the median: the spread the driver's pair check quotes ("leaves out
+    the run farthest from its median")."""
+    if len(values) < 3:
+        return None
+    med = statistics.median(values)
+    far = max(range(len(values)), key=lambda i: abs(values[i] - med))
+    rest = [v for i, v in enumerate(values) if i != far]
+    return (max(rest) - min(rest)) / med if med else None
+
+
+def meets_rule(sets: Sequence[Sequence[float]], bound: float) -> bool:
+    """PR 27's rule: a metric may stand under ``bound`` only if the trimmed
+    range of EVERY set is at most half of it and the sets' medians lie
+    within half of it of each other."""
+    meds = [statistics.median(s) for s in sets]
+    apart = (max(meds) - min(meds)) / statistics.median(meds)
+    return (all(trimmed_range_share(s) <= 0.5 * bound for s in sets)
+            and apart <= 0.5 * bound)

@@ -12,7 +12,7 @@ import sys
 
 import pytest
 
-from benchmarks import harness
+from benchmarks import harness, records
 
 BENCH = harness.load_json(harness.ROOT, "BENCHMARK.json")
 TINY = {"source": "test", "hidden_size": 64, "intermediate_size": 128,
@@ -289,7 +289,7 @@ def test_a_later_pr_adds_a_cell_by_adding_files_only(tmp_path):
             m["workloads"].append(name)
     bench["per_layer"].append({"name": "dummy.prompt_tokens", "unit": "tokens",
                                "better": "higher", "source": "program_counter",
-                               "layer": "scheduler", "moves": "ttft_p90_ms",
+                               "layer": "scheduler", "moves": "itl_p995_ms",
                                "workloads": [name]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
@@ -298,15 +298,25 @@ def test_a_later_pr_adds_a_cell_by_adding_files_only(tmp_path):
     for trace in (False, True):
         out = io.StringIO()
         rc = run.run_cell(name, 3_000_000_019, 4.0, trace, root=root,
-                          platform="cpu", out=out)
+                          platform="cpu", out=out, records_path=""
+                          if trace else os.path.join(root, "rec", "r.json.gz"))
         assert rc == 0
         lines[trace] = json.loads(out.getvalue().strip().splitlines()[-1])
     e2e, layer = lines[False], lines[True]
     assert e2e["correct"] and e2e["failed"] == 0 and e2e["attempted"] == 24
-    assert set(e2e["metrics"]) == {"ttft_p90_ms", "tpot_p50_ms", "itl_p995_ms",
-                                   "setup_s"}
+    assert set(e2e["metrics"]) == {"tpot_p50_ms", "itl_p995_ms", "setup_s"}
     assert all(v["value"] > 0 for v in e2e["metrics"].values())
     assert e2e["device"]["platform"] == "cpu"      # never a device metric
+    # the window's records, written on request, give the same metrics back
+    rec = records.load(records.read(os.path.join(root, "rec", "r.json.gz")))
+    for m in ("tpot_p50_ms", "itl_p995_ms"):
+        again = harness.load_module("e2e_metrics", m).compute(rec)
+        assert again == pytest.approx(e2e["metrics"][m]["value"], abs=2e-3)
+    # the TTFT tail is per-layer since PR 27: in the traced line, and among
+    # what the client prints in both
+    assert layer["metrics"]["frontdoor.ttft_p90_ms"]["value"] == \
+        layer["detail"]["client"]["ttft_p90_ms"] > 0
+    assert e2e["detail"]["client"]["ttft_p90_ms"] > 0
     assert layer["metrics"]["dummy.prompt_tokens"]["value"] > 0
     assert layer["metrics"]["programs.compiles_in_window.chat"]["value"] == 0
     assert 0 < layer["metrics"]["cache.pool_peak_share.chat"]["value"] <= 100

@@ -100,7 +100,7 @@ def logit_stats(logits):
 
     Pure ``jnp`` — the engine calls this INSIDE its traced step
     programs, so the stats ride the jitted launch as one extra (tiny)
-    output.  Non-finite entries are masked to 0 before the max/top-k so
+    output.  Non-finite entries are masked to 0 before the maxima so
     absmax/margin stay finite; the non-finite count carries the alarm.
     A 1-D ``[vocab]`` row (the prefill programs' last-token logits) is
     treated as one row."""
@@ -108,9 +108,12 @@ def logit_stats(logits):
     import jax.numpy as jnp
 
     # ``logit_stats`` is the name a device trace knows these operations
-    # by (metadata only).  It is worth a name: on the TPU the top-2
-    # lowers to a full-vocabulary sort, in every step program, audit on
-    # or off — as long as the sampler's own (PERF.md, PR 25)
+    # by (metadata only).  The margin is two max-reductions and not a
+    # ``top_k``: the TPU lowers a top-2 to a full-vocabulary sort, and
+    # this runs in every step program, audit on or off — as long as the
+    # sampler's own sort (PERF.md, PRs 25 and 28).  Only the one lane at
+    # the argmax is masked for the second maximum, so a tie at the top
+    # still reads margin 0
     with jax.named_scope("logit_stats"):
         l = logits.astype(jnp.float32)
         if l.ndim == 1:
@@ -119,8 +122,11 @@ def logit_stats(logits):
         nonfinite = jnp.sum(~finite, axis=-1).astype(jnp.float32)
         safe = jnp.where(finite, l, 0.0)
         absmax = jnp.max(jnp.abs(safe), axis=-1)
-        top2 = jax.lax.top_k(safe, 2)[0]
-        margin = top2[:, 0] - top2[:, 1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, safe.shape, 1)
+        top1_lane = jnp.argmax(safe, axis=-1)[:, None]
+        top1 = jnp.max(safe, axis=-1)
+        top2 = jnp.max(jnp.where(lane == top1_lane, -jnp.inf, safe), axis=-1)
+        margin = top1 - top2
         return jnp.stack([nonfinite, absmax, margin], axis=-1)
 
 

@@ -14,6 +14,14 @@ Design constraints the serving layer relies on:
   with ``temperature <= 0`` reduce to a pure argmax, bit-identical to
   the pre-ISSUE-18 host argmax.  One compiled program serves greedy and
   sampled batches — bucket sets and trace counts are unchanged.
+* **A launch pays for the filter pipeline only if one of its rows
+  samples.**  The full-vocabulary sort, the top-k / top-p masks and the
+  Gumbel hash sit in one branch of a ``lax.cond`` on
+  ``any(temperature > 0)``, decided in the trace from the launch's own
+  temperatures: a launch of greedy rows (padding rows are temperature
+  0) runs one argmax over the vocabulary and nothing else, a launch
+  with a sampling row runs the whole pipeline for every row.  Tokens
+  are the same either way, row for row.
 * **Determinism under seed via counter-keyed Gumbel-max.**  The key for
   a draw is the raw u32 pair ``(seed, draw_index)`` (the request's
   output position) — a pure function of request state, NOT of engine
@@ -88,26 +96,11 @@ def make_keys(seed_draws, out=None):
     return keys
 
 
-@jax.named_scope("sampler")
-def sample_tokens(logits, temps, top_ks, top_ps, keys):
-    """Sample one token per row, in-trace.  Everything here runs under
-    the ``sampler`` scope: the name the device trace knows the whole
-    epilogue by (its sort, its masks, its draw), metadata only.
-
-    Args:
-      logits: ``[R, V]`` float (any float dtype; upcast to f32).
-      temps:  ``[R]`` f32 — ``<= 0`` means greedy (pure argmax).
-      top_ks: ``[R]`` i32 — ``<= 0`` means no top-k filter.
-      top_ps: ``[R]`` f32 — nucleus mass in ``(0, 1]``; ``1.0`` = off.
-      keys:   ``[R, 2]`` u32 — raw ``(seed, draw_index)`` PRNG key data.
-
-    Returns:
-      ``[R]`` i32 token ids.
-    """
-    x32 = logits.astype(jnp.float32)
+def _draw_tokens(x32, greedy, temps, top_ks, top_ps, keys):
+    """The filter pipeline and the Gumbel-max draw over ``[R, V]`` f32
+    logits: the branch of :func:`sample_tokens` a launch takes when a
+    row of it samples.  Greedy rows of such a launch keep ``greedy``."""
     V = x32.shape[-1]
-    greedy = jnp.argmax(x32, axis=-1).astype(jnp.int32)
-
     x = x32 / jnp.maximum(temps[:, None], 1e-6)
 
     # top-k: mask everything below the k-th largest scaled logit.
@@ -145,3 +138,30 @@ def sample_tokens(logits, temps, top_ks, top_ps, keys):
     sampled = jnp.argmax(x + g, axis=-1).astype(jnp.int32)
 
     return jnp.where(temps <= 0.0, greedy, sampled)
+
+
+@jax.named_scope("sampler")
+def sample_tokens(logits, temps, top_ks, top_ps, keys):
+    """Sample one token per row, in-trace.  Everything here runs under
+    the ``sampler`` scope: the name the device trace knows the whole
+    epilogue by (its sort, its masks, its draw), metadata only.
+
+    Args:
+      logits: ``[R, V]`` float (any float dtype; upcast to f32).
+      temps:  ``[R]`` f32 — ``<= 0`` means greedy (pure argmax).
+      top_ks: ``[R]`` i32 — ``<= 0`` means no top-k filter.
+      top_ps: ``[R]`` f32 — nucleus mass in ``(0, 1]``; ``1.0`` = off.
+      keys:   ``[R, 2]`` u32 — raw ``(seed, draw_index)`` PRNG key data.
+
+    Returns:
+      ``[R]`` i32 token ids.
+    """
+    x32 = logits.astype(jnp.float32)
+    greedy = jnp.argmax(x32, axis=-1).astype(jnp.int32)
+    # the sort is the second-largest device operation of a greedy decode
+    # launch at a 100k vocabulary (PERF.md, PR 28), and greedy rows throw
+    # its result away: only a launch with a sampling row runs it
+    return jax.lax.cond(
+        jnp.any(temps > 0.0), _draw_tokens,
+        lambda x32, greedy, *quartet: greedy,
+        x32, greedy, temps, top_ks, top_ps, keys)

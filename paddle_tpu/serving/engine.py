@@ -576,6 +576,13 @@ class EngineCore:
         self.stepprof.record_aot_hit(program)
         return out
 
+    def _count_launch(self, pack: SamplingPack) -> None:
+        """Count the launch ``pack`` was built for by the branch its
+        in-trace sampler will take: the sort, the masks and the draw run
+        only where a row samples (``ops.sampling.sample_tokens``)."""
+        kind = "sampling" if pack.any_sampling() else "greedy"
+        self._sampling_counters[f"{kind}_launches"].inc()
+
     def _launch(self, program: str, bucket, jit_fn, args, rows: int,
                 fetch_logits: bool = True):
         """One step-program launch, shared by all five families, cut
@@ -1141,6 +1148,7 @@ class EngineCore:
             # re-drawn)
             pack = SamplingPack(1)
             pack.set_request(0, req)
+            self._count_launch(pack)
             if start == 0 and n == target:
                 # cold one-shot: dense-cache forward + scatter (the
                 # cheapest program when nothing is cached and no budget
@@ -1238,6 +1246,7 @@ class EngineCore:
                 lens[i] = p + 1           # cache length AFTER this token
                 slot_blocks[i], slot_offsets[i] = r._slot
                 pack.set_request(i, r)
+            self._count_launch(pack)
             self.decode_buckets.add(("decode", Bb, Wb))
             # shadow-oracle capture (ISSUE 10): on sampled audit steps the
             # PRE-step pools are snapshotted so the auditor can re-execute
@@ -1355,6 +1364,7 @@ class EngineCore:
                 if r.sampling.eos_token_id is not None:
                     eos_ids[i] = int(r.sampling.eos_token_id)
                 pack.set_request(i, r)
+            self._count_launch(pack)
             self.burst_buckets.add(("burst", Bb, Nb))
             rids = tuple(r.request_id for r in reqs)
         with self.tracer.span("burst_step", cat="serving", batch=B,
@@ -1513,6 +1523,7 @@ class EngineCore:
                         pack.set_request(cursor + n - 1, req)
                 cursor += n
                 last_idx[i] = cursor - 1
+            self._count_launch(pack)
             self.ragged_buckets.add(("ragged", Tb, TWb))
             self.metrics.count("unified_steps")
             pre_pools = self.audit.snapshot_pools(self._k_pools,

@@ -34,9 +34,17 @@ import numpy as np
 #     (device Gumbel-max draws); greedy emissions are not counted here
 #   serving_greedy_tokens_total  — tokens emitted by greedy rows via the
 #     same in-trace program (the two together = all emitted tokens)
+#   serving_sampling_launches_total — step-program launches in which at
+#     least one row has temperature > 0: the in-trace sampler took the
+#     branch with the full-vocabulary sort, the masks and the draw
+#   serving_greedy_launches_total   — launches whose every row is greedy
+#     (padding rows included): one argmax and no sort (the two together
+#     = all launches = serving_host_roundtrips_total)
 METRIC_NAMES = (
     "serving_sampled_tokens_total",
     "serving_greedy_tokens_total",
+    "serving_sampling_launches_total",
+    "serving_greedy_launches_total",
 )
 
 
@@ -50,6 +58,12 @@ def register_metrics(registry):
         "greedy": registry.counter(
             "serving_greedy_tokens_total",
             help="tokens emitted via in-trace greedy (temperature==0) rows"),
+        "sampling_launches": registry.counter(
+            "serving_sampling_launches_total",
+            help="step launches with a temperature>0 row (sampler sorts)"),
+        "greedy_launches": registry.counter(
+            "serving_greedy_launches_total",
+            help="step launches of greedy rows only (sampler skips its sort)"),
     }
 
 
@@ -87,6 +101,12 @@ class SamplingPack:
 
     def set_request(self, i: int, req, offset: int = 0) -> None:
         self.set(i, req.sampling, draw_index(req, offset))
+
+    def any_sampling(self) -> bool:
+        """Whether any row samples (``temperature > 0``): the condition
+        ``sample_tokens`` branches on in the trace, known here before
+        dispatch."""
+        return bool((self.temps > 0).any())
 
     def arrays(self):
         return self.temps, self.top_ks, self.top_ps, self.keys

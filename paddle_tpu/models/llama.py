@@ -122,8 +122,13 @@ class LlamaConfig:
 
     @classmethod
     def deepseek_moe_16b(cls, **kw):
-        """DeepSeekMoE-16B (BASELINE config #5): 64 routed + 2 shared
-        experts, top-6 routing, 0.4B-ish expert FFNs."""
+        """DeepSeekMoE-16B widths (BASELINE config #5): 64 routed + 2
+        shared experts, top-6 routing.  A TRAINING preset: its expert layer
+        is ``LlamaMoEBlock`` on the GShard dispatch (``parallel/moe.py``
+        ``MoELayer``), which has a capacity and DROPS the tokens past it,
+        with softmax gates.  It is not served and has never run on the
+        chip; the served expert layer is the dropless one
+        (``parallel.moe.dropless_experts``, ``models/moe_mla.py``)."""
         defaults = dict(
             vocab_size=102400, hidden_size=2048, intermediate_size=10944,
             num_hidden_layers=28, num_attention_heads=16,
@@ -137,8 +142,10 @@ class LlamaConfig:
 
     @classmethod
     def qwen2_moe_a14b(cls, **kw):
-        """Qwen2-57B-A14B MoE (BASELINE config #5): 64 routed + shared
-        expert, top-8 routing, GQA 4:1."""
+        """Qwen2-57B-A14B MoE widths (BASELINE config #5): 64 routed +
+        shared expert, top-8 routing, GQA 4:1.  A TRAINING preset like
+        :meth:`deepseek_moe_16b`: the capacity-dropping GShard layer,
+        not served; the served expert layer is the dropless one."""
         defaults = dict(
             vocab_size=151936, hidden_size=3584, intermediate_size=18944,
             num_hidden_layers=28, num_attention_heads=28,
@@ -486,6 +493,15 @@ class LlamaDecoderLayer(Layer):
         else:
             self.mlp = LlamaMLP(config)
 
+    def cache_spec(self):
+        """What a cached token holds in this layer (``EngineCore``
+        allocates its pools by it): keys and values, ``num_key_value_heads``
+        heads of ``head_dim`` each."""
+        from ..ops.paged_attention import CacheSpec
+
+        row = (self.config.num_key_value_heads, self.config.head_dim)
+        return CacheSpec(k=row, v=row)
+
     def _sp(self, x):
         # Megatron-SP layout between blocks: seq sharded over mp (+sep for CP)
         if self.config.sequence_parallel:
@@ -516,9 +532,12 @@ class LlamaModel(Layer):
         self.embed_tokens = VocabParallelEmbedding(
             config.vocab_size, config.hidden_size,
             weight_attr=Normal(0.0, config.initializer_range))
+        # the configuration chooses the kind of decoder layer: one that
+        # brings ``make_decoder_layer`` (models/moe_mla.py) builds its own
+        make = getattr(config, "make_decoder_layer", None) or (
+            lambda i: LlamaDecoderLayer(config, layer_idx=i))
         self.layers = LayerList(
-            [LlamaDecoderLayer(config, layer_idx=i)
-             for i in range(config.num_hidden_layers)])
+            [make(i) for i in range(config.num_hidden_layers)])
         self.norm = RMSNorm(config.hidden_size, config.rms_norm_eps)
         self._pipe: Optional[PipelineLayer] = None
         self._scan_prep = None              # lazy (roles, per_layer, specs)
@@ -692,6 +711,25 @@ class LlamaForCausalLM(Layer):
                 w = self.llama.embed_tokens.weight
                 return run_op("tied_head", lambda a, wv: a @ wv.T, h, w)
             return self.lm_head(h)
+
+    def cache_specs(self):
+        """Per decoder layer, what a cached token holds there
+        (``ops.paged_attention.CacheSpec``): the serving engine sizes its
+        pools and its prefill buffers by this and by nothing else."""
+        return [layer.cache_spec() for layer in self.llama.layers]
+
+    def pop_expert_load(self):
+        """``[expert layers, experts]`` int32: the tokens each routed
+        expert received in the forward just run (``RoutedExperts.load``),
+        or ``None`` for a model without such layers.  Clears what the
+        layers held, so a traced value never outlives its trace."""
+        loads = []
+        for layer in self.llama.layers:
+            load = getattr(layer.mlp, "load", None)
+            if load is not None:
+                loads.append(load)
+                layer.mlp.load = None
+        return jnp.stack(loads) if loads else None
 
     def train_batch_1f1b(self, input_ids, labels, n_microbatch: int,
                          criterion=None, recompute: bool = False):

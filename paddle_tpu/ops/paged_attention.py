@@ -25,6 +25,7 @@ from __future__ import annotations
 import hashlib
 import math
 from collections import OrderedDict
+from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -765,6 +766,144 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhrqk,bkhd->bqhrd", probs.astype(v.dtype), v)
     return out.reshape(B, S, H, D).astype(q.dtype)
+
+
+@dataclass(frozen=True)
+class CacheSpec:
+    """What ONE cached token holds in one decoder layer, as the layer
+    declares it (``layer.cache_spec()``) and ``EngineCore`` allocates it:
+    ``k`` is the ``(heads, dim)`` of the token's row in the layer's
+    ``k_pools`` entry (``[num_blocks, block_size, heads, dim]``), ``v`` the
+    same for ``v_pools`` or ``None`` where the layer keeps nothing there
+    (a latent cache: one row shared by all heads, no separate values).
+    ``kind`` names the layout for the paths that can take only one:
+    ``"kv"`` shards its head dimension over ``mp`` and is what the ragged,
+    burst and hand-off paths move; ``"latent"`` is refused by them, by
+    name, when the engine is built."""
+
+    k: Tuple[int, int]
+    v: Optional[Tuple[int, int]]
+    kind: str = "kv"
+
+    def values_per_token(self) -> int:
+        return self.k[0] * self.k[1] + (self.v[0] * self.v[1] if self.v else 0)
+
+
+def _block_queries(fn, q, block: int = 1024):
+    """``fn(q_block, first_row)`` over blocks of the query axis (axis 1)
+    where it is long, so that a prefill's ``[heads, S, M]`` float32 scores
+    are never whole in memory (4,096 x 4,096 x 20 heads is 1.3 GB)."""
+    S = q.shape[1]
+    if S <= block or S % block:
+        return fn(q, 0)
+    n = S // block
+    qs = jnp.moveaxis(q.reshape(q.shape[0], n, block, *q.shape[2:]), 1, 0)
+    out = jax.lax.map(lambda a: fn(a[0], a[1] * block),
+                      (qs, jnp.arange(n, dtype=jnp.int32)))
+    out = jnp.moveaxis(out, 0, 1)
+    return out.reshape(out.shape[0], S, *out.shape[3:])
+
+
+def latent_expanded_attention(q, lat, w_ukv, rank: int, scale: float,
+                              q_start=0, lens=None):
+    """Latent attention with keys and values REBUILT from the cache rows.
+
+    q: ``[B, S, heads, nope + rope]`` (rope part rotated), at positions
+    ``q_start + [0, S)``; lat: ``[B, M, rank + rope]`` — per token the
+    normalised latent ``c_kv`` beside the rotated shared key ``k_r``;
+    ``w_ukv = (W_UK [heads, rank, nope], W_UV [heads, rank, v])``.  A query
+    sees the columns up to its own position, and under ``lens`` (``[B]``)
+    where given.  Returns ``[B, S, heads * v]``."""
+    w_uk, w_uv = w_ukv
+    B, S, heads, _ = q.shape
+    M, nope = lat.shape[1], w_uk.shape[-1]
+    c_kv = lat[..., :rank].astype(q.dtype)
+    k_r = lat[..., rank:].astype(q.dtype)
+    k_nope = jnp.einsum("bmr,hrn->bmhn", c_kv, w_uk)
+    v = jnp.einsum("bmr,hrv->bmhv", c_kv, w_uv)
+    col = jnp.arange(M)[None, None, :]
+    starts = (q_start[:, None, None] if jnp.ndim(q_start) == 1 else q_start)
+
+    def block(qb, first):
+        s = (jnp.einsum("bqhn,bkhn->bhqk", qb[..., :nope], k_nope,
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bqhr,bkr->bhqk", qb[..., nope:], k_r,
+                          preferred_element_type=jnp.float32)) * scale
+        row = starts + first + jnp.arange(qb.shape[1])[None, :, None]
+        mask = col <= row
+        if lens is not None:
+            mask = mask & (col < lens[:, None, None])
+        probs = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1)
+        return jnp.einsum("bhqk,bkhv->bqhv", probs.astype(v.dtype), v)
+
+    out = _block_queries(block, q)
+    return out.reshape(B, S, heads * w_uv.shape[-1]).astype(q.dtype)
+
+
+def latent_paged_prefill_attention(q, pool, w_ukv, block_tables, seq_lens,
+                                   q_start, rank: int, scale: float):
+    """A prefill chunk over a paged LATENT cache: gather the rows of each
+    sequence's pages (``pool [num_blocks, block_size, 1, rank + rope]``,
+    the chunk's own rows already written) and attend expanded
+    (:func:`latent_expanded_attention`).  XLA gather path, like
+    :func:`paged_prefill_attention`."""
+    B, W = block_tables.shape
+    lat = pool[block_tables].reshape(B, W * pool.shape[1], pool.shape[-1])
+    return latent_expanded_attention(q, lat, w_ukv, rank, scale, q_start,
+                                     seq_lens)
+
+
+# the gathered context of one decode launch is held to this size by
+# splitting the ROWS of the batch into groups run one after another: above
+# it (128 rows of 4,096 tokens are 604 MB) the TPU compiler gave every
+# layer's context a buffer of its own, 6.2 GB of temporaries in a 7-layer
+# step program against 0.8 GB at half the width (compiled for a v5e, PR 29)
+_LATENT_CONTEXT_BYTES = 320 * 2 ** 20
+
+
+def latent_paged_decode_attention(q, pool, w_ukv, block_tables, seq_lens,
+                                  rank: int, scale: float):
+    """One decode token a row over a paged latent cache, ABSORBED: ``W_UK``
+    is folded into the query (``q~_h = W_UK,h^T q_nope_h``), scores and the
+    weighted sum run on the 576-wide rows themselves, and ``W_UV`` comes
+    after — the mathematics of :func:`latent_expanded_attention` with no
+    key or value ever built.  q: ``[B, heads, nope + rope]``; returns
+    ``[B, heads * v]``.  XLA gather path: the padded ``[rows, W *
+    block_size, 576]`` context is materialised, a group of rows at a time
+    (a kernel that walks the pages is ROADMAP's)."""
+    w_uk, w_uv = w_ukv
+    B, W = block_tables.shape
+    nope = w_uk.shape[-1]
+    q_lat = jnp.einsum("bhn,hrn->bhr", q[..., :nope], w_uk,
+                       preferred_element_type=jnp.float32).astype(q.dtype)
+    qc = jnp.concatenate([q_lat, q[..., nope:]], axis=-1)
+
+    def rows(args):
+        qg, tables, lens = args
+        ctx = pool[tables].reshape(tables.shape[0], -1,
+                                   pool.shape[-1]).astype(q.dtype)
+        s = jnp.einsum("bhd,bmd->bhm", qg, ctx,
+                       preferred_element_type=jnp.float32) * scale
+        mask = jnp.arange(ctx.shape[1])[None, None, :] < lens[:, None, None]
+        probs = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+        # the weighted sum runs over the whole 576-wide row and the rope
+        # part is dropped after: slicing the context first would copy it
+        return jnp.einsum("bhm,bmd->bhd", probs.astype(ctx.dtype), ctx,
+                          preferred_element_type=jnp.float32)[..., :rank]
+
+    per_row = W * (pool[0].size // pool.shape[-1]) * pool.shape[-1] \
+        * jnp.dtype(q.dtype).itemsize
+    groups = 1
+    while B * per_row > groups * _LATENT_CONTEXT_BYTES and B % (2 * groups) == 0:
+        groups *= 2
+    # unrolled, not a loop: two groups at most in practice, and a ``while``
+    # in the program is an event the accepted scope readers count twice
+    n = B // groups
+    u = jnp.concatenate([rows((qc[i:i + n], block_tables[i:i + n],
+                               seq_lens[i:i + n]))
+                         for i in range(0, B, n)], axis=0)
+    o = jnp.einsum("bhr,hrv->bhv", u.astype(q.dtype), w_uv)
+    return o.reshape(B, -1)
 
 
 def paged_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,

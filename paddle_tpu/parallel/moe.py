@@ -21,6 +21,7 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.dispatch import run_op
 from ..core.tensor import Tensor
@@ -246,6 +247,85 @@ class MoELayer(Layer):
 
         out = run_op("moe_combine", combine_fn, expert_out, combine)
         return ops.reshape(out, orig_shape)
+
+
+# --- routed experts that drop no token (the SERVED expert layer) -------------
+#
+# ``MoELayer`` above is the training layer: a ``[T, E, C]`` one-hot
+# dispatch with a capacity, which DROPS the tokens past it, so a row's
+# output depends on who shares its batch.  Serving cannot have that: the
+# two functions below route without a capacity and compute every routed
+# token, at a cost proportional to the tokens routed.
+
+def sigmoid_topk_route(x, w_gate, select_bias, k: int, scale: float = 1.0,
+                       normalize: bool = True):
+    """``noaux_tc`` routing with one group: scores ``s = sigmoid(x W_g)``;
+    the ``k`` experts of a token are the top ``k`` of ``s + select_bias``
+    (the bias SELECTS, it does not weigh); their weights are ``s_i``,
+    renormalised over the chosen (``normalize``) and times ``scale``.
+
+    x ``[T, H]``, w_gate ``[H, E]``, select_bias ``[E]`` float32.  Returns
+    ``(ids [T, k] int32, weights [T, k] float32)``.  The matmul and the
+    scores are float32 at the highest matmul precision whatever ``x`` is:
+    two scores a bf16 rounding apart pick another expert, and the output
+    then differs by a whole expert's contribution.
+    """
+    logits = jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(s + select_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), w * scale
+
+
+def dropless_experts(x, ids, weights, w_gate_up, w_down, num_experts: int,
+                     held=None):
+    """``out[t] = sum_j weights[t, j] * E_{ids[t, j]}(x[t])`` over the
+    experts THIS process holds, with ``E(x) = W_down(silu(W_gate x) * W_up
+    x)``: the step's (token, expert) pairs are sorted by expert and each
+    expert multiplies only the rows routed to it (``jax.lax.ragged_dot``
+    over the stacked weights).  No capacity: every routed token is
+    computed, so a token's result does not depend on the rest of the batch.
+
+    x ``[T, H]``; ids / weights ``[T, k]``; w_gate_up ``[E_held, H, 2 F]``
+    (gate columns, then up); w_down ``[E_held, F, H]``; ``held`` the ids
+    (into ``num_experts``) of the stacked experts in order, ``None`` = all.
+    A pair routed to an expert that is not held adds nothing here (another
+    chip's share).  Returns ``(out [T, H], load [num_experts] int32)``,
+    ``load`` the pairs each expert received, held or not.
+
+    Scopes: ``moe_dispatch`` (sort and gather), ``moe_experts`` (the
+    grouped matmuls), ``moe_combine`` (weigh and sum per token)."""
+    T, k = ids.shape
+    n_held = w_gate_up.shape[0]
+    f = w_down.shape[1]
+    with jax.named_scope("moe_dispatch"):
+        flat_ids = ids.reshape(-1)
+        load = jnp.bincount(flat_ids, length=num_experts).astype(jnp.int32)
+        if held is None or tuple(held) == tuple(range(num_experts)):
+            local = flat_ids
+        else:
+            lut = np.full((num_experts,), n_held, np.int32)
+            lut[np.asarray(held)] = np.arange(n_held, dtype=np.int32)
+            local = jnp.asarray(lut)[flat_ids]      # n_held = not here
+        order = jnp.argsort(local, stable=True)
+        token = order // k
+        rows = x[token]
+        sizes = jnp.bincount(local, length=n_held + 1)[:n_held] \
+            .astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        h = jax.lax.ragged_dot(rows, w_gate_up, sizes)
+        h = jax.nn.silu(h[:, :f]) * h[:, f:]
+        y = jax.lax.ragged_dot(h, w_down, sizes)
+    with jax.named_scope("moe_combine"):
+        w = jnp.where(local < n_held, weights.reshape(-1), 0.0)[order]
+        # rows past the last group (pairs of experts not held) are whatever
+        # the grouped matmul left there: select, do not multiply by 0
+        y = jnp.where(w[:, None] != 0, y.astype(jnp.float32) * w[:, None], 0.0)
+        out = y[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
+    return out.astype(x.dtype), load
 
 
 def global_scatter(x: Tensor, local_count, global_count, group=None) -> Tensor:

@@ -254,9 +254,9 @@ def _arg_specs(engine, program: str, bucket: Tuple[int, ...]):
 
     params = tuple(s(np.shape(p._value), np.dtype(p._value.dtype))
                    for p in engine._params)
-    pools = tuple(s(tuple(k.shape), np.dtype(k.dtype))
-                  for k in engine._k_pools)
-    head = (params, pools, pools)
+    pools, v_pools = (tuple(s(tuple(p.shape), np.dtype(p.dtype)) for p in side)
+                      for side in (engine._k_pools, engine._v_pools))
+    head = (params, pools, v_pools)
     if program == "decode":
         Bb, Wb = bucket
         return head + (s((Bb, 1), i32), s((Bb,), i32), s((Bb, Wb), i32),

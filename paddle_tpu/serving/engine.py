@@ -96,6 +96,25 @@ from .scheduler import (
 _EVICT_EVENTS_PER_STEP = 8
 
 
+def _register_moe_metrics(registry, labels: Dict[str, str]):
+    """Routing-load series of a model with routed experts."""
+    return {
+        "assignments": registry.counter(
+            "serving_moe_assignments_total",
+            help="(token, expert) pairs routed, over expert layers and "
+                 "launches (padding rows included)", **labels),
+        "touched": registry.counter(
+            "serving_moe_experts_touched_total",
+            help="experts that received a token, summed over expert "
+                 "layers and launches", **labels),
+        "max_over_mean": registry.gauge(
+            "serving_moe_load_max_over_mean",
+            help="last launch: the fullest expert's tokens over the mean "
+                 "expert's, averaged over expert layers (1.0 = balanced)",
+            **labels),
+    }
+
+
 # StepTimer series and collective-phase label of each program family
 _STEP_TIMERS = {
     "prefill": ("prefill_step", "prefill"),
@@ -352,17 +371,23 @@ class EngineCore:
                 f"mp={self.mp}; call distributed.topology.init_mesh(mp=...) "
                 "before building the engine")
         self._unified = bool(config.unified_step)
+        # --- what a cached token holds, as the model declares it ------------
+        # (ROADMAP D4) the pools, the prefill buffers, the mp check and the
+        # mesh shardings below all go by this and never by head counts
+        self.cache_specs = list(model.cache_specs())
+        self._refuse_latent_paths(config)
         self._use_pallas = config.use_pallas_paged
         # the unified ragged program keeps its own routing: its Pallas
         # kernel is expressed through shard_map over the mp axis, so it
         # is NEVER subject to the legacy single-shard pin below
         self._use_pallas_ragged = config.use_pallas_paged
         if self.mp > 1:
-            if cfg.num_key_value_heads % self.mp or \
+            kv_heads = sorted({spec.k[0] for spec in self.cache_specs})
+            if any(h % self.mp for h in kv_heads) or \
                     cfg.num_attention_heads % self.mp:
                 raise ValueError(
                     f"mp={self.mp} must divide num_key_value_heads="
-                    f"{cfg.num_key_value_heads} and num_attention_heads="
+                    f"{kv_heads[0]} and num_attention_heads="
                     f"{cfg.num_attention_heads} (the KV pools shard along "
                     "the head dim)")
             if self._use_pallas and not self._unified:
@@ -386,11 +411,25 @@ class EngineCore:
             # specs from parallel/mp_layers.py) onto the mesh shard-wise
             apply_param_shardings(model, mesh)
         self.metrics.set_mp_shards(self.mp)
-        shape = (num_blocks, block_size, cfg.num_key_value_heads, cfg.head_dim)
-        self._k_pools = tuple(shard_kv_pool(jnp.zeros(shape, dtype))
-                              for _ in range(cfg.num_hidden_layers))
-        self._v_pools = tuple(shard_kv_pool(jnp.zeros(shape, dtype))
-                              for _ in range(cfg.num_hidden_layers))
+        def pool(row):
+            # a layer that keeps nothing on this side holds an empty array
+            if row is None:
+                return jnp.zeros((0,), dtype)
+            return shard_kv_pool(
+                jnp.zeros((num_blocks, block_size) + tuple(row), dtype))
+
+        self._k_pools = tuple(pool(spec.k) for spec in self.cache_specs)
+        self._v_pools = tuple(pool(spec.v) for spec in self.cache_specs)
+        self.metrics.registry.gauge(
+            "serving_kv_bytes_per_token",
+            help="bytes one cached token holds over all layers, as the "
+                 "model declares its cache",
+            **self.metrics.labels).set(
+            sum(spec.values_per_token() for spec in self.cache_specs)
+            * jnp.dtype(dtype).itemsize)
+        # routing-load series: made when a launch first brings a load, so a
+        # model without routed experts never has them on /metrics
+        self._moe_counters = None
         self._params = list(model.parameters())
         # retrace counters: += 1 runs only while JAX traces the function,
         # so these count COMPILATIONS, not calls (the N31 acceptance hook)
@@ -525,6 +564,33 @@ class EngineCore:
         sp.record_aot_load(artifact.load_seconds,
                            artifact.program_count, observe=observe)
 
+    def _refuse_latent_paths(self, config: "EngineConfig") -> None:
+        """A model that declares a LATENT cache (one row a token shared by
+        all heads, nothing in ``v_pools``) is served by the prefill, chunk
+        / resume and decode programs.  The paths that move or shard
+        ``(heads, dim)`` keys and values have no latent form yet: refuse
+        them here, by name, rather than run a wrong one."""
+        if not any(spec.kind == "latent" for spec in self.cache_specs):
+            return
+        refused = []
+        if config.unified_step:
+            refused.append("unified_step (the unified ragged program)")
+        if int(config.burst_steps or 0) >= 2:
+            refused.append("burst_steps (device-resident decode bursts)")
+        if config.spec is not None and getattr(config.spec, "enabled", True):
+            refused.append("spec (speculative verify)")
+        if config.role != "unified":
+            refused.append(f"role={config.role!r} (KV hand-off)")
+        if self.mp > 1:
+            refused.append(f"mp={self.mp} (head-sharded pools)")
+        if config.use_pallas_paged:
+            refused.append("use_pallas_paged=True (the paged decode "
+                           "kernel reads keys and values)")
+        if refused:
+            raise ValueError(
+                "this model declares a latent KV cache; EngineCore has no "
+                "latent path for: " + "; ".join(refused))
+
     def _cap_seq_len(self, cap: int, why: str) -> None:
         """Lower the admission cap on prompt + max_new_tokens (never
         raise it: the tightest limit and its reason win)."""
@@ -615,12 +681,18 @@ class EngineCore:
                     self._step_call(program, bucket, jit_fn,
                                     self._param_vals(), self._k_pools,
                                     self._v_pools, *args)
+                load = None
+                if isinstance(stats, tuple):    # a model with routed experts
+                    stats, load = stats
                 if fetch_logits:
                     logits.copy_to_host_async()
+                    if load is not None:
+                        load.copy_to_host_async()
             if fetch_logits:
                 with phase("engine.device_wait", prof):
                     logits.block_until_ready()
-                with phase("engine.fetch", prof, bytes=logits.nbytes):
+                    moe = self._moe_load_ints(program, load)
+                with phase("engine.fetch", prof, bytes=logits.nbytes, **moe):
                     logits = np.asarray(logits, np.float32)
                     if self.audit.enabled:
                         stats = np.asarray(stats, np.float32)
@@ -632,6 +704,29 @@ class EngineCore:
                 + self.ragged_trace_count + self.burst_trace_count) > traces0:
             self.stepprof.record_compile(program, bucket, st.dt)
         return toks, logits, stats, st.dt
+
+    def _moe_load_ints(self, program: str, load) -> Dict[str, int]:
+        """The routing load of the launch just run (``[expert layers,
+        experts]`` int32, a few hundred integers that rode the launch
+        beside the tokens) as the integers ``engine.fetch`` carries and
+        ``/metrics`` counts; ``{}`` for a model without routed experts."""
+        if load is None:
+            return {}
+        if self._moe_counters is None:
+            self._moe_counters = _register_moe_metrics(
+                self.metrics.registry, self.metrics.labels)
+        load = np.asarray(load)
+        assignments = int(load.sum())
+        touched = int(np.count_nonzero(load))
+        max_load = int(load.max(axis=1).sum())
+        c = self._moe_counters
+        c["assignments"].inc(assignments)
+        c["touched"].inc(touched)
+        if assignments:
+            c["max_over_mean"].set(max_load * load.shape[1] / assignments)
+        return {"moe_assignments": assignments,
+                "moe_experts_touched": touched, "moe_max_load": max_load,
+                "moe_decode": int(program == "decode")}
 
     def _mesh_jit_shardings(self, mesh, cfg) -> Dict[str, dict]:
         """Explicit in/out shardings for the three mesh-spanning jitted
@@ -648,7 +743,7 @@ class EngineCore:
         repl = NamedSharding(mesh, PartitionSpec())
         kv = NamedSharding(mesh, PartitionSpec(*KV_POOL_SPEC))  # matches
         # shard_kv_pool's placement — same constant, cannot drift
-        pools = tuple(kv for _ in range(cfg.num_hidden_layers))
+        pools = tuple(kv for _ in self.cache_specs)
         params = tuple(
             NamedSharding(mesh, _fit_spec(param_spec(p), tuple(p.shape), mesh))
             for p in self._params)
@@ -709,6 +804,15 @@ class EngineCore:
             for p, v in zip(self._params, saved):
                 p._value = v
 
+    def _launch_stats(self, last):
+        """The ``stats`` output of a step program: the numerics audit's
+        logit sentinel, and for a model with routed experts the tokens
+        each expert of each layer received in this forward beside it."""
+        stats = logit_stats(last)
+        pop = getattr(self.model, "pop_expert_load", None)
+        load = pop() if pop is not None else None
+        return stats if load is None else (stats, load)
+
     def _decode_fn(self, param_vals, k_pools, v_pools, ids, pos,
                    tables, lens, slot_blocks, slot_offsets,
                    temps, top_ks, top_ps, keys):
@@ -739,7 +843,7 @@ class EngineCore:
         # numerics-audit sentinel (ISSUE 10): tiny in-trace reductions
         # over the output logits ride the launch as one extra output —
         # computed unconditionally so audit on/off is the SAME program
-        return (tokens, last, logit_stats(last),
+        return (tokens, last, self._launch_stats(last),
                 tuple(c.k_pool._value for c in caches),
                 tuple(c.v_pool._value for c in caches))
 
@@ -778,7 +882,7 @@ class EngineCore:
             model_step, n_steps, self.model.config.vocab_size, ids, pos,
             lens, active, eos_ids, slot_blocks, slot_offsets, temps,
             top_ks, top_ps, keys, k_pools, v_pools)
-        return buf, last, logit_stats(last), k_out, v_out
+        return buf, last, self._launch_stats(last), k_out, v_out
 
     def _prefill_fn(self, param_vals, k_pools, v_pools, ids, last_pos,
                     blocks, offs, temps, top_ks, top_ps, keys):
@@ -790,15 +894,14 @@ class EngineCore:
         self.metrics.count("prefill_jit_traces")
         self.tracer.instant("prefill_jit_trace", cat="jit",
                             prompt_bucket=int(ids.shape[1]))
-        cfg = self.model.config
         Tb = ids.shape[1]
-        dense = [
-            (Tensor(jnp.zeros((1, Tb, cfg.num_key_value_heads, cfg.head_dim),
-                              self._pool_dtype)),
-             Tensor(jnp.zeros((1, Tb, cfg.num_key_value_heads, cfg.head_dim),
-                              self._pool_dtype)))
-            for _ in range(cfg.num_hidden_layers)
-        ]
+
+        def buffer(row):
+            return None if row is None else Tensor(
+                jnp.zeros((1, Tb) + tuple(row), self._pool_dtype))
+
+        dense = [(buffer(spec.k), buffer(spec.v))
+                 for spec in self.cache_specs]
         logits = self._call_model(ids, dense, jnp.int32(0), param_vals)
         last = jnp.take(logits[0], last_pos, axis=0).astype(jnp.float32)
         tokens = sample_tokens(last[None], temps, top_ks, top_ps, keys)
@@ -806,9 +909,10 @@ class EngineCore:
             kp.at[blocks, offs].set(kb._value[0].astype(kp.dtype))
             for kp, (kb, _) in zip(k_pools, dense))
         new_v = tuple(
+            vp if vb is None else
             vp.at[blocks, offs].set(vb._value[0].astype(vp.dtype))
             for vp, (_, vb) in zip(v_pools, dense))
-        return tokens, last, logit_stats(last), new_k, new_v
+        return tokens, last, self._launch_stats(last), new_k, new_v
 
     def _chunk_prefill_fn(self, param_vals, k_pools, v_pools, ids, start,
                           last_pos, tables, lens, slot_blocks,
@@ -833,7 +937,7 @@ class EngineCore:
         logits = self._call_model(ids, caches, start, param_vals)
         last = jnp.take(logits[0], last_pos, axis=0).astype(jnp.float32)
         tokens = sample_tokens(last[None], temps, top_ks, top_ps, keys)
-        return (tokens, last, logit_stats(last),
+        return (tokens, last, self._launch_stats(last),
                 tuple(c.k_pool._value for c in caches),
                 tuple(c.v_pool._value for c in caches))
 
@@ -873,7 +977,7 @@ class EngineCore:
         # decode row's single position uses — no new program family
         tokens = sample_tokens(logits[0].astype(jnp.float32),
                                temps, top_ks, top_ps, keys)
-        return (tokens, last, logit_stats(last),
+        return (tokens, last, self._launch_stats(last),
                 tuple(c.k_pool._value for c in caches),
                 tuple(c.v_pool._value for c in caches))
 

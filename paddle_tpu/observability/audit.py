@@ -67,6 +67,9 @@ import numpy as np
 # the bucketed program families the engine dispatches: the legacy three
 # (PR 1/4) plus the unified packed ragged step (ISSUE 11)
 AUDIT_PROGRAMS = ("prefill", "chunk", "decode", "ragged")
+# ... of which the shadow oracle re-executes these on a sampled step, and
+# so reads their logits on the host
+SHADOW_PROGRAMS = ("decode", "ragged")
 
 # divergence kinds: greedy token flipped / logits outside tolerance /
 # non-finite values in the primary output
@@ -275,6 +278,14 @@ class NumericsAuditor:
         """True while the CURRENT engine step is shadow-audited."""
         return self.enabled and self._sampled
 
+    def wants_logits(self, program: str) -> bool:
+        """True where a launch of ``program`` in the CURRENT engine step
+        is compared with the shadow oracle: the one launch whose logits
+        the engine brings to the host with its tokens.  Any other launch
+        hands :meth:`observe_program` a callable, called only if a
+        bundle is written."""
+        return self.sampled and program in SHADOW_PROGRAMS
+
     @property
     def degraded(self) -> bool:
         return self._degraded
@@ -304,15 +315,19 @@ class NumericsAuditor:
 
     # --- the audit hook (engine thread) -------------------------------------
     def observe_program(self, program: str, stats, bucket: Tuple[int, ...],
-                        logits: Optional[np.ndarray] = None,
+                        logits=None,
                         inputs: Optional[Dict[str, np.ndarray]] = None,
                         pre_pools=None,
                         requests: Sequence[Dict] = ()) -> Optional[str]:
         """One bucketed program launch: sentinel over the in-trace
         ``stats`` rows (every launch), plus — for a decode launch on a
         sampled step with captured inputs — the shadow-oracle
-        differential re-execution.  Returns the divergence kind when one
-        fired (``None`` otherwise)."""
+        differential re-execution.  ``logits`` is the host array of the
+        launch's real rows where :meth:`wants_logits` asked for it, else
+        a callable that fetches them: the sentinel needs only ``stats``,
+        so the rows cross to the host for a non-finite bundle alone.
+        Returns the divergence kind when one fired (``None``
+        otherwise)."""
         if not self.enabled:
             return None
         stats = np.asarray(stats, np.float32).reshape(-1, 3)
@@ -335,11 +350,12 @@ class NumericsAuditor:
                 info={"nonfinite_values": nonfinite,
                       "nonfinite_rows": int((stats[:, 0] > 0).sum()),
                       "requests": [str(r.get("id")) for r in requests]},
-                arrays_fn=lambda: self._repro_arrays(inputs, pre_pools,
-                                                     primary=logits))
+                arrays_fn=lambda: self._repro_arrays(
+                    inputs, pre_pools,
+                    primary=logits() if callable(logits) else logits))
             return "nonfinite"
-        if program in ("decode", "ragged") and self.sampled \
-                and pre_pools is not None and logits is not None:
+        if self.wants_logits(program) and pre_pools is not None \
+                and logits is not None:
             return self._shadow_step(program, pre_pools, inputs, logits,
                                      bucket, requests)
         return None

@@ -46,8 +46,11 @@ STEP_PHASES = (
     "engine.build",         # numpy routing arrays + the SamplingPack
     "engine.dispatch",      # the step call, until the jit call returns
     "engine.device_wait",   # blocked until the program has ended
-    "engine.fetch",         # logits (bytes=), audit stats, tokens to the host;
-                            # for a model with routed experts also the
+    "engine.fetch",         # host arrays of what the step reads (bytes=):
+                            # the int32 tokens; with the audit on its
+                            # stats, and a sampled decode / ragged
+                            # launch's real rows of logits.  For a
+                            # model with routed experts also the
                             # launch's routing load: moe_assignments=,
                             # moe_experts_touched=, moe_max_load= (summed
                             # over expert layers), moe_decode= 1 on decode

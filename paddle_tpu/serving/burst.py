@@ -37,6 +37,8 @@ METRIC_NAMES = (
     "serving_burst_tokens_total",
     "serving_burst_length",
     "serving_host_roundtrips_total",
+    "serving_logits_fetches_total",
+    "serving_logits_fetch_bytes_total",
 )
 
 # a burst length is clamped to config.burst_steps, itself bounded by the
@@ -69,6 +71,18 @@ def register_metrics(registry, labels=None):
             help="host->device step-program launches (a burst counts "
                  "once; the saving vs per-step decode is this series' "
                  "slope)", **lb),
+        # what a launch copies back beside its tokens: nothing, unless
+        # the numerics audit reads the logits (EngineCore._launch)
+        "logits_fetches": registry.counter(
+            "serving_logits_fetches_total",
+            help="step-program launches that copied rows of float32 "
+                 "logits to the host (the numerics audit's sampled "
+                 "decode / ragged launches, and non-finite bundles)",
+            **lb),
+        "logits_fetch_bytes": registry.counter(
+            "serving_logits_fetch_bytes_total",
+            help="bytes of logits those launches copied to the host "
+                 "(real rows x vocabulary x 4)", **lb),
     }
 
 

@@ -59,7 +59,12 @@ import numpy as np
 from ..core.tensor import Tensor
 from ..distributed import topology
 from ..observability import lifecycle as _lc
-from ..observability.audit import AuditConfig, NumericsAuditor, logit_stats
+from ..observability.audit import (
+    AUDIT_PROGRAMS,
+    AuditConfig,
+    NumericsAuditor,
+    logit_stats,
+)
 from ..observability.cachestat import CacheStatTracker
 from ..observability.lifecycle import LifecycleTracker
 from ..observability.stepprof import StepProfiler
@@ -180,7 +185,10 @@ class EngineConfig:
     # = disabled: zero serving_audit_*/serving_logit_* series on
     # /metrics and no host-side audit work (the in-trace logit stats
     # are computed unconditionally, so audit on vs off is the SAME
-    # compiled program — trace counts provably unchanged).
+    # compiled program — trace counts provably unchanged).  What a
+    # launch copies to the host differs: the tokens alone with the audit
+    # off; with it on the stats too, and the logits' real rows on a
+    # decode / ragged launch of a sampled step (EngineCore._launch).
     audit: Optional[AuditConfig] = None
     # KV-cache & memory observability (ISSUE 13): per-step pool-timeline
     # sampling (free/reuse/allocated block counts with the exact
@@ -649,28 +657,35 @@ class EngineCore:
         kind = "sampling" if pack.any_sampling() else "greedy"
         self._sampling_counters[f"{kind}_launches"].inc()
 
-    def _launch(self, program: str, bucket, jit_fn, args, rows: int,
-                fetch_logits: bool = True):
+    def _launch(self, program: str, bucket, jit_fn, args, rows: int):
         """One step-program launch, shared by all five families, cut
         into the phases the device trace can tell apart
         (``observability.tracer.STEP_PHASES``): ``engine.dispatch`` is
         the step call alone, until the jit call returns;
-        ``engine.device_wait`` waits for the program to end and copies
-        nothing; ``engine.fetch`` brings back what the step reads — the
-        ``[rows, vocab]`` float32 logits (and the audit's logit stats
-        when the audit is on), then the int32 tokens.  The copies run in
-        the order and from the moment they always did (the logits' copy
-        is asked for as soon as the program is dispatched, the tokens'
-        after it): only the waiting is cut in two, so that the wait for
-        the device and the copy are separate spans.  A burst fetches its
-        token buffer alone, so its ``engine.device_wait`` is that fetch.
-        Returns ``(tokens, logits, stats, wall seconds)``, numpy where
-        fetched (``stats`` stays a device array with the audit off:
-        nothing reads it).  A launch during which a trace counter moved
-        IS that bucket's trace+compile, so its wall time goes to the
-        compile table."""
+        ``engine.device_wait`` waits on the tokens for the program to
+        end; ``engine.fetch`` makes the host arrays of what the step
+        reads and of nothing else.  That is the int32 tokens (a burst's
+        ``[rows, steps]`` buffer), with the audit on also the three
+        floats a row of ``stats``, and on a decode / ragged launch of a
+        step the auditor's schedule samples the ``rows`` real rows of
+        the float32 logits, sliced on the device before they cross
+        (counted by ``serving_logits_fetches_total``).  Every copy is
+        asked for as soon as the program is dispatched, so the fetch
+        finds the arrays on the host; its ``bytes`` are what it copied.
+        Returns ``(tokens, logits, stats, wall seconds)``: ``logits`` is
+        the device array the program returned, ``[.., vocab]`` with the
+        bucket's padding rows, except on such a sampled launch, where it
+        is the host copy of the real rows.  A caller must not keep the
+        device array past its step: it is ``rows x vocab`` float32 of
+        device memory, free again once the step's frame drops it.
+        ``stats`` stays a device array with the audit off: nothing reads
+        it.  A launch during which a trace counter moved IS that
+        bucket's trace+compile, so its wall time goes to the compile
+        table."""
         phase, prof = self.tracer.phase, self.stepprof
         timer, collective = _STEP_TIMERS[program]
+        audit = self.audit.enabled and program in AUDIT_PROGRAMS
+        shadow = self.audit.wants_logits(program)
         traces0 = (self.prefill_trace_count + self.decode_trace_count
                    + self.ragged_trace_count + self.burst_trace_count)
         with StepTimer(self.metrics, timer,
@@ -684,26 +699,50 @@ class EngineCore:
                 load = None
                 if isinstance(stats, tuple):    # a model with routed experts
                     stats, load = stats
-                if fetch_logits:
-                    logits.copy_to_host_async()
-                    if load is not None:
-                        load.copy_to_host_async()
-            if fetch_logits:
-                with phase("engine.device_wait", prof):
-                    logits.block_until_ready()
-                    moe = self._moe_load_ints(program, load)
-                with phase("engine.fetch", prof, bytes=logits.nbytes, **moe):
-                    logits = np.asarray(logits, np.float32)
-                    if self.audit.enabled:
-                        stats = np.asarray(stats, np.float32)
-                    toks = np.asarray(toks, np.int32)
-            else:
-                with phase("engine.device_wait", prof):
-                    toks = np.asarray(toks, np.int32)
+                fetched = [toks]
+                if audit:
+                    fetched.append(stats)
+                if shadow:
+                    logits = logits[:rows]
+                    fetched.append(logits)
+                for arr in fetched:
+                    arr.copy_to_host_async()
+                if load is not None:
+                    load.copy_to_host_async()
+            with phase("engine.device_wait", prof):
+                toks.block_until_ready()
+                moe = self._moe_load_ints(program, load)
+            with phase("engine.fetch", prof,
+                       bytes=sum(arr.nbytes for arr in fetched), **moe):
+                toks = np.asarray(toks, np.int32)
+                if audit:
+                    stats = np.asarray(stats, np.float32)
+                if shadow:
+                    logits = self._host_logits(logits)
         if (self.prefill_trace_count + self.decode_trace_count
                 + self.ragged_trace_count + self.burst_trace_count) > traces0:
             self.stepprof.record_compile(program, bucket, st.dt)
         return toks, logits, stats, st.dt
+
+    def _audit_logits(self, out, rows: int):
+        """What the auditor gets as the logits of a decode / ragged
+        launch (``out`` as ``_launch`` handed it back).  On a sampled
+        step that is the host copy of the real rows, through the
+        ``kernel_corrupt`` fault where one is planned; on any other a
+        callable that fetches them, for the non-finite bundle alone."""
+        if not self.audit.sampled:
+            return lambda: self._host_logits(out[:rows])
+        if self._fault is not None:
+            out = self._fault.corrupt_logits(self.step_seq, out)
+        return out
+
+    def _host_logits(self, rows) -> np.ndarray:
+        """Bring ``rows`` (a launch's logits, already cut to its real
+        rows on the device) to the host, and count the copy."""
+        host = np.asarray(rows, np.float32)
+        self._burst_counters["logits_fetches"].inc()
+        self._burst_counters["logits_fetch_bytes"].inc(host.nbytes)
+        return host
 
     def _moe_load_ints(self, program: str, load) -> Dict[str, int]:
         """The routing load of the launch just run (``[expert layers,
@@ -1317,7 +1356,8 @@ class EngineCore:
                 wall_s=dt, request=str(rid), **attrs)
             if self.audit.enabled:
                 self.audit.observe_program(
-                    program, stats, bucket, logits=logits[None, :],
+                    program, stats, bucket,
+                    logits=lambda: self._host_logits(logits[None, :]),
                     inputs=inputs,
                     requests=[{"id": str(rid),
                                "greedy": req.sampling.temperature == 0.0}])
@@ -1382,20 +1422,17 @@ class EngineCore:
                 # sentinel over the REAL rows (pad rows attend the null
                 # page — their logits are not part of the serving
                 # contract), plus the shadow re-execution when this step
-                # is sampled.  kernel_corrupt (ISSUE 12) corrupts ONLY
-                # this audit copy — the emitted tokens were sampled on
-                # the device from the untouched logits, so served tokens
-                # stay correct while the divergence net trips.  Only
-                # SAMPLED steps run the shadow compare, so the
+                # is sampled: ``out`` is then the host copy of the real
+                # rows (``_launch``).  kernel_corrupt (ISSUE 12) corrupts
+                # ONLY this audit copy — the emitted tokens were sampled
+                # on the device from the untouched logits, so served
+                # tokens stay correct while the divergence net trips.
+                # Only SAMPLED steps run the shadow compare, so the
                 # exactly-once plan entry must not be consumed by a
                 # launch the oracle never checks.
-                audit_logits = out[:B]
-                if self._fault is not None and self.audit.sampled:
-                    audit_logits = self._fault.corrupt_logits(
-                        self.step_seq, audit_logits)
                 self.audit.observe_program(
                     "decode", stats[:B], (Bb, Wb),
-                    logits=audit_logits,
+                    logits=self._audit_logits(out, B),
                     inputs={"ids": ids, "pos": poss, "tables": tables,
                             "lens": lens, "slot_blocks": slot_blocks,
                             "slot_offsets": slot_offsets},
@@ -1475,13 +1512,12 @@ class EngineCore:
                               batch_bucket=Bb, burst_len=n_steps,
                               burst_bucket=Nb, requests=rids,
                               traces=tuple(r.trace_id for r in reqs)):
-            # only the [Bb, Nb] token buffer crosses to the host: a
-            # burst never fetched its logits, and still does not
+            # only the [Bb, Nb] token buffer crosses to the host
             buf, _out, _stats, dt = self._launch(
                 "burst", (Bb, Nb), self._jit_burst,
                 (ids, poss, tables, lens, slot_blocks, slot_offsets,
                  np.int32(n_steps), active, eos_ids, *pack.arrays()),
-                rows=B, fetch_logits=False)
+                rows=B)
         with phase("engine.emit", prof, rows=B):
             result = {}
             emitted_total = 0
@@ -1654,13 +1690,9 @@ class EngineCore:
                 # ragged reference (audit._reference_ragged).  kernel_corrupt
                 # corrupts only this audit copy, on sampled steps only — see
                 # _decode.
-                audit_logits = out[:R]
-                if self._fault is not None and self.audit.sampled:
-                    audit_logits = self._fault.corrupt_logits(
-                        self.step_seq, audit_logits)
                 self.audit.observe_program(
                     "ragged", stats[:R], (Tb, TWb),
-                    logits=audit_logits,
+                    logits=self._audit_logits(out, R),
                     inputs={"ids": ids, "pos": pos, "seg_ids": seg,
                             "last_idx": last_idx, "tables": tables,
                             "lens": lens, "slot_blocks": slot_blocks,

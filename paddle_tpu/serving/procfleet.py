@@ -1573,12 +1573,15 @@ class FleetAutoscaler:
                     continue
                 r.request_stop()
             r.join(10.0)
+            # counted before the replica reads as parked: closing the
+            # worker takes a while, and whoever sees the live count fall
+            # must find the event on the counter
+            self._scale_c["down"].inc()
             r.thread = None  # parked again: invisible to routing and
             # to the supervisor's healing scan, reclaimable by scale-up
             proxy = self.fleet.shared.active.get(r.index)
             if proxy is not None:
                 proxy.close()
-            self._scale_c["down"].inc()
             router.lifecycle.event(
                 None, "scale_event", direction="down",
                 replica=str(r.index),

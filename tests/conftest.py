@@ -27,3 +27,15 @@ def _seed():
     paddle.seed(1234)
     np.random.seed(1234)
     yield
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_mesh_from_another_file():
+    """A test file starts with no global mesh, whichever file the worker
+    ran before it: ``topology`` keeps the mesh in a module global, and a
+    file whose last engine was built at ``mp=2`` used to hand it on (the
+    next file's engines then read ``mp=2``)."""
+    from paddle_tpu.distributed import topology
+
+    topology.set_mesh(None)
+    yield

@@ -231,7 +231,10 @@ class TestCleanAudit:
         _run(off, _prompts(n=1), max_new=3)
         text = off.metrics.prometheus_text()
         assert "serving_audit" not in text
-        assert "serving_logit" not in text
+        # the auditor's histograms (serving_logit_absmax / _margin); the
+        # launch loop's own serving_logits_fetches_total is there, at 0
+        assert "serving_logit_" not in text
+        assert "serving_logits_fetches_total 0" in text
 
     def test_sample_schedule_deterministic(self):
         eng = _engine(audit=AuditConfig(enabled=True, sample_every=3),

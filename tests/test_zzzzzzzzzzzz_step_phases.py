@@ -11,6 +11,9 @@ The contract of ``SpanTracer.phase`` on the serving step path:
   as child spans, nested, in the order they ran;
 * the phases, the wait cut off the fetch and the named scopes add no jit
   trace and leave greedy tokens as they were;
+* ``engine.fetch`` carries the bytes the launch copied to the host: its
+  int32 tokens, and nothing of the ``[rows, vocab]`` logits unless the
+  numerics audit reads them (ISSUE 30);
 * ``arm_capture`` holds the step's lock across neither ``start_trace`` nor
   ``stop_trace``: steps complete while a slow profiler starts and stops;
 * request ids ride step records and step spans as the tuple the engine
@@ -25,6 +28,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.observability import tracer as tracer_mod
+from paddle_tpu.observability.audit import AuditConfig
 from paddle_tpu.observability.export import chrome_trace_dict
 from paddle_tpu.observability.tracer import STEP_PHASES, SpanTracer
 from paddle_tpu.serving import (
@@ -70,6 +74,26 @@ def _submit(eng, prompts, max_new=6):
             for p in prompts]
 
 
+def _copied(rec, audit=False, sampled=False, vocab=0):
+    """Bytes the launch behind one program record of the step profiler
+    copies to the host in ``engine.fetch``: one int32 a token slot of
+    its bucket; with the audit on three float32 a logits row of the
+    bucket beside them, and on a sampled decode / ragged launch the REAL
+    rows of float32 logits."""
+    program = rec["program"]
+    bucket = [int(b) for b in rec["bucket"].split("x")]
+    slots = {"prefill": 1, "chunk": 1, "decode": bucket[0],
+             "ragged": bucket[0], "burst": bucket[0] * bucket[-1]}[program]
+    n = 4 * slots
+    if audit and program != "burst":
+        n += 12 * slots
+        if sampled and program in ("decode", "ragged"):
+            real = rec["rows"] if program == "ragged" \
+                else rec["scheduled_tokens"]
+            n += 4 * vocab * real
+    return n
+
+
 class _FakeAnnotation:
     """Stands in for ``jax.profiler.TraceAnnotation``: records what each
     phase site made."""
@@ -106,12 +130,13 @@ class TestUnarmedStep:
                                                        recorded):
         eng = _engine(family)
         _submit(eng, _prompts())
-        per_step = []
+        per_step, copied = [], []
         while eng.scheduler.has_work():
             del recorded[:]
             eng.step()
-            launches = len(eng.stepprof.last_record()["programs"])
-            per_step.append((launches, list(recorded)))
+            programs = eng.stepprof.last_record()["programs"]
+            per_step.append((len(programs), list(recorded)))
+            copied += [_copied(rec) for rec in programs]
             assert len(per_step) < 400
         assert eng.stepprof.bucket_set(family), \
             f"the run never launched a {family} program"
@@ -125,21 +150,47 @@ class TestUnarmedStep:
                     (name, kwargs)
             for name in PER_STEP:
                 assert names.count(name) == 1, (name, names)
-            for name in LAUNCH[:3]:
+            # one of each a launch, a burst's too
+            for name in LAUNCH[:4]:
                 assert names.count(name) == launches, (name, names)
-            # a burst brings back its token buffer alone
-            assert names.count("engine.fetch") <= launches
             # every launch's emission, and the step's retire
             assert names.count("engine.emit") == launches + 1
-        fetches = [kw for _, made in per_step for n, kw in made
+        # audit off: a launch brings back its int32 tokens -- one a row
+        # of the bucket, a burst's [rows, steps] buffer -- and no logits
+        fetches = [kw["bytes"] for _, made in per_step for n, kw in made
                    if n == "engine.fetch"]
-        if family == "burst":
-            assert any(launches and "engine.fetch" not in
-                       [n for n, _ in made] for launches, made in per_step)
-        else:
-            vocab = eng.model.config.vocab_size
-            assert fetches and all(kw["bytes"] % (4 * vocab) == 0
-                                   and kw["bytes"] > 0 for kw in fetches)
+        assert fetches and fetches == copied
+        assert max(fetches) < 4 * eng.model.config.vocab_size
+
+    @pytest.mark.parametrize("family", sorted(FAMILIES))
+    def test_fetch_bytes_with_the_audit_on(self, family, recorded):
+        """Audit on: the stats ride every launch the auditor observes,
+        and the logits cross on the sampled decode / ragged launches
+        alone, cut to their real rows."""
+        eng = _engine(family, audit=AuditConfig(enabled=True,
+                                                sample_every=2))
+        vocab = eng.model.config.vocab_size
+        _submit(eng, _prompts())
+        fetches, copied, with_logits = [], [], 0
+        while eng.scheduler.has_work():
+            del recorded[:]
+            eng.step()
+            sampled = eng.audit.sampled
+            for rec in eng.stepprof.last_record()["programs"]:
+                copied.append(_copied(rec, audit=True, sampled=sampled,
+                                      vocab=vocab))
+                with_logits += copied[-1] > 4 * vocab
+            fetches += [kw["bytes"] for n, kw in recorded
+                        if n == "engine.fetch"]
+            assert len(fetches) < 400
+        assert eng.stepprof.bucket_set(family)
+        assert fetches == copied
+        assert eng.audit.snapshot()["divergences"] == \
+            {"token": 0, "logit": 0, "nonfinite": 0}
+        # the launch loop's own count of the copies agrees
+        count = eng.metrics.registry.counter(
+            "serving_logits_fetches_total", **eng.metrics.labels).value
+        assert count == with_logits > 0
 
     def test_phase_is_the_annotation_itself(self):
         """Off the capture path the helper hands back the annotation: no
@@ -193,9 +244,12 @@ class TestArmedWindow:
             assert all(a <= b["ts"] + 1.0
                        for a, b in zip(ends, phases[1:]))
             fetch = phases[names.index("engine.fetch")]
-            assert fetch["args"]["bytes"] > 0
-            assert phases[names.index("engine.dispatch")]["args"]["rows"] \
-                >= 1
+            dispatch = phases[names.index("engine.dispatch")]["args"]
+            # audit off: the int32 tokens alone, one a row of the decode
+            # bucket; a prefill's one token
+            assert fetch["args"]["bytes"] == \
+                4 * (dispatch["bucket"] if program == "decode" else 1)
+            assert dispatch["rows"] >= 1
         assert found >= 1
 
     def test_window_closes_the_sink(self):

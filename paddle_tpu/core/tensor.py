@@ -433,6 +433,9 @@ def _unwrap_index(idx):
         return tuple(_unwrap_index(i) for i in idx)
     if isinstance(idx, list):
         return jnp.asarray(idx)
+    if isinstance(idx, np.bool_):
+        # JAX's indexer takes a scalar mask only as the builtin bool
+        return bool(idx)
     return idx
 
 

@@ -96,8 +96,8 @@ def train_flops_per_token(n_params: float, num_layers: int = 0,
     """PaLM-style training FLOPs per token: ``6N`` for the parameter ops
     (fwd 2N + bwd 4N) plus ``12·L·S·H`` for the attention score/context
     matmuls when the geometry is given.  The MFU denominator everyone
-    reports against — the one accounting shared by the cost model below,
-    ``bench.py`` and ``observability.telemetry`` (pinned by
+    reports against — the one accounting shared by the cost model below
+    and ``observability.telemetry`` (pinned by
     tests/test_mfu_accounting.py)."""
     return 6.0 * n_params + 12.0 * num_layers * seq_len * hidden
 

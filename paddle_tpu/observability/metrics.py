@@ -9,7 +9,7 @@ observations a long-lived server records (the invariant
   format 0.0.4 (``# HELP`` / ``# TYPE`` lines, label escaping,
   cumulative ``_bucket{le=...}`` histogram series);
 * :meth:`MetricsRegistry.snapshot` — a JSON-able dict, the shape
-  ``bench.py`` embeds into its per-phase records.
+  flight bundles and the debug endpoints embed.
 
 Series cardinality is capped (``max_series``): creating a metric beyond
 the cap raises instead of silently growing, because unbounded label

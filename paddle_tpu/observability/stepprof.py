@@ -528,9 +528,8 @@ class StepProfiler:
         return rows
 
     def utilization_report(self) -> Dict:
-        """JSON-able padding-waste report (``bench.py`` embeds this per
-        serving phase): per-program totals + per-bucket rows + the
-        overall scheduled/padding split."""
+        """JSON-able padding-waste report: per-program totals +
+        per-bucket rows + the overall scheduled/padding split."""
         rows = self.program_table()
         programs: Dict[str, Dict] = {}
         for r in rows:

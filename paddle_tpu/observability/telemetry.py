@@ -4,9 +4,9 @@ A thin helper that turns per-step wall times into the registry series
 and tracer spans the ROADMAP's "fast as the hardware allows" work needs,
 reusing the flops accounting of
 :func:`paddle_tpu.distributed.auto_tuner.train_flops_per_token` (the
-same ``6N + 12·L·S·H`` formula ``bench.py`` pins in
-tests/test_mfu_accounting.py) so MFU numbers are comparable across the
-bench harness, the auto-tuner cost model, and live training telemetry.
+same ``6N + 12·L·S·H`` formula tests/test_mfu_accounting.py pins) so
+MFU numbers are comparable across the auto-tuner cost model and live
+training telemetry.
 
 Usage::
 

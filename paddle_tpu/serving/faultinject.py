@@ -9,8 +9,8 @@ fleet-config-style value (the :class:`~paddle_tpu.observability.audit
 no randomness) listing *exactly when* each fault fires, keyed by the
 target replica's deterministic engine-step counter.  The same plan on
 the same request stream produces the same chaos run every time, which
-is what lets ``bench.py --serving`` and ``tests/test_zz_resilience.py``
-assert greedy token identity *across* injected failures.
+is what lets ``tests/test_zz_resilience.py`` assert greedy token
+identity *across* injected failures.
 
 Named injection points, threaded through :class:`~paddle_tpu.serving
 .EngineCore` (see ``engine.step()``):

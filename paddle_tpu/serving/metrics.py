@@ -103,7 +103,7 @@ _HISTOGRAM_NAMES = (
     "e2e",
 )
 
-# the SLO breakdown quartet, in pipeline order (bench.py embeds these)
+# the SLO breakdown quartet, in pipeline order
 SLO_PHASES = ("queue_wait", "prefill", "decode_itl", "e2e")
 
 # mesh-spanning step phases (ISSUE 5): pre-registered so the
@@ -252,9 +252,8 @@ class ServingMetrics:
             return int(good_c.value), int(slo_c.value)
 
     def slo_breakdown(self) -> Dict[str, Dict]:
-        """JSON-able per-phase latency breakdown (the shape ``bench.py``
-        embeds per phase): count/avg/p50/p95/p99 for each SLO phase plus
-        the goodput pair."""
+        """JSON-able per-phase latency breakdown: count/avg/p50/p95/p99
+        for each SLO phase plus the goodput pair."""
         out: Dict[str, Dict] = {}
         for name in SLO_PHASES:
             h = self._hist(name)

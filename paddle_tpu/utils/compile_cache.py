@@ -2,7 +2,7 @@
 
 The cache key includes the directory, so a directory that moves never
 hits: every entry point (``chip_smoke.py``, the serving server and worker
-mains, ``bench.py``, the tools) calls :func:`configure_compile_cache` and
+mains, ``benchmarks/``, the tools) calls :func:`configure_compile_cache` and
 nothing else in the repo names a cache directory.
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself and

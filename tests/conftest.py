@@ -39,3 +39,25 @@ def _no_mesh_from_another_file():
 
     topology.set_mesh(None)
     yield
+
+
+@pytest.fixture(scope="session")
+def hold_intake():
+    """``hold_intake(replica)`` keeps a fleet replica's engine thread from
+    taking anything in until the returned event is set.  What it then
+    finds queued it meets in one piece and in submission order, so the
+    step, launch and cache counts of a fixed stream do not depend on how
+    the submitting thread and the engine threads interleave."""
+    import threading
+
+    def hold(replica):
+        gate = threading.Event()
+
+        def held(real=replica._drain_submissions):
+            gate.wait()
+            real()
+
+        replica._drain_submissions = held
+        return gate
+
+    return hold

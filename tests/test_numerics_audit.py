@@ -208,6 +208,7 @@ class TestCleanAudit:
         assert on.decode_buckets == off.decode_buckets
         # the run preempted/chunked and still audited clean
         assert on.metrics.counters["preemptions"] > 0
+        assert off.metrics.counters["preemptions"] > 0
         snap = on.audit.snapshot()
         assert snap["status"] == "ok"
         assert sum(snap["divergences"].values()) == 0
@@ -442,9 +443,16 @@ class TestFleetAudit:
         assert {b["replica"] for b in bundles} == diverged
         assert len(bundles) == 2
         for r in fleet.replicas:
-            snap = r.engine.audit.snapshot()
-            assert len(snap["repros"]) == 1
-            assert f"_r{r.index}_" in snap["repros"][0]
+            # the auditor writes one repro per (kind, program): the token
+            # divergence always, and a logit one beside it when a launch
+            # whose argmax flip was a tie diverged in value first
+            repros = r.engine.audit.snapshot()["repros"]
+            metas = [load_repro(p)["meta"] for p in repros]
+            kinds = sorted(m["kind"] for m in metas)
+            assert kinds in (["token"], ["logit", "token"])
+            assert {m["program"] for m in metas} == {"decode"}
+            assert {m["replica"] for m in metas} == {str(r.index)}
+            assert all(f"_r{r.index}_" in path for path in repros)
         # per-replica-labeled divergence series on the shared registry
         text = fleet.registry.prometheus_text()
         assert 'serving_audit_divergence_total' in text

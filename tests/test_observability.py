@@ -404,7 +404,7 @@ class TestServingObservability:
 
 
 # --------------------------------------------------------------------------
-# train-step telemetry (MFU accounting shared with bench/auto_tuner)
+# train-step telemetry (MFU accounting shared with auto_tuner)
 # --------------------------------------------------------------------------
 class TestTrainStepTelemetry:
     def test_mfu_matches_shared_flops_accounting(self):
@@ -426,15 +426,6 @@ class TestTrainStepTelemetry:
         assert snap["train_step_seconds"]["count"] == 1
         (ev,) = [s for s in tr.spans() if s.name == "train_step"]
         assert ev.attrs["tokens"] == 4096
-
-    def test_bench_delegates_to_auto_tuner_accounting(self):
-        from bench import train_flops_per_token as bench_fn
-        from paddle_tpu.distributed.auto_tuner import (
-            train_flops_per_token as tuner_fn,
-        )
-
-        assert (bench_fn(100_000_000, 6, 2048, 1024)
-                == tuner_fn(100_000_000, 6, 2048, 1024))
 
 
 # --------------------------------------------------------------------------

@@ -122,7 +122,7 @@ class TestAutotuneCache:
 
     def test_key_is_batch_invariant(self, monkeypatch):
         """Block choice depends on (seq, heads, head_dim), not batch —
-        bench's OOM-ladder batch halving must keep hitting the cache."""
+        a caller that halves its batch must keep hitting the cache."""
         from paddle_tpu.ops import autotune as at
 
         monkeypatch.setattr(at, "_memory", {})

@@ -88,13 +88,28 @@ class TestHostOpStats:
         report = prof.summary()
         assert "stale_op" not in report
 
-    def test_device_stats_from_trace_fixture(self):
-        import os
+    def test_device_stats_from_trace_fixture(self, tmp_path):
+        import gzip
+        import json
 
         from paddle_tpu.profiler.statistic import collect_device_stats
 
-        fixture = os.path.join(os.path.dirname(__file__), "fixtures",
-                               "mfu_trace")
-        dev = collect_device_stats(fixture)
+        # one device lane and one host lane, in the chrome format
+        # ``jax.profiler`` writes
+        run = tmp_path / "plugins" / "profile" / "fixture_run"
+        run.mkdir(parents=True)
+        with gzip.open(str(run / "device.trace.json.gz"), "wt") as f:
+            json.dump({"traceEvents": [
+                {"ph": "M", "name": "process_name", "pid": 1,
+                 "args": {"name": "/device:TPU:0"}},
+                {"ph": "M", "name": "process_name", "pid": 2,
+                 "args": {"name": "python host"}},
+                {"ph": "X", "name": "python_dispatch", "pid": 2, "tid": 1,
+                 "ts": 900, "dur": 5000},
+                {"ph": "X", "name": "dot_general.7", "pid": 1, "tid": 1,
+                 "ts": 1000, "dur": 300},
+                {"ph": "X", "name": "fusion.12", "pid": 1, "tid": 1,
+                 "ts": 1300, "dur": 200}]}, f)
+        dev = collect_device_stats(str(tmp_path))
         assert dev["dot_general.7"].total == pytest.approx(300e-6)
         assert "python_dispatch" not in dev  # host lane excluded

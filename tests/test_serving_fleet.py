@@ -40,6 +40,7 @@ from paddle_tpu.serving import (
     SamplingParams,
     SchedulerConfig,
 )
+from paddle_tpu.serving.fleet import affinity_replica_index
 from paddle_tpu.serving.server import CompletionServer, ServerConfig
 
 BS = 4  # block size everywhere in this file
@@ -257,6 +258,102 @@ class TestDpTokenIdentity:
             for r in fleet.replicas:
                 assert r.engine.kv.occupancy() == 0.0, \
                     f"replica {r.index} leaked blocks"
+
+
+# --- affinity keeps the cache warm -------------------------------------------
+
+class TestAffinityKeepsCachedRatio:
+    """Two shared-prefix families, picked so the dp=2 ring sends one to
+    each replica, through a FIXED total capacity: dp=1 serves the stream
+    on one engine with the combined pool (29 blocks, 8 seqs), dp=2
+    halves both per replica (15 blocks, 4 seqs).  Either pool is too
+    small for its concurrent 16 + 10-token sequences, so every engine
+    preempts.  Consistent-hash affinity keeps each family on ONE replica,
+    so no replica's cached-token ratio falls under the dp=1 ratio
+    (round-robin would recompute every prefix on every replica it
+    touched).  Ratios are asserted as the hit and computed token counts
+    they are made of, exact on this stream."""
+
+    @pytest.fixture(scope="class")
+    def runs(self, hold_intake):
+        rng = np.random.default_rng(0)
+        fam_a = rng.integers(0, 256, 8).tolist()
+        target_a = affinity_replica_index(fam_a, dp=2, block_size=BS)
+        while True:
+            fam_b = rng.integers(0, 256, 8).tolist()
+            if affinity_replica_index(fam_b, dp=2, block_size=BS) \
+                    != target_a:
+                break
+        prompts = []
+        for _ in range(4):
+            prompts.append(fam_a + rng.integers(0, 256, 8).tolist())
+            prompts.append(fam_b + rng.integers(0, 256, 8).tolist())
+
+        def replica_row(eng):
+            c = eng.metrics.counters
+            return {"admitted": c["requests_admitted"],
+                    "hit": c["prefix_cache_hit_tokens"],
+                    "computed": c["prefill_tokens_computed"],
+                    "preempted": c["preemptions"] > 0,
+                    "bounded": (eng.prefill_trace_count
+                                <= len(eng.prefill_buckets)
+                                and eng.decode_trace_count
+                                <= len(eng.decode_buckets))}
+
+        out = {}
+        for dp, num_blocks, max_seqs in ((1, 29, 8), (2, 15, 4)):
+            fleet = _fleet(dp, num_blocks=num_blocks,
+                           max_num_seqs=max_seqs, chunk=8)
+            try:
+                gates = [hold_intake(r) for r in fleet.replicas]
+                handles = [fleet.submit_request(
+                    p, SamplingParams(max_new_tokens=10),
+                    request_id=f"r{i}") for i, p in enumerate(prompts)]
+                for gate in gates:
+                    gate.set()
+                fleet.wait(handles, timeout=600)
+                out[dp] = {
+                    "outputs": {h.rid: h.output_tokens for h in handles},
+                    "routing": dict(fleet.routing_counts),
+                    "evaluations": fleet.alerts.snapshot()["evaluations"],
+                    "transitioned": sorted(
+                        name for name, trs in
+                        fleet.alerts.transitions_report().items() if trs),
+                    "replicas": [replica_row(r.engine)
+                                 for r in fleet.replicas]}
+            finally:
+                fleet.shutdown(drain_timeout=2.0)
+        return out
+
+    def test_outputs_identical(self, runs):
+        assert runs[1]["outputs"] == runs[2]["outputs"]
+
+    def test_every_request_routed_by_affinity(self, runs):
+        assert runs[2]["routing"] == {"affinity_hit": 8,
+                                      "fallback_routed": 0}
+
+    @pytest.mark.parametrize("dp", [1, 2])
+    def test_fault_free_run_never_alerts_on_restarts(self, runs, dp):
+        # the router's default-on history and rule set saw the whole
+        # stream, and nothing restarted
+        assert runs[dp]["evaluations"] > 0
+        assert "restart_churn" not in runs[dp]["transitioned"]
+
+    @pytest.mark.parametrize("dp,replica,want", [
+        (1, 0, {"admitted": 8, "hit": 60, "computed": 86}),
+        (2, 0, {"admitted": 4, "hit": 48, "computed": 57}),
+        (2, 1, {"admitted": 4, "hit": 48, "computed": 57})])
+    def test_replica_counts(self, runs, dp, replica, want):
+        row = runs[dp]["replicas"][replica]
+        assert {k: row[k] for k in want} == want
+        assert row["preempted"] and row["bounded"]
+
+    def test_no_replica_under_the_dp1_ratio(self, runs):
+        base = runs[1]["replicas"][0]
+        for row in runs[2]["replicas"]:
+            # hit/(hit+computed) >= base's, without the division
+            assert row["hit"] * (base["hit"] + base["computed"]) \
+                >= base["hit"] * (row["hit"] + row["computed"])
 
 
 # --- affinity routing --------------------------------------------------------

@@ -127,19 +127,46 @@ class TestTokenIdentity:
 
 
 class TestTraceBounds:
-    def test_trace_count_bounded_and_mp_invariant(self):
+    """Six prompts sharing two full blocks through a pool too small for
+    four of them (14 usable blocks, 16 + 10 tokens each), chunk budget
+    8, prefix cache on: what each degree compiles and preempts."""
+
+    @pytest.fixture(scope="class")
+    def runs(self):
+        rng = np.random.default_rng(0)
+        prefix = rng.integers(0, 256, 8).tolist()
+        prompts = [prefix + rng.integers(0, 256, 8).tolist()
+                   for _ in range(6)]
+
+        def scenario(mp):
+            eng = _engine(mp, num_blocks=15, prefill_budget=8)
+            return {"outputs": _run(eng, prompts, max_new=10),
+                    "prefill_traces": eng.prefill_trace_count,
+                    "decode_traces": eng.decode_trace_count,
+                    "prefill_buckets": eng.prefill_buckets,
+                    "decode_buckets": eng.decode_buckets,
+                    "preemptions": eng.metrics.counters["preemptions"]}
+
+        return dict(zip((1, 2), _both_degrees(scenario)))
+
+    def test_trace_count_bounded_and_mp_invariant(self, runs):
         """jit trace counts stay bounded by the bucket sets at mp=2 and
         equal the mp=1 counts — sharding must not add retraces."""
-        def scenario(mp):
-            eng = _engine(mp, num_blocks=12, prefill_budget=8)
-            _run(eng, PROMPTS, max_new=8)
-            assert eng.prefill_trace_count <= len(eng.prefill_buckets)
-            assert eng.decode_trace_count <= len(eng.decode_buckets)
-            return (eng.prefill_trace_count, eng.decode_trace_count,
-                    eng.prefill_buckets, eng.decode_buckets)
+        for r in runs.values():
+            assert r["prefill_traces"] <= len(r["prefill_buckets"])
+            assert r["decode_traces"] <= len(r["decode_buckets"])
+        assert ({k: v for k, v in runs[1].items() if k != "outputs"}
+                == {k: v for k, v in runs[2].items() if k != "outputs"})
 
-        r1, r2 = _both_degrees(scenario)
-        assert r1 == r2
+    @pytest.mark.parametrize("mp", [1, 2])
+    @pytest.mark.parametrize("name,want", [
+        ("prefill_traces", 5), ("decode_traces", 3), ("preemptions", 3)])
+    def test_count(self, runs, mp, name, want):
+        # exact on this fixed stream: one more trace IS the regression
+        assert runs[mp][name] == want
+
+    def test_outputs_identical(self, runs):
+        assert runs[1]["outputs"] == runs[2]["outputs"]
 
 
 class TestConfig:

@@ -10,12 +10,9 @@ Covers the tentpole's correctness bar:
 * jit trace count still bounded by the bucket sets with chunking on;
 * the admission fix: a warm cache admits prompts a cold pool cannot
   (charging the uncached tail, not the whole prompt);
-* the bench serving phase's counter contract (cached-token ratio > 0,
-  fewer prefill tokens computed, trace counts unchanged).
+* the shared-prefix stream's exact counters cache on vs off (hit and
+  computed prefill tokens, trace counts unchanged).
 """
-
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -31,9 +28,6 @@ from paddle_tpu.serving import (
     SamplingParams,
     SchedulerConfig,
 )
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, _REPO)
 
 PROMPTS = [[5, 9, 23, 7], [40, 2, 11], [1, 2, 3, 4, 5, 6], [100, 101]]
 
@@ -425,30 +419,56 @@ class TestAdmissionCapacity:
 
 
 # --------------------------------------------------------------------------
-# bench serving phase (ISSUE 4 satellite)
+# the shared-prefix stream, cache on vs off: exact counters
 # --------------------------------------------------------------------------
-class TestBenchServingPhase:
+class TestSharedPrefixStream:
     def test_shared_prefix_phase_counters(self):
-        """Acceptance: cached-token ratio > 0 and FEWER prefill tokens
-        computed with the cache on, greedy outputs identical, jit trace
-        counts unchanged between the two runs."""
-        import bench
+        """Six prompts sharing two full blocks, chunk budget 8 (prefix =
+        one chunk, so the chunk buckets coincide cache on and off):
+        FEWER prefill tokens computed with the cache on, greedy outputs
+        identical, jit trace counts unchanged — each as the exact count
+        this stream gives."""
+        rng = np.random.default_rng(0)
+        prefix = rng.integers(0, 256, 8).tolist()
+        prompts = [prefix + rng.integers(0, 256, 8).tolist()
+                   for _ in range(6)]
 
-        res = bench.serving_bench()
-        on, off = res["cache_on"], res["cache_off"]
-        assert res["greedy_token_identical"]
-        assert on["cached_token_ratio"] > 0
-        assert off["cached_token_ratio"] == 0
-        assert on["prefix_cache_hit_tokens"] > 0
-        assert (on["prefill_tokens_computed"]
-                < off["prefill_tokens_computed"])
-        assert res["value"] == (off["prefill_tokens_computed"]
-                                - on["prefill_tokens_computed"])
+        def run(prefix_cache):
+            eng = _engine(_model(), num_blocks=128, budget=8,
+                          prefix_cache=prefix_cache)
+            # 6 new tokens keep requests alive long enough that BOTH
+            # runs sweep the same decode batch buckets {1,2,4}
+            reqs = [eng.add_request(p, SamplingParams(max_new_tokens=6),
+                                    slo_ms=60_000.0) for p in prompts]
+            eng.run(max_steps=2000)
+            assert all(r.finished for r in reqs)
+            return eng, [list(r.output_tokens) for r in reqs]
+
+        (on, on_out), (off, off_out) = run(True), run(False)
+        assert on_out == off_out
+        c_on, c_off = on.metrics.counters, off.metrics.counters
+        # cached-token ratio 40 / (40 + 56): five followers fork the
+        # 8-token prefix, and the saving is exactly those 40 tokens
+        assert c_on["prefix_cache_hit_tokens"] == 40
+        assert c_on["prefill_tokens_computed"] == 56
+        assert c_off["prefix_cache_hit_tokens"] == 0
+        assert c_off["prefill_tokens_computed"] == 96
         # fixed-shape discipline: the cache changes WHICH tokens run, not
         # which programs compile
-        assert on["prefill_traces"] == off["prefill_traces"]
-        assert on["decode_traces"] == off["decode_traces"]
-        # TTFT/ITL histograms ride in the phase snapshots
-        for snap in (on["metrics"], off["metrics"]):
+        for eng in (on, off):
+            assert eng.prefill_trace_count == 2
+            assert eng.decode_trace_count == 3
+            # every request was scored against its SLO, and the step
+            # profiler's scheduled-token sum is the scheduler's ledger
+            good = eng.metrics.slo_breakdown()["goodput"]
+            assert good["slo_total"] == 6 and good["slo_good"] == 6
+            rep = eng.stepprof.utilization_report()
+            assert rep["padding_ratio"] is not None
+            assert rep["scheduled_tokens"] == eng.scheduler.tokens_planned
+            # TTFT/ITL histograms ride in the registry snapshot
+            snap = eng.metrics.snapshot()
             assert "serving_time_to_first_token_seconds" in snap
             assert "serving_inter_token_latency_seconds" in snap
+        # per-request cache attribution sums to the hit counter
+        attr = on.cachestat.snapshot()["attribution"]
+        assert attr["cached_tokens_total"] == 40

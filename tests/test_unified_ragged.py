@@ -282,49 +282,58 @@ class TestUnifiedTokenIdentity:
         legacy, uni, _ = _legacy_vs_unified(scenario)
         assert legacy == uni
 
-    def test_bucket_set_collapses(self):
-        """The unified engine's one program family compiles strictly
-        fewer shapes than the legacy three on the same preempting,
-        chunk-budgeted, prefix-cached stream — the compile-count half of
-        the padding-waste claim."""
+    @pytest.fixture(scope="class")
+    def collapse(self):
+        """Legacy and unified engines after the same preempting,
+        chunk-budgeted, prefix-cached stream (six prompts sharing two
+        full blocks, 14 usable blocks, one 8-token budget)."""
         rng = np.random.default_rng(0)
         prefix = rng.integers(0, 256, 8).tolist()
         prompts = [prefix + rng.integers(0, 256, 8).tolist()
                    for _ in range(6)]
+        engines = {}
 
         def scenario(unified):
-            eng = _engine(unified=unified, num_blocks=15,
-                          prefill_budget=8,
-                          token_budget=8 if unified else None)
-            outs = _run(eng, prompts, max_new=10)
-            assert eng.metrics.counters["preemptions"] > 0
-            return outs, eng
+            eng = engines[unified] = _engine(
+                unified=unified, num_blocks=15, prefill_budget=8,
+                token_budget=8 if unified else None)
+            return _run(eng, prompts, max_new=10), eng
 
-        legacy_eng = None
+        legacy, uni, _ = _legacy_vs_unified(scenario)
+        return legacy, uni, engines[False], engines[True]
 
-        def legacy_scenario(unified):
-            nonlocal legacy_eng
-            outs, eng = scenario(unified)
-            if not unified:
-                legacy_eng = eng
-            return outs, eng
-
-        legacy, uni, eng = _legacy_vs_unified(legacy_scenario)
+    def test_bucket_set_collapses(self, collapse):
+        """The unified engine's one program family compiles strictly
+        fewer shapes than the legacy three on the same stream — the
+        compile-count half of the padding-waste claim."""
+        legacy, uni, legacy_eng, eng = collapse
         assert legacy == uni
         legacy_buckets = (len(legacy_eng.prefill_buckets)
                           + len(legacy_eng.decode_buckets))
-        legacy_traces = (legacy_eng.prefill_trace_count
-                         + legacy_eng.decode_trace_count)
         assert len(eng.ragged_buckets) < legacy_buckets, (
             f"unified bucket set {sorted(eng.ragged_buckets)} is not "
             f"smaller than the legacy three-family set "
             f"({sorted(legacy_eng.prefill_buckets)} + "
             f"{sorted(legacy_eng.decode_buckets)})")
-        assert eng.ragged_trace_count < legacy_traces
-        # the scheduled-token invariant holds in unified mode: the
-        # packed program's scheduled sum equals the planner's ledger
+
+    @pytest.mark.parametrize("unified,name,want", [
+        (True, "traces", 6), (False, "traces", 8),
+        # padding ratio 14/142 unified against 17/146 legacy, as the
+        # integer counts it is made of
+        (True, "padding_tokens", 14), (True, "capacity_tokens", 142),
+        (False, "padding_tokens", 17), (False, "capacity_tokens", 146),
+        (True, "preempted", True), (False, "preempted", True)])
+    def test_fixed_stream_count(self, collapse, unified, name, want):
+        eng = collapse[3] if unified else collapse[2]
         rep = eng.stepprof.utilization_report()
+        # the scheduled-token invariant that makes the padding numbers
+        # trustworthy: the profiler's sum is the planner's ledger
         assert rep["scheduled_tokens"] == eng.scheduler.tokens_planned
+        got = {"traces": (eng.prefill_trace_count + eng.decode_trace_count
+                          + eng.ragged_trace_count),
+               "preempted": eng.metrics.counters["preemptions"] > 0,
+               **rep}[name]
+        assert got == want
 
 
 # --- audit soak --------------------------------------------------------------

@@ -445,21 +445,38 @@ class TestPoolExhaustInjection:
 # quarantine-and-replace (kernel_corrupt -> audit degraded)
 # --------------------------------------------------------------------------
 class TestQuarantine:
-    def test_corrupt_quarantines_replaces_audit_ok(self, tmp_path):
+    @pytest.mark.parametrize("cascade", [False, True],
+                             ids=["corrupt", "death_then_corrupt"])
+    def test_corrupt_quarantines_replaces_audit_ok(self, tmp_path, cascade):
+        """``corrupt``: the replica with the traffic audits a corrupted
+        copy and is quarantined and replaced.  ``death_then_corrupt``:
+        the cascade — the replica with the traffic dies, its stream is
+        re-dispatched onto the OTHER replica, which only then starts
+        stepping and meets its own corruption: exactly one restart per
+        cause, and every request still finishes with the fault-free
+        greedy tokens across BOTH faults."""
         prompts = _prompts(6)
         _expected(max_new=8, n=6)  # reference cached before the rebuild
         target = _affinity_target(prompts[0])
-        plan = FaultPlan(faults=(
-            FaultSpec(point="kernel_corrupt", step=5,
-                      replica=str(target)),))
+        if cascade:
+            faults = (FaultSpec(point="engine_step_raise", step=6,
+                                replica=str(target)),
+                      FaultSpec(point="kernel_corrupt", step=4,
+                                replica=str(1 - target)))
+            corrupted = 1 - target
+        else:
+            faults = (FaultSpec(point="kernel_corrupt", step=5,
+                                replica=str(target)),)
+            corrupted = target
         fleet, sup = _build(
-            plan=plan, flight_dir=str(tmp_path),
+            plan=FaultPlan(faults=faults), flight_dir=str(tmp_path),
             audit=AuditConfig(enabled=True, sample_every=1),
             sup_cfg=SupervisorConfig(quarantine_drain_s=10.0,
                                      **_FAST_SUP))
         try:
             hs = [fleet.submit_request(
-                p, SamplingParams(max_new_tokens=8), request_id=f"q{i}")
+                p, SamplingParams(max_new_tokens=8), request_id=f"q{i}",
+                retryable=cascade)
                 for i, p in enumerate(prompts)]
             fleet.wait(hs, timeout=120)
             # the corruption hit only the AUDIT copy: every request
@@ -469,21 +486,29 @@ class TestQuarantine:
                 assert h.finish_reason == "length"
                 assert h.output_tokens == expected[i]
             # quarantine completed: replica replaced, audit ok again
-            _wait(lambda: (int(sup._quar_c.value) == 1
-                           and fleet.replicas[target].healthy
-                           and fleet.replicas[target].engine.audit.status
+            # (a restart's counter moves last, after the swap)
+            restarts = {"engine_death": int(cascade), "watchdog": 0,
+                        "quarantine": 1}
+            _wait(lambda: ({c: int(v.value)
+                            for c, v in sup._restarts.items()} == restarts
+                           and all(r.healthy for r in fleet.replicas)
+                           and fleet.replicas[corrupted].engine.audit.status
                            == "ok"),
                   msg="quarantine + replacement")
             assert all(r.engine.audit.status == "ok"
                        for r in fleet.replicas)
-            assert int(sup._restarts["quarantine"].value) == 1
+            assert int(sup._quar_c.value) == 1
+            assert int(sup._failed_c.value) == 0
             # exactly one flight bundle per action: the audit's
-            # divergence dump + the supervisor's quarantine dump
+            # divergence dump + the supervisor's quarantine dump (+ the
+            # death's own)
             names = sorted(os.listdir(str(tmp_path)))
             assert sum(n.startswith("flight_divergence")
                        for n in names) == 1, names
             assert sum(n.startswith("flight_quarantine")
                        for n in names) == 1, names
+            assert sum(n.startswith("flight_engine_death")
+                       for n in names) == int(cascade), names
             # the replacement serves
             h = fleet.submit_request(prompts[0],
                                      SamplingParams(max_new_tokens=4),

@@ -665,13 +665,24 @@ class TestCompileCacheReuse:
         monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
         monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
 
-        def boot():
+        def boot(serve=False):
             pf = ProcessFleet(_cfg(aot_dir, dp=1, warm_boot=True))
             try:
                 wh = pf.proxy(0).worker
                 assert wh.compile_cache is not None, \
                     "worker printed no compile-cache boot line"
                 assert wh.compile_cache["dir"] == cache
+                if serve:
+                    # the warm boot's own gauge reaches the router's
+                    # registry with the worker's first reply
+                    pf.start()
+                    h = pf.router.submit_request(
+                        PROMPTS[0], SamplingParams(max_new_tokens=4),
+                        request_id="wave-0")
+                    pf.router.wait([h], timeout=300)
+                    assert h.finish_reason == "length"
+                    assert _csum(pf.registry,
+                                 "serving_aot_warm_seconds") > 0
                 return dict(wh.compile_cache), wh.boot_s
             finally:
                 pf.stop()
@@ -681,7 +692,9 @@ class TestCompileCacheReuse:
         if first["entries_after"] == 0:
             pytest.skip("jax persistent compilation cache wrote no "
                         "entries on this jax build")
-        second, second_boot = boot()
+        # (serving writes cache entries of its own, so only the last
+        # boot serves)
+        second, second_boot = boot(serve=True)
         assert second["entries_before"] == first["entries_after"]
         assert second["entries_after"] == second["entries_before"], \
             "second worker re-compiled despite the shared cache"

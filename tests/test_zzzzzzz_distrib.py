@@ -421,6 +421,14 @@ class TestProcfleetTracing:
             assert all(h.finish_reason == "length" for h in hs)
             time.sleep(0.3)  # one heartbeat carries trailing deltas
 
+            # --- nothing killed yet: the bounded mirror rings dropped
+            # EXACTLY zero events (one drop here means the rings are
+            # sized wrong or the piggyback drain starved)
+            for i, proxy in sorted(dict(pf.shared.active).items()):
+                st = proxy.distrib_state()
+                assert st["mirror"]["dropped"] == 0, (i, st["mirror"])
+                assert (st["merge"] or {}).get("worker_dropped", 0) == 0
+
             # --- satellite 3: /v1/requests answers honestly
             status, data = _http(server.port, "GET",
                                  "/v1/requests?state=recent")

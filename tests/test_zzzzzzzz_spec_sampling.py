@@ -34,7 +34,7 @@ from paddle_tpu.serving.spec import NgramProposer, SpecConfig, SpecDecoder
 
 # repetitive prompts so the n-gram proposer has something to chew on;
 # tiny greedy models also settle into cycles, which is the self-spec
-# sweet spot the bench gates
+# sweet spot
 _RNG = np.random.default_rng(7)
 LOOP_PROMPT = [5, 6, 7, 8] * 3
 # ends mid-repeat: the suffix [5,6,7] already occurred, so the proposer
@@ -366,10 +366,15 @@ class TestSpecEngine:
         assert squeezed == calm
 
     def test_spec_preemption_recompute_identity(self):
+        # 12 new tokens, not the sampled test's 8: accepted drafts end
+        # the streams early, and at 8 all three finish inside the 11
+        # usable blocks.  At 12 they need 16 and the pool preempts at
+        # every size from 11 to 14, spec on or off.
         calm = _run(_engine(num_blocks=64, spec=SpecConfig(k=4)),
-                    PROMPTS, max_new=8)
+                    PROMPTS, max_new=12)
         tight = _engine(num_blocks=12, spec=SpecConfig(k=4))
-        squeezed = _run(tight, PROMPTS, max_new=8)
+        squeezed = _run(tight, PROMPTS, max_new=12)
+        assert tight.spec.accepted_total > 0
         assert tight.metrics.counters["preemptions"] > 0
         assert squeezed == calm
         assert tight.kv.occupancy() == 0.0
@@ -387,6 +392,56 @@ class TestSpecEngine:
         eng.run(max_steps=4000)
         assert [list(r1.output_tokens)] == solo_greedy
         assert [list(r2.output_tokens)] == solo_sampled
+
+
+class TestSpecFixedStreamCounts:
+    """A decode-heavy stream, spec off against spec on (k=4), a greedy
+    wave and then a seeded-sampled wave on the same engine.  Three
+    cyclic prompts the n-gram proposer can predict carry 24 new tokens;
+    one aperiodic prompt rides along with 12, so that absent and
+    rejected drafts share the packed launches and a no-accept straggler
+    does not pin the step count.  The counts are exact on this stream."""
+
+    @pytest.fixture(scope="class")
+    def runs(self):
+        rng = np.random.default_rng(0)
+        prompts = [([5, 6, 7, 8] * 3, 24), ([40, 2, 11] * 4, 24),
+                   ([5, 6, 7, 8] * 2 + [5, 6, 7], 24),
+                   (rng.integers(0, 256, 8).tolist(), 12)]
+        out = {}
+        for spec in (False, True):
+            eng = _engine(spec=SpecConfig(k=4) if spec else None)
+            waves, lost = [], 0
+            for sp in ({}, SAMPLED):
+                reqs = [eng.add_request(
+                    p, SamplingParams(max_new_tokens=mx, **sp))
+                    for p, mx in prompts]
+                eng.run(max_steps=4000)
+                lost += sum(not r.finished for r in reqs)
+                waves.append([list(r.output_tokens) for r in reqs])
+            out[spec] = {"outputs": waves, "lost": lost,
+                         "engine_steps": _steps(eng),
+                         "traces": eng.ragged_trace_count,
+                         "drafted": eng.spec.drafted_total if spec else 0,
+                         "accepted": (eng.spec.accepted_total
+                                      if spec else 0)}
+        return out
+
+    def test_token_identity_both_waves(self, runs):
+        mismatches = sum(
+            a != b for pw, sw in zip(runs[False]["outputs"],
+                                     runs[True]["outputs"])
+            for a, b in zip(pw, sw))
+        assert mismatches == 0
+
+    @pytest.mark.parametrize("spec,name,want", [
+        (False, "lost", 0), (True, "lost", 0),
+        (False, "engine_steps", 52), (True, "engine_steps", 43),
+        (False, "traces", 7), (True, "traces", 9),
+        # accept ratio 26/55, as the two counts it is made of
+        (True, "drafted", 55), (True, "accepted", 26)])
+    def test_count(self, runs, spec, name, want):
+        assert runs[spec][name] == want
 
 
 # --- AOT: the plain unified artifact IS the spec artifact -------------------

@@ -371,6 +371,61 @@ class TestEngineIdentity:
 
 # --- AOT: the burst lattice rides the artifact (v3) --------------------------
 
+class TestBurstFixedStreamCounts:
+    """A decode-heavy stream, bursts off against up to 8 decode steps a
+    launch, a greedy wave and then a seeded-sampled wave on the same
+    engine: short prompts and long continuations, so after the brief
+    admission window every step is burstable, and one short stream so
+    the cohort shrinks mid-run and the row-bucket axis is exercised.
+    The counts are exact on this stream."""
+
+    @pytest.fixture(scope="class")
+    def runs(self):
+        rng = np.random.default_rng(0)
+        prompts = [(rng.integers(0, 256, 6).tolist(), 24),
+                   (rng.integers(0, 256, 6).tolist(), 24),
+                   (rng.integers(0, 256, 8).tolist(), 24),
+                   (rng.integers(0, 256, 8).tolist(), 12)]
+        out = {}
+        for burst in (0, 8):
+            eng = _engine(burst=burst)
+            waves, lost = [], 0
+            for sp in ({}, SAMPLED):
+                reqs = [eng.add_request(
+                    p, SamplingParams(max_new_tokens=mx, **sp))
+                    for p, mx in prompts]
+                eng.run(max_steps=4000)
+                lost += sum(not r.finished for r in reqs)
+                waves.append([list(r.output_tokens) for r in reqs])
+            out[burst] = {
+                "outputs": waves, "lost": lost,
+                "engine_steps": eng.metrics.counters["engine_steps"],
+                "roundtrips": _roundtrips(eng),
+                "launches": _launches(eng),
+                "burst_tokens": int(eng._burst_counters["tokens"].value),
+                "traces": eng.burst_trace_count,
+                "buckets": sorted(eng.burst_buckets)}
+        return out
+
+    def test_token_identity_both_waves(self, runs):
+        mismatches = sum(
+            a != b for pw, bw in zip(runs[0]["outputs"],
+                                     runs[8]["outputs"])
+            for a, b in zip(pw, bw))
+        assert mismatches == 0
+
+    @pytest.mark.parametrize("burst,name,want", [
+        (0, "lost", 0), (8, "lost", 0),
+        (0, "engine_steps", 52), (8, "engine_steps", 20),
+        (0, "roundtrips", 58), (8, "roundtrips", 26),
+        (8, "launches", 6), (8, "burst_tokens", 136),
+        # one trace per bucket of the two-axis lattice this stream meets
+        (8, "traces", 2),
+        (8, "buckets", [("burst", 4, 4), ("burst", 4, 8)])])
+    def test_count(self, runs, burst, name, want):
+        assert runs[burst][name] == want
+
+
 class TestBurstAot:
     def test_save_load_zero_retrace_identity(self, tmp_path):
         """An artifact saved from a burst-armed engine enumerates the

@@ -62,11 +62,12 @@ SUP = dict(backoff_initial_s=0.02, backoff_max_s=0.5,
 
 
 def _engine(role="unified", layers=2, num_blocks=32, max_num_seqs=4,
-            registry=None, labels=None):
+            registry=None, labels=None, burst_steps=0):
     paddle.seed(0)
     model = LlamaForCausalLM(LlamaConfig.tiny(num_hidden_layers=layers))
     return EngineCore(model, config=EngineConfig(
         num_blocks=num_blocks, block_size=BS, role=role,
+        burst_steps=burst_steps,
         scheduler=SchedulerConfig(max_num_seqs=max_num_seqs)),
         registry=registry, metrics_labels=labels)
 
@@ -289,25 +290,43 @@ class TestWireFrames:
 # dp=2 disaggregated fleet: token identity + pool/trace discipline
 # --------------------------------------------------------------------------
 class TestDisaggIdentity:
-    def _run(self, roles):
+    def _run(self, hold_intake, roles, burst=0):
         from paddle_tpu.observability.metrics import MetricsRegistry
         reg = MetricsRegistry()
 
         def factory(i, registry):
-            return _engine(role=(roles[i] if roles else "unified"),
-                           layers=1, registry=registry,
-                           labels={"replica": str(i)})
+            role = roles[i] if roles else "unified"
+            return _engine(role=role, layers=1, registry=registry,
+                           labels={"replica": str(i)},
+                           burst_steps=0 if role == "prefill" else burst)
 
         fleet = FleetRouter.build(
             factory, dp=2, config=FleetConfig(roles=roles),
             registry=reg).start()
         try:
+            gates = [hold_intake(r) for r in fleet.replicas]
             hs = [fleet.submit_request(
                 p, SamplingParams(max_new_tokens=10, temperature=0.0),
                 request_id=f"r{i}")
                 for i, p in enumerate(PROMPTS)]
-            fleet.wait(hs, timeout=300)
-            assert all(h.finish_reason == "length" for h in hs)
+            # prefill-only jobs (they finish at their first token and
+            # never hand off) on the same prefix, so that the role-less
+            # fleet routes them to the replica that decodes PROMPTS
+            noise = [fleet.submit_request(
+                PREFIX + [200 + i] * 6,
+                SamplingParams(max_new_tokens=1, temperature=0.0),
+                request_id=f"noise{i}")
+                for i in range(4 if burst else 0)]
+            gates[0].set()
+            if roles:
+                # the decode specialist starts on the whole migrated
+                # cohort, not on whichever hand-off reached it first
+                _wait(lambda: reg.snapshot().get(
+                    "serving_handoff_total", {}).get("value") == len(hs),
+                    msg="every first token handed off")
+            gates[1].set()
+            fleet.wait(hs + noise, timeout=300)
+            assert all(h.finish_reason == "length" for h in hs + noise)
             for r in fleet.replicas:
                 _check_invariant(r.engine)
                 for f in ("prefill", "decode", "ragged", "burst"):
@@ -319,20 +338,47 @@ class TestDisaggIdentity:
             by_replica = {r.index: sum(
                 1 for h in hs if h.replica is r)
                 for r in fleet.replicas}
-            return [list(h.output_tokens) for h in hs], hand, by_replica
+            launches = {r.index: {
+                k: int(r.engine._burst_counters[k].value)
+                for k in ("roundtrips", "launches")}
+                for r in fleet.replicas}
+            return ([list(h.output_tokens) for h in hs], hand,
+                    by_replica, launches)
         finally:
             fleet.shutdown(drain_timeout=5.0)
 
-    def test_disaggregated_matches_unified_greedy(self):
+    @pytest.mark.parametrize("burst", [0, 8])
+    def test_disaggregated_matches_unified_greedy(self, hold_intake, burst):
         topology.set_mesh(None)
-        uni, uni_hand, _ = self._run(None)
-        dis, dis_hand, finished_on = self._run(["prefill", "decode"])
+        uni, uni_hand, _, uni_launches = self._run(hold_intake, None, burst)
+        dis, dis_hand, finished_on, launches = self._run(
+            hold_intake, ["prefill", "decode"], burst)
         assert uni == dis, "disaggregation changed greedy tokens"
         assert uni_hand == 0.0
         # every request prefilled on replica 0, migrated at its first
-        # token, and FINISHED on the decode specialist
+        # token — exactly once — and FINISHED on the decode specialist
         assert dis_hand == float(len(PROMPTS))
         assert finished_on == {0: 0, 1: len(PROMPTS)}
+        if not burst:
+            assert launches[1]["launches"] == 0
+            return
+        # bursts belong to the decode specialist, which never sees a
+        # prefill: it emits its 36 tokens (all but each request's
+        # first) in 11 host round-trips, one of them an 8-step burst.
+        # The role-less replica that took the same stream keeps the
+        # four prefill-only jobs waiting behind its four decoders, a
+        # waiting queue gates bursts off, and its 44 tokens cost 20.
+        assert launches[0]["launches"] == 0
+        emitted = sum(len(t) for t in dis) - len(PROMPTS)
+        assert (launches[1], emitted) == (
+            {"roundtrips": 11, "launches": 1}, 36)
+        owner = uni_launches[max(
+            uni_launches, key=lambda i: uni_launches[i]["roundtrips"])]
+        served = sum(len(t) for t in uni) + 4  # + one token a noise job
+        assert (owner, served) == ({"roundtrips": 20, "launches": 0}, 44)
+        # fewer round-trips per token, without the division
+        assert launches[1]["roundtrips"] * served \
+            < owner["roundtrips"] * emitted
 
 
 # --------------------------------------------------------------------------

@@ -34,7 +34,7 @@ def main() -> None:
     from paddle_tpu.ops.pallas_flash import flash_attention
 
     rng = np.random.default_rng(0)
-    # every attention shape the bench phases dispatch (bench.py A/B/C);
+    # three training attention shapes, small to large;
     # (batch, seq, q_heads, kv_heads, head_dim) — C is GQA 16q/8kv
     shapes = [
         (8, 2048, 8, 8, 128),   # B_flagship

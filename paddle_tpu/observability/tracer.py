@@ -54,7 +54,11 @@ STEP_PHASES = (
                             # launch's routing load: moe_assignments=,
                             # moe_experts_touched=, moe_max_load= (summed
                             # over expert layers), moe_decode= 1 on decode
-    "engine.emit",          # commit, emission, retire, stream hand-off
+    "engine.emit",          # commit, emission, retire; then the stream
+                            # hand-off: one callback posted to the
+                            # server's loop a step (streams= the
+                            # replica's open handles), which wakes the
+                            # handlers over there
     "engine.trackers",      # end-of-step trackers and stepprof.end_step
 )
 

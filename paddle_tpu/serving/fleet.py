@@ -275,8 +275,10 @@ class SubmitHandle:
     :class:`~paddle_tpu.serving.Request` once the replica admits it;
     ``done`` covers the admission-less terminal paths (cancelled before
     admission, or the owning engine thread died).  ``event`` is an
-    optional waker the HTTP frontend attaches (an ``asyncio.Event`` set
-    via ``call_soon_threadsafe``); direct callers poll instead."""
+    optional waker the HTTP frontend attaches: an ``asyncio.Event`` that
+    the frontend's per-step callback sets ON its loop thread, and only
+    when this request has news (``CompletionServer._wake_streams``) —
+    no engine thread touches it; direct callers poll instead."""
 
     __slots__ = ("rid", "prompt_ids", "sampling", "priority",
                  "prefix_hashes", "req", "done", "cancel_reason", "event",
@@ -385,10 +387,14 @@ class EngineReplica:
         self.stall = None             # (steps_done, t) stamped by the
         # watchdog's on-fire handler; cleared when progress resumes
         # notify/on_finish are scoped to THIS replica: the frontend
-        # wakes only the handlers whose requests this replica owns (so
-        # wakeup work per step stays per-replica instead of dp x
+        # looks only at the handlers whose requests this replica owns
+        # (so wakeup work per step stays per-replica instead of dp x
         # fleet-wide), and an owner-map eviction names its replica so a
-        # stale eviction can never drop another replica's entry
+        # stale eviction can never drop another replica's entry.
+        # ``notify`` runs on this replica's engine thread after every
+        # step, with the device idle, so it must cost next to nothing:
+        # the frontend posts one callback to its loop (none while the
+        # last is pending) and walks the handles over there
         self._notify = lambda: notify(self)
         self._on_finish = lambda rid: on_finish(rid, self)
 
@@ -513,7 +519,8 @@ class EngineReplica:
                     else:
                         eng.step()
                     self.steps_done += 1
-                    with phase("engine.emit", prof):
+                    with phase("engine.emit", prof,
+                               streams=len(self.handles)):
                         self._notify()
                 else:
                     with phase("engine.wait", prof):

@@ -40,8 +40,13 @@ hashing** over its leading prompt blocks (least-loaded fallback), and
 routes aborts through the request→replica owner map so a deadline or
 disconnect reaches the replica that actually holds the blocks.
 Handlers read each request's append-only ``output_tokens`` directly
-(safe under the GIL); engine threads wake sleeping handlers via
-``loop.call_soon_threadsafe`` after every step.
+(safe under the GIL).  The hand-off of a step's news costs the engine
+thread ONE ``loop.call_soon_threadsafe`` a step however many streams are
+open (none while the last one is still pending): the callback walks the
+open handles ON the loop thread and wakes only those whose request has
+something to read — a new token, a finish, a terminal handle
+(``CompletionServer._notify`` / ``_wake_streams``;
+``serving_stream_wakes_total`` and its two siblings on ``/metrics``).
 
 The frontend owns three policies the engines deliberately do not:
 
@@ -109,6 +114,10 @@ from .protocol import (
 from .request import FinishReason
 
 _MAX_HEADER_BYTES = 16384
+# a waiting handler re-checks its request this often whatever happens: a
+# safety net only — every token and finish a step produces reaches its
+# handler through the step's wake-up (``_notify``), never through this
+_POLL_S = 0.25
 _ROUTES = ("/v1/completions", "/v1/requests", "/v1/debug/compiles",
            "/v1/debug/profile", "/v1/debug/audit", "/v1/debug/cache",
            "/v1/debug/alerts", "/v1/debug/history", "/v1/debug/wire",
@@ -119,6 +128,9 @@ _ROUTES = ("/v1/completions", "/v1/requests", "/v1/debug/compiles",
 METRIC_NAMES = (
     "serving_admission_rejected_total",
     "serving_http_requests_total",
+    "serving_stream_wakes_total",
+    "serving_stream_wakes_coalesced_total",
+    "serving_stream_handles_woken_total",
 )
 
 
@@ -145,9 +157,13 @@ class _Handle(SubmitHandle):
     """One in-flight HTTP completion: the fleet's :class:`SubmitHandle`
     (rid / prompt / sampling / req / done / cancel_reason, routed and
     owned by one replica) plus the parsed protocol request and the
-    asyncio waker created on the server's loop."""
+    asyncio waker created on the server's loop.  ``woken_at`` /
+    ``woken_end`` are the loop thread's own note of what the handler has
+    been woken for — the request's token count at the last wake, and
+    whether its end was announced — so a step's wake-up skips a handle
+    with no news (:meth:`CompletionServer._wake_streams`)."""
 
-    __slots__ = ("creq",)
+    __slots__ = ("creq", "woken_at", "woken_end")
 
     def __init__(self, rid: str, creq: CompletionRequest,
                  event: asyncio.Event):
@@ -155,6 +171,35 @@ class _Handle(SubmitHandle):
                          priority=creq.priority, event=event,
                          slo_ms=creq.slo_ms, retryable=creq.retryable)
         self.creq = creq
+        self.woken_at = 0
+        self.woken_end = False
+
+
+class _StreamWake:
+    """One replica's side of the stream hand-off (``index`` None: the
+    fleet-wide sweeps of supervisor, drain and abort paths): the mark
+    that a wake-up callback is posted and has not started its walk, and
+    the three ``serving_stream_*`` series."""
+
+    __slots__ = ("index", "pending", "wakes", "coalesced", "woken")
+
+    def __init__(self, index: Optional[int], registry, labels):
+        self.index = index
+        self.pending = False
+        self.wakes = registry.counter(
+            "serving_stream_wakes_total",
+            "wake-up callbacks engine threads posted to the server's "
+            "loop (one a step, whatever the number of open streams)",
+            **labels)
+        self.coalesced = registry.counter(
+            "serving_stream_wakes_coalesced_total",
+            "notifies that posted nothing: the replica's last callback "
+            "had not started its walk", **labels)
+        self.woken = registry.counter(
+            "serving_stream_handles_woken_total",
+            "handler events set by those callbacks (handles with a new "
+            "token, a finish or a terminal mark; every handle in a "
+            "fleet-wide sweep)", **labels)
 
 
 class CompletionServer:
@@ -192,7 +237,10 @@ class CompletionServer:
                 engine, max_queue=self.cfg.max_queue)
         self.registry = (registry if registry is not None
                          else self.fleet.registry)
-        self._handles: Dict[str, _Handle] = {}
+        self._handles: Dict[str, _Handle] = {}  # loop thread only
+        # replica index (None: fleet-wide sweep) -> its hand-off state;
+        # bounded by dp + 1
+        self._wakes: Dict[Optional[int], _StreamWake] = {}
         self._ids = itertools.count(1)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._server: Optional[asyncio.AbstractServer] = None
@@ -231,6 +279,8 @@ class CompletionServer:
     async def start(self) -> "CompletionServer":
         self._loop = asyncio.get_running_loop()
         self._shutdown_done = asyncio.Event()
+        for r in self.fleet.replicas:
+            self._stream_wake(r)  # the series exist from the first scrape
         self.fleet.start(notify=self._notify)
         self._server = await asyncio.start_server(
             self._handle_conn, self.cfg.host, self.cfg.port)
@@ -295,21 +345,70 @@ class CompletionServer:
                 and self.fleet.alive)
 
     # --- fleet bridge -------------------------------------------------------
+    def _stream_wake(self, replica) -> _StreamWake:
+        key = None if replica is None else replica.index
+        wake = self._wakes.get(key)
+        if wake is None:
+            # the replica's own labels, as its engine's serving series
+            # have them (none in a fleet made from a bare engine, where
+            # the sweeps' unlabeled series are therefore the same ones)
+            labels = {} if replica is None \
+                else replica.engine.metrics.labels or {}
+            wake = self._wakes.setdefault(
+                key, _StreamWake(key, self.registry, labels))
+        return wake
+
     def _notify(self, replica=None) -> None:
-        """Wake waiting handlers (engine threads → loop thread).  The
-        stepping replica passes itself, so only the handlers whose
-        requests it owns are woken — wakeup work per step stays
-        per-replica instead of dp × fleet-wide.  ``None`` wakes all."""
+        """A step's news (engine threads → loop thread).  Posts at most
+        ONE callback to the loop however many handles are open, and none
+        while the replica's last one has not started its walk: the walk
+        over the handles and every ``event.set()`` happen in
+        :meth:`_wake_streams`, on the loop thread, so this thread pays
+        one system call a step and never reads ``_handles``.  The
+        stepping replica passes itself and only its own handles are
+        looked at; ``None`` (supervisor, drain, abort sweeps) wakes
+        all."""
         loop = self._loop
         if loop is None or loop.is_closed():
             return
-        for h in list(self._handles.values()):
-            if replica is not None and h.replica is not replica:
-                continue
-            try:
-                loop.call_soon_threadsafe(h.event.set)
-            except RuntimeError:
-                return  # swallow-ok: loop shut down mid-iteration — the handlers it would wake are being torn down with it
+        wake = self._stream_wake(replica)
+        if wake.pending:
+            wake.coalesced.inc()
+            return
+        wake.pending = True
+        try:
+            loop.call_soon_threadsafe(self._wake_streams, wake)
+        except RuntimeError:
+            wake.pending = False
+            return  # swallow-ok: loop shut down under us — the handlers it would wake are being torn down with it
+        wake.wakes.inc()
+
+    def _wake_streams(self, wake: _StreamWake) -> None:
+        """The loop thread's half of :meth:`_notify`: wake the handlers
+        that have something to read — the request emitted a token since
+        the handle was last woken, it finished, or the handle is
+        ``done`` — and leave the others asleep.  The pending mark goes
+        BEFORE the walk: a step that ends during it posts anew and is
+        never lost."""
+        wake.pending = False
+        index, woken = wake.index, 0
+        for h in self._handles.values():
+            if index is not None:
+                r = h.replica
+                if r is None or r.index != index:
+                    continue
+                req = h.req
+                n = len(req.output_tokens) if req is not None else 0
+                end = h.finished
+                if not (n > h.woken_at or (end and not h.woken_end)):
+                    continue
+                # max: a re-dispatched request starts over below what
+                # its handler has already read
+                h.woken_at, h.woken_end = max(n, h.woken_at), end
+            h.event.set()
+            woken += 1
+        if woken:
+            wake.woken.inc(woken)
 
     def _unavailable_503(self) -> Tuple[str, Tuple]:
         """(message, extra headers) for a 503.  A draining server is
@@ -1083,8 +1182,8 @@ class CompletionServer:
                 self._request_abort(handle, FinishReason.TIMEOUT)
                 deadline = None
                 continue
-            wait = 0.25 if deadline is None \
-                else max(0.0, min(0.25, deadline - time.monotonic()))
+            wait = _POLL_S if deadline is None \
+                else max(0.0, min(_POLL_S, deadline - time.monotonic()))
             try:
                 await asyncio.wait_for(handle.event.wait(), wait + 1e-3)
             except asyncio.TimeoutError:

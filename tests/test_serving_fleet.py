@@ -659,6 +659,240 @@ class TestHTTPFleet:
                 == "engine is not running")
 
 
+# --- the stream hand-off across replicas (ISSUE 32) --------------------------
+
+class _Req:
+    """What a handler reads of an engine request."""
+
+    def __init__(self, tokens=0, finished=False):
+        self.output_tokens = list(range(tokens))
+        self.finished = finished
+        self.finish_reason = None
+
+
+def _unstarted_server():
+    """A CompletionServer on a dp=2 fleet whose engine threads never
+    start, with a loop of its own that the test turns or runs."""
+    fleet = FleetRouter.build(_factory(), dp=2)
+    server = CompletionServer(fleet)
+    loop = server._loop = asyncio.new_event_loop()
+    return server, fleet, loop
+
+
+def _open_handle(server, rid, replica, req=None, done=False):
+    from paddle_tpu.serving.protocol import parse_completion_request
+    from paddle_tpu.serving.server import _Handle
+
+    h = _Handle(rid, parse_completion_request(b'{"prompt": [1, 2, 3]}'),
+                asyncio.Event())
+    h.replica, h.req, h.done = replica, req, done
+    server._handles[rid] = h
+    return h
+
+
+class TestStreamWakeScope:
+    """``CompletionServer._notify`` on a dp=2 fleet whose loop the test
+    turns by hand: which events one replica's wake sets, what ``None``
+    sets, what coalesces, what a closed loop swallows."""
+
+    @pytest.fixture
+    def wired(self):
+        server, fleet, loop = _unstarted_server()
+
+        def handle(rid, replica, req=None, done=False):
+            return _open_handle(server, rid, fleet.replicas[replica],
+                                req, done)
+
+        def turn():
+            """Run what has been posted to the loop, once."""
+            loop.call_soon(loop.stop)
+            loop.run_forever()
+
+        def series(replica):
+            c = server.registry.counter
+            lb = {} if replica is None else {"replica": str(replica)}
+            return tuple(int(c(name, **lb).value) for name in (
+                "serving_stream_wakes_total",
+                "serving_stream_wakes_coalesced_total",
+                "serving_stream_handles_woken_total"))
+
+        yield server, fleet, handle, turn, series
+        if not loop.is_closed():
+            loop.close()
+        fleet.stop()
+
+    def test_a_replica_wakes_its_own_handles_with_news(self, wired):
+        server, fleet, handle, turn, series = wired
+        token = handle("a", 0, _Req(tokens=1))
+        ended = handle("b", 0, _Req(tokens=0, finished=True))
+        gone = handle("c", 0, None, done=True)
+        queued = handle("d", 0, None)
+        silent = handle("e", 0, _Req(tokens=0))
+        other = handle("f", 1, _Req(tokens=3, finished=True))
+        server._notify(fleet.replicas[0])
+        assert not token.event.is_set()       # nothing on this thread
+        turn()
+        assert [h.event.is_set() for h in (token, ended, gone)] == [True] * 3
+        assert not queued.event.is_set() and not silent.event.is_set()
+        assert not other.event.is_set()       # replica 1's, with news
+        assert series(0) == (1, 0, 3) and series(1) == (0, 0, 0)
+        # the same state again is no news; one more token is
+        for h in (token, ended, gone):
+            h.event.clear()
+        silent.req.output_tokens.append(7)
+        server._notify(fleet.replicas[0])
+        turn()
+        assert silent.event.is_set()
+        assert not any(h.event.is_set() for h in (token, ended, gone,
+                                                  queued, other))
+        assert series(0) == (2, 0, 4)
+
+    def test_none_wakes_every_handle(self, wired):
+        server, fleet, handle, turn, series = wired
+        hs = [handle("a", 0, _Req(tokens=0)), handle("b", 1, None),
+              handle("c", 1, _Req(tokens=2))]
+        server._notify(None)
+        turn()
+        assert all(h.event.is_set() for h in hs)
+        assert series(None) == (1, 0, 3)
+        assert series(0) == series(1) == (0, 0, 0)
+
+    def test_notifies_coalesce_until_the_walk_starts(self, wired):
+        server, fleet, handle, turn, series = wired
+        h0 = handle("a", 0, _Req(tokens=1))
+        h1 = handle("b", 1, _Req(tokens=1))
+        r0, r1 = fleet.replicas
+        for _ in range(3):
+            server._notify(r0)
+        server._notify(r1)                    # a mark each replica
+        assert series(0) == (1, 2, 0) and series(1) == (1, 0, 0)
+        # a step that ends DURING the walk posts anew: the mark is
+        # cleared before the first handle is looked at
+        real_set = h0.event.set
+
+        def set_and_step():
+            real_set()
+            h0.req.output_tokens.append(9)
+            server._notify(r0)
+
+        h0.event.set = set_and_step
+        turn()
+        assert h0.event.is_set() and h1.event.is_set()
+        assert series(0) == (2, 2, 1)
+        h0.event.set = real_set
+        h0.event.clear()
+        turn()                                # the second callback
+        assert h0.event.is_set() and series(0) == (2, 2, 2)
+
+    def test_a_closed_loop_swallows_the_notify(self, wired):
+        server, fleet, handle, turn, series = wired
+        h = handle("a", 0, _Req(tokens=1))
+
+        def closing(*a, **kw):                # closed under the caller
+            raise RuntimeError("Event loop is closed")
+
+        real, server._loop.call_soon_threadsafe = \
+            server._loop.call_soon_threadsafe, closing
+        server._notify(fleet.replicas[0])
+        server._notify(None)
+        server._loop.call_soon_threadsafe = real
+        assert series(0) == (0, 0, 0) and series(None) == (0, 0, 0)
+        server._notify(fleet.replicas[0])     # no mark was left behind
+        turn()
+        assert h.event.is_set() and series(0) == (1, 0, 1)
+        server._loop.close()
+        h.event.clear()
+        server._notify(fleet.replicas[0])
+        server._notify(None)
+        assert not h.event.is_set() and series(0) == (1, 0, 1)
+
+
+class TestStreamWakeStress:
+    def test_no_news_is_lost_between_notifying_threads_and_the_loop(
+            self, monkeypatch):
+        """The pending mark is shared by the notifying threads and the
+        loop thread with no lock.  Two threads a replica (a replica and
+        the incarnation that replaces it) append tokens and notify as
+        fast as they can, under a switch interval that makes every
+        interleaving likely; with the handlers' poll at 30 s each of
+        them must still read every token: a wake lost between the mark
+        and the walk would leave its handler asleep."""
+        import sys
+
+        from paddle_tpu.serving import server as server_mod
+
+        monkeypatch.setattr(server_mod, "_POLL_S", 30.0)
+        server, fleet, loop = _unstarted_server()
+        thread = threading.Thread(target=loop.run_forever, daemon=True)
+        thread.start()
+        rounds, per_replica, got = 400, 3, {}
+
+        async def open_handle(rid, replica):
+            h = _open_handle(server, rid, replica, _Req())
+            got[rid] = []
+
+            async def sink(new):
+                got[rid].extend(new)
+
+            return h, loop.create_task(server._collect(h, None, sink))
+
+        def on_loop(coro):
+            return asyncio.run_coroutine_threadsafe(coro, loop).result(30)
+
+        opened = [on_loop(open_handle(f"r{r.index}-{i}", r))
+                  for r in fleet.replicas for i in range(per_replica)]
+
+        def engine(replica, base):
+            mine = [h for h, _ in opened if h.replica is replica]
+            for k in range(rounds):
+                for h in mine:
+                    h.req.output_tokens.append(base + k)
+                server._notify(replica)
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=engine, args=(r, base))
+                       for r in fleet.replicas for base in (0, 10_000)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+            assert not any(t.is_alive() for t in threads)
+            deadline = time.monotonic() + 10
+            while (any(len(v) < 2 * rounds for v in got.values())
+                   and time.monotonic() < deadline):
+                time.sleep(0.005)
+        finally:
+            sys.setswitchinterval(old)
+        try:
+            assert {rid: len(v) for rid, v in got.items()} == \
+                {rid: 2 * rounds for rid in got}
+            for h, _ in opened:
+                assert got[h.rid] == h.req.output_tokens  # and in order
+                h.req.finished = True
+            server._notify(None)
+
+            async def results():
+                return await asyncio.wait_for(
+                    asyncio.gather(*(task for _, task in opened)), 10)
+
+            ended = on_loop(results())
+            assert [len(tokens) for tokens, _ in ended] == \
+                [2 * rounds] * len(opened)
+            c = server.registry.counter
+            for r in fleet.replicas:
+                lb = {"replica": str(r.index)}
+                assert (c("serving_stream_wakes_total", **lb).value
+                        + c("serving_stream_wakes_coalesced_total",
+                            **lb).value) == 2 * rounds
+        finally:
+            loop.call_soon_threadsafe(loop.stop)
+            thread.join(10)
+            loop.close()
+            fleet.stop()
+
+
 # --- lint coverage -----------------------------------------------------------
 
 class TestFleetLintCoverage:

@@ -99,10 +99,11 @@ def _request(port, method, path, body=None, timeout=120):
     return resp.status, headers, data
 
 
-def _sse_request(port, body, timeout=120, stop_after=None):
+def _sse_request(port, body, timeout=120, stop_after=None, frames=None):
     """POST a streaming completion; parse SSE frames.  Returns
     (tokens, finish_reason, saw_done).  ``stop_after=n`` closes the
-    connection after n tokens (client walks away)."""
+    connection after n tokens (client walks away); ``frames`` (a list)
+    collects every parsed chunk."""
     conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
     conn.request("POST", "/v1/completions", json.dumps(dict(body, stream=True)),
                  {"Content-Type": "application/json"})
@@ -123,6 +124,8 @@ def _sse_request(port, body, timeout=120, stop_after=None):
             done = True
             break
         obj = json.loads(payload)
+        if frames is not None:
+            frames.append(obj)
         choice = obj["choices"][0]
         tokens.extend(choice["token_ids"])
         if choice["finish_reason"] is not None:
@@ -270,6 +273,206 @@ class TestEndpoints:
         for line in text.strip().splitlines():
             if not line.startswith("#"):
                 assert sample.match(line), line
+
+
+# --- the stream hand-off (ISSUE 32) -----------------------------------------
+
+def _sse_full(port, body):
+    """A streamed completion: (tokens, finish_reason, usage block of the
+    final chunk, [DONE] seen).  Only the final chunk may end the
+    request, and only it carries usage."""
+    frames = []
+    tokens, finish, done = _sse_request(port, body, frames=frames)
+    assert all(f["choices"][0]["finish_reason"] is None
+               and "usage" not in f for f in frames[:-1])
+    return tokens, finish, frames[-1].get("usage"), done
+
+
+def _in_threads(n, fn):
+    out = [None] * n
+
+    def worker(i):
+        out[i] = fn(i)
+
+    threads = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+    return threads, out
+
+
+def _stream_series(server):
+    """(wakes, coalesced, handles woken) of a fleet made from a bare
+    engine: unlabeled, like its other serving series."""
+    c = server.registry.counter
+    return (int(c("serving_stream_wakes_total").value),
+            int(c("serving_stream_wakes_coalesced_total").value),
+            int(c("serving_stream_handles_woken_total").value))
+
+
+def _wait_for(cond, seconds=30.0):
+    deadline = time.monotonic() + seconds
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    return cond()
+
+
+class TestStreamHandoff:
+    """How a step's news reaches the handlers that stream it: one
+    callback posted to the loop a step and a replica, the walk over the
+    handles on the loop thread, a wake only for a handle with news."""
+
+    def _held_server(self, harness_factory, hold_intake, max_num_seqs,
+                     num_blocks=512):
+        engine = EngineCore(
+            _model(layers=1), num_blocks=num_blocks, block_size=4,
+            prefix_cache=False,
+            scheduler_config=SchedulerConfig(max_num_seqs=max_num_seqs))
+        h = harness_factory(engine, ServerConfig(max_queue=64))
+        replica = h.server.fleet.replicas[0]
+        return h, replica, hold_intake(replica)
+
+    @pytest.mark.parametrize("n", [1, 8, 64])
+    def test_one_loop_callback_a_step(self, n, harness_factory,
+                                      hold_intake):
+        """With n streams open the engine thread makes exactly one
+        ``call_soon_threadsafe`` a step (none where the last had not
+        run yet), and every stream reads what a non-streamed run of the
+        same requests reads."""
+        h, replica, gate = self._held_server(harness_factory, hold_intake,
+                                             max_num_seqs=64)
+        prompts = [[(7 * i + j) % 250 + 1 for j in range(4)]
+                   for i in range(n)]
+        posted = []
+        real = h.loop.call_soon_threadsafe
+
+        def counting(callback, *args, **kw):
+            if threading.current_thread() is replica.thread:
+                posted.append(callback)
+            return real(callback, *args, **kw)
+
+        h.loop.call_soon_threadsafe = counting
+
+        def run(fn):
+            """All n requests open before the first step."""
+            gate.clear()
+            threads, out = _in_threads(n, fn)
+            assert _wait_for(lambda: len(h.server._handles) == n)
+            gate.set()
+            for t in threads:
+                t.join(120)
+            return out
+
+        streamed = run(lambda i: _sse_full(
+            h.port, {"prompt": prompts[i], "max_tokens": 6}))
+        # the last step's notify may still be on its way
+        assert _wait_for(lambda: sum(_stream_series(h.server)[:2])
+                         == replica.steps_done, 5.0)
+        steps = replica.steps_done
+        wakes, coalesced, woken = _stream_series(h.server)
+        assert steps >= 6
+        assert len(posted) == wakes          # one system call a wake ...
+        assert wakes + coalesced == steps    # ... and at most one a step
+        assert all(cb == h.server._wake_streams for cb in posted)
+        # a handle is woken for a new token or its end, never for
+        # nothing: at least once each, at most once a token and once
+        # more where a walk fell between a last token and its finish
+        assert n <= woken <= (6 + 1) * n
+
+        plain = run(lambda i: json.loads(_request(
+            h.port, "POST", "/v1/completions",
+            {"prompt": prompts[i], "max_tokens": 6})[2]))
+        for (tokens, finish, usage, done), ref in zip(streamed, plain):
+            assert done
+            assert tokens == ref["choices"][0]["token_ids"]
+            assert len(tokens) == 6
+            assert finish == ref["choices"][0]["finish_reason"] == "length"
+            assert usage == ref["usage"]
+
+    def test_handle_without_news_is_not_woken(self, harness_factory,
+                                              hold_intake):
+        """Eight streams open, two rows a step: the six requests still
+        queued have no news and their handlers sleep, so the events set
+        count the emitting rows and not the open handles."""
+        h, replica, gate = self._held_server(harness_factory, hold_intake,
+                                             max_num_seqs=2)
+        n, max_tokens = 8, 8
+        threads, out = _in_threads(n, lambda i: _sse_full(
+            h.port, {"prompt": [10 + i, 20 + i, 30 + i],
+                     "max_tokens": max_tokens}))
+        assert _wait_for(lambda: len(h.server._handles) == n)
+        gate.set()
+        for t in threads:
+            t.join(120)
+        assert all(len(tokens) == max_tokens and finish == "length"
+                   for tokens, finish, _, _ in out)
+        assert _wait_for(lambda: sum(_stream_series(h.server)[:2])
+                         == replica.steps_done, 5.0)
+        wakes, coalesced, woken = _stream_series(h.server)
+        # waking every open handle a step would have set about
+        # (8 + 6 + 4 + 2) x 8 = 160 events
+        assert replica.steps_done >= 4 * max_tokens
+        assert n <= woken <= n * (max_tokens + 1)
+
+    @pytest.mark.parametrize("ending", ["length", "eos", "abort",
+                                        "deadline", "rejected"])
+    def test_no_ending_waits_for_the_poll(self, ending, harness_factory,
+                                          monkeypatch):
+        """With the handlers' safety-net poll at 30 s every request
+        still ends at once: its last news came with a step's wake-up."""
+        from paddle_tpu.serving import server as server_mod
+        from paddle_tpu.serving.request import FinishReason
+
+        engine = _engine(_model(layers=1), num_blocks=32)
+        h = harness_factory(engine)
+        body = {"prompt": PROMPTS[0], "max_tokens": 6}
+        first = json.loads(_request(h.port, "POST", "/v1/completions",
+                                    body)[2])["choices"][0]["token_ids"]
+        monkeypatch.setattr(server_mod, "_POLL_S", 30.0)
+        want_tokens = None
+        if ending == "length":
+            want, want_tokens = "length", first
+        elif ending == "eos":
+            body["eos_token_id"] = first[2]
+            want, want_tokens = "eos", first[:first.index(first[2]) + 1]
+        elif ending == "deadline":
+            body.update(max_tokens=10000, timeout=0.3)
+            want = "timeout"
+        elif ending == "rejected":
+            # more blocks than the pool has: refused at admission,
+            # inside the step that planned it
+            body["prompt"] = list(range(1, 200))
+            want, want_tokens = "abort", []
+        else:
+            body["max_tokens"] = 10000
+            want = "abort"
+        t0 = time.monotonic()
+        threads, out = _in_threads(1, lambda i: _sse_full(h.port, body))
+        if ending == "abort":
+            assert _wait_for(lambda: any(
+                hd.req is not None and hd.req.output_tokens
+                for hd in list(h.server._handles.values())))
+            (hd,) = h.server._handles.values()
+            h.loop.call_soon_threadsafe(h.server._request_abort, hd,
+                                        FinishReason.ABORT)
+        threads[0].join(60)
+        tokens, finish, usage, done = out[0]
+        assert time.monotonic() - t0 < 10.0, "an ending waited for the poll"
+        assert done and finish == want
+        if want_tokens is not None:
+            assert tokens == want_tokens
+        assert usage["completion_tokens"] == len(tokens)
+
+    def test_metrics_page_carries_the_three_series(self, harness_factory):
+        h = harness_factory(_engine(_model(layers=1)))
+        text = _request(h.port, "GET", "/metrics")[2].decode()
+        for name in ("serving_stream_wakes_total",
+                     "serving_stream_wakes_coalesced_total",
+                     "serving_stream_handles_woken_total"):
+            assert f"# TYPE {name} counter" in text
+            assert f"\n{name} 0\n" in text   # there from the first scrape
+        _sse_full(h.port, {"prompt": PROMPTS[1], "max_tokens": 4})
+        wakes, coalesced, woken = _stream_series(h.server)
+        assert wakes >= 1 and 1 <= woken <= 4
 
 
 class TestKeepAlive:

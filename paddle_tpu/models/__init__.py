@@ -5,7 +5,7 @@ Analog of the PaddleNLP/PaddleClas model zoos the reference's configs target
 framework models so the capability rungs are runnable in-repo.
 """
 
-from . import bert, gpt, llama, moe_mla  # noqa: F401
+from . import bert, gpt, llama, mamba_hybrid, moe_mla  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig,
     BertForQuestionAnswering,
@@ -29,6 +29,11 @@ from .llama import (  # noqa: F401
     LlamaModel,
     LlamaMoEBlock,
     LlamaPretrainingCriterion,
+)
+from .mamba_hybrid import (  # noqa: F401
+    HybridMambaConfig,
+    MambaDecoderLayer,
+    MambaMixer,
 )
 from .moe_mla import (  # noqa: F401
     LatentAttention,

@@ -80,6 +80,9 @@ class LlamaConfig:
     dtype: str = "float32"
     virtual_pp_degree: int = 1          # interleaved VPP chunks per device
     attention_bias: bool = False        # q/k/v biases (Qwen2 family)
+    use_rope: bool = True               # False: no positional rotation (a
+                                        # hybrid whose recurrent layers carry
+                                        # the order, models/mamba_hybrid.py)
     # MoE knobs (0 experts = dense; DeepSeek/Qwen2-MoE style otherwise)
     num_experts: int = 0
     num_experts_per_tok: int = 2
@@ -215,7 +218,8 @@ class LlamaAttention(Layer):
         self.o_proj = RowParallelLinear(self.num_heads * hd, h, has_bias=False,
                                         input_is_parallel=True, weight_attr=init)
         self._rope_cos, self._rope_sin = _rope_tables(
-            hd, config.max_position_embeddings, config.rope_theta)
+            hd, config.max_position_embeddings, config.rope_theta) \
+            if config.use_rope else (None, None)
 
     def forward(self, x, cache=None, pos=None):
         B, S = x.shape[0], x.shape[1]
@@ -233,7 +237,9 @@ class LlamaAttention(Layer):
         k = shape_heads(k, self.num_kv_heads)
         v = shape_heads(v, self.num_kv_heads)
 
-        if pos is None:
+        if not self.config.use_rope:
+            pass        # the configuration rotates nothing
+        elif pos is None:
             cos, sin = self._rope_cos[:S], self._rope_sin[:S]
             q = run_op("rope", lambda a: _apply_rope(a, cos, sin), q)
             k = run_op("rope", lambda a: _apply_rope(a, cos, sin), k)
@@ -559,7 +565,7 @@ class LlamaModel(Layer):
         elif pp_microbatches and axis_size("pp") > 1:
             h = pipeline_forward(self._pipeline(), h, pp_microbatches)
         elif (self.config.scan_layers and self.config.num_experts == 0
-                and not self.config.attention_bias
+                and not self.config.attention_bias and self.config.use_rope
                 and axis_size("sep") == 1):
             # biased attention (Qwen2-style) keeps the module loop: the
             # scan body's stacked-weight roles are the bias-free dense set

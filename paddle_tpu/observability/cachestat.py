@@ -169,7 +169,7 @@ class CacheStatTracker:
         # free blocks", not as the gauge default 0.0 — an alert rule
         # with a free-blocks floor (ISSUE 14) would otherwise fire on
         # every idle replica at boot
-        self._g_free.set(len(pool._free))
+        self._g_free.set(pool.num_free)
         self._g_reuse.set(len(pool._reuse))
         self._g_avail.set(len(pool._free) + len(pool._reuse))
         self._g_alloc.set(1 + len(pool._ref))
@@ -192,7 +192,7 @@ class CacheStatTracker:
         if not self.enabled:
             return None
         pool = self.pool
-        free = len(pool._free)
+        free = pool.num_free    # with the free first-block slots, if any
         reuse = len(pool._reuse)
         allocated = 1 + len(pool._ref)  # + the reserved null page
         if free + reuse + allocated != pool.num_blocks:

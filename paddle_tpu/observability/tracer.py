@@ -44,6 +44,10 @@ STEP_PHASES = (
     "sched.plan",           # scheduler.schedule()
     "engine.admit",         # per-request admission bookkeeping
     "engine.build",         # numpy routing arrays + the SamplingPack
+                            # (rows= on a decode launch).  For a model
+                            # with per-sequence state also state_rows=
+                            # (real rows whose state the launch advances)
+                            # and state_slots_held=
     "engine.dispatch",      # the step call, until the jit call returns
     "engine.device_wait",   # blocked until the program has ended
     "engine.fetch",         # host arrays of what the step reads (bytes=):

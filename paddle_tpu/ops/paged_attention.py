@@ -770,23 +770,56 @@ def paged_prefill_attention(q: jax.Array, k_cache: jax.Array,
 
 @dataclass(frozen=True)
 class CacheSpec:
-    """What ONE cached token holds in one decoder layer, as the layer
-    declares it (``layer.cache_spec()``) and ``EngineCore`` allocates it:
-    ``k`` is the ``(heads, dim)`` of the token's row in the layer's
-    ``k_pools`` entry (``[num_blocks, block_size, heads, dim]``), ``v`` the
-    same for ``v_pools`` or ``None`` where the layer keeps nothing there
-    (a latent cache: one row shared by all heads, no separate values).
-    ``kind`` names the layout for the paths that can take only one:
-    ``"kv"`` shards its head dimension over ``mp`` and is what the ragged,
-    burst and hand-off paths move; ``"latent"`` is refused by them, by
-    name, when the engine is built."""
+    """What one decoder layer keeps between steps, as the layer declares it
+    (``layer.cache_spec()``) and ``EngineCore`` allocates it.  Two kinds of
+    memory, and a layer may declare either:
 
-    k: Tuple[int, int]
-    v: Optional[Tuple[int, int]]
+    **Per TOKEN** (pages): ``k`` is the ``(heads, dim)`` of the token's row
+    in the layer's ``k_pools`` entry (``[num_blocks, block_size, heads,
+    dim]``), ``v`` the same for ``v_pools`` or ``None`` where the layer
+    keeps nothing there (a latent cache: one row shared by all heads, no
+    separate values).  ``kind`` names that layout for the paths that can
+    take only one: ``"kv"`` shards its head dimension over ``mp`` and is
+    what the ragged, burst and hand-off paths move; ``"latent"`` is refused
+    by them, by name, when the engine is built.  A layer with no per-token
+    row (a state-space mixer) leaves ``k`` and ``v`` ``None``.
+
+    **Per SEQUENCE** (slots): ``state`` is ``None`` or two ``(shape,
+    dtype)`` pairs, arrays of FIXED shape one live sequence holds in this
+    layer whatever its length (a selective scan's recurrent state and the
+    last inputs of its causal convolution).  The engine allocates
+    ``[max_num_seqs + 1, *shape]`` of each (slot 0 is the null slot that
+    padding rows use) in the layer's ``k_pools`` / ``v_pools`` entry; a
+    dtype of ``None`` is the pool's.  The slot of a sequence is the id of
+    its first block (``KVCacheManager``), so the step programs take no
+    argument for it.  Such state cannot be rebuilt from a block prefix of
+    pages nor rolled back a token, so ``EngineCore`` refuses, by name,
+    every path that would need to (prefix cache, speculative verify,
+    bursts, the ragged program, hand-off, ``mp > 1``).  A layer declares
+    pages OR slots, not both."""
+
+    k: Optional[Tuple[int, int]] = None
+    v: Optional[Tuple[int, int]] = None
     kind: str = "kv"
+    state: Optional[Tuple[Tuple[Tuple[int, ...], Optional[str]], ...]] = None
+
+    def __post_init__(self):
+        if self.state is not None and (self.k or self.v):
+            raise ValueError("a layer declares per-token rows or "
+                             "per-sequence state, not both")
+        if self.state is not None and len(self.state) != 2:
+            raise ValueError("per-sequence state is two (shape, dtype) "
+                             "pairs, one a side of the pools")
+        if self.state is None and self.k is None:
+            raise ValueError("a layer that keeps nothing declares no cache")
 
     def values_per_token(self) -> int:
-        return self.k[0] * self.k[1] + (self.v[0] * self.v[1] if self.v else 0)
+        return sum(r[0] * r[1] for r in (self.k, self.v) if r)
+
+    def state_bytes_per_sequence(self, pool_dtype) -> int:
+        """Bytes one live sequence holds in this layer's slots."""
+        return sum(math.prod(shape) * jnp.dtype(dtype or pool_dtype).itemsize
+                   for shape, dtype in self.state or ())
 
 
 def _block_queries(fn, q, block: int = 1024):
@@ -933,7 +966,13 @@ def paged_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     global last_path
 
     B, H, D = q.shape
-    tileable = D % 128 == 0 and k_cache.shape[1] % 8 == 0
+    # ONE KV head (multi-query) goes down the gather path: the kernel's
+    # cost is a step a (row, page) whatever the heads on the page, so with
+    # one head it moves an eighth of a 4:1 group's bytes in the same time
+    # (20 query heads on 1 KV head at 256 rows x 2,048 tokens: kernel
+    # 17.5 ms, gather 1.6 ms; my chip run, PR 33)
+    tileable = (D % 128 == 0 and k_cache.shape[1] % 8 == 0
+                and k_cache.shape[2] > 1)
 
     def kernel():
         from .pallas_paged import paged_attention_decode

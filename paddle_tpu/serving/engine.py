@@ -77,6 +77,7 @@ from ..ops.paged_attention import (
     shard_kv_pool,
 )
 from ..ops.decode_burst import run_burst
+from ..ops.selective_scan import StateCache
 from ..ops.sampling import sample_tokens
 from .burst import burst_eligible, clamp_burst
 from .burst import register_metrics as _register_burst_metrics
@@ -142,6 +143,11 @@ class EngineConfig:
     num_blocks: int = 256
     block_size: int = 16
     dtype: object = None              # pool dtype; None = jnp.float32
+    # Automatic prefix caching over the block pool.  REFUSED, by name, for
+    # a model whose layers declare per-sequence recurrent state
+    # (CacheSpec.state): a state cannot be forked from a block prefix, so
+    # such a model is built with prefix_cache=False (as are unified_step,
+    # burst_steps, spec, role != "unified", mp > 1, audit and aot for it).
     prefix_cache: bool = True
     profile_ops: bool = False
     scheduler: Optional[SchedulerConfig] = None
@@ -298,12 +304,23 @@ class EngineCore:
         dtype = config.dtype if config.dtype is not None else jnp.float32
         cfg = model.config
         self.model = model
+        # --- what each layer keeps, as the model declares it (ROADMAP D4) ---
+        # per TOKEN (pages) and per SEQUENCE (slots): the pools, the prefill
+        # buffers, the mp check and the mesh shardings below all go by this
+        # and never by head counts
+        self.cache_specs = list(model.cache_specs())
+        sched_cfg = config.scheduler or SchedulerConfig()
+        self._has_state = any(spec.state for spec in self.cache_specs)
+        self._refuse_state_paths(config)
+        # a slot a running sequence: the running set is capped at
+        # max_num_seqs, so admission never waits on a slot it cannot get
+        self.state_slots = sched_cfg.max_num_seqs if self._has_state else 0
         self.kv = KVCacheManager(num_blocks, block_size,
-                                 enable_prefix_cache=config.prefix_cache)
+                                 enable_prefix_cache=config.prefix_cache,
+                                 state_slots=self.state_slots)
         self.block_size = block_size
         self.num_blocks = num_blocks
-        self.scheduler = ContinuousBatchingScheduler(
-            config.scheduler or SchedulerConfig(), self.kv)
+        self.scheduler = ContinuousBatchingScheduler(sched_cfg, self.kv)
         # registry=None keeps counts per-engine; pass
         # observability.get_registry() to publish serving series on the
         # process-wide Prometheus page next to the jit compile counters.
@@ -379,10 +396,6 @@ class EngineCore:
                 f"mp={self.mp}; call distributed.topology.init_mesh(mp=...) "
                 "before building the engine")
         self._unified = bool(config.unified_step)
-        # --- what a cached token holds, as the model declares it ------------
-        # (ROADMAP D4) the pools, the prefill buffers, the mp check and the
-        # mesh shardings below all go by this and never by head counts
-        self.cache_specs = list(model.cache_specs())
         self._refuse_latent_paths(config)
         self._use_pallas = config.use_pallas_paged
         # the unified ragged program keeps its own routing: its Pallas
@@ -390,7 +403,8 @@ class EngineCore:
         # is NEVER subject to the legacy single-shard pin below
         self._use_pallas_ragged = config.use_pallas_paged
         if self.mp > 1:
-            kv_heads = sorted({spec.k[0] for spec in self.cache_specs})
+            kv_heads = sorted({spec.k[0] for spec in self.cache_specs
+                               if spec.k})
             if any(h % self.mp for h in kv_heads) or \
                     cfg.num_attention_heads % self.mp:
                 raise ValueError(
@@ -426,8 +440,19 @@ class EngineCore:
             return shard_kv_pool(
                 jnp.zeros((num_blocks, block_size) + tuple(row), dtype))
 
-        self._k_pools = tuple(pool(spec.k) for spec in self.cache_specs)
-        self._v_pools = tuple(pool(spec.v) for spec in self.cache_specs)
+        def slots(side):
+            # per-sequence state: a slot a running sequence and the null
+            # slot 0, in the layer's entry of that side of the pools
+            shape, slot_dtype = side
+            return jnp.zeros((self.state_slots + 1,) + tuple(shape),
+                             jnp.dtype(slot_dtype or dtype))
+
+        self._k_pools = tuple(
+            slots(spec.state[0]) if spec.state else pool(spec.k)
+            for spec in self.cache_specs)
+        self._v_pools = tuple(
+            slots(spec.state[1]) if spec.state else pool(spec.v)
+            for spec in self.cache_specs)
         self.metrics.registry.gauge(
             "serving_kv_bytes_per_token",
             help="bytes one cached token holds over all layers, as the "
@@ -435,6 +460,25 @@ class EngineCore:
             **self.metrics.labels).set(
             sum(spec.values_per_token() for spec in self.cache_specs)
             * jnp.dtype(dtype).itemsize)
+        # slot series: only a model that declares per-sequence state has them
+        self._state_gauge = None
+        if self._has_state:
+            reg, labels = self.metrics.registry, self.metrics.labels
+            self._state_gauge = reg.gauge(
+                "serving_state_slots_held",
+                help="per-sequence state slots held by running sequences",
+                **labels)
+            reg.gauge("serving_state_slots_capacity",
+                      help="per-sequence state slots (max_num_seqs; the "
+                           "null slot is not counted)",
+                      **labels).set(self.state_slots)
+            reg.gauge("serving_state_bytes_per_sequence",
+                      help="bytes one live sequence holds in slots over all "
+                           "layers, whatever its length, as the model "
+                           "declares its state",
+                      **labels).set(sum(
+                          spec.state_bytes_per_sequence(dtype)
+                          for spec in self.cache_specs))
         # routing-load series: made when a launch first brings a load, so a
         # model without routed experts never has them on /metrics
         self._moe_counters = None
@@ -598,6 +642,72 @@ class EngineCore:
             raise ValueError(
                 "this model declares a latent KV cache; EngineCore has no "
                 "latent path for: " + "; ".join(refused))
+
+    def _refuse_state_paths(self, config: "EngineConfig") -> None:
+        """A model with layers that declare per-SEQUENCE state
+        (``CacheSpec.state``: a recurrence's state after the last token)
+        is served by the prefill, chunk and decode programs, with
+        preemption by recompute.  Such state cannot be forked from a block
+        prefix, rolled back a token, or moved with pages; the paths that
+        would need to have no form for it yet and are refused here, by
+        name, rather than run a wrong one.  It goes by the declaration, not
+        by a layout name."""
+        if not self._has_state:
+            return
+        from ..parallel.utils import axis_size
+
+        refused = []
+        if config.prefix_cache:
+            refused.append("prefix_cache (a recurrent state cannot be forked "
+                           "from a block prefix; pass prefix_cache=False)")
+        if config.unified_step:
+            refused.append("unified_step (the unified ragged program)")
+        if int(config.burst_steps or 0) >= 2:
+            refused.append("burst_steps (device-resident decode bursts)")
+        if config.spec is not None and getattr(config.spec, "enabled", True):
+            refused.append("spec (speculative verify: no rollback of a "
+                           "state)")
+        if config.role != "unified":
+            refused.append(f"role={config.role!r} (KV hand-off moves pages, "
+                           "not slots)")
+        mp = axis_size("mp")        # self.mp is set after the pools' manager
+        if mp > 1:
+            refused.append(f"mp={mp} (slot pools are not sharded)")
+        if config.audit is not None and config.audit.enabled:
+            refused.append("audit (the shadow re-execution builds a paged "
+                           "cache a layer and snapshots every pool)")
+        if config.aot is not None or config.aot_path:
+            refused.append("aot / aot_path (no artifact was ever saved with "
+                           "slot pools)")
+        if refused:
+            raise ValueError(
+                "this model declares per-sequence recurrent state; "
+                "EngineCore has no path with such state for: "
+                + "; ".join(refused))
+
+    def _state_ints(self, rows: int) -> Dict[str, int]:
+        """What ``engine.build`` carries for a model with per-sequence
+        state: the real rows whose state the launch advances, and the
+        slots held; nothing for any other model."""
+        if not self._has_state:
+            return {}
+        return {"state_rows": rows,
+                "state_slots_held": self.kv.state_slots_held}
+
+    def _layer_caches(self, k_pools, v_pools, route_pages, route_state):
+        """One cache object a layer for a step program, by what the layer
+        declared: ``route_pages(PagedCache)`` for per-token rows,
+        ``route_state(StateCache)`` for per-sequence state."""
+        caches = []
+        for spec, k, v in zip(self.cache_specs, k_pools, v_pools):
+            if spec.state:
+                c = StateCache(Tensor(k), Tensor(v))
+                route_state(c)
+            else:
+                c = PagedCache(Tensor(k), Tensor(v))
+                route_pages(c)
+            caches.append(c)
+        return caches
 
     def _cap_seq_len(self, cap: int, why: str) -> None:
         """Lower the admission cap on prompt + max_new_tokens (never
@@ -866,12 +976,14 @@ class EngineCore:
         self.tracer.instant("decode_jit_trace", cat="jit",
                             batch=int(ids.shape[0]),
                             table_width=int(tables.shape[1]))
-        caches = []
-        for k, v in zip(k_pools, v_pools):
-            c = PagedCache(Tensor(k), Tensor(v))
+        def pages(c):
             c.route(tables, lens, slot_blocks, slot_offsets)
             c.use_pallas = self._use_pallas  # EngineConfig.use_pallas_paged
-            caches.append(c)
+
+        # a row's state slot is the id of its first block (kv_manager.py);
+        # padding rows have table 0, the null slot
+        caches = self._layer_caches(
+            k_pools, v_pools, pages, lambda c: c.route(tables[:, 0]))
         logits = self._call_model(ids, caches, pos, param_vals)
         self.attention_paths["decode"] = _paged_ops.last_path
         last = logits[:, -1, :].astype(jnp.float32)
@@ -939,18 +1051,29 @@ class EngineCore:
             return None if row is None else Tensor(
                 jnp.zeros((1, Tb) + tuple(row), self._pool_dtype))
 
-        dense = [(buffer(spec.k), buffer(spec.v))
-                 for spec in self.cache_specs]
+        dense = []
+        for spec, kp, vp in zip(self.cache_specs, k_pools, v_pools):
+            if spec.state:
+                # the state after the last REAL token goes to the slot of
+                # the sequence's first block; it starts from zero
+                c = StateCache(Tensor(kp), Tensor(vp))
+                c.route(blocks[:1], n_valid=last_pos + 1)
+                dense.append(c)
+            else:
+                dense.append((buffer(spec.k), buffer(spec.v)))
         logits = self._call_model(ids, dense, jnp.int32(0), param_vals)
         last = jnp.take(logits[0], last_pos, axis=0).astype(jnp.float32)
         tokens = sample_tokens(last[None], temps, top_ks, top_ps, keys)
+        # every k side, then every v side: the order of the parent's program
         new_k = tuple(
-            kp.at[blocks, offs].set(kb._value[0].astype(kp.dtype))
-            for kp, (kb, _) in zip(k_pools, dense))
+            c.state_pool._value if spec.state else
+            kp.at[blocks, offs].set(c[0]._value[0].astype(kp.dtype))
+            for spec, kp, c in zip(self.cache_specs, k_pools, dense))
         new_v = tuple(
-            vp if vb is None else
-            vp.at[blocks, offs].set(vb._value[0].astype(vp.dtype))
-            for vp, (_, vb) in zip(v_pools, dense))
+            c.conv_pool._value if spec.state else
+            vp if c[1] is None else
+            vp.at[blocks, offs].set(c[1]._value[0].astype(vp.dtype))
+            for spec, vp, c in zip(self.cache_specs, v_pools, dense))
         return tokens, last, self._launch_stats(last), new_k, new_v
 
     def _chunk_prefill_fn(self, param_vals, k_pools, v_pools, ids, start,
@@ -968,11 +1091,13 @@ class EngineCore:
         self.tracer.instant("prefill_jit_trace", cat="jit",
                             chunk_bucket=int(ids.shape[1]),
                             table_bucket=int(tables.shape[1]))
-        caches = []
-        for k, v in zip(k_pools, v_pools):
-            c = PagedCache(Tensor(k), Tensor(v))
-            c.route(tables, lens, slot_blocks, slot_offsets, q_start=start)
-            caches.append(c)
+        caches = self._layer_caches(
+            k_pools, v_pools,
+            lambda c: c.route(tables, lens, slot_blocks, slot_offsets,
+                              q_start=start),
+            # state carried in from the slot when the chunk starts past 0
+            lambda c: c.route(tables[:, 0], start=start,
+                              n_valid=last_pos + 1))
         logits = self._call_model(ids, caches, start, param_vals)
         last = jnp.take(logits[0], last_pos, axis=0).astype(jnp.float32)
         tokens = sample_tokens(last[None], temps, top_ks, top_ps, keys)
@@ -1280,7 +1405,7 @@ class EngineCore:
         phase, prof = self.tracer.phase, self.stepprof
         t_chunk0 = time.perf_counter()
         one_shot = False
-        with phase("engine.build", prof):
+        with phase("engine.build", prof, **self._state_ints(1)):
             ids, target, start, n, recompute = \
                 self._begin_prefill_chunk(req, t_chunk0)
             table = self.kv.table(rid)
@@ -1369,7 +1494,7 @@ class EngineCore:
         by the scheduler on ``req._slot``)."""
         phase, prof = self.tracer.phase, self.stepprof
         B = len(reqs)
-        with phase("engine.build", prof, rows=B):
+        with phase("engine.build", prof, rows=B, **self._state_ints(B)):
             Bb = bucket_size(B)
             width = max(len(self.kv.table(r.request_id)) for r in reqs)
             Wb = bucket_size(width)
@@ -1910,6 +2035,8 @@ class EngineCore:
                 self.metrics.sample_gauges(self.scheduler.queue_depth,
                                            self.scheduler.num_running,
                                            self.kv.occupancy())
+                if self._state_gauge is not None:
+                    self._state_gauge.set(self.kv.state_slots_held)
                 if self.history is not None:
                     # metrics history + alert evaluation (ISSUE 14):
                     # deterministic engine-step cadence, host-side only

@@ -94,6 +94,11 @@ def pool_meta(engine) -> Dict:
     """The pool-compatibility header both ends must agree on before any
     page content moves."""
     cfg = engine.model.config
+    if any(spec.state for spec in engine.cache_specs):
+        raise HandoffError(
+            "KV hand-off moves pages; this engine's model declares "
+            "per-sequence recurrent state, and slots have no hand-off "
+            "form yet")
     if any(spec.kind != "kv" for spec in engine.cache_specs):
         raise HandoffError(
             "KV hand-off moves a (2, layers, ...) payload of keys and "

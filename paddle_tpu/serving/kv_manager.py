@@ -15,6 +15,17 @@ lowest-priority running request (freeing its blocks for recompute later)
 instead of failing anyone.  Block 0 is the reserved null page that padding
 rows of a bucketed batch write into.
 
+Two kinds of memory (ISSUE 33): beside pages, which grow a token at a
+time, a layer may declare state of FIXED size a sequence (a selective scan's
+recurrent state: ``CacheSpec.state``), kept in ``state_slots`` slots.  One
+manager owns both: a sequence takes its slot with its first block and gives
+it back with its last, a slot is never shared, and running out of either is
+the same scheduling event.  The slot of a sequence IS the id of its first
+block: with ``state_slots = S`` the ids ``1..S`` are handed out as FIRST
+blocks only and every later block comes from ``S+1..``, so the step
+programs find a row's slot in the block table they already take
+(``tables[:, 0]``; 0, the null page, is the null slot of padding rows).
+
 Multi-chip (ISSUE 5): this manager is **per-process host state and stays
 replicated** when the engine serves tensor-parallel over the ``mp`` mesh
 axis.  The pool tensors shard along the head dim on device, but a block
@@ -53,12 +64,35 @@ class KVCacheManager(BlockPool):
     longest cached block-prefix of a new prompt for free
     (``fork_prefix``).  Capacity planning must then use
     :attr:`num_available` (free + evictable-cached), not ``num_free``.
+
+    With ``state_slots=S`` (a model whose layers declare per-sequence
+    state) the manager owns ``S`` slots beside the pages (module
+    docstring): blocks ``1..S`` are first blocks only (a sequence's slot
+    is ``table(seq)[0]``), :attr:`state_slots_held` is how many are taken,
+    and :attr:`num_available` counts the blocks any token can take
+    (``S+1..``).  A recurrent state cannot be forked from a block prefix,
+    so ``state_slots`` and the prefix cache exclude each other.
     """
 
     def __init__(self, num_blocks: int, block_size: int,
-                 enable_prefix_cache: bool = True):
+                 enable_prefix_cache: bool = True, state_slots: int = 0):
         super().__init__(num_blocks, block_size,
                          enable_prefix_cache=enable_prefix_cache)
+        self.state_slots = int(state_slots)
+        self._free_slots: list = []
+        if self.state_slots:
+            if enable_prefix_cache:
+                raise ValueError(
+                    "state_slots with the prefix cache on: a recurrent state "
+                    "cannot be forked from a block prefix")
+            if num_blocks < 2 * self.state_slots + 1:
+                raise ValueError(
+                    f"{self.state_slots} state slots need at least "
+                    f"{2 * self.state_slots + 1} blocks (the null page, a "
+                    "first block a slot and one more a sequence)")
+            # ids 1..S leave the common free list: first blocks only
+            self._free = list(range(num_blocks - 1, self.state_slots, -1))
+            self._free_slots = list(range(self.state_slots, 0, -1))
         # fault injection (ISSUE 12): while True, the pool reports zero
         # available capacity — the `pool_exhaust` injection point.  The
         # engine arms it for exactly ONE scheduler-planning pass, so the
@@ -67,6 +101,12 @@ class KVCacheManager(BlockPool):
         self.refuse_allocations = False
 
     # --- capacity ----------------------------------------------------------
+    @property
+    def num_free(self) -> int:
+        """Free blocks of both lists: the common one and, with state
+        slots, the first blocks no sequence holds."""
+        return len(self._free) + len(self._free_slots)
+
     @property
     def num_available(self) -> int:
         if self.refuse_allocations:
@@ -78,7 +118,51 @@ class KVCacheManager(BlockPool):
         Reuse-LRU blocks (cached content, no owner) count as free capacity
         — they are evictable on demand."""
         usable = self.num_blocks - 1
-        return (usable - self.num_available) / usable if usable else 0.0
+        free = self.num_available + len(self._free_slots)
+        return (usable - free) / usable if usable else 0.0
+
+    # --- per-sequence state slots ---------------------------------------------
+    @property
+    def state_slots_held(self) -> int:
+        return self.state_slots - len(self._free_slots)
+
+    def can_start_sequence(self) -> bool:
+        """Whether a sequence that holds nothing yet can take its first
+        block: always without state slots, else while a slot is free."""
+        return not self.state_slots or bool(self._free_slots)
+
+    def blocks_needed(self, seq_id, num_tokens: int) -> int:
+        """Blocks off the COMMON free list: a first block comes with the
+        sequence's slot and is not counted against ``num_available``."""
+        need = super().blocks_needed(seq_id, num_tokens)
+        if self.state_slots and need and not self._tables.get(seq_id):
+            need -= 1
+        return need
+
+    def allocate(self, seq_id, num_tokens: int,
+                 cause: str = "other") -> bool:
+        if self.state_slots and not self._tables.get(seq_id) \
+                and super().blocks_needed(seq_id, num_tokens):
+            # all or nothing, the slot included: no slot, or too few blocks
+            # for the rest, takes nothing
+            if not self._free_slots or \
+                    self.blocks_needed(seq_id, num_tokens) > self.num_available:
+                return False
+            first = self._free_slots.pop()
+            self._ref[first] = 1
+            self._tables[seq_id] = [first]
+        return super().allocate(seq_id, num_tokens, cause)
+
+    def free(self, seq_id) -> int:
+        returned = super().free(seq_id)
+        self._return_slots()
+        return returned
+
+    def _return_slots(self) -> None:
+        """A first block just released sits at the tail of the common free
+        list (a table is released last block first): back to the slots."""
+        while self._free and self._free[-1] <= self.state_slots:
+            self._free_slots.append(self._free.pop())
 
     def burst_capacity(self, rows: int) -> int:
         """Largest per-row decode-burst length N the pool can promise
@@ -145,6 +229,7 @@ class KVCacheManager(BlockPool):
                 self._free.append(b)
                 freed += 1
         self._lens[seq_id] = new_len
+        self._return_slots()
         return freed
 
     # --- views -------------------------------------------------------------

@@ -325,8 +325,10 @@ class ContinuousBatchingScheduler:
             # HAS: a prompt filling the pool exactly is still servable when
             # its decode tokens fit the last block's free slots
             need = min(uncached + 1, self._usable_blocks())
-            if need + from_reuse > self.kv.num_available - promised:
-                break  # admission never preempts running work
+            if need + from_reuse > self.kv.num_available - promised \
+                    or not self.kv.can_start_sequence():
+                break  # admission never preempts running work; a model
+                       # with per-sequence state also waits for a free slot
             self.waiting.popleft()
             cached = self.kv.fork_prefix(req.request_id, ids, blocks=hit)
             req.num_cached_tokens = cached
@@ -343,7 +345,10 @@ class ContinuousBatchingScheduler:
         self.promised_blocks = promised
 
     def _preempt(self, victim: Request) -> None:
-        """Evict ``victim``: free its blocks (shared prefix blocks stay
+        """Evict ``victim``: free its blocks, and with them its state slot
+        where the model keeps per-sequence state (a recurrent state cannot
+        be kept without its sequence: re-admission recomputes it from
+        zero) (shared prefix blocks stay
         with their other owners — refcounts guarantee a preemption never
         clobbers a block someone else forked), re-enqueue at the FRONT of
         the waiting queue (a preempted request outranks new arrivals, so

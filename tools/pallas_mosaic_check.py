@@ -211,6 +211,10 @@ CHECKS = [
     ("paged_decode_gqa_32q8kv", paged_decode_check(32, 8)),
     ("paged_decode_gqa_8q2kv_mp4_shard", paged_decode_check(8, 2)),
     ("paged_decode_mha_8q8kv", paged_decode_check(8, 8)),
+    # multi-query: 20 query heads on ONE KV head (the hybrid's two
+    # attention layers), at the backlog cell's 256 rows x 4,096 tokens
+    ("paged_decode_mqa_20q1kv_rows256",
+     paged_decode_check(20, 1, batch=256, width=256)),
     # EngineConfig.dtype=None: float32 pools under bf16 queries
     ("paged_decode_f32_pool_bf16_q",
      paged_decode_check(32, 8, pool_dtype=jnp.float32)),

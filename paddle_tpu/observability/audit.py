@@ -278,6 +278,12 @@ class NumericsAuditor:
         """True while the CURRENT engine step is shadow-audited."""
         return self.enabled and self._sampled
 
+    @property
+    def next_sampled(self) -> bool:
+        """True if the engine step about to open will be shadow-audited:
+        what :meth:`begin_step` is going to set, asked beforehand."""
+        return self.enabled and self._step % self.cfg.sample_every == 0
+
     def wants_logits(self, program: str) -> bool:
         """True where a launch of ``program`` in the CURRENT engine step
         is compared with the shadow oracle: the one launch whose logits

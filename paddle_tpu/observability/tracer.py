@@ -35,7 +35,11 @@ from typing import Any, Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
 
-# the phases of one serving-engine step, in the order a step runs them.
+# the phases of one serving-engine step, in the order a synchronous step
+# (``EngineCore.step``) runs them.  A step of the serving loop that runs
+# ahead (``EngineCore.step_ahead``) runs the same phases in another order:
+# plan, admit, build and dispatch of launch N+1 FIRST, then device_wait,
+# fetch and emit of launch N, the one dispatched a step earlier.
 # The names are a contract: the benchmark's readers
 # (benchmarks/host_spans.py) and PERF.md key on them.
 STEP_PHASES = (
@@ -49,7 +53,13 @@ STEP_PHASES = (
                             # (real rows whose state the launch advances)
                             # and state_slots_held=
     "engine.dispatch",      # the step call, until the jit call returns
-    "engine.device_wait",   # blocked until the program has ended
+                            # (rows=, bucket=, ahead= 1 where the launch
+                            # went out before the tokens of the launch
+                            # before it were read, launch= its number)
+    "engine.device_wait",   # blocked until the program whose tokens are
+                            # wanted has ended (launch= its number): in a
+                            # step that ran ahead that is the launch
+                            # BEFORE the one just dispatched
     "engine.fetch",         # host arrays of what the step reads (bytes=):
                             # the int32 tokens; with the audit on its
                             # stats, and a sampled decode / ragged

@@ -48,6 +48,7 @@ bucket sets (and therefore the jit trace count) are mp-invariant.
 
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Sequence
@@ -121,6 +122,15 @@ def _register_moe_metrics(registry, labels: Dict[str, str]):
     }
 
 
+_AHEAD_SETTLES_HELP = (
+    "steps of the serving loop that read the launch in flight before "
+    "planning instead of running ahead of it, by the rule that made them "
+    "(prefill, admit, preempt, finish, audit, fault, task, bare), and "
+    "steps with decode rows of an engine that never leaves a launch in "
+    "flight (family: the unified step, bursts, mp > 1, or step programs "
+    "whose outputs are committed to a device)")
+
+
 # StepTimer series and collective-phase label of each program family
 _STEP_TIMERS = {
     "prefill": ("prefill_step", "prefill"),
@@ -129,6 +139,69 @@ _STEP_TIMERS = {
     "ragged": ("unified_step", "ragged"),
     "burst": ("burst_step", "burst"),
 }
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _pad_tokens(tokens, rows: int):
+    """A launch's ``[bucket]`` tokens at the width of the engine's largest
+    row bucket, so that :func:`_ids_program` compiles once a row bucket and
+    not once a PAIR of them (81 pairs at 256 sequences took 8.8 s on the
+    chip).  A launch of the largest bucket needs none."""
+    return jnp.zeros((rows,), tokens.dtype).at[:tokens.shape[0]].set(tokens)
+
+
+@jax.jit
+def _ids_program(prev_tokens, src, host_ids):
+    """The input ids of a decode launch built while the launch before it
+    is in flight: row ``i`` takes row ``src[i]`` of that launch's int32
+    tokens, on the device, or where ``src[i]`` is -1 the token the host
+    knows, ``host_ids[i]``.  A program of its own, OUTSIDE the decode step
+    program (which keeps its arguments and sees an ``ids`` of the shape
+    and dtype the host array has), compiled before the serving loop
+    starts (``EngineCore.warm_ahead``)."""
+    took = jnp.take(prev_tokens, jnp.maximum(src, 0))
+    return jnp.where(src >= 0, took.astype(host_ids.dtype),
+                     host_ids)[:, None]
+
+
+@dataclass(slots=True)
+class _Flight:
+    """One step-program launch between :meth:`EngineCore._dispatch` and
+    :meth:`EngineCore._collect`: what the program returned, still on the
+    device, and what the second half needs to finish it."""
+
+    program: str
+    bucket: tuple
+    seq: int                # the launch's number: rides both of its phases
+    timer: StepTimer
+    toks: object
+    logits: object
+    stats: object
+    load: object            # routed-expert load, or None
+    nbytes: int             # what the host copies asked for at dispatch hold
+    audit: bool
+    shadow: bool
+    traced: bool            # a trace counter moved during the step call
+
+
+@dataclass(slots=True)
+class _DecodeLaunch:
+    """One decode launch as the engine built it: its rows in order, the
+    step program's arguments after the pools (``ids, pos, tables, lens,
+    slot_blocks, slot_offsets`` and the sampling quartet), and, once
+    dispatched, its :class:`_Flight`."""
+
+    reqs: List[Request]
+    rids: tuple
+    bucket: tuple
+    width: int
+    args: tuple
+    pre_pools: object
+    flight: Optional[_Flight] = None
+
+    @property
+    def rows(self) -> int:
+        return len(self.reqs)
 
 
 @dataclass
@@ -527,6 +600,34 @@ class EngineCore:
         self._jit_burst = jax.jit(self._burst_fn, donate_argnums=donate,
                                   **jit_kw["burst"])
         self._profile_ops = config.profile_ops
+        # --- running ahead of the read (ISSUE 35) ---------------------------
+        # the serving loop's decode launch may stay on the device while
+        # the next one is planned, built and dispatched (step_ahead): the
+        # launch in flight, when the last launch was seen to end, the
+        # largest row bucket (the width the small programs that hand a
+        # launch's tokens to the next are compiled at: _ids_program), and
+        # how often it engages.
+        # The families with no such path never leave a launch in flight.
+        self._inflight: Optional[_DecodeLaunch] = None
+        self._last_ready = 0.0
+        self._launch_seq = 0
+        self._top_rows = bucket_size(sched_cfg.max_num_seqs)
+        self._flies = not (self._unified or self._burst_steps >= 2
+                           or self.mp > 1)
+        reg, labels = self.metrics.registry, self.metrics.labels
+        self._ahead_counters = {
+            "launches": reg.counter(
+                "serving_ahead_launches_total",
+                help="decode launches dispatched before the tokens of the "
+                     "launch before them were read", **labels),
+            "dropped_rows": reg.counter(
+                "serving_ahead_dropped_rows_total",
+                help="rows of a launch that ran ahead whose request had "
+                     "ended (an EOS token, an abort) by the time its "
+                     "tokens were read: nothing was emitted for them",
+                **labels),
+            "settles": {},      # reason -> counter, made on first use
+        }
         if self._unified and jax.default_backend() == "tpu" \
                 and config.use_pallas_paged is not False:
             self._cap_ragged_context()
@@ -768,71 +869,102 @@ class EngineCore:
         self._sampling_counters[f"{kind}_launches"].inc()
 
     def _launch(self, program: str, bucket, jit_fn, args, rows: int):
-        """One step-program launch, shared by all five families, cut
-        into the phases the device trace can tell apart
-        (``observability.tracer.STEP_PHASES``): ``engine.dispatch`` is
-        the step call alone, until the jit call returns;
-        ``engine.device_wait`` waits on the tokens for the program to
-        end; ``engine.fetch`` makes the host arrays of what the step
-        reads and of nothing else.  That is the int32 tokens (a burst's
-        ``[rows, steps]`` buffer), with the audit on also the three
-        floats a row of ``stats``, and on a decode / ragged launch of a
-        step the auditor's schedule samples the ``rows`` real rows of
+        """One step-program launch run to its end, shared by all five
+        families: :meth:`_dispatch`, then :meth:`_collect` at once.
+        Returns ``(tokens, logits, stats, wall seconds)``."""
+        return self._collect(
+            self._dispatch(program, bucket, jit_fn, args, rows))
+
+    def _dispatch(self, program: str, bucket, jit_fn, args, rows: int,
+                  ahead: bool = False) -> "_Flight":
+        """The first half of a launch (``observability.tracer
+        .STEP_PHASES``): ``engine.dispatch`` is the step call alone, until
+        the jit call returns, and asks for the host copy of what the step
+        will read and of nothing else.  That is the int32 tokens (a
+        burst's ``[rows, steps]`` buffer), with the audit on also the
+        three floats a row of ``stats``, and on a decode / ragged launch
+        of a step the auditor's schedule samples the ``rows`` real rows of
         the float32 logits, sliced on the device before they cross
-        (counted by ``serving_logits_fetches_total``).  Every copy is
-        asked for as soon as the program is dispatched, so the fetch
-        finds the arrays on the host; its ``bytes`` are what it copied.
-        Returns ``(tokens, logits, stats, wall seconds)``: ``logits`` is
-        the device array the program returned, ``[.., vocab]`` with the
-        bucket's padding rows, except on such a sampled launch, where it
-        is the host copy of the real rows.  A caller must not keep the
-        device array past its step: it is ``rows x vocab`` float32 of
-        device memory, free again once the step's frame drops it.
-        ``stats`` stays a device array with the audit off: nothing reads
-        it.  A launch during which a trace counter moved IS that
-        bucket's trace+compile, so its wall time goes to the compile
-        table."""
-        phase, prof = self.tracer.phase, self.stepprof
+        (counted by ``serving_logits_fetches_total``).  ``ahead`` says the
+        launch goes out before the tokens of the one before it were read;
+        it rides the phase as an integer, as does the launch's number
+        (``launch``), which its ``engine.device_wait`` carries too: the
+        wait that follows a dispatch is no longer always its own.  What
+        comes back is the launch
+        in flight: the device arrays, and what :meth:`_collect` needs to
+        finish it, now or a step later."""
         timer, collective = _STEP_TIMERS[program]
         audit = self.audit.enabled and program in AUDIT_PROGRAMS
         shadow = self.audit.wants_logits(program)
-        traces0 = (self.prefill_trace_count + self.decode_trace_count
-                   + self.ragged_trace_count + self.burst_trace_count)
-        with StepTimer(self.metrics, timer,
-                       self._collective_phase(collective)) as st:
-            with phase("engine.dispatch", prof, rows=rows,
-                       bucket=bucket[0]):
-                toks, logits, stats, self._k_pools, self._v_pools = \
-                    self._step_call(program, bucket, jit_fn,
-                                    self._param_vals(), self._k_pools,
-                                    self._v_pools, *args)
-                load = None
-                if isinstance(stats, tuple):    # a model with routed experts
-                    stats, load = stats
-                fetched = [toks]
-                if audit:
-                    fetched.append(stats)
-                if shadow:
-                    logits = logits[:rows]
-                    fetched.append(logits)
-                for arr in fetched:
-                    arr.copy_to_host_async()
-                if load is not None:
-                    load.copy_to_host_async()
-            with phase("engine.device_wait", prof):
-                toks.block_until_ready()
-                moe = self._moe_load_ints(program, load)
-            with phase("engine.fetch", prof,
-                       bytes=sum(arr.nbytes for arr in fetched), **moe):
-                toks = np.asarray(toks, np.int32)
-                if audit:
-                    stats = np.asarray(stats, np.float32)
-                if shadow:
-                    logits = self._host_logits(logits)
-        if (self.prefill_trace_count + self.decode_trace_count
-                + self.ragged_trace_count + self.burst_trace_count) > traces0:
-            self.stepprof.record_compile(program, bucket, st.dt)
-        return toks, logits, stats, st.dt
+        traces0 = self._traces()
+        st = StepTimer(self.metrics, timer,
+                       self._collective_phase(collective))
+        st.__enter__()
+        self._launch_seq += 1
+        with self.tracer.phase("engine.dispatch", self.stepprof, rows=rows,
+                               bucket=bucket[0], ahead=int(ahead),
+                               launch=self._launch_seq):
+            toks, logits, stats, self._k_pools, self._v_pools = \
+                self._step_call(program, bucket, jit_fn,
+                                self._param_vals(), self._k_pools,
+                                self._v_pools, *args)
+            load = None
+            if isinstance(stats, tuple):    # a model with routed experts
+                stats, load = stats
+            fetched = [toks]
+            if audit:
+                fetched.append(stats)
+            if shadow:
+                logits = logits[:rows]
+                fetched.append(logits)
+            for arr in fetched:
+                arr.copy_to_host_async()
+            if load is not None:
+                load.copy_to_host_async()
+        # a launch during which a trace counter moved IS that bucket's
+        # trace+compile: its wall time goes to the compile table
+        return _Flight(program, bucket, self._launch_seq, st, toks, logits,
+                       stats, load, sum(arr.nbytes for arr in fetched),
+                       audit, shadow, self._traces() > traces0)
+
+    def _traces(self) -> int:
+        return (self.prefill_trace_count + self.decode_trace_count
+                + self.ragged_trace_count + self.burst_trace_count)
+
+    def _collect(self, fl: "_Flight"):
+        """The second half of a launch: ``engine.device_wait`` waits on
+        ITS tokens for ITS program to end (a launch dispatched after it
+        may be running by then), ``engine.fetch`` makes the host arrays of
+        what :meth:`_dispatch` asked for; its ``bytes`` are what it
+        copied.  Returns ``(tokens, logits, stats, wall seconds)``:
+        ``logits`` is the device array the program returned, ``[.., vocab]``
+        with the bucket's padding rows, except on a launch the audit
+        samples, where it is the host copy of the real rows.  A caller
+        must not keep the device array past its step: it is ``rows x
+        vocab`` float32 of device memory, free again once the step's frame
+        drops it.  ``stats`` stays a device array with the audit off:
+        nothing reads it.  The wall seconds run from the dispatch, or from
+        when the launch before this one was seen to end where that is
+        later: a launch that went out ahead spent the time before that in
+        the device's queue."""
+        phase, prof = self.tracer.phase, self.stepprof
+        toks, logits, stats = fl.toks, fl.logits, fl.stats
+        with phase("engine.device_wait", prof, launch=fl.seq):
+            toks.block_until_ready()
+            moe = self._moe_load_ints(fl.program, fl.load)
+        fl.timer.start_no_earlier_than(self._last_ready)
+        with phase("engine.fetch", prof, bytes=fl.nbytes, **moe):
+            toks = np.asarray(toks, np.int32)
+            if fl.audit:
+                stats = np.asarray(stats, np.float32)
+            if fl.shadow:
+                logits = self._host_logits(logits)
+        fl.timer.__exit__()
+        self._last_ready = time.perf_counter()
+        if fl.traced:
+            self.stepprof.record_compile(fl.program, fl.bucket,
+                                         fl.timer.dt)
+        return toks, logits, stats, fl.timer.dt
 
     def _audit_logits(self, out, rows: int):
         """What the auditor gets as the logits of a decode / ragged
@@ -997,6 +1129,29 @@ class EngineCore:
         return (tokens, last, self._launch_stats(last),
                 tuple(c.k_pool._value for c in caches),
                 tuple(c.v_pool._value for c in caches))
+
+    def warm_ahead(self) -> None:
+        """Compile the two small programs that hand a launch's tokens to
+        the next one, for every row bucket of this engine (a closed set:
+        powers of two up to ``max_num_seqs``), so that no step of the
+        serving loop ever does.  The loop's owner calls it before the loop
+        serves anything (``fleet.EngineReplica.start``); an engine that
+        never leaves a launch in flight compiles nothing."""
+        if not self._flies:
+            return
+        for k in range(self._top_rows.bit_length()):
+            rows = 1 << k
+            ids = _ids_program(
+                self._widest(jnp.zeros((rows,), jnp.int32)),
+                np.full((rows,), -1, np.int32), np.zeros((rows,), np.int64))
+        jax.block_until_ready(ids)
+
+    def _widest(self, tokens):
+        """A launch's tokens at the width of the largest row bucket, the
+        one width :func:`_ids_program` reads them at."""
+        if tokens.shape[0] == self._top_rows:
+            return tokens
+        return _pad_tokens(tokens, self._top_rows)
 
     def _burst_fn(self, param_vals, k_pools, v_pools, ids, pos, tables,
                   lens, slot_blocks, slot_offsets, n_steps, active,
@@ -1276,7 +1431,28 @@ class EngineCore:
         self.kv.free(req.request_id)
         self._finish(req, reason)
         self.requests.pop(request_id, None)
+        self._let_go_if_ended()
         return True
+
+    def _let_go_if_ended(self) -> None:
+        """If every row of the launch in flight has ended (aborts, EOS
+        tokens in the launch before it), nothing of it will ever be
+        emitted, and with no running request no step would come to read
+        it: it is let go unread, its rows counted as dropped.  No wait:
+        this runs on the engine's death path too, which aborts every
+        request."""
+        launch = self._inflight
+        if launch is None or not all(r.finished for r in launch.reqs):
+            return
+        self._inflight = None
+        self._ahead_counters["dropped_rows"].inc(launch.rows)
+        self._record_decode(launch, 0.0)
+
+    def _record_decode(self, launch: "_DecodeLaunch", wall_s: float) -> None:
+        self.stepprof.record_program(
+            "decode", launch.bucket, scheduled=launch.rows,
+            capacity=launch.bucket[0], wall_s=wall_s,
+            table_width=launch.width, requests=launch.rids)
 
     def _finish(self, req: Request, reason: FinishReason) -> None:
         req.state = RequestState.FINISHED
@@ -1491,7 +1667,33 @@ class EngineCore:
 
     def _decode(self, reqs: List[Request]) -> Dict[object, int]:
         """One bucketed decode step for ``reqs`` (slots already reserved
-        by the scheduler on ``req._slot``)."""
+        by the scheduler on ``req._slot``), run to its end."""
+        launch = self._build_decode(reqs)
+        with self._decode_span(launch):
+            out = self._launch("decode", launch.bucket, self._jit_decode,
+                               launch.args, rows=launch.rows)
+        return self._emit_decode(launch, *out)
+
+    def _decode_span(self, launch: "_DecodeLaunch"):
+        Bb, Wb = launch.bucket
+        return self.tracer.span(
+            "decode_step", cat="serving", batch=launch.rows,
+            batch_bucket=Bb, width_bucket=Wb, requests=launch.rids,
+            traces=tuple(r.trace_id for r in launch.reqs))
+
+    def _build_decode(self, reqs: List[Request],
+                      prev: Optional["_DecodeLaunch"] = None
+                      ) -> "_DecodeLaunch":
+        """The routing arrays of one decode launch, and the commit of the
+        position each row writes: a launch is counted into ``kv.seq_len``
+        when it is built, so the next one can be planned and built before
+        this one's tokens are read.  Everything but the input token
+        follows from lengths; the sampling key is the pure ``(seed,
+        output position)`` pair.  With ``prev`` -- the launch in flight --
+        a row that is also a row of ``prev`` takes its input token from
+        ``prev``'s tokens ON THE DEVICE (``_ids_program``: one small
+        gather, the step program is the same) and its output position is
+        one further; any other row's last token is on the host."""
         phase, prof = self.tracer.phase, self.stepprof
         B = len(reqs)
         with phase("engine.build", prof, rows=B, **self._state_ints(B)):
@@ -1505,18 +1707,32 @@ class EngineCore:
             slot_blocks = np.zeros((Bb,), np.int32)
             slot_offsets = np.zeros((Bb,), np.int32)
             pack = SamplingPack(Bb)  # pad rows stay temp=0 → argmax, ignored
+            # the row of ``prev`` each request in flight sits in; -1: the
+            # host knows the row's last token
+            src = np.full((Bb,), -1, np.int32)
+            flying = {} if prev is None else {
+                rid: j for j, rid in enumerate(prev.rids)}
             for i, r in enumerate(reqs):
                 rid = r.request_id
                 t = self.kv.table(rid)
                 p = self.kv.seq_len(rid)
-                ids[i, 0] = r.last_token
+                j = flying.get(rid)
+                if j is None:
+                    ids[i, 0] = r.last_token
+                    pack.set_request(i, r)
+                else:
+                    src[i] = j
+                    pack.set_request(i, r, offset=1)
                 poss[i] = p
                 tables[i, :len(t)] = t
                 lens[i] = p + 1           # cache length AFTER this token
                 slot_blocks[i], slot_offsets[i] = r._slot
-                pack.set_request(i, r)
+                self.kv.commit(rid, 1)
             self._count_launch(pack)
             self.decode_buckets.add(("decode", Bb, Wb))
+            if prev is not None:
+                ids = _ids_program(self._widest(prev.flight.toks), src,
+                                   ids[:, 0])
             # shadow-oracle capture (ISSUE 10): on sampled audit steps the
             # PRE-step pools are snapshotted so the auditor can re-execute
             # this exact step through the XLA gather reference program
@@ -1525,30 +1741,36 @@ class EngineCore:
             # the rows' ids ride the span and the step record as the
             # tuple; whoever reads them joins them (export, records())
             rids = tuple(r.request_id for r in reqs)
-        with self.tracer.span("decode_step", cat="serving", batch=B,
-                              batch_bucket=Bb, width_bucket=Wb,
-                              requests=rids,
-                              traces=tuple(r.trace_id for r in reqs)):
-            toks, out, stats, dt = self._launch(
-                "decode", (Bb, Wb), self._jit_decode,
-                (ids, poss, tables, lens, slot_blocks, slot_offsets,
-                 *pack.arrays()), rows=B)
-        with phase("engine.emit", prof, rows=B):
+        return _DecodeLaunch(
+            reqs, rids, (Bb, Wb), width,
+            (ids, poss, tables, lens, slot_blocks, slot_offsets,
+             *pack.arrays()), pre_pools)
+
+    def _emit_decode(self, launch: "_DecodeLaunch", toks, out, stats,
+                     dt: float) -> Dict[object, int]:
+        """What follows the read of a decode launch's tokens: the step
+        record, the audit, and one emission a row.  A row that ended
+        after the launch was built (an EOS token in the launch before it,
+        an abort) has no use for its result: nothing is emitted or
+        counted as a token for it, its blocks are free already, and what
+        the launch wrote for it lies where only later launches can be
+        handed room."""
+        B, (Bb, Wb), reqs = launch.rows, launch.bucket, launch.reqs
+        with self.tracer.phase("engine.emit", self.stepprof, rows=B):
             # token/row accounting only: scheduled = B real rows (one
             # token each) vs the Bb row bucket — this is the axis the
             # scheduler's tokens_planned ledger counts, so the invariant
-            # stays exact.  Width-bucket padding (tables padded `width`
-            # -> Wb with null pages) is NOT in these counters; it rides
-            # the record as the table_width attr next to the bucket shape.
-            self.stepprof.record_program(
-                "decode", (Bb, Wb), scheduled=B, capacity=Bb, wall_s=dt,
-                table_width=width, requests=rids)
+            # stays exact (a row whose result is dropped was planned and
+            # ran).  Width-bucket padding (tables padded `width` -> Wb
+            # with null pages) is NOT in these counters; it rides the
+            # record as the table_width attr next to the bucket shape.
+            self._record_decode(launch, dt)
             if self.audit.enabled:
                 # sentinel over the REAL rows (pad rows attend the null
                 # page — their logits are not part of the serving
                 # contract), plus the shadow re-execution when this step
                 # is sampled: ``out`` is then the host copy of the real
-                # rows (``_launch``).  kernel_corrupt (ISSUE 12) corrupts
+                # rows (``_collect``).  kernel_corrupt (ISSUE 12) corrupts
                 # ONLY this audit copy — the emitted tokens were sampled
                 # on the device from the untouched logits, so served
                 # tokens stay correct while the divergence net trips.
@@ -1558,19 +1780,24 @@ class EngineCore:
                 self.audit.observe_program(
                     "decode", stats[:B], (Bb, Wb),
                     logits=self._audit_logits(out, B),
-                    inputs={"ids": ids, "pos": poss, "tables": tables,
-                            "lens": lens, "slot_blocks": slot_blocks,
-                            "slot_offsets": slot_offsets},
-                    pre_pools=pre_pools,
+                    inputs=dict(zip(
+                        ("ids", "pos", "tables", "lens", "slot_blocks",
+                         "slot_offsets"), launch.args)),
+                    pre_pools=launch.pre_pools,
                     requests=[{"id": str(r.request_id),
                                "greedy": r.sampling.temperature == 0.0}
                               for r in reqs])
             result = {}
+            dropped = 0
             for i, r in enumerate(reqs):
-                self.kv.commit(r.request_id, 1)
+                if r.finished:
+                    dropped += 1
+                    continue
                 tok = int(toks[i])
                 self._emit_device(r, tok)
                 result[r.request_id] = tok
+            if dropped:
+                self._ahead_counters["dropped_rows"].inc(dropped)
         return result
 
     def _burst_exec(self, reqs: List[Request],
@@ -1889,18 +2116,160 @@ class EngineCore:
         return emitted
 
     def step(self) -> Dict[object, int]:
-        """One engine iteration: schedule → prefill(s) → decode batch →
-        retire.  Returns {request_id: token} emitted this step."""
+        """One engine iteration, run to its end: schedule → prefill(s) →
+        decode batch → wait → emit → retire.  Returns {request_id: token}
+        emitted by this call, and on return NOTHING is in flight: this is
+        the contract of every direct caller (``run``, ``stream``, the
+        benchmark's reference check, the worker process's loop).  Should a
+        launch be in flight when it is called (the serving loop's, left by
+        :meth:`step_ahead`), it is read first."""
+        return self._step(ahead=False)
+
+    def step_ahead(self) -> Dict[object, int]:
+        """One engine iteration of the SERVING LOOP (``fleet.EngineReplica
+        ._loop``), which may end with its decode launch still on the
+        device.  While decode launch N is in flight the next call plans
+        step N+1 on the assumption that every row of N yields one token
+        (``scheduler.plan_ahead``), builds it with the input tokens taken
+        from N's output on the device, and dispatches it; only then does
+        it wait for N's tokens, bring them to the host, emit them, retire
+        what finished and run the trackers, all while N+1 runs.  The order
+        of the phases in such a step is ``sched.plan`` → ``engine.admit``
+        → ``engine.build`` → ``engine.dispatch`` (N+1, ``ahead=1``) →
+        ``engine.device_wait`` → ``engine.fetch`` → ``engine.emit`` (N) →
+        ``engine.trackers``: the wait is for the launch BEFORE the one
+        just dispatched.
+
+        It runs ahead only where the plan is a pure continuation, and
+        reads the launch in flight FIRST (:meth:`settle`, counted by its
+        reason in ``serving_ahead_settles_total``) wherever it is not: a
+        prompt to compute, an admission, a preemption, a step the numerics
+        audit samples, a fault planned for this step.  The step then runs
+        in the order of :meth:`step`, prefills synchronously, and leaves
+        only its decode launch in flight.  An engine built with the
+        unified step, decode bursts, speculative drafting or ``mp > 1``
+        never leaves one (reason ``family``).  A row that ended on an EOS
+        token in N has a row in N+1 already; its result is dropped at the
+        read (``serving_ahead_dropped_rows_total``).  Returns what this
+        call emitted: the tokens of the launch it read, and a prefill's
+        first token."""
+        return self._step(ahead=True)
+
+    def settle(self, reason: str) -> Dict[object, int]:
+        """Read the decode launch in flight, if there is one: wait for its
+        tokens, emit them, retire what finished.  Counted under ``reason``
+        in ``serving_ahead_settles_total``.  Whatever reads or moves the
+        engine's state between steps (a KV export, an import, a detach)
+        calls this first; so does a step that cannot run ahead."""
+        launch, self._inflight = self._inflight, None
+        if launch is None:
+            return {}
+        self._count_settle(reason)
+        emitted = self._emit_decode(launch, *self._collect(launch.flight))
+        self._retire_finished()
+        return emitted
+
+    def _count_settle(self, reason: str) -> None:
+        c = self._ahead_counters["settles"].get(reason)
+        if c is None:
+            c = self._ahead_counters["settles"][reason] = \
+                self.metrics.registry.counter(
+                    "serving_ahead_settles_total",
+                    help=_AHEAD_SETTLES_HELP,
+                    **dict(self.metrics.labels, reason=reason))
+        c.inc()
+
+    def _retire_finished(self) -> None:
+        with self.tracer.phase("engine.emit", self.stepprof):
+            for req in list(self.scheduler.running):
+                if req.finished:
+                    self._retire(req)
+
+    def _admit(self, plan) -> None:
+        """Admission bookkeeping of one plan: counters, preemption and
+        abort events, cache attribution of what was admitted."""
+        phase, prof = self.tracer.phase, self.stepprof
+        with phase("engine.admit", prof):
+            self.metrics.count("engine_steps")
+            self.metrics.count("preemptions", len(plan.preempted))
+            for req in plan.preempted:
+                self.tracer.instant(
+                    "preemption", cat="serving",
+                    request=str(req.request_id), trace=req.trace_id,
+                    generated=len(req.output_tokens))
+                self._lc(req.request_id, _lc.EV_PREEMPTED,
+                         generated=len(req.output_tokens))
+            for req in plan.aborted:
+                # unservable at admission: scheduler set state/reason,
+                # the engine owns finish bookkeeping (timestamp +
+                # counter)
+                self._lc(req.request_id, _lc.EV_ADMISSION_REJECTED,
+                         reason="abort", error=req.error)
+                self._finish(req, FinishReason.ABORT)
+                self.requests.pop(req.request_id, None)
+            for req in plan.admitted:
+                cached = req.num_cached_tokens
+                total = len(req.prompt_ids) + len(req.output_tokens)
+                self.metrics.count("prefix_cache_hit_tokens", cached)
+                self.metrics.count("prefix_cache_miss_tokens",
+                                   total - cached)
+                if req.prompt_cached_tokens is None:
+                    # FIRST admission (output empty, so cached <=
+                    # prompt): the client-facing usage attribution
+                    req.prompt_cached_tokens = cached
+                # per-request attribution (ISSUE 13): accumulated at
+                # the SAME points as the counters above, so
+                # sum(per-request cached) == prefix_cache_hit_tokens
+                # exactly (asserted in tests and bench)
+                self.cachestat.record_admission(
+                    req.request_id, cached, total - cached,
+                    len(req.prompt_ids),
+                    recompute=bool(req.output_tokens))
+                self._lc(req.request_id, _lc.EV_ADMITTED,
+                         cached_tokens=cached,
+                         computed_tokens=total - cached,
+                         recompute=bool(req.output_tokens))
+                if cached:
+                    self.tracer.instant(
+                        "prefix_cache_hit", cat="serving",
+                        request=str(req.request_id),
+                        trace=req.trace_id, cached_tokens=cached)
+                if cached and self.cachestat.enabled:
+                    # prefix-heat (ISSUE 13): keyed by the DEEPEST
+                    # matched block's chain hash — it commits to the
+                    # whole cached prefix.  Guarded: the table copy
+                    # + hash lookup must cost nothing when the
+                    # tracker is disabled.
+                    depth = cached // self.block_size
+                    table = self.kv.table(req.request_id)
+                    self.cachestat.record_prefix_hit(
+                        self.kv.block_chain_hash(table[depth - 1])
+                        if 0 < depth <= len(table) else None,
+                        depth, cached, self.step_seq)
+
+    def _step(self, ahead: bool) -> Dict[object, int]:
         remove_timer = (self.metrics.install_dispatch_timer()
                         if self._profile_ops else lambda: None)
+        emitted: Dict[object, int] = {}
+        fi = self._fault
         self.step_seq += 1
         self.kv.clock = self.step_seq  # park lifetimes tick in steps
         self.stepprof.begin_step()
-        self.audit.begin_step()
-        fi = self._fault
         phase, prof = self.tracer.phase, self.stepprof
         trackers = None
         try:
+            if self._inflight is not None:
+                # what stands in the way of running ahead and is known
+                # before anything is planned.  The launch in flight is the
+                # step before's: it is read before the audit's schedule
+                # moves on and before a planned fault fires
+                why = ("bare" if not ahead else
+                       "audit" if self.audit.next_sampled else
+                       "fault" if fi is not None
+                       and fi.pending(self.step_seq) else None)
+                if why is not None:
+                    emitted.update(self.settle(why))
+            self.audit.begin_step()
             if fi is not None:
                 # named injection points (ISSUE 12): slow_step sleeps
                 # here (inside the replica's watchdog-watched section),
@@ -1911,74 +2280,26 @@ class EngineCore:
                 # allocation refusal consumed just below
                 fi.begin_step(self.step_seq)
             with self.tracer.span("engine_step", cat="serving") as sp:
-                if fi is not None and fi.pool_exhausted:
-                    self.kv.refuse_allocations = True
-                try:
+                plan = None
+                flying = self._inflight
+                if flying is not None:
                     with phase("sched.plan", prof):
-                        plan = self.scheduler.schedule()
-                finally:
-                    # refusal applies to PLANNING only: the launches
-                    # below must still allocate the chunks the (starved)
-                    # plan actually contains
-                    self.kv.refuse_allocations = False
-                with phase("engine.admit", prof):
-                    self.metrics.count("engine_steps")
-                    self.metrics.count("preemptions", len(plan.preempted))
-                    for req in plan.preempted:
-                        self.tracer.instant(
-                            "preemption", cat="serving",
-                            request=str(req.request_id), trace=req.trace_id,
-                            generated=len(req.output_tokens))
-                        self._lc(req.request_id, _lc.EV_PREEMPTED,
-                                 generated=len(req.output_tokens))
-                    for req in plan.aborted:
-                        # unservable at admission: scheduler set state/reason,
-                        # the engine owns finish bookkeeping (timestamp +
-                        # counter)
-                        self._lc(req.request_id, _lc.EV_ADMISSION_REJECTED,
-                                 reason="abort", error=req.error)
-                        self._finish(req, FinishReason.ABORT)
-                        self.requests.pop(req.request_id, None)
-                    for req in plan.admitted:
-                        cached = req.num_cached_tokens
-                        total = len(req.prompt_ids) + len(req.output_tokens)
-                        self.metrics.count("prefix_cache_hit_tokens", cached)
-                        self.metrics.count("prefix_cache_miss_tokens",
-                                           total - cached)
-                        if req.prompt_cached_tokens is None:
-                            # FIRST admission (output empty, so cached <=
-                            # prompt): the client-facing usage attribution
-                            req.prompt_cached_tokens = cached
-                        # per-request attribution (ISSUE 13): accumulated at
-                        # the SAME points as the counters above, so
-                        # sum(per-request cached) == prefix_cache_hit_tokens
-                        # exactly (asserted in tests and bench)
-                        self.cachestat.record_admission(
-                            req.request_id, cached, total - cached,
-                            len(req.prompt_ids),
-                            recompute=bool(req.output_tokens))
-                        self._lc(req.request_id, _lc.EV_ADMITTED,
-                                 cached_tokens=cached,
-                                 computed_tokens=total - cached,
-                                 recompute=bool(req.output_tokens))
-                        if cached:
-                            self.tracer.instant(
-                                "prefix_cache_hit", cat="serving",
-                                request=str(req.request_id),
-                                trace=req.trace_id, cached_tokens=cached)
-                        if cached and self.cachestat.enabled:
-                            # prefix-heat (ISSUE 13): keyed by the DEEPEST
-                            # matched block's chain hash — it commits to the
-                            # whole cached prefix.  Guarded: the table copy
-                            # + hash lookup must cost nothing when the
-                            # tracker is disabled.
-                            depth = cached // self.block_size
-                            table = self.kv.table(req.request_id)
-                            self.cachestat.record_prefix_hit(
-                                self.kv.block_chain_hash(table[depth - 1])
-                                if 0 < depth <= len(table) else None,
-                                depth, cached, self.step_seq)
-                emitted: Dict[object, int] = {}
+                        plan, why = self.scheduler.plan_ahead(flying.reqs)
+                    if plan is None:
+                        emitted.update(self.settle(why))
+                        flying = None
+                if plan is None:
+                    if fi is not None and fi.pool_exhausted:
+                        self.kv.refuse_allocations = True
+                    try:
+                        with phase("sched.plan", prof):
+                            plan = self.scheduler.schedule()
+                    finally:
+                        # refusal applies to PLANNING only: the launches
+                        # below must still allocate the chunks the
+                        # (starved) plan actually contains
+                        self.kv.refuse_allocations = False
+                self._admit(plan)
                 decodes = [r for r in plan.decodes
                            if r.state is RequestState.RUNNING]
                 # device-resident decode burst (ISSUE 19): a decode-only
@@ -1993,16 +2314,15 @@ class EngineCore:
                     burst_n = clamp_burst(self._burst_steps, decodes,
                                           plan.burst_capacity)
                 if burst_n >= 2:
-                    emitted = self._burst_exec(decodes, burst_n)
+                    emitted.update(self._burst_exec(decodes, burst_n))
                 elif self._unified:
                     # unified ragged step (ISSUE 11): the whole plan —
                     # decode rows + prefill chunks — is ONE packed launch
                     # (draft tokens compete for the leftover budget,
                     # ISSUE 18)
                     if plan.prefills or decodes:
-                        emitted = self._unified_exec(plan.prefills,
-                                                     decodes,
-                                                     plan.draft_budget)
+                        emitted.update(self._unified_exec(
+                            plan.prefills, decodes, plan.draft_budget))
                 else:
                     for req in plan.prefills:
                         before = len(req.output_tokens)
@@ -2010,12 +2330,14 @@ class EngineCore:
                         if len(req.output_tokens) > before:  # done —
                             # a partial chunk emits nothing yet
                             emitted[req.request_id] = req.output_tokens[-1]
-                    if decodes:
+                    if decodes and not ahead:
                         emitted.update(self._decode(decodes))
-                with phase("engine.emit", prof):
-                    for req in list(self.scheduler.running):
-                        if req.finished:
-                            self._retire(req)
+                    elif decodes:
+                        emitted.update(self._decode_ahead(decodes, flying))
+                if ahead and decodes and not self._flies:
+                    self._count_settle("family")
+                self._retire_finished()
+                self._let_go_if_ended()
                 # the end-of-step trackers, one phase from here to the
                 # end of ``stepprof.end_step`` in the ``finally`` below
                 trackers = phase("engine.trackers", prof)
@@ -2054,6 +2376,43 @@ class EngineCore:
             if trackers is not None:
                 trackers.__exit__(None, None, None)
             remove_timer()
+
+    def _decode_ahead(self, reqs: List[Request],
+                      flying: Optional["_DecodeLaunch"]
+                      ) -> Dict[object, int]:
+        """The serving loop's decode launch.  With ``flying`` -- the
+        launch in flight, whose rows ``reqs`` continue -- this one goes
+        out first and ``flying`` is read while it runs.  The new launch
+        stays in flight for the next step to read, unless this engine or
+        this step cannot have one (a family with no such path, a step the
+        audit samples): then it is read at once.  Returns what was
+        emitted."""
+        launch = self._build_decode(reqs, prev=flying)
+        with self._decode_span(launch):
+            launch.flight = self._dispatch(
+                "decode", launch.bucket, self._jit_decode, launch.args,
+                rows=launch.rows, ahead=flying is not None)
+        emitted: Dict[object, int] = {}
+        if flying is not None:
+            self._ahead_counters["launches"].inc()
+            self._inflight = None
+            emitted = self._emit_decode(flying,
+                                        *self._collect(flying.flight))
+        if launch.flight.toks.committed:
+            # weights placed with an explicit device make every output
+            # committed to it, and an ids array made from committed tokens
+            # would re-lower each decode program once (a host array and an
+            # uncommitted one lower alike): such an engine reads at once
+            self._flies = False
+        if self._flies and not self.audit.sampled:
+            if not self.audit.enabled:
+                # nothing reads the [rows, vocab] float32 output again
+                launch.flight.logits = None
+            self._inflight = launch
+        else:
+            emitted.update(self._emit_decode(
+                launch, *self._collect(launch.flight)))
+        return emitted
 
     def run(self, max_steps: Optional[int] = None) -> None:
         """Drive ``step()`` until every request finishes."""
@@ -2150,6 +2509,7 @@ class EngineCore:
         :meth:`detach_request`."""
         from . import handoff
 
+        self.settle("task")
         return handoff.export_request_run(self, request_id)
 
     def export_prefix_chain(self, chain_hash, max_blocks=None):
@@ -2157,6 +2517,7 @@ class EngineCore:
         digest (hot-prefix migration); ``None`` on a broken chain."""
         from . import handoff
 
+        self.settle("task")
         return handoff.export_prefix_run(self, chain_hash,
                                          max_blocks=max_blocks)
 
@@ -2173,6 +2534,7 @@ class EngineCore:
         Returns fresh-block count, or ``None`` on capacity refusal."""
         from . import handoff
 
+        self.settle("task")
         return handoff.import_run(self, run)
 
     def detach_request(self, request_id) -> bool:
@@ -2182,6 +2544,7 @@ class EngineCore:
         freed; with the prefix cache on, the hashed prompt blocks park
         WARM in the reuse LRU — a failed migration that re-admits here
         revives them at zero recompute."""
+        self.settle("task")
         req = self.requests.pop(request_id, None)
         if req is None:
             return False

@@ -223,6 +223,15 @@ class FaultInjector:
                 plan_index=idx, plan_seed=self.plan.seed)
         return spec
 
+    def pending(self, step: int) -> bool:
+        """Whether :meth:`begin_step` would fire anything at ``step``
+        (consumes nothing): the engine reads the launch it has in flight
+        before a step that sleeps, starves its planning or dies."""
+        with self._lock:
+            return any(spec.point != "kernel_corrupt"
+                       and idx not in self._fired and step >= spec.step
+                       for idx, spec in self._specs)
+
     def begin_step(self, step: int) -> None:
         """Engine-step hook (called with the engine's step counter
         BEFORE any scheduling): fires ``slow_step`` (sleeps in place,

@@ -382,7 +382,7 @@ class EngineReplica:
         # routing eligibility the router consults
         self.unhealthy = False
         self.watchdog = None          # StepWatchdog, supervisor-armed
-        self.steps_done = 0           # completed eng.step() calls — the
+        self.steps_done = 0           # completed engine steps — the
         # stall detector's progress signal (GIL-atomic increments)
         self.stall = None             # (steps_done, t) stamped by the
         # watchdog's on-fire handler; cleared when progress resumes
@@ -392,7 +392,9 @@ class EngineReplica:
         # fleet-wide), and an owner-map eviction names its replica so a
         # stale eviction can never drop another replica's entry.
         # ``notify`` runs on this replica's engine thread after every
-        # step, with the device idle, so it must cost next to nothing:
+        # step -- with the device idle where the step read its decode
+        # launch at once, behind the launch in flight where it ran ahead
+        # -- so it must cost next to nothing:
         # the frontend posts one callback to its loop (none while the
         # last is pending) and walks the handles over there
         self._notify = lambda: notify(self)
@@ -437,6 +439,9 @@ class EngineReplica:
         return len(self.handles)
 
     def start(self) -> None:
+        # the small programs the loop's running ahead needs are compiled
+        # here, before anything is served: never inside a step
+        self.engine.warm_ahead()
         self.thread = threading.Thread(
             target=self._loop, name=f"serving-engine-{self.index}",
             daemon=True)
@@ -494,7 +499,7 @@ class EngineReplica:
     def _loop(self) -> None:
         eng = self.engine
         # the step's phases that happen on this thread OUTSIDE
-        # ``eng.step()`` (observability.tracer.STEP_PHASES): taking
+        # ``eng.step_ahead()`` (observability.tracer.STEP_PHASES): taking
         # requests in, handing tokens to the streams, waiting for work
         phase, prof = eng.tracer.phase, eng.stepprof
         try:
@@ -515,9 +520,12 @@ class EngineReplica:
                         # wedged step marks this replica unhealthy the
                         # moment the section expires
                         with wd.watch(f"engine-step-r{self.index}"):
-                            eng.step()
+                            eng.step_ahead()
                     else:
-                        eng.step()
+                        # a step of THIS loop may leave its decode launch
+                        # on the device and read it in the next one, after
+                        # the next launch went out (EngineCore.step_ahead)
+                        eng.step_ahead()
                     self.steps_done += 1
                     with phase("engine.emit", prof,
                                streams=len(self.handles)):

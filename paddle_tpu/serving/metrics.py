@@ -496,6 +496,11 @@ class StepTimer:
         self._t0 = time.perf_counter()
         return self
 
+    def start_no_earlier_than(self, t: float) -> None:
+        """Move the start up to ``t`` if it lies before it: what was timed
+        could not begin until then (a launch queued behind another)."""
+        self._t0 = max(self._t0, t)
+
     def __exit__(self, *exc):
         dt = self.dt = time.perf_counter() - self._t0
         self.metrics.observe(self.name, dt)

@@ -942,6 +942,16 @@ class WorkerEngineProxy:
                 f"worker {self.index} died during kv detach: {e}") from e
         return bool(reply.get("ok")) and m is not None
 
+    def warm_ahead(self) -> None:
+        """Nothing to compile on this side: see :meth:`step_ahead`."""
+
+    def step_ahead(self) -> Dict:
+        """What the stock replica loop calls.  A worker steps
+        synchronously (``serving/worker.py`` ``handle_step``: one
+        ``EngineCore.step()`` a ``step`` frame, its reply carries that
+        step's tokens), so nothing is in flight between two frames."""
+        return self.step()
+
     def step(self) -> Dict:
         """One worker engine step, one wire round-trip: the ``step_done``
         frame carries the step's full emission batch (``emitted``:

@@ -23,7 +23,7 @@ already emits and **acts** on them —
   one.  ``GET /v1/debug/audit`` returns to ``ok`` because the degraded
   auditor is gone with the engine it judged.
 * **watchdog stall** → the per-replica :class:`~paddle_tpu.distributed
-  .StepWatchdog` (armed around every ``eng.step()``) marks the replica
+  .StepWatchdog` (armed around every engine step of the loop) marks the replica
   **unhealthy on fire** — excluded from routing immediately, not only
   when the thread eventually dies — and the supervisor escalates to a
   full restart after ``watchdog_grace_s`` if the step counter still has

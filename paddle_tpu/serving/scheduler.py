@@ -24,6 +24,14 @@ Attention shape, PAPERS.md): every engine step the scheduler
    re-enqueued at the FRONT of the waiting queue for prefill-recompute.
    Exhaustion is a scheduling event, not an error.
 
+The serving loop plans one step ahead of the device: while a decode launch
+is in flight, :meth:`ContinuousBatchingScheduler.plan_ahead` plans the next
+step on the assumption that every row of it yields one token, and only if
+that step is a pure continuation (steps 1 and 2 above would do nothing, step
+3 would preempt nobody); otherwise it names the rule that stood in the way
+and changes nothing, and :meth:`~ContinuousBatchingScheduler.schedule` plans
+the step once the launch has been read.
+
 Invariants (tested by ``tests/test_serving_engine.py``):
 
 * slot reservation is all-or-nothing per request — a preemption pass never
@@ -233,11 +241,9 @@ class ContinuousBatchingScheduler:
         avail = max(0, self.kv.num_available - promised)
         return min(want, free_slots + avail * self.kv.block_size)
 
-    def _plan_prefills(self, out: SchedulerOutput) -> None:
-        """Plan this step's prefill work under the chunk token budget:
-        first continue partial prefills (most-important first — finishing
-        an in-flight prompt beats admitting a new one), then admit from
-        the waiting queue."""
+    def _prefill_budget(self, decode_rows: int):
+        """Tokens this step's prefill work may take, after ``decode_rows``
+        decode rows claimed theirs."""
         budget = self.config.max_prefill_tokens_per_step
         remaining = float("inf") if budget is None else int(budget)
         total = self.config.max_tokens_per_step
@@ -249,8 +255,64 @@ class ContinuousBatchingScheduler:
             # rows themselves are never split across steps, so the
             # packed token count is bounded by max(total, num decode
             # rows), not by total alone.
-            remaining = min(remaining,
-                            max(0, int(total) - len(out.decodes)))
+            remaining = min(remaining, max(0, int(total) - decode_rows))
+        return remaining
+
+    def _admission(self, req: Request, available: int, slot_free: bool):
+        """What admission does with ``req``, the head of ``waiting``, when
+        ``available`` blocks are not yet pledged and ``slot_free`` says a
+        new sequence can take its first block
+        (``kv.can_start_sequence``): ``("abort", error)`` for
+        a request no pool or program of this engine can ever take,
+        ``("wait", None)`` while the pool (or the state slots) cannot cover
+        it without preempting, else ``("admit", (hit, need))``.  It frees,
+        forks and queues nothing — the ONE copy of the admission math, for
+        the planning pass that admits and for the plan made while a launch
+        is in flight, which may only say that it would."""
+        ids = req.prompt_ids + req.output_tokens
+        prompt_blocks = self.kv.blocks_for(len(ids))
+        target_len = len(req.prompt_ids) + req.sampling.max_new_tokens
+        if self.seq_len_cap is not None and target_len > self.seq_len_cap:
+            # a sequence no program of this engine can take (outside the
+            # AOT artifact's saved buckets, or a block table the ragged
+            # kernel cannot prefetch)
+            return "abort", (
+                f"request targets {target_len} tokens (prompt "
+                f"{len(req.prompt_ids)} + max_new_tokens "
+                f"{req.sampling.max_new_tokens}) but "
+                f"{self.seq_len_cap_why}")
+        if prompt_blocks > self._usable_blocks():
+            # can never fit, even with the whole pool
+            return "abort", (f"request needs {prompt_blocks} KV blocks; "
+                             f"pool has {self._usable_blocks()} usable")
+        # admit on the UNCACHED tail, not the whole prompt: blocks
+        # already in the prefix cache cost nothing new (live shares)
+        # or only their reuse-LRU slot (``from_reuse`` — those leave
+        # the available set when forked, so they are charged).  This
+        # is what makes a warm cache raise admission capacity.
+        if req._probe_epoch != self.kv.cache_epoch:
+            # leading-block hashes the fleet router already computed
+            # (req.prefix_hashes) are reused, not re-hashed
+            req._probe_blocks = self.kv.match_prefix(
+                ids, precomputed=req.prefix_hashes)
+            req._probe_epoch = self.kv.cache_epoch
+        hit = req._probe_blocks
+        from_reuse = self.kv.reuse_count(hit)
+        uncached = prompt_blocks - len(hit)
+        # +1 decode-slot headroom, but never demand more than the pool
+        # HAS: a prompt filling the pool exactly is still servable when
+        # its decode tokens fit the last block's free slots
+        need = min(uncached + 1, self._usable_blocks())
+        if need + from_reuse > available or not slot_free:
+            return "wait", None
+        return "admit", (hit, need)
+
+    def _plan_prefills(self, out: SchedulerOutput) -> None:
+        """Plan this step's prefill work under the chunk token budget:
+        first continue partial prefills (most-important first — finishing
+        an in-flight prompt beats admitting a new one), then admit from
+        the waiting queue."""
+        remaining = self._prefill_budget(len(out.decodes))
         promised = 0  # blocks pledged to prefills planned THIS pass: the
                       # engine allocates them only when it runs the chunk,
                       # so kv.num_available alone would double-count
@@ -277,58 +339,24 @@ class ContinuousBatchingScheduler:
                and admitted < self.config.max_prefills_per_step
                and remaining > 0):
             req = self.waiting[0]
-            ids = req.prompt_ids + req.output_tokens
-            prompt_blocks = self.kv.blocks_for(len(ids))
-            target_len = len(req.prompt_ids) + req.sampling.max_new_tokens
-            if self.seq_len_cap is not None \
-                    and target_len > self.seq_len_cap:
-                # a sequence no program of this engine can take (outside
-                # the AOT artifact's saved buckets, or a block table the
-                # ragged kernel cannot prefetch): fail it honestly AT
-                # ADMISSION instead of raising from the engine thread
-                # mid-stream
+            verdict, detail = self._admission(
+                req, self.kv.num_available - promised,
+                self.kv.can_start_sequence())
+            if verdict == "abort":
+                # fail THIS request honestly AT ADMISSION rather than
+                # raising from the engine thread mid-stream or live-locking
+                # everyone behind it
                 self.waiting.popleft()
                 req.state = RequestState.FINISHED
                 req.finish_reason = FinishReason.ABORT
-                req.error = (
-                    f"request targets {target_len} tokens (prompt "
-                    f"{len(req.prompt_ids)} + max_new_tokens "
-                    f"{req.sampling.max_new_tokens}) but "
-                    f"{self.seq_len_cap_why}")
+                req.error = detail
                 out.aborted.append(req)
                 continue
-            if prompt_blocks > self._usable_blocks():
-                # can never fit, even with the whole pool: fail THIS request
-                # honestly rather than live-locking everyone behind it
-                self.waiting.popleft()
-                req.state = RequestState.FINISHED
-                req.finish_reason = FinishReason.ABORT
-                req.error = (f"request needs {prompt_blocks} KV blocks; "
-                             f"pool has {self._usable_blocks()} usable")
-                out.aborted.append(req)
-                continue
-            # admit on the UNCACHED tail, not the whole prompt: blocks
-            # already in the prefix cache cost nothing new (live shares)
-            # or only their reuse-LRU slot (``from_reuse`` — those leave
-            # the available set when forked, so they are charged).  This
-            # is what makes a warm cache raise admission capacity.
-            if req._probe_epoch != self.kv.cache_epoch:
-                # leading-block hashes the fleet router already computed
-                # (req.prefix_hashes) are reused, not re-hashed
-                req._probe_blocks = self.kv.match_prefix(
-                    ids, precomputed=req.prefix_hashes)
-                req._probe_epoch = self.kv.cache_epoch
-            hit = req._probe_blocks
-            from_reuse = self.kv.reuse_count(hit)
-            uncached = prompt_blocks - len(hit)
-            # +1 decode-slot headroom, but never demand more than the pool
-            # HAS: a prompt filling the pool exactly is still servable when
-            # its decode tokens fit the last block's free slots
-            need = min(uncached + 1, self._usable_blocks())
-            if need + from_reuse > self.kv.num_available - promised \
-                    or not self.kv.can_start_sequence():
+            if verdict == "wait":
                 break  # admission never preempts running work; a model
                        # with per-sequence state also waits for a free slot
+            ids = req.prompt_ids + req.output_tokens
+            hit, need = detail
             self.waiting.popleft()
             cached = self.kv.fork_prefix(req.request_id, ids, blocks=hit)
             req.num_cached_tokens = cached
@@ -426,6 +454,62 @@ class ContinuousBatchingScheduler:
                 r._chunk_tokens or 0 for r in out.prefills)
             out.draft_budget = max(0, int(total) - used)
         return out
+
+    def plan_ahead(self, flying) -> Tuple[Optional[SchedulerOutput], str]:
+        """Plan the next step WHILE a decode launch is in flight, on the
+        assumption that every row of it (``flying``: its requests) yields
+        one token the host has not read yet.  Only a pure continuation is
+        planned: every running row that goes on gets its decode slot from
+        blocks that are free now, nothing is preempted, nothing admitted.
+        Anything else is for :meth:`schedule`, after the launch has been
+        read, and this pass says which rule stood in the way BEFORE it
+        changes anything: ``(None, reason)`` with ``reason`` one of
+        ``prefill`` (a running request still has prompt to compute),
+        ``finish`` (every row ends with the token in flight), ``preempt``
+        (the rows need more blocks than are free) and ``admit`` (the head
+        of ``waiting`` could be admitted, or is to be refused there: a
+        queue that is merely non-empty does not stand in the way).  A row
+        whose token in flight is its ``max_new_tokens``-th ends by length
+        and gets no slot; what it gives back when it retires (its blocks,
+        its place in the running set, its state slot) is counted as free
+        for the admission test, so that an admission the synchronous order
+        would make in this step is not put off by one.  A row that ends on
+        an EOS token cannot be known and keeps its row, whose result the
+        engine drops."""
+        flying = {r.request_id for r in flying}
+        rows: List[Request] = []
+        need = ending = freed = 0
+        for req in sorted(self.running, key=lambda r: r.preempt_key):
+            if self._needs_prefill(req):
+                return None, "prefill"
+            rid = req.request_id
+            if rid in flying and (len(req.output_tokens) + 1
+                                  >= req.sampling.max_new_tokens):
+                ending += 1
+                freed += self.kv.num_owned_blocks(rid)
+                continue
+            need += self.kv.blocks_needed(rid, 1)
+            rows.append(req)
+        if not rows:
+            return None, "finish"
+        if need > self.kv.num_available:
+            return None, "preempt"
+        if (self.waiting
+                and len(self.running) - ending < self.config.max_num_seqs
+                and self.config.max_prefills_per_step > 0
+                and self._prefill_budget(len(rows)) > 0
+                and self._admission(
+                    self.waiting[0], self.kv.num_available - need + freed,
+                    bool(ending) or self.kv.can_start_sequence()
+                )[0] != "wait"):
+            return None, "admit"
+        out = SchedulerOutput()
+        for req in rows:
+            req._slot = self.kv.append_slot(req.request_id)
+            out.decodes.append(req)
+        self.promised_blocks = 0
+        self.tokens_planned_decode += len(rows)
+        return out, ""
 
     @property
     def tokens_planned(self) -> int:

@@ -601,7 +601,7 @@ class TestFleetLifecycleAndFlight:
             def boom():
                 raise RuntimeError("induced crash on replica 0")
 
-            victim.engine.step = boom
+            victim.engine.step_ahead = boom
             dying = fleet.submit_request(
                 _prompt_targeting(fleet, 0),
                 SamplingParams(max_new_tokens=4), request_id="dying-1")

@@ -466,14 +466,14 @@ class TestOwningReplicaAbort:
 # --- replica death failover --------------------------------------------------
 
 def _kill_replica(fleet, index):
-    """Crash replica ``index``'s engine thread by poisoning step() and
+    """Crash replica ``index``'s engine thread by poisoning the loop's step and
     feeding it work routed to it; waits for the thread to die."""
     replica = fleet.replicas[index]
 
     def boom():
         raise RuntimeError(f"induced crash on replica {index}")
 
-    replica.engine.step = boom
+    replica.engine.step_ahead = boom
     prompt = _prompt_targeting(fleet, index)
     h = fleet.submit_request(prompt, SamplingParams(max_new_tokens=4))
     assert h.replica is replica

@@ -711,7 +711,7 @@ class TestEngineDeath:
         def boom():
             raise RuntimeError("induced engine crash")
 
-        engine.step = boom
+        engine.step_ahead = boom
         # this request crashes the engine loop; its handler must still
         # answer (finish_reason abort, empty output), not hang
         status, _, data = _request(h.port, "POST", "/v1/completions",

@@ -775,7 +775,7 @@ class TestHTTPRestarting:
                 def boom():
                     raise RuntimeError(f"induced crash on replica {idx}")
 
-                replica.engine.step = boom
+                replica.engine.step_ahead = boom
             # feed each replica work so both engines die
             for i, p in enumerate(prompts):
                 try:

@@ -145,7 +145,7 @@ class TestUnarmedStep:
             assert set(names) <= set(STEP_PHASES)
             for name, kwargs in made:
                 # integers the step already holds, nothing formatted
-                assert set(kwargs) <= {"rows", "bucket", "bytes"}
+                assert set(kwargs) <= {"rows", "bucket", "bytes", "ahead", "launch"}
                 assert all(type(v) is int for v in kwargs.values()), \
                     (name, kwargs)
             for name in PER_STEP:

@@ -5,7 +5,14 @@ Analog of the PaddleNLP/PaddleClas model zoos the reference's configs target
 framework models so the capability rungs are runnable in-repo.
 """
 
-from . import bert, gpt, llama, mamba_hybrid, moe_mla  # noqa: F401
+from . import (  # noqa: F401
+    bert,
+    gpt,
+    llama,
+    mamba_hybrid,
+    moe_mla,
+    window_moe,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForQuestionAnswering,
@@ -40,4 +47,10 @@ from .moe_mla import (  # noqa: F401
     MLAMoEDecoderLayer,
     MoEMLAConfig,
     RoutedExperts,
+)
+from .window_moe import (  # noqa: F401
+    HeldExperts,
+    ParallelWindowMoELayer,
+    WindowedAttention,
+    WindowMoEConfig,
 )

@@ -544,7 +544,10 @@ class LlamaModel(Layer):
             lambda i: LlamaDecoderLayer(config, layer_idx=i))
         self.layers = LayerList(
             [make(i) for i in range(config.num_hidden_layers)])
-        self.norm = RMSNorm(config.hidden_size, config.rms_norm_eps)
+        # and its own final norm where it has one (``make_final_norm``)
+        make_norm = getattr(config, "make_final_norm", None)
+        self.norm = make_norm() if make_norm else \
+            RMSNorm(config.hidden_size, config.rms_norm_eps)
         self._pipe: Optional[PipelineLayer] = None
         self._scan_prep = None              # lazy (roles, per_layer, specs)
 
@@ -715,8 +718,11 @@ class LlamaForCausalLM(Layer):
         with jax.named_scope("lm_head"):
             if self.lm_head is None:
                 w = self.llama.embed_tokens.weight
-                return run_op("tied_head", lambda a, wv: a @ wv.T, h, w)
-            return self.lm_head(h)
+                logits = run_op("tied_head", lambda a, wv: a @ wv.T, h, w)
+            else:
+                logits = self.lm_head(h)
+            scale = getattr(self.config, "logit_scale", 1.0)
+            return logits if scale == 1.0 else logits * scale
 
     def cache_specs(self):
         """Per decoder layer, what a cached token holds there

@@ -51,7 +51,10 @@ STEP_PHASES = (
                             # (rows= on a decode launch).  For a model
                             # with per-sequence state also state_rows=
                             # (real rows whose state the launch advances)
-                            # and state_slots_held=
+                            # and state_slots_held=; where that state is
+                            # a window's ring, a decode launch also
+                            # window_tokens= (the ring entries its rows
+                            # read: min(length, window) summed over them)
     "engine.dispatch",      # the step call, until the jit call returns
                             # (rows=, bucket=, ahead= 1 where the launch
                             # went out before the tokens of the launch
@@ -67,7 +70,10 @@ STEP_PHASES = (
                             # model with routed experts also the
                             # launch's routing load: moe_assignments=,
                             # moe_experts_touched=, moe_max_load= (summed
-                            # over expert layers), moe_decode= 1 on decode
+                            # over expert layers), moe_decode= 1 on
+                            # decode; where the model holds a SHARE of
+                            # its experts also moe_pairs_held= (pairs
+                            # routed to them) and moe_held_touched=
     "engine.emit",          # commit, emission, retire; then the stream
                             # hand-off: one callback posted to the
                             # server's loop a step (streams= the

@@ -796,17 +796,30 @@ class CacheSpec:
     pages nor rolled back a token, so ``EngineCore`` refuses, by name,
     every path that would need to (prefix cache, speculative verify,
     bursts, the ragged program, hand-off, ``mp > 1``).  A layer declares
-    pages OR slots, not both."""
+    pages OR slots, not both.
+
+    The two are two LIFETIMES of one model's layers: a layer that needs
+    every token of a sequence declares pages, which grow with it; a
+    sliding-window layer needs the last ``window`` tokens and nothing
+    older, and declares as its state a RING of them (``window`` set, two
+    ``[window, heads, dim]`` arrays: ``ops/window_attention.py``), so what
+    it holds a sequence never grows past the window.  ``window`` says what
+    the state IS to whoever counts its use (the engine's ``window_tokens``
+    on ``engine.build``); the allocation goes by ``state`` alone."""
 
     k: Optional[Tuple[int, int]] = None
     v: Optional[Tuple[int, int]] = None
     kind: str = "kv"
     state: Optional[Tuple[Tuple[Tuple[int, ...], Optional[str]], ...]] = None
+    window: Optional[int] = None
 
     def __post_init__(self):
         if self.state is not None and (self.k or self.v):
             raise ValueError("a layer declares per-token rows or "
                              "per-sequence state, not both")
+        if self.window is not None and self.state is None:
+            raise ValueError("a window's ring is per-sequence state: "
+                             "declare it under state")
         if self.state is not None and len(self.state) != 2:
             raise ValueError("per-sequence state is two (shape, dtype) "
                              "pairs, one a side of the pools")

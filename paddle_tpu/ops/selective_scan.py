@@ -53,6 +53,8 @@ class StateCache:
         self.slots = None
         self.start = None
         self.n_valid = None
+        self.use_pallas = None      # the engine's kernel routing hint, for
+                                    # a layer whose slots a kernel can read
 
     # the names the engine's step programs read the pools back by
     k_pool = property(lambda self: self.state_pool)

@@ -280,6 +280,32 @@ def sigmoid_topk_route(x, w_gate, select_bias, k: int, scale: float = 1.0,
     return ids.astype(jnp.int32), w * scale
 
 
+#: (token, expert) pairs up to which a share of the experts is computed in
+#: one pass over ALL pairs, the absent ones a tail no group owns (a decode
+#: launch: 32 rows x 8 = 256 pairs, two megabytes of rows)
+ONE_PASS_PAIRS = 1024
+#: and the most pairs one pass of the bounded path holds at a time
+MAX_PAIR_CHUNK = 16384
+
+
+def held_pair_chunk(pairs: int, n_held: int, num_experts: int) -> int:
+    """Pairs a pass of the bounded path takes: twice what ``n_held`` of
+    ``num_experts`` experts receive under uniform routing, as a power of
+    two, so one pass is the rule and a second the exception."""
+    want = max(2 * pairs * n_held // num_experts, 8)
+    return min(1 << (want - 1).bit_length(), MAX_PAIR_CHUNK)
+
+
+def _grouped_swiglu(rows, w_gate_up, w_down, sizes):
+    """``E_g(row)`` for rows sorted by group, ``sizes`` rows a group; rows
+    past the last group are whatever the grouped matmul leaves there."""
+    f = w_down.shape[1]
+    with jax.named_scope("moe_experts"):
+        h = jax.lax.ragged_dot(rows, w_gate_up, sizes)
+        h = jax.nn.silu(h[:, :f]) * h[:, f:]
+        return jax.lax.ragged_dot(h, w_down, sizes)
+
+
 def dropless_experts(x, ids, weights, w_gate_up, w_down, num_experts: int,
                      held=None):
     """``out[t] = sum_j weights[t, j] * E_{ids[t, j]}(x[t])`` over the
@@ -296,29 +322,42 @@ def dropless_experts(x, ids, weights, w_gate_up, w_down, num_experts: int,
     chip's share).  Returns ``(out [T, H], load [num_experts] int32)``,
     ``load`` the pairs each expert received, held or not.
 
+    With every expert held, and with a share of them up to
+    ``ONE_PASS_PAIRS`` pairs, all ``T k`` pairs are gathered and the absent
+    ones are a tail of the grouped matmul that no group owns.  Above that a
+    share is computed by :func:`_held_pairs_in_chunks`: the work follows the
+    pairs that ARE held (:func:`held_pair_chunk` pairs a pass), exactly,
+    whatever the routing.
+
     Scopes: ``moe_dispatch`` (sort and gather), ``moe_experts`` (the
     grouped matmuls), ``moe_combine`` (weigh and sum per token)."""
     T, k = ids.shape
     n_held = w_gate_up.shape[0]
-    f = w_down.shape[1]
+    all_held = held is None or tuple(held) == tuple(range(num_experts))
+    chunk = T * k
+    if not all_held and T * k > ONE_PASS_PAIRS:
+        chunk = held_pair_chunk(T * k, n_held, num_experts)
+    in_chunks = chunk < T * k
     with jax.named_scope("moe_dispatch"):
         flat_ids = ids.reshape(-1)
         load = jnp.bincount(flat_ids, length=num_experts).astype(jnp.int32)
-        if held is None or tuple(held) == tuple(range(num_experts)):
+        if all_held:
             local = flat_ids
         else:
             lut = np.full((num_experts,), n_held, np.int32)
             lut[np.asarray(held)] = np.arange(n_held, dtype=np.int32)
             local = jnp.asarray(lut)[flat_ids]      # n_held = not here
         order = jnp.argsort(local, stable=True)
-        token = order // k
-        rows = x[token]
+        if not in_chunks:
+            token = order // k
+            rows = x[token]
         sizes = jnp.bincount(local, length=n_held + 1)[:n_held] \
             .astype(jnp.int32)
-    with jax.named_scope("moe_experts"):
-        h = jax.lax.ragged_dot(rows, w_gate_up, sizes)
-        h = jax.nn.silu(h[:, :f]) * h[:, f:]
-        y = jax.lax.ragged_dot(h, w_down, sizes)
+    if in_chunks:
+        out = _held_pairs_in_chunks(x, weights.reshape(-1), order, sizes,
+                                    w_gate_up, w_down, k, chunk)
+        return out.astype(x.dtype), load
+    y = _grouped_swiglu(rows, w_gate_up, w_down, sizes)
     with jax.named_scope("moe_combine"):
         w = jnp.where(local < n_held, weights.reshape(-1), 0.0)[order]
         # rows past the last group (pairs of experts not held) are whatever
@@ -326,6 +365,43 @@ def dropless_experts(x, ids, weights, w_gate_up, w_down, num_experts: int,
         y = jnp.where(w[:, None] != 0, y.astype(jnp.float32) * w[:, None], 0.0)
         out = y[jnp.argsort(order)].reshape(T, k, -1).sum(axis=1)
     return out.astype(x.dtype), load
+
+
+def _held_pairs_in_chunks(x, flat_w, order, sizes, w_gate_up, w_down, k: int,
+                          chunk: int):
+    """The held pairs alone, ``chunk`` of them a pass: ``order`` lists the
+    pairs sorted by local expert id, the held ones first, so pass ``i``
+    takes ``order[i chunk : (i + 1) chunk]``, gathers THOSE rows of ``x``,
+    gives each expert the part of its group that falls in the pass, and
+    adds the weighted results to their tokens.  As many passes as the held
+    pairs need (a ``while`` in the program), none for the absent ones.
+    Returns ``[T, H]`` float32."""
+    T, H = x.shape
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    n_pairs = ends[-1]
+    pad = -order.shape[0] % chunk
+    order = jnp.pad(order, (0, pad))
+    lane = jnp.arange(chunk, dtype=jnp.int32)
+
+    def one_pass(i, out):
+        lo = i * chunk
+        with jax.named_scope("moe_dispatch"):
+            pair = jax.lax.dynamic_slice_in_dim(order, lo, chunk)
+            token = pair // k
+            rows = x[token]
+            part = (jnp.clip(ends, lo, lo + chunk)
+                    - jnp.clip(starts, lo, lo + chunk)).astype(jnp.int32)
+        y = _grouped_swiglu(rows, w_gate_up, w_down, part)
+        with jax.named_scope("moe_combine"):
+            w = jnp.where(lo + lane < n_pairs, flat_w[pair], 0.0)
+            y = jnp.where(w[:, None] != 0,
+                          y.astype(jnp.float32) * w[:, None], 0.0)
+            return out.at[token].add(y)
+
+    passes = (n_pairs + chunk - 1) // chunk
+    return jax.lax.fori_loop(0, passes, one_pass,
+                             jnp.zeros((T, H), jnp.float32))
 
 
 def global_scatter(x: Tensor, local_count, global_count, group=None) -> Tensor:

@@ -122,6 +122,21 @@ def _register_moe_metrics(registry, labels: Dict[str, str]):
     }
 
 
+def _register_held_metrics(registry, labels: Dict[str, str]):
+    """Series of a model that holds a SHARE of its routed experts."""
+    return {
+        "pairs_held": registry.counter(
+            "serving_moe_pairs_held_total",
+            help="(token, expert) pairs routed to an expert this process "
+                 "holds, over expert layers and launches (the rest are "
+                 "another chip's)", **labels),
+        "held_share": registry.gauge(
+            "serving_moe_held_pair_share",
+            help="pairs routed to experts held here over all pairs routed, "
+                 "since the start", **labels),
+    }
+
+
 _AHEAD_SETTLES_HELP = (
     "steps of the serving loop that read the launch in flight before "
     "planning instead of running ahead of it, by the rule that made them "
@@ -384,6 +399,9 @@ class EngineCore:
         self.cache_specs = list(model.cache_specs())
         sched_cfg = config.scheduler or SchedulerConfig()
         self._has_state = any(spec.state for spec in self.cache_specs)
+        # the window whose ring some layers declare as their state, if any
+        self._window = max((spec.window or 0 for spec in self.cache_specs),
+                           default=0)
         self._refuse_state_paths(config)
         # a slot a running sequence: the running set is capped at
         # max_num_seqs, so admission never waits on a slot it cannot get
@@ -555,6 +573,10 @@ class EngineCore:
         # routing-load series: made when a launch first brings a load, so a
         # model without routed experts never has them on /metrics
         self._moe_counters = None
+        # the routed experts this process holds where that is a share of
+        # them (the model's configuration says which), else None
+        held = getattr(cfg, "experts_held", None)
+        self._experts_held = None if held is None else np.asarray(held, int)
         self._params = list(model.parameters())
         # retrace counters: += 1 runs only while JAX traces the function,
         # so these count COMPILATIONS, not calls (the N31 acceptance hook)
@@ -786,14 +808,23 @@ class EngineCore:
                 "EngineCore has no path with such state for: "
                 + "; ".join(refused))
 
-    def _state_ints(self, rows: int) -> Dict[str, int]:
+    def _state_ints(self, rows: int, reqs=()) -> Dict[str, int]:
         """What ``engine.build`` carries for a model with per-sequence
         state: the real rows whose state the launch advances, and the
-        slots held; nothing for any other model."""
+        slots held; where that state is a window's ring, for the decode
+        rows ``reqs`` also the ring entries their step reads
+        (``window_tokens``: a row of length ``n`` after its token reads
+        ``min(n, window)`` of them in every window layer); nothing for any
+        other model."""
         if not self._has_state:
             return {}
-        return {"state_rows": rows,
+        ints = {"state_rows": rows,
                 "state_slots_held": self.kv.state_slots_held}
+        if self._window and reqs:
+            ints["window_tokens"] = sum(
+                min(self.kv.seq_len(r.request_id) + 1, self._window)
+                for r in reqs)
+        return ints
 
     def _layer_caches(self, k_pools, v_pools, route_pages, route_state):
         """One cache object a layer for a step program, by what the layer
@@ -803,6 +834,7 @@ class EngineCore:
         for spec, k, v in zip(self.cache_specs, k_pools, v_pools):
             if spec.state:
                 c = StateCache(Tensor(k), Tensor(v))
+                c.use_pallas = self._use_pallas
                 route_state(c)
             else:
                 c = PagedCache(Tensor(k), Tensor(v))
@@ -996,6 +1028,9 @@ class EngineCore:
         if self._moe_counters is None:
             self._moe_counters = _register_moe_metrics(
                 self.metrics.registry, self.metrics.labels)
+            if self._experts_held is not None:
+                self._moe_counters.update(_register_held_metrics(
+                    self.metrics.registry, self.metrics.labels))
         load = np.asarray(load)
         assignments = int(load.sum())
         touched = int(np.count_nonzero(load))
@@ -1005,9 +1040,20 @@ class EngineCore:
         c["touched"].inc(touched)
         if assignments:
             c["max_over_mean"].set(max_load * load.shape[1] / assignments)
-        return {"moe_assignments": assignments,
+        ints = {"moe_assignments": assignments,
                 "moe_experts_touched": touched, "moe_max_load": max_load,
                 "moe_decode": int(program == "decode")}
+        if self._experts_held is not None:
+            # a share of the experts: the pairs that are this chip's, and
+            # how many of its experts a pair reached
+            mine = load[:, self._experts_held]
+            ints["moe_pairs_held"] = int(mine.sum())
+            ints["moe_held_touched"] = int(np.count_nonzero(mine))
+            c["pairs_held"].inc(ints["moe_pairs_held"])
+            if c["assignments"].value:
+                c["held_share"].set(c["pairs_held"].value
+                                    / c["assignments"].value)
+        return ints
 
     def _mesh_jit_shardings(self, mesh, cfg) -> Dict[str, dict]:
         """Explicit in/out shardings for the three mesh-spanning jitted
@@ -1696,7 +1742,8 @@ class EngineCore:
         one further; any other row's last token is on the host."""
         phase, prof = self.tracer.phase, self.stepprof
         B = len(reqs)
-        with phase("engine.build", prof, rows=B, **self._state_ints(B)):
+        with phase("engine.build", prof, rows=B,
+                   **self._state_ints(B, reqs)):
             Bb = bucket_size(B)
             width = max(len(self.kv.table(r.request_id)) for r in reqs)
             Wb = bucket_size(width)

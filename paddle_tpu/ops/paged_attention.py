@@ -961,8 +961,9 @@ def paged_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     [num_blocks, block_size, Hkv, D]; block_tables: [B, max_blocks] int32;
     seq_lens: [B] int32.  Returns [B, H, D].
 
-    Dispatches to the Pallas kernel (``pallas_paged.py`` — scalar-prefetch
-    page DMA, no dense context copy) when shapes are TPU-tileable, to
+    Dispatches to the Pallas kernels (``pallas_paged.py`` — the pages a
+    row holds copied by its scalar-prefetched table, small pages in groups,
+    no dense context copy) when shapes are TPU-tileable, to
     the XLA gather path otherwise; a kernel failure raises.
 
     ``use_pallas`` overrides the auto dispatch (``EngineConfig.
@@ -979,11 +980,13 @@ def paged_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     global last_path
 
     B, H, D = q.shape
-    # ONE KV head (multi-query) goes down the gather path: the kernel's
-    # cost is a step a (row, page) whatever the heads on the page, so with
-    # one head it moves an eighth of a 4:1 group's bytes in the same time
-    # (20 query heads on 1 KV head at 256 rows x 2,048 tokens: kernel
-    # 17.5 ms, gather 1.6 ms; my chip run, PR 33)
+    # ONE KV head (multi-query) still goes down the gather path: the
+    # kernel's step at 16-token pages is a group of 128 tokens of a row,
+    # and with one head that is 64 KB of K and V -- the step's fixed cost,
+    # not the copy, sets its time (20 query heads on 1 KV head at 256 rows
+    # x 4,096-token tables, mean length 2,143: the group walk 3.60 ms, the
+    # gather 1.07 ms, the kernel of a step a (row, page) before it 24.2 ms;
+    # my chip run, PR 38)
     tileable = (D % 128 == 0 and k_cache.shape[1] % 8 == 0
                 and k_cache.shape[2] > 1)
 

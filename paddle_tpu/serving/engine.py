@@ -590,6 +590,10 @@ class EngineCore:
         # by any later trace (the auditor's gather reference, another
         # replica), this record is not
         self.attention_paths: Dict[str, str] = {}
+        # decode bucket -> pages of a row its paged kernel moves a step
+        # (``ops.pallas_paged.kernel_pages``), written where the program
+        # is traced; rides ``engine.dispatch`` as ``pages_per_step``
+        self._kernel_pages: Dict[tuple, int] = {}
         self.decode_buckets = set()
         self.prefill_buckets = set()
         self.ragged_buckets = set()
@@ -921,8 +925,12 @@ class EngineCore:
         launch goes out before the tokens of the one before it were read;
         it rides the phase as an integer, as does the launch's number
         (``launch``), which its ``engine.device_wait`` carries too: the
-        wait that follows a dispatch is no longer always its own.  What
-        comes back is the launch
+        wait that follows a dispatch is no longer always its own.  A
+        decode launch also carries ``pages_per_step``: the pages of a row
+        the paged decode kernel of its program moves a step over the paged
+        pool, as written where the program was traced (0: the gather path,
+        an AOT-served program, a bucket's first call).  What comes back is
+        the launch
         in flight: the device arrays, and what :meth:`_collect` needs to
         finish it, now or a step later."""
         timer, collective = _STEP_TIMERS[program]
@@ -935,7 +943,10 @@ class EngineCore:
         self._launch_seq += 1
         with self.tracer.phase("engine.dispatch", self.stepprof, rows=rows,
                                bucket=bucket[0], ahead=int(ahead),
-                               launch=self._launch_seq):
+                               launch=self._launch_seq,
+                               pages_per_step=self._kernel_pages.get(
+                                   tuple(bucket), 0)
+                               if program == "decode" else 0):
             toks, logits, stats, self._k_pools, self._v_pools = \
                 self._step_call(program, bucket, jit_fn,
                                 self._param_vals(), self._k_pools,
@@ -1164,6 +1175,14 @@ class EngineCore:
             k_pools, v_pools, pages, lambda c: c.route(tables[:, 0]))
         logits = self._call_model(ids, caches, pos, param_vals)
         self.attention_paths["decode"] = _paged_ops.last_path
+        pages = 0           # the gather path moves no pages a step
+        if _paged_ops.last_path == "pallas":
+            from ..ops.pallas_paged import kernel_pages
+            pages = kernel_pages(
+                next(p for p, spec in zip(k_pools, self.cache_specs)
+                     if spec.k is not None and not spec.state),
+                tables.shape[1])
+        self._kernel_pages[tuple(tables.shape)] = pages
         last = logits[:, -1, :].astype(jnp.float32)
         # in-trace sampling epilogue (ISSUE 18): greedy rows (temp 0,
         # padding included) reduce to argmax inside the same program —

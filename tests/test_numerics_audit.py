@@ -188,6 +188,176 @@ class TestKernelOracleParity:
             jnp.asarray(tables), jnp.asarray(lens)))
         np.testing.assert_allclose(out_k, out_o, atol=2e-5, rtol=2e-5)
 
+    @pytest.mark.parametrize("bs,rep,Hkv,dtype", [
+        (16, 1, 2, "float32"), (16, 4, 2, "float32"), (16, 16, 2, "float32"),
+        # the rings' pages: the kernel of a step a (row, page), as before
+        (256, 1, 2, "float32"), (256, 16, 2, "float32"),
+        (256, 16, 8, "bfloat16"),
+        # up to HEADS_UNROLLED heads in one iteration, every index static:
+        # float32 heads, PAIRS of bfloat16 heads in a 32-bit word, a count
+        # that is no multiple of 8, and the one head that is no word
+        (16, 2, 8, "float32"), (16, 4, 8, "bfloat16"), (16, 1, 12, "float32"),
+        (16, 4, 1, "bfloat16"),
+        # more heads: a LOOP of HEADS_UNROLLED an iteration, both kinds
+        (16, 2, 16, "float32"), (16, 1, 32, "bfloat16"),
+        (16, 1, 32, "float32"),
+        # a pool the group walk cannot take keeps the other kernel
+        (16, 2, 3, "bfloat16"),
+    ], ids=lambda v: str(v))
+    def test_decode_group_walk(self, bs, rep, Hkv, dtype):
+        """Small pages are walked in groups of ``P`` and the walk stops at
+        the row's length: lengths one under, at and one over a whole
+        number of groups, 1 and 0 (bucket padding), one longer than its
+        table (read as the whole table), a table width that is no multiple
+        of ``P``, and NOTHING past a row's last page read into the result.
+        Pages of 16 tokens get tables whose padding names a block of NaN;
+        pages of 256 tokens (``P`` = 1, the kernel of a step a (row,
+        page)) get the ring's tables (``ops.window_attention
+        .ring_decode_attention``), whose padding repeats the row's last
+        page."""
+        import jax
+        import jax.numpy as jnp
+
+        D = 16
+        ring = bs == 256
+        W = 5 if ring else 19
+        P = pallas_paged.pages_per_step(
+            bs, Hkv, D, jnp.dtype(dtype).itemsize, W)
+        assert P == (1 if ring else 8) and (ring or W % P)
+        groups = pallas_paged.kernel_pages(jax.ShapeDtypeStruct(
+            (1, bs, Hkv, D), jnp.dtype(dtype)), W) > 1
+        step = P * bs
+        lens = np.array([n for k in (1, 2) for n in
+                         (k * step - 1, k * step, k * step + 1)]
+                        + [1, 0, W * bs + 3], np.int32)
+        B = len(lens)
+        owned = np.minimum(-(-lens // bs), W)   # the last: a full table
+        rng = np.random.default_rng(bs + rep)
+        num_blocks = B * W + 2 if ring else int(owned.sum()) + 2
+        k = rng.standard_normal((num_blocks, bs, Hkv, D)).astype(np.float32)
+        v = rng.standard_normal((num_blocks, bs, Hkv, D)).astype(np.float32)
+        q = rng.standard_normal((B, Hkv * rep, D)).astype(np.float32)
+        if ring:    # slot b's pages in order, clamped to its last live one
+            last = (np.maximum(lens, 1) - 1) // bs
+            tables = clean = (np.arange(B)[:, None] * W + np.minimum(
+                np.arange(W)[None, :], last[:, None])).astype(np.int32)
+        else:
+            # the kernel of a step a (row, page) COPIES a padded entry's
+            # page (and computes nothing on it): its padding names block 0
+            poison = num_blocks - 1 if groups else 0
+            k[num_blocks - 1] = v[num_blocks - 1] = np.nan
+            k[0] = v[0] = 0.0
+            tables = np.full((B, W), poison, np.int32)
+            clean = np.zeros((B, W), np.int32)
+            blocks = iter(rng.permutation(np.arange(1, num_blocks - 1)))
+            for i in range(B):
+                tables[i, :owned[i]] = clean[i, :owned[i]] = [
+                    next(blocks) for _ in range(owned[i])]
+        q, k, v = (jnp.asarray(a, dtype) for a in (q, k, v))
+        out_k = np.asarray(pallas_paged.paged_attention_decode(
+            q, k, v, jnp.asarray(tables), jnp.asarray(lens)), np.float32)
+        out_o = np.asarray(pallas_paged.decode_oracle(
+            q, k, v, jnp.asarray(clean), jnp.asarray(lens)), np.float32)
+        assert np.isfinite(out_k).all()
+        live = lens > 0
+        tol = 2e-5 if dtype == "float32" else 2e-2   # the output's rounding
+        np.testing.assert_allclose(out_k[live], out_o[live],
+                                   atol=tol, rtol=tol)
+        assert not out_k[~live].any()       # bucket padding: zeros
+
+    @pytest.mark.parametrize("shape,pages", [
+        # block size, KV heads, head size, bytes an element, table width
+        ((16, 8, 128, 2, 256), 8),      # mistral-7b-v0.3
+        ((16, 32, 128, 2, 64), 8),      # deepseek-llm-7b
+        ((16, 8, 128, 2, 512), 8),      # command-a-plus, the global layer
+        ((256, 8, 128, 2, 16), 1),      # command-a-plus, a ring
+    ], ids=["mistral", "deepseek", "command-a-global", "command-a-ring"])
+    def test_pages_per_step(self, shape, pages):
+        bs, hkv, d, itemsize, width = shape
+        assert pallas_paged.pages_per_step(*shape) == pages
+        # K and V, two buffers each, of ``pages`` pages
+        assert 4 * pages * bs * hkv * d * itemsize \
+            <= pallas_paged.PAGE_BUFFER_BYTES
+        assert pages == 1 or pages * bs == pallas_paged.STEP_TOKENS
+        assert pallas_paged.pages_per_step(bs, hkv, d, itemsize, 3) <= 3
+        # pages too large for the budget: fewer a step, one at least
+        assert pallas_paged.pages_per_step(16, 8, 128, 2, 512) > \
+            pallas_paged.pages_per_step(16, 2048, 128, 2, 512) >= 1
+
+    @pytest.mark.parametrize("bs,width,grid", [
+        (16, 24, "(B,)"), (256, 16, "(B, n_pages)")],
+        ids=["pages-of-16", "pages-of-256"])
+    def test_the_pools_shape_chooses_the_kernel(self, bs, width, grid):
+        """Read off the traced program, not off a flag: pages of 16 tokens
+        launch the group walk, one grid step a row, the pools left in HBM
+        (``ANY``); pages of 256 the kernel of a step a (row, page)."""
+        import jax
+        import jax.numpy as jnp
+
+        B, H, Hkv, D = 3, 8, 2, 16
+        pool = jax.ShapeDtypeStruct((40, bs, Hkv, D), jnp.float32)
+        jaxpr = jax.make_jaxpr(pallas_paged.paged_attention_decode)(
+            jax.ShapeDtypeStruct((B, H, D), jnp.float32), pool, pool,
+            jax.ShapeDtypeStruct((B, width), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.int32))
+        calls = []
+
+        def walk(j):
+            for eqn in j.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    calls.append(eqn.params["grid_mapping"])
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jaxpr.jaxpr)
+        assert len(calls) == 1
+        assert tuple(calls[0].grid) == \
+            ((pallas_paged.ROWS_MIN,) if grid == "(B,)" else (B, width))
+
+    def test_the_group_walk_is_traced_once_a_row_bucket(self, monkeypatch):
+        """The walk reads nothing of a table's width, so tables narrower
+        than ``TABLE_WIDTH`` go in at that width: programs that differ in
+        their table width alone share ONE trace of the kernel (a warm start
+        traces it once a row bucket, not once a program); the kernel of a
+        step a (row, page), whose grid IS the width, does not."""
+        import jax
+        import jax.numpy as jnp
+
+        traced = []
+        for name in ("_group_walk_kernel", "_decode_kernel"):
+            def counting(*a, _kernel=getattr(pallas_paged, name), **kw):
+                traced.append(_kernel.__name__)
+                return _kernel(*a, **kw)
+            monkeypatch.setattr(pallas_paged, name, counting)
+
+        def traces(bs, widths):
+            B, H, Hkv, D = 3, 8, 2, 16
+            pool = jax.ShapeDtypeStruct((40, bs, Hkv, D), jnp.float32)
+            del traced[:]
+            for width in widths:
+                jax.make_jaxpr(pallas_paged.paged_attention_decode)(
+                    jax.ShapeDtypeStruct((B, H, D), jnp.float32), pool, pool,
+                    jax.ShapeDtypeStruct((B, width), jnp.int32),
+                    jax.ShapeDtypeStruct((B,), jnp.int32))
+            return list(traced)
+
+        jax.clear_caches()
+        assert traces(16, (24, 40, 56)) == ["_group_walk_kernel"]
+        # and fewer than ROWS_MIN rows (B is 3 above) with it
+        assert pallas_paged.ROWS_MIN == 8
+        pool = jax.ShapeDtypeStruct((40, 16, 2, 16), jnp.float32)
+        del traced[:]
+        for rows in (1, 8, 16):
+            jax.make_jaxpr(pallas_paged.paged_attention_decode)(
+                jax.ShapeDtypeStruct((rows, 8, 16), jnp.float32), pool, pool,
+                jax.ShapeDtypeStruct((rows, 24), jnp.int32),
+                jax.ShapeDtypeStruct((rows,), jnp.int32))
+        assert traced == ["_group_walk_kernel"]     # 16 rows: its own
+        assert traces(256, (4, 8)) == ["_decode_kernel"] * 2
+        # wider than the shared width: its own
+        wide = pallas_paged.TABLE_WIDTH
+        assert traces(16, (wide + 8, wide + 16)) == ["_group_walk_kernel"] * 2
+
 
 # --------------------------------------------------------------------------
 # engine integration: clean audits

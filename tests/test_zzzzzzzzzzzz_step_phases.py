@@ -145,7 +145,8 @@ class TestUnarmedStep:
             assert set(names) <= set(STEP_PHASES)
             for name, kwargs in made:
                 # integers the step already holds, nothing formatted
-                assert set(kwargs) <= {"rows", "bucket", "bytes", "ahead", "launch"}
+                assert set(kwargs) <= {"rows", "bucket", "bytes", "ahead",
+                                       "launch", "pages_per_step"}
                 assert all(type(v) is int for v in kwargs.values()), \
                     (name, kwargs)
             for name in PER_STEP:
@@ -161,6 +162,34 @@ class TestUnarmedStep:
                    if n == "engine.fetch"]
         assert fetches and fetches == copied
         assert max(fetches) < 4 * eng.model.config.vocab_size
+
+    @pytest.mark.parametrize("use_pallas", [True, False])
+    def test_a_decode_dispatch_carries_the_kernels_pages_per_step(
+            self, use_pallas, recorded):
+        """What the paged decode kernel of a decode launch's program moves
+        a step rides ``engine.dispatch``: what ``kernel_pages`` gives for
+        the pool and the launch's table width, written where the program
+        was traced (so a bucket's first call still says 0); 0 on the
+        gather path and on every launch that is no decode."""
+        from paddle_tpu.ops.pallas_paged import kernel_pages
+
+        eng = _engine("decode", use_pallas_paged=use_pallas)
+        _submit(eng, _prompts())
+        eng.run(max_steps=200)
+        assert eng.attention_paths["decode"] == \
+            ("pallas" if use_pallas else "xla")
+        pool = eng._k_pools[0]
+        # what the kernel moves a step at each table width a launch had
+        widths = {width for _, _, width in eng.decode_buckets}
+        pages = {kernel_pages(pool, width) if use_pallas else 0
+                 for width in widths}
+        assert widths and set(eng._kernel_pages.values()) == pages
+        carried = {kw["pages_per_step"] for n, kw in recorded
+                   if n == "engine.dispatch"}
+        assert 0 in carried        # the prefill launches
+        # a bucket's later calls carry what its trace wrote
+        assert carried <= {0} | pages
+        assert (max(carried) > 1) == use_pallas
 
     @pytest.mark.parametrize("family", sorted(FAMILIES))
     def test_fetch_bytes_with_the_audit_on(self, family, recorded):

@@ -438,14 +438,28 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("name,blocks,page,width", [
-    ("a ring as pages of 256 tokens", 33 * 16, 256, 16),
-    ("the global layer's pages at 8,192 tokens", 16896, 16, 512),
+@pytest.mark.parametrize("name,rows,heads,kv_heads,blocks,page,width,dtype", [
+    ("a ring as pages of 256 tokens", 32, 128, 8, 33 * 16, 256, 16, "bfloat16"),
+    ("the global layer's pages at 8,192 tokens", 32, 128, 8, 16896, 16, 512,
+     "bfloat16"),
+    ("mistral-7b-v0.3 at 4,096 tokens", 32, 32, 8, 4800, 16, 256, "bfloat16"),
+    ("deepseek-llm-7b at 1,024 tokens", 64, 32, 32, 1280, 16, 64, "bfloat16"),
+    ("one key/value head under 20 query heads", 256, 20, 1, 8192, 16, 256,
+     "bfloat16"),
+    ("float32 pools: 8 heads at once", 8, 32, 8, 512, 16, 64, "float32"),
+    ("float32 pools: 16 heads in a loop of 8", 8, 32, 16, 512, 16, 64,
+     "float32"),
+    ("a shard's 2 heads of 8", 8, 8, 2, 512, 16, 64, "bfloat16"),
+    ("3 heads: not the group walk's", 8, 12, 3, 512, 16, 64, "bfloat16"),
 ])
-def test_paged_decode_kernel_compiles_at_16_query_heads_a_kv_head(
-        one_chip, monkeypatch, name, blocks, page, width):
-    """The TPU compiler takes the kernel at the cell's shapes: 32 rows,
-    128 query heads on 8 key/value heads of 128, bf16."""
+def test_paged_decode_kernel_compiles_at_the_cells_shapes(
+        one_chip, monkeypatch, name, rows, heads, kv_heads, blocks, page,
+        width, dtype):
+    """The TPU compiler takes the decode kernel at every cell's shapes
+    (heads of 128): the kernel of a step a (row, page) at the rings' pages,
+    and at 16-token pages the group walk — the groups of pages it copies
+    into VMEM, the strided read of a pair of heads' keys out of them, the
+    loop over more than 8 heads, and the one-head pool it squeezes."""
     from paddle_tpu.ops import pallas_paged
 
     monkeypatch.setattr(pallas_paged, "_interpret", lambda: False)
@@ -454,10 +468,10 @@ def test_paged_decode_kernel_compiles_at_16_query_heads_a_kv_head(
         def s(shape, dtype):
             return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-        pool = s((blocks, page, 8, 128), jnp.bfloat16)
+        pool = s((blocks, page, kv_heads, 128), jnp.dtype(dtype))
         compiled = jax.jit(pallas_paged.paged_attention_decode).lower(
-            s((32, 128, 128), jnp.bfloat16), pool, pool,
-            s((32, width), jnp.int32), s((32,), jnp.int32)).compile()
+            s((rows, heads, 128), jnp.bfloat16), pool, pool,
+            s((rows, width), jnp.int32), s((rows,), jnp.int32)).compile()
         assert "tpu_custom_call" in compiled.as_text(), name
     finally:
         jax.config.update("jax_enable_compilation_cache", True)

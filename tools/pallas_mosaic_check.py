@@ -7,11 +7,13 @@ real backend at the shapes the Llama-3-8B serving path launches, compares
 each against its XLA reference, and records the compiler's own words where
 it refuses.  Times are host-clock information, not a benchmark.
 
-    chiprun -- python tools/pallas_mosaic_check.py
+    chiprun -- python tools/pallas_mosaic_check.py [--beside FILE] [check ...]
 
 One JSON line per check on stdout; the table lands in
 ``chiprun_out/PALLAS_VERDICT.json`` (copy it over the committed record).
-Exits non-zero without a TPU, and when any check fails.
+``--beside FILE`` names the table another commit's run of this tool wrote
+in the same call: each check then carries that run's milliseconds too, as
+``beside_ms``.  Exits non-zero without a TPU, and when any check fails.
 """
 
 from __future__ import annotations
@@ -95,16 +97,26 @@ def flash_check(causal, hkv=8, d=D, grad=False):
 
 # --- paged decode (one token per row over the block pool) -------------------
 
-def paged_decode_check(h, hkv, pool_dtype=jnp.bfloat16, batch=8, width=64):
+def paged_decode_check(h, hkv, pool_dtype=jnp.bfloat16, batch=8, width=64,
+                       block=BS, ring=False):
+    """``ring``: the pool is ``batch`` rings of ``width`` pages, a row's
+    table its own pages in order, clamped to the last one it has written
+    (``ops.window_attention.ring_decode_attention``)."""
     def run():
         from paddle_tpu.ops import pallas_paged
 
         rng = np.random.default_rng(1)
-        kc = _rand(rng, (NB, BS, hkv, D), pool_dtype)
-        vc = _rand(rng, (NB, BS, hkv, D), pool_dtype)
+        nb = batch * width if ring else NB
+        kc = _rand(rng, (nb, block, hkv, D), pool_dtype)
+        vc = _rand(rng, (nb, block, hkv, D), pool_dtype)
         q = _rand(rng, (batch, h, D))
-        bt = jnp.asarray(rng.integers(1, NB, (batch, width)), jnp.int32)
-        sl = jnp.asarray(rng.integers(1, width * BS, (batch,)), jnp.int32)
+        sl = rng.integers(1, width * block, (batch,))
+        if ring:
+            bt = np.arange(batch)[:, None] * width + np.minimum(
+                np.arange(width)[None, :], ((sl - 1) // block)[:, None])
+        else:
+            bt = rng.integers(1, NB, (batch, width))
+        bt, sl = jnp.asarray(bt, jnp.int32), jnp.asarray(sl, jnp.int32)
         f = jax.jit(pallas_paged.paged_attention_decode)
         out = f(q, kc, vc, bt, sl)
         ref = jax.jit(pallas_paged.decode_oracle)(q, kc, vc, bt, sl)
@@ -218,6 +230,16 @@ CHECKS = [
     # EngineConfig.dtype=None: float32 pools under bf16 queries
     ("paged_decode_f32_pool_bf16_q",
      paged_decode_check(32, 8, pool_dtype=jnp.float32)),
+    # the benchmark cells' launches (BENCHMARK.json): the widest bucket of
+    # rows and the widest table each runs
+    ("paged_decode_cell_mistral_32q8kv_rows32_w256",
+     paged_decode_check(32, 8, batch=32, width=256)),
+    ("paged_decode_cell_deepseek_32q32kv_rows64_w64",
+     paged_decode_check(32, 32, batch=64, width=64)),
+    ("paged_decode_cell_command_a_global_128q8kv_rows32_w512",
+     paged_decode_check(128, 8, batch=32, width=512)),
+    ("paged_decode_cell_command_a_ring_128q8kv_rows32_page256",
+     paged_decode_check(128, 8, batch=32, width=16, block=256, ring=True)),
     ("ragged_T64_W64_32q8kv", ragged_check(64, 64)),
     ("ragged_T64_W64_8q2kv_mp4_shard", ragged_check(64, 64, h=8, hkv=2)),
     ("ragged_T256_W64_32q8kv", ragged_check(256, 64)),
@@ -247,7 +269,15 @@ def main(argv) -> int:
                             for p in ("jax", "jaxlib", "libtpu")},
                "compile_cache": cache, "checks": []}
     print(json.dumps({k: results[k] for k in ("device", "versions")}))
-    only = set(argv[1:])
+    args = argv[1:]
+    beside = {}
+    if "--beside" in args:
+        at = args.index("--beside")
+        with open(args[at + 1]) as f:
+            beside = {c["name"]: c.get("pallas_ms", c.get("xla_ms"))
+                      for c in json.load(f)["checks"]}
+        del args[at:at + 2]
+    only = set(args)
     errors = []
     for name, run in CHECKS:
         if only and name not in only:
@@ -262,6 +292,8 @@ def main(argv) -> int:
             rec = {"status": "refused",
                    "error": text if len(text) < 1600
                    else text[:600] + " … " + text[-900:]}
+        if beside.get(name) is not None:
+            rec["beside_ms"] = beside[name]
         rec = {"name": name, **rec,
                "seconds": round(time.perf_counter() - t0, 1)}
         results["checks"].append(rec)

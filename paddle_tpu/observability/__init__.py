@@ -65,6 +65,18 @@ router's tracker (offset-corrected by the NTP-style
 kill -9 post-mortems), and :class:`WireStats` attributes each step's
 wall to host vs wire vs engine — served at ``GET /v1/debug/wire``.
 
+What runs on which thread of a serving process (ISSUE 39;
+``tracer.py`` has the names).  A replica's ENGINE thread runs every phase
+of the step (``tracer.STEP_PHASES``) and ``ahead.settle``; the server's
+asyncio LOOP thread, which shares the interpreter lock with it, runs
+``server.accept`` / ``server.wake`` / ``server.write``; ``proc.gc`` is
+on whichever thread the collector ran on.  ONE monitor thread a process
+(:class:`~paddle_tpu.observability.pauses.PauseMonitor`, ``pauses.py``,
+started and stopped with the fleet) and the collector's callback make a
+pause name itself, always on: a collection, a frozen process, a stalled
+engine thread each become a ``serving_pause*`` sample, an event in the
+replica's flight ring and one ``WARNING`` line on standard error.
+
 Process-wide defaults: :func:`get_tracer` / :func:`get_registry` return
 one shared instance each, so spans from the serving engine, jit compile
 events and watchdog timeouts land in one trace, and compile counters /
@@ -128,6 +140,9 @@ from .metrics import (  # noqa: F401
     get_registry,
     set_registry,
 )
+from .pauses import (  # noqa: F401
+    PauseMonitor,
+)
 from .push import (  # noqa: F401
     PushGateway,
     start_push_gateway,
@@ -138,6 +153,9 @@ from .stepprof import (  # noqa: F401
     StepProfiler,
 )
 from .tracer import (  # noqa: F401
+    SETTLE_REASONS,
+    STEP_PHASES,
+    THREAD_SPANS,
     Span,
     SpanTracer,
     get_tracer,

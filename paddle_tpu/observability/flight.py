@@ -192,13 +192,19 @@ class FlightRecorder:
                              self.cfg.storm_window_s,
                              "preemption_storm", replica)
 
+    def note(self, replica: str, name: str, **attrs) -> None:
+        """Put one event that is no request's into ``replica``'s ring (a
+        pause the pause monitor saw, a 429 of the frontend): a bundle of
+        any trigger then holds it with the lifecycle events around it."""
+        with self._lock:
+            self._ring(replica).append(
+                {"t": round(time.perf_counter(), 6), "name": name,
+                 **attrs, "replica": replica})
+
     def note_rejection(self) -> None:
         """One HTTP 429 (the frontend calls this): feeds the
         ``rejection_burst`` trigger window."""
-        with self._lock:
-            self._ring("router").append(
-                {"t": round(time.perf_counter(), 6),
-                 "name": "admission_rejected_http", "replica": "router"})
+        self.note("router", "admission_rejected_http")
         self._window_hit("rejection_burst", self.cfg.burst_threshold,
                          self.cfg.burst_window_s, "rejection_burst", None)
 

@@ -23,6 +23,17 @@ collectives, watchdog timeouts.  Design constraints:
   beside the device planes.  That annotation is ALL a phase costs on the
   step path; a :class:`Span` is recorded too only while the step
   profiler's capture window is armed (``GET /v1/debug/profile``).
+
+What runs on which thread.  :data:`STEP_PHASES` are all on a replica's
+ENGINE thread (``fleet.EngineReplica._loop``), and so is ``ahead.settle``
+of :data:`THREAD_SPANS`.  The three ``server.*`` spans are on the
+server's asyncio LOOP thread, which shares the interpreter lock with the
+engine thread: the walk that wakes the streams, every chunk written and
+every request taken in run there while the engine thread plans, builds
+and dispatches the next launch.  ``proc.gc`` is on whichever thread the
+collector happened to run on and stops them all.  The profiler keeps one
+line a thread in its host plane, so a reader tells them apart by what a
+line holds (``benchmarks/thread_spans.py``).
 """
 
 from __future__ import annotations
@@ -80,6 +91,51 @@ STEP_PHASES = (
                             # replica's open handles), which wakes the
                             # handlers over there
     "engine.trackers",      # end-of-step trackers and stepprof.end_step
+)
+
+# why a step of the serving loop read the launch in flight before it
+# planned, instead of running ahead of it (``EngineCore.settle``).  The
+# ORDER is a contract: ``ahead.settle`` carries a reason as its index
+# here, ``serving_ahead_settles_total{reason}`` as the word.
+SETTLE_REASONS = (
+    "prefill",      # a running request still has prompt to compute
+    "admit",        # a waiting request could be admitted
+    "preempt",      # the pool cannot hold every row's next token
+    "finish",       # a row of the launch in flight ends with its token
+    "audit",        # the numerics audit samples this step
+    "fault",        # a fault is planned for this step
+    "task",         # a KV export / import / detach between steps
+    "bare",         # ``EngineCore.step()`` met the serving loop's launch
+    "family",       # an engine that never leaves a launch in flight (the
+                    # unified step, bursts, mp > 1, committed outputs):
+                    # counted, and never a span -- nothing is in flight
+)
+(SETTLE_PREFILL, SETTLE_ADMIT, SETTLE_PREEMPT, SETTLE_FINISH, SETTLE_AUDIT,
+ SETTLE_FAULT, SETTLE_TASK, SETTLE_BARE, SETTLE_FAMILY) = SETTLE_REASONS
+
+# spans beside the step's phases, made the same way (``SpanTracer.phase(
+# name, None, **ints)``: one ``TraceAnnotation``, a no-op in C++ while no
+# profiler runs) on the thread named.  No name starts with ``engine.`` or
+# ``sched.``: the benchmark's ``host_spans.load_host`` collects those two
+# prefixes from every thread's line as phases of the step.  The names and
+# integers are a contract with ``benchmarks/thread_spans.py`` and PERF.md.
+THREAD_SPANS = (
+    "ahead.settle",     # engine thread: ``EngineCore.settle`` reading the
+                        # launch in flight for a step that could not run
+                        # ahead; CONTAINS that launch's engine.device_wait
+                        # / engine.fetch / engine.emit (reason= index into
+                        # SETTLE_REASONS, launch= the launch read)
+    "server.accept",    # loop thread: a parsed completion request up to
+                        # its hand-over to the fleet, no await inside
+                        # (req= the n of its id ``cmpl-<n>``,
+                        # prompt_tokens=)
+    "server.wake",      # loop thread: one walk over the open handles and
+                        # every ``event.set()`` (handles= looked at)
+    "server.write",     # loop thread: one chunk built and handed to the
+                        # socket, not the drain (req= as its
+                        # server.accept, tokens=)
+    "proc.gc",          # any thread: one collection of the cyclic
+                        # collector, from ``gc.callbacks`` (gen=)
 )
 
 

@@ -69,6 +69,14 @@ from ..observability.audit import (
 from ..observability.cachestat import CacheStatTracker
 from ..observability.lifecycle import LifecycleTracker
 from ..observability.stepprof import StepProfiler
+from ..observability.tracer import (
+    SETTLE_AUDIT,
+    SETTLE_BARE,
+    SETTLE_FAMILY,
+    SETTLE_FAULT,
+    SETTLE_REASONS,
+    SETTLE_TASK,
+)
 from ..ops import paged_attention as _paged_ops
 from ..ops import ragged_paged as _ragged_ops
 from ..ops.paged_attention import (
@@ -140,10 +148,10 @@ def _register_held_metrics(registry, labels: Dict[str, str]):
 _AHEAD_SETTLES_HELP = (
     "steps of the serving loop that read the launch in flight before "
     "planning instead of running ahead of it, by the rule that made them "
-    "(prefill, admit, preempt, finish, audit, fault, task, bare), and "
+    f"({', '.join(r for r in SETTLE_REASONS if r != SETTLE_FAMILY)}), and "
     "steps with decode rows of an engine that never leaves a launch in "
-    "flight (family: the unified step, bursts, mp > 1, or step programs "
-    "whose outputs are committed to a device)")
+    f"flight ({SETTLE_FAMILY}: the unified step, bursts, mp > 1, or step "
+    "programs whose outputs are committed to a device)")
 
 
 # StepTimer series and collective-phase label of each program family
@@ -2224,20 +2232,30 @@ class EngineCore:
     def settle(self, reason: str) -> Dict[object, int]:
         """Read the decode launch in flight, if there is one: wait for its
         tokens, emit them, retire what finished.  Counted under ``reason``
-        in ``serving_ahead_settles_total``.  Whatever reads or moves the
+        (one of ``observability.tracer.SETTLE_REASONS``) in
+        ``serving_ahead_settles_total``, and one ``ahead.settle`` span on
+        the profiler's clock around the read, with the reason's index and
+        the number of the launch read.  Whatever reads or moves the
         engine's state between steps (a KV export, an import, a detach)
         calls this first; so does a step that cannot run ahead."""
         launch, self._inflight = self._inflight, None
         if launch is None:
             return {}
-        self._count_settle(reason)
-        emitted = self._emit_decode(launch, *self._collect(launch.flight))
-        self._retire_finished()
+        with self.tracer.phase("ahead.settle", None,
+                               reason=SETTLE_REASONS.index(reason),
+                               launch=launch.flight.seq):
+            self._count_settle(reason)
+            emitted = self._emit_decode(launch,
+                                        *self._collect(launch.flight))
+            self._retire_finished()
         return emitted
 
     def _count_settle(self, reason: str) -> None:
         c = self._ahead_counters["settles"].get(reason)
         if c is None:
+            if reason not in SETTLE_REASONS:
+                raise ValueError(f"settle reason {reason!r} is none of "
+                                 f"{SETTLE_REASONS}")
             c = self._ahead_counters["settles"][reason] = \
                 self.metrics.registry.counter(
                     "serving_ahead_settles_total",
@@ -2329,9 +2347,9 @@ class EngineCore:
                 # before anything is planned.  The launch in flight is the
                 # step before's: it is read before the audit's schedule
                 # moves on and before a planned fault fires
-                why = ("bare" if not ahead else
-                       "audit" if self.audit.next_sampled else
-                       "fault" if fi is not None
+                why = (SETTLE_BARE if not ahead else
+                       SETTLE_AUDIT if self.audit.next_sampled else
+                       SETTLE_FAULT if fi is not None
                        and fi.pending(self.step_seq) else None)
                 if why is not None:
                     emitted.update(self.settle(why))
@@ -2401,7 +2419,7 @@ class EngineCore:
                     elif decodes:
                         emitted.update(self._decode_ahead(decodes, flying))
                 if ahead and decodes and not self._flies:
-                    self._count_settle("family")
+                    self._count_settle(SETTLE_FAMILY)
                 self._retire_finished()
                 self._let_go_if_ended()
                 # the end-of-step trackers, one phase from here to the
@@ -2575,7 +2593,7 @@ class EngineCore:
         :meth:`detach_request`."""
         from . import handoff
 
-        self.settle("task")
+        self.settle(SETTLE_TASK)
         return handoff.export_request_run(self, request_id)
 
     def export_prefix_chain(self, chain_hash, max_blocks=None):
@@ -2583,7 +2601,7 @@ class EngineCore:
         digest (hot-prefix migration); ``None`` on a broken chain."""
         from . import handoff
 
-        self.settle("task")
+        self.settle(SETTLE_TASK)
         return handoff.export_prefix_run(self, chain_hash,
                                          max_blocks=max_blocks)
 
@@ -2600,7 +2618,7 @@ class EngineCore:
         Returns fresh-block count, or ``None`` on capacity refusal."""
         from . import handoff
 
-        self.settle("task")
+        self.settle(SETTLE_TASK)
         return handoff.import_run(self, run)
 
     def detach_request(self, request_id) -> bool:
@@ -2610,7 +2628,7 @@ class EngineCore:
         freed; with the prefix cache on, the hashed prompt blocks park
         WARM in the reuse LRU — a failed migration that re-admits here
         revives them at zero recompute."""
-        self.settle("task")
+        self.settle(SETTLE_TASK)
         req = self.requests.pop(request_id, None)
         if req is None:
             return False

@@ -97,6 +97,7 @@ from ..observability.flight import FlightConfig, FlightRecorder
 from ..observability.history import HistoryConfig, HistoryStore
 from ..observability.lifecycle import LifecycleTracker
 from ..observability.metrics import MetricsRegistry
+from ..observability.pauses import PauseMonitor
 from ..ops.paged_attention import prefix_chain_hashes
 from .engine import EngineCore
 from .faultinject import FaultInjector, FaultPlan
@@ -969,6 +970,11 @@ class FleetRouter:
                 flight=self.flight)
             for eng in self.engines:
                 eng.set_history(self.history)
+        # a pause names itself (ISSUE 39): the collector's callback and one
+        # monitor thread, installed by start() and taken off by stop();
+        # the series exist from the first scrape
+        self.pauses = PauseMonitor(self.registry, lambda: self.replicas,
+                                   flight=self.flight)
         # register the hook LAST, after everything above that can raise
         # (gate validation, history/alert series creation on a shared
         # registry near its max_series cap): an aborted __init__ never
@@ -1059,6 +1065,10 @@ class FleetRouter:
         for r in self.replicas:
             if r.thread is None:
                 r.start()
+        # the HTTP frontend starts its fleet from its loop thread: that
+        # is the stack a stall shows beside the engine thread's
+        self.pauses.loop_thread = threading.get_ident()
+        self.pauses.start()
         self.sample_gauges()
         return self
 
@@ -1078,6 +1088,7 @@ class FleetRouter:
             r.request_stop()
         for r in self.replicas:
             r.join(join_timeout)
+        self.pauses.stop()
         self.sample_gauges()
         # stop collecting from (and alerting on) a stopped fleet: the
         # registry may outlive the router, and a later scrape must not

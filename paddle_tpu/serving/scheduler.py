@@ -59,6 +59,12 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, List, Optional, Tuple
 
+from ..observability.tracer import (
+    SETTLE_ADMIT,
+    SETTLE_FINISH,
+    SETTLE_PREEMPT,
+    SETTLE_PREFILL,
+)
 from .kv_manager import KVCacheManager
 from .request import FinishReason, Request, RequestState
 
@@ -464,6 +470,7 @@ class ContinuousBatchingScheduler:
         Anything else is for :meth:`schedule`, after the launch has been
         read, and this pass says which rule stood in the way BEFORE it
         changes anything: ``(None, reason)`` with ``reason`` one of
+        ``observability.tracer.SETTLE_REASONS``:
         ``prefill`` (a running request still has prompt to compute),
         ``finish`` (every row ends with the token in flight), ``preempt``
         (the rows need more blocks than are free) and ``admit`` (the head
@@ -481,7 +488,7 @@ class ContinuousBatchingScheduler:
         need = ending = freed = 0
         for req in sorted(self.running, key=lambda r: r.preempt_key):
             if self._needs_prefill(req):
-                return None, "prefill"
+                return None, SETTLE_PREFILL
             rid = req.request_id
             if rid in flying and (len(req.output_tokens) + 1
                                   >= req.sampling.max_new_tokens):
@@ -491,9 +498,9 @@ class ContinuousBatchingScheduler:
             need += self.kv.blocks_needed(rid, 1)
             rows.append(req)
         if not rows:
-            return None, "finish"
+            return None, SETTLE_FINISH
         if need > self.kv.num_available:
-            return None, "preempt"
+            return None, SETTLE_PREEMPT
         if (self.waiting
                 and len(self.running) - ending < self.config.max_num_seqs
                 and self.config.max_prefills_per_step > 0
@@ -502,7 +509,7 @@ class ContinuousBatchingScheduler:
                     self.waiting[0], self.kv.num_available - need + freed,
                     bool(ending) or self.kv.can_start_sequence()
                 )[0] != "wait"):
-            return None, "admit"
+            return None, SETTLE_ADMIT
         out = SchedulerOutput()
         for req in rows:
             req._slot = self.kv.append_slot(req.request_id)

@@ -47,6 +47,12 @@ open handles ON the loop thread and wakes only those whose request has
 something to read — a new token, a finish, a terminal handle
 (``CompletionServer._notify`` / ``_wake_streams``;
 ``serving_stream_wakes_total`` and its two siblings on ``/metrics``).
+What the LOOP thread does for the streams is three spans on the
+profiler's clock (``observability.tracer.THREAD_SPANS``; no-ops while no
+profiler runs): ``server.accept`` (a parsed request up to its hand-over
+to the fleet), ``server.wake`` (that walk) and ``server.write`` (a chunk
+built and handed to the socket), the last two sharing the request's
+number.
 
 The frontend owns three policies the engines deliberately do not:
 
@@ -92,6 +98,7 @@ from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ..observability.httpd import PROMETHEUS_CONTENT_TYPE, metrics_page
+from ..observability.tracer import SpanTracer
 from .engine import EngineCore
 from .fleet import (
     FleetConfig,
@@ -161,16 +168,18 @@ class _Handle(SubmitHandle):
     ``woken_end`` are the loop thread's own note of what the handler has
     been woken for — the request's token count at the last wake, and
     whether its end was announced — so a step's wake-up skips a handle
-    with no news (:meth:`CompletionServer._wake_streams`)."""
+    with no news (:meth:`CompletionServer._wake_streams`).  ``num`` is the
+    ``n`` of the id ``cmpl-<n>``: the integer the request's spans share."""
 
-    __slots__ = ("creq", "woken_at", "woken_end")
+    __slots__ = ("creq", "num", "woken_at", "woken_end")
 
     def __init__(self, rid: str, creq: CompletionRequest,
-                 event: asyncio.Event):
+                 event: asyncio.Event, num: int = 0):
         super().__init__(rid, creq.prompt_ids, sampling=creq.sampling(),
                          priority=creq.priority, event=event,
                          slo_ms=creq.slo_ms, retryable=creq.retryable)
         self.creq = creq
+        self.num = num
         self.woken_at = 0
         self.woken_end = False
 
@@ -392,21 +401,23 @@ class CompletionServer:
         never lost."""
         wake.pending = False
         index, woken = wake.index, 0
-        for h in self._handles.values():
-            if index is not None:
-                r = h.replica
-                if r is None or r.index != index:
-                    continue
-                req = h.req
-                n = len(req.output_tokens) if req is not None else 0
-                end = h.finished
-                if not (n > h.woken_at or (end and not h.woken_end)):
-                    continue
-                # max: a re-dispatched request starts over below what
-                # its handler has already read
-                h.woken_at, h.woken_end = max(n, h.woken_at), end
-            h.event.set()
-            woken += 1
+        with SpanTracer.phase("server.wake", None,
+                              handles=len(self._handles)):
+            for h in self._handles.values():
+                if index is not None:
+                    r = h.replica
+                    if r is None or r.index != index:
+                        continue
+                    req = h.req
+                    n = len(req.output_tokens) if req is not None else 0
+                    end = h.finished
+                    if not (n > h.woken_at or (end and not h.woken_end)):
+                        continue
+                    # max: a re-dispatched request starts over below what
+                    # its handler has already read
+                    h.woken_at, h.woken_end = max(n, h.woken_at), end
+                h.event.set()
+                woken += 1
         if woken:
             wake.woken.inc(woken)
 
@@ -1104,11 +1115,21 @@ class CompletionServer:
         # router admission is per replica: prefix-affinity target first,
         # least-loaded fallback; 429 only when EVERY eligible replica is
         # at its in-flight cap
-        rid = f"cmpl-{next(self._ids)}"
-        handle = _Handle(rid, creq, asyncio.Event())
-        try:
-            self.fleet.submit(handle)
-        except FleetSaturated:
+        num = next(self._ids)
+        refused = None
+        # the loop thread's synchronous stretch for an accepted request,
+        # no await inside: the handle, the router's placement, the queue
+        with SpanTracer.phase("server.accept", None, req=num,
+                              prompt_tokens=len(creq.prompt_ids)):
+            rid = f"cmpl-{num}"
+            handle = _Handle(rid, creq, asyncio.Event(), num)
+            try:
+                self.fleet.submit(handle)
+            except (FleetSaturated, FleetDown) as e:
+                refused = e  # swallow-ok: answered 429 / 503 just below, outside the span (no await may sit inside it)
+            else:
+                self._handles[rid] = handle
+        if isinstance(refused, FleetSaturated):
             self._rejected.inc()
             self.fleet.flight.note_rejection()
             await self._respond(
@@ -1118,13 +1139,12 @@ class CompletionServer:
                 extra=(("Retry-After", str(self.cfg.retry_after_s)),),
                 keep_alive=keep_alive)
             return 429, keep_alive
-        except FleetDown:
+        if refused is not None:
             unavailable_msg, unavailable_extra = self._unavailable_503()
             await self._respond(writer, 503, error_body(
                 unavailable_msg, "unavailable_error"),
                 extra=unavailable_extra, keep_alive=keep_alive)
             return 503, keep_alive
-        self._handles[rid] = handle
 
         timeout = creq.timeout if creq.timeout is not None \
             else self.cfg.default_timeout_s
@@ -1214,31 +1234,39 @@ class CompletionServer:
     async def _stream_response(self, handle: _Handle,
                                timeout: Optional[float],
                                writer: asyncio.StreamWriter) -> int:
-        writer.write(b"HTTP/1.1 200 OK\r\n"
-                     b"Content-Type: text/event-stream\r\n"
-                     b"Cache-Control: no-store\r\n"
-                     + f"X-Request-Id: {handle.rid}\r\n".encode("latin-1")
-                     + b"Connection: close\r\n\r\n")
-        # id-bearing FIRST chunk, before any token exists: an SSE client
-        # learns the request id immediately (for /v1/requests/{id} or an
-        # out-of-band abort) instead of only once the first token lands
-        writer.write(sse_event(chunk_body(
-            handle.rid, self.cfg.model_name, [], None)))
+        # every synchronous stretch that builds a chunk and hands it to
+        # the socket is one ``server.write`` span (never the drain, which
+        # gives the loop away)
+        phase, num = SpanTracer.phase, handle.num
+        with phase("server.write", None, req=num, tokens=0):
+            writer.write(b"HTTP/1.1 200 OK\r\n"
+                         b"Content-Type: text/event-stream\r\n"
+                         b"Cache-Control: no-store\r\n"
+                         + f"X-Request-Id: {handle.rid}\r\n".encode("latin-1")
+                         + b"Connection: close\r\n\r\n")
+            # id-bearing FIRST chunk, before any token exists: an SSE
+            # client learns the request id immediately (for
+            # /v1/requests/{id} or an out-of-band abort) instead of only
+            # once the first token lands
+            writer.write(sse_event(chunk_body(
+                handle.rid, self.cfg.model_name, [], None)))
         await writer.drain()
 
         async def on_tokens(new: List[int]) -> None:
-            writer.write(sse_event(chunk_body(
-                handle.rid, self.cfg.model_name, new, None)))
+            with phase("server.write", None, req=num, tokens=len(new)):
+                writer.write(sse_event(chunk_body(
+                    handle.rid, self.cfg.model_name, new, None)))
             await writer.drain()
 
         tokens, reason = await self._collect(handle, timeout, on_tokens)
         # the FINAL chunk carries the usage block — SSE clients see the
         # prefix-cache attribution too (ISSUE 13 satellite)
-        writer.write(sse_event(chunk_body(
-            handle.rid, self.cfg.model_name, [], reason,
-            usage=usage_body(len(handle.creq.prompt_ids), len(tokens),
-                             self._prompt_cached(handle)))))
-        writer.write(SSE_DONE)
+        with phase("server.write", None, req=num, tokens=0):
+            writer.write(sse_event(chunk_body(
+                handle.rid, self.cfg.model_name, [], reason,
+                usage=usage_body(len(handle.creq.prompt_ids), len(tokens),
+                                 self._prompt_cached(handle)))))
+            writer.write(SSE_DONE)
         await writer.drain()
         return 200
 

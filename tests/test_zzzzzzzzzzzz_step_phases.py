@@ -17,7 +17,12 @@ The contract of ``SpanTracer.phase`` on the serving step path:
 * ``arm_capture`` holds the step's lock across neither ``start_trace`` nor
   ``stop_trace``: steps complete while a slow profiler starts and stops;
 * request ids ride step records and step spans as the tuple the engine
-  holds, and become text where they are read.
+  holds, and become text where they are read;
+* the spans beside the phases (``tracer.THREAD_SPANS``, ISSUE 39):
+  ``ahead.settle`` carries the reason a step did not run ahead and the
+  launch it read; the loop thread's three spans lie on ITS line of a
+  profiler trace, one ``req`` across a request's; none of them is a phase
+  to ``benchmarks/host_spans.load_host``.
 """
 
 import threading
@@ -30,7 +35,12 @@ from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.observability import tracer as tracer_mod
 from paddle_tpu.observability.audit import AuditConfig
 from paddle_tpu.observability.export import chrome_trace_dict
-from paddle_tpu.observability.tracer import STEP_PHASES, SpanTracer
+from paddle_tpu.observability.tracer import (
+    SETTLE_REASONS,
+    STEP_PHASES,
+    THREAD_SPANS,
+    SpanTracer,
+)
 from paddle_tpu.serving import (
     EngineConfig,
     EngineCore,
@@ -391,3 +401,224 @@ class TestIdsJoinedWhereRead:
                        for p in last["programs"] for v in p.values())
         finally:
             tracer_mod.set_tracer(prev)
+
+
+class TestSettleSpan:
+    """``ahead.settle``: the read of the launch in flight by a step that
+    could not run ahead, with its reason and the launch's number."""
+
+    def _settles(self, recorded):
+        return [(SETTLE_REASONS[kw["reason"]], kw["launch"])
+                for n, kw in recorded if n == "ahead.settle"]
+
+    def _dispatches(self, recorded):
+        return [kw for n, kw in recorded if n == "engine.dispatch"]
+
+    def _fly(self, eng, recorded, prompts=1, max_new=6):
+        """Steps of the serving loop until a decode launch is in flight."""
+        reqs = _submit(eng, _prompts(n=prompts), max_new=max_new)
+        for _ in range(20):
+            if eng._inflight is not None:
+                break
+            eng.step_ahead()
+        assert eng._inflight is not None
+        del recorded[:]
+        return reqs
+
+    def test_a_step_that_runs_ahead_settles_nothing(self, recorded):
+        eng = _engine()
+        self._fly(eng, recorded)
+        flying = eng._inflight.flight.seq
+        eng.step_ahead()
+        assert self._settles(recorded) == []
+        [d] = self._dispatches(recorded)
+        assert d["ahead"] == 1 and d["launch"] == flying + 1
+        names = [n for n, _ in recorded]
+        assert set(names) <= set(STEP_PHASES)
+
+    def test_a_prompt_to_compute_settles_for_prefill_or_admit(self, recorded):
+        eng = _engine()
+        self._fly(eng, recorded)
+        flying = eng._inflight.flight.seq
+        _submit(eng, _prompts(n=1, seed=3))
+        eng.step_ahead()
+        # the waiting request can be admitted: the launch is read first
+        assert self._settles(recorded) == [("admit", flying)]
+        assert all(d["ahead"] == 0 for d in self._dispatches(recorded))
+
+    def test_a_chunked_prompt_settles_for_prefill(self, recorded):
+        eng = _engine("chunk")
+        self._fly(eng, recorded)
+        _submit(eng, [list(range(1, 30))])     # four chunks of 8
+        reasons = []
+        for _ in range(6):
+            del recorded[:]
+            eng.step_ahead()
+            reasons += [r for r, _ in self._settles(recorded)]
+        # admitted once, then a running request still has prompt to compute
+        assert reasons[0] == "admit" and "prefill" in reasons
+
+    def test_the_last_token_settles_for_finish(self, recorded):
+        eng = _engine()
+        self._fly(eng, recorded, max_new=3)
+        seen = []
+        while eng.scheduler.has_work():
+            eng.step_ahead()
+            assert len(seen) < 50
+            seen = self._settles(recorded)
+        [(reason, launch)] = seen
+        assert reason == "finish" and launch == eng._launch_seq
+        # the settle CONTAINS that launch's wait, fetch and emit
+        names = [n for n, _ in recorded]
+        at = names.index("ahead.settle")
+        assert names[at + 1:at + 4] == ["engine.device_wait",
+                                        "engine.fetch", "engine.emit"]
+        wait = [kw for n, kw in recorded if n == "engine.device_wait"][-1]
+        assert wait["launch"] == launch
+
+    def test_a_bare_step_settles_the_loops_launch(self, recorded):
+        eng = _engine()
+        self._fly(eng, recorded)
+        flying = eng._inflight.flight.seq
+        eng.step()
+        assert self._settles(recorded) == [("bare", flying)]
+        assert eng._inflight is None
+        # and with nothing in flight a settle is no span at all
+        del recorded[:]
+        assert eng.settle("task") == {}
+        assert recorded == []
+
+    def test_every_settle_is_counted_under_the_same_word(self, recorded):
+        eng = _engine()
+        self._fly(eng, recorded, max_new=4)
+        while eng.scheduler.has_work():
+            eng.step_ahead()
+        spans = {}
+        for reason, _ in self._settles(recorded):
+            spans[reason] = spans.get(reason, 0) + 1
+        counted = {
+            r: eng.metrics.registry.counter(
+                "serving_ahead_settles_total",
+                **dict(eng.metrics.labels, reason=r)).value
+            for r in spans}
+        assert spans and counted == spans
+
+    def test_a_reason_that_is_no_settle_reason_fails(self):
+        eng = _engine()
+        with pytest.raises(ValueError, match="settle reason"):
+            eng._count_settle("because")
+        assert "family" in SETTLE_REASONS and len(set(SETTLE_REASONS)) == 9
+        from paddle_tpu.serving import engine as engine_mod
+
+        for reason in SETTLE_REASONS:
+            assert reason in engine_mod._AHEAD_SETTLES_HELP
+
+
+class TestLoopThreadSpans:
+    """A streamed request through the real server under the CPU profiler:
+    which line of the host plane holds which span."""
+
+    @pytest.fixture(scope="class")
+    def trace(self, tmp_path_factory):
+        import asyncio
+        import gc
+
+        import jax
+
+        from benchmarks import thread_spans, trace_reduce
+        from paddle_tpu.serving.server import (
+            CompletionServer,
+            ServerConfig,
+            _http,
+            _toy_engine,
+        )
+
+        log_dir = str(tmp_path_factory.mktemp("loop_trace"))
+        body = {"prompt": [5, 6, 7, 8, 9, 10], "max_tokens": 8,
+                "stream": True}
+
+        async def drive():
+            server = CompletionServer(_toy_engine(), ServerConfig(port=0))
+            await server.start()
+            loop = asyncio.get_running_loop()
+            try:
+                # compile everything first: the trace is of warm steps
+                await loop.run_in_executor(
+                    None, _http, server.port, "POST", "/v1/completions",
+                    body)
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(log_dir, profiler_options=options)
+                try:
+                    status, raw = await loop.run_in_executor(
+                        None, _http, server.port, "POST",
+                        "/v1/completions", body)
+                    gc.collect()
+                finally:
+                    jax.profiler.stop_trace()
+            finally:
+                await server.shutdown(drain_timeout=1.0)
+            return status
+
+        assert asyncio.run(drive()) == 200
+        path = trace_reduce.find_xplane(log_dir)
+        return path, thread_spans.load_lines(path)[0]
+
+    def test_the_loop_threads_spans_lie_on_one_line(self, trace):
+        from benchmarks import thread_spans as ts
+
+        _, lines = trace
+        loop = ts.line_of(lines, ts.WAKE)
+        names = {p[0] for p in loop}
+        assert {ts.ACCEPT, ts.WAKE, ts.WRITE} <= names
+        # the loop thread runs no phase of the step, the engine thread no
+        # span of the front door
+        assert not any(n.startswith(("engine.", "sched.")) for n in names)
+        engine = ts.line_of(lines, "engine.dispatch")
+        assert engine is not loop
+        assert not {p[0] for p in engine} & set(ts.FRONT_DOOR)
+        assert "ahead.settle" in {p[0] for p in engine}
+
+    def test_one_req_across_a_requests_spans(self, trace):
+        from benchmarks import thread_spans as ts
+
+        loop = ts.line_of(trace[1], ts.WAKE)
+        [accept] = [p for p in loop if p[0] == ts.ACCEPT]
+        writes = [p for p in loop if p[0] == ts.WRITE]
+        # the second request of the server: cmpl-2
+        assert accept[3] == {"req": 2, "prompt_tokens": 6}
+        assert writes and {w[3]["req"] for w in writes} == {2}
+        # the id-bearing first chunk, the tokens, the final chunk
+        assert writes[0][3]["tokens"] == 0 == writes[-1][3]["tokens"]
+        assert sum(w[3]["tokens"] for w in writes) == 8
+        # accepted before anything was written, every write after a wake
+        assert accept[2] <= writes[0][1]
+        wakes = [p for p in loop if p[0] == ts.WAKE]
+        assert all(w[3]["handles"] >= 0 for w in wakes)
+        assert wakes[0][1] < writes[1][1]
+
+    def test_a_collection_is_a_span_on_the_thread_it_ran_on(self, trace):
+        from benchmarks import thread_spans as ts
+
+        gcs = [p for line in trace[1] for p in line if p[0] == ts.GC]
+        assert gcs and all(set(p[3]) == {"gen"} for p in gcs)
+        assert 2 in {p[3]["gen"] for p in gcs}     # the forced one
+
+    def test_host_spans_takes_none_of_them_for_a_phase(self, trace):
+        from benchmarks import host_spans
+
+        phases, _, _ = host_spans.load_host(trace[0])
+        names = {p[0] for p in phases}
+        assert names and names <= set(STEP_PHASES)
+        assert not names & set(THREAD_SPANS)
+
+    def test_the_readers_read_the_trace(self, trace):
+        from benchmarks import thread_spans as ts
+
+        a = ts.analyse(trace[1], {}, {})
+        # a CPU trace has no device plane: what needs programs is silent
+        assert a["ahead_share"] is None and a["gap_s"] == 0.0
+        assert ts.value(None, "frontdoor.loop_busy_share", a) > 0
+        assert ts.value(None, "frontdoor.handoff_ms", a) > 0
+        assert ts.value(None, "engine.gc_ms_per_s", a) > 0
+        assert a["spans"]["server.accept"]["count"] == 1

@@ -65,6 +65,9 @@ DECLARING_MODULES = (
     # ISSUE 20: prefill/decode disaggregation — the KV hand-off
     # counter/histograms the router registers for every fleet
     os.path.join(_REPO, "paddle_tpu", "serving", "handoff.py"),
+    # ISSUE 39: the pause monitor's collector histogram and the three
+    # serving_pause* series
+    os.path.join(_REPO, "paddle_tpu", "observability", "pauses.py"),
 )
 
 _NAME_RE = re.compile(r"\b(?:serving|push)_[a-z0-9_:]+\b")

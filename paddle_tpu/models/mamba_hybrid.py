@@ -30,13 +30,16 @@ cache_spec`: no per-token row, two per-sequence arrays) and the engine
 allocates slot pools from it and hands them in as a
 :class:`~paddle_tpu.ops.selective_scan.StateCache`.  Three paths: a scan
 over a (padded) bucket that stops at the last real token and starts from
-zero or from the slot; one step a row for decode (gather by slot, step,
-scatter in place); and the cache-less forward over a whole sequence.
+zero or from the slot; one step a row for decode (on the chip at tileable
+widths one Pallas kernel that steps the state in its slot,
+``ops/pallas_ssm.py``; otherwise gather by slot, step, scatter in place);
+and the cache-less forward over a whole sequence.
 
 Device scopes, under an outer ``ssm`` that is NOT inside ``attn``:
 ``ssm_in_proj``, ``ssm_conv``, ``ssm_x_proj`` (projection, the three
-norms, dt), ``ssm_scan`` (prefill and chunk), ``ssm_step`` (decode),
-``ssm_out`` (gate and out-projection).
+norms, dt), ``ssm_scan`` (prefill and chunk), ``ssm_step`` (decode: EVERY
+operation that reads or writes either slot pool, the kernel's custom call
+included), ``ssm_out`` (gate and out-projection).
 """
 
 from __future__ import annotations
@@ -56,6 +59,7 @@ from ..ops.selective_scan import (
     StateCache,
     causal_conv,
     conv_window,
+    route_state_step,
     selective_scan,
     selective_step,
 )
@@ -184,15 +188,20 @@ class MambaMixer(Layer):
             B, S = xz.shape[0], xz.shape[1]
             u, z = xz[..., :d], xz[..., d:]
             f32 = jnp.float32
+            in_place = False
             if cache is None:
                 window = jnp.zeros((B, k - 1, d), u.dtype)
                 h0 = jnp.zeros((B, n, d), f32)
             else:
                 state_pool, conv_pool = pools
                 slots = cache.slots
+                # a decode launch the kernel can take steps each row's
+                # state in its slot: nothing of the state is gathered
+                in_place = route_state_step(cache,
+                                            state_pool.shape) == "pallas"
                 with jax.named_scope("ssm_step" if decode else "ssm_scan"):
                     h0, window = _carried_state(
-                        cache, state_pool[slots],
+                        cache, None if in_place else state_pool[slots],
                         conv_pool[slots].reshape(B, k - 1, d), decode)
             with jax.named_scope("ssm_conv"):
                 xc, padded = causal_conv(u, window, conv_w, conv_b)
@@ -205,8 +214,13 @@ class MambaMixer(Layer):
             A = -jnp.exp(a_log.astype(f32))
             if decode:
                 with jax.named_scope("ssm_step"):
-                    y, h = selective_step(xc[:, 0], dt[:, 0], A, Bm[:, 0],
-                                          Cm[:, 0], h0)
+                    step = (xc[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0])
+                    if in_place:
+                        from ..ops.pallas_ssm import state_step
+
+                        y, state_pool = state_step(*step, state_pool, slots)
+                    else:
+                        y, h = selective_step(*step, h0)
                     y = y[:, None]
                     keep = padded[:, 1:]
             else:
@@ -223,7 +237,8 @@ class MambaMixer(Layer):
             with jax.named_scope("ssm_step" if decode else "ssm_scan"):
                 # in place on the donated pools; padding rows all write
                 # the null slot 0, which no sequence reads
-                state_pool = state_pool.at[slots].set(h)
+                if not in_place:
+                    state_pool = state_pool.at[slots].set(h)
                 conv_pool = conv_pool.at[slots].set(
                     keep.reshape(B, -1).astype(conv_pool.dtype))
             return y, state_pool, conv_pool

@@ -15,18 +15,27 @@ padding) and the last ``K - 1`` conv INPUTS ``u[t-K+2 .. t]``, flat as
 allocates from the layer's declaration (``CacheSpec.state``) and hands to
 the layer as a :class:`StateCache`.
 
-Three paths, one mathematics:
+Four paths, one mathematics:
 
 * :func:`selective_scan` — over a padded bucket of positions, position by
   position with the state as the carry (no ``[T, N, D]`` intermediate: at
   4,096 x 5,120 x 16 float32 that is 1.3 GB).  A position at or past
   ``n_valid`` leaves the state as it was, so what is written is the state
   after the last REAL token.  ``h0`` carries a chunk's state in.
-* :func:`selective_step` — one token a row, for decode.
+* :func:`selective_step` — one token a row, for decode, on states gathered
+  from their slots (the caller scatters the new ones back): the oracle,
+  and what a CPU run and an untileable width execute.
+* ``pallas_ssm.state_step`` — the same step IN PLACE on the slot pool, one
+  Pallas kernel: a decode launch on the chip at ``N % 8 == 0`` and
+  ``D % 128 == 0`` (:func:`state_step_path` decides, through the one
+  dispatch policy ``paged_attention.pallas_dispatch``; :data:`last_path`
+  says which of the two a launch was traced through).
 * :func:`conv_window` — the conv inputs to keep, cut at ``n_valid``.
 """
 
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -53,8 +62,14 @@ class StateCache:
         self.slots = None
         self.start = None
         self.n_valid = None
-        self.use_pallas = None      # the engine's kernel routing hint, for
-                                    # a layer whose slots a kernel can read
+        self.use_pallas = None      # the engine's kernel routing hint
+                                    # (``EngineConfig.use_pallas_paged``),
+                                    # read by :func:`route_state_step` for
+                                    # a decode launch: True forces the
+                                    # in-place Pallas step (interpret mode
+                                    # off the chip), False pins the XLA
+                                    # gather / step / scatter, None leaves
+                                    # it to shape and platform
 
     # the names the engine's step programs read the pools back by
     k_pool = property(lambda self: self.state_pool)
@@ -65,6 +80,38 @@ class StateCache:
         self.start = None if start is None else jnp.asarray(start, jnp.int32)
         self.n_valid = None if n_valid is None \
             else jnp.asarray(n_valid, jnp.int32)
+
+
+# Which path the most recent launch's state update was traced through:
+# "pallas" (the in-place kernel) | "xla".
+last_path: Optional[str] = None
+
+
+def state_step_path(state_shape, use_pallas, decode: bool = True) -> str:
+    """``"pallas"`` where a launch over a ``[slots, N, D]`` state pool
+    steps the state in its slot (``pallas_ssm.state_step``), ``"xla"``
+    where it gathers, steps (or scans) and scatters: the kernel is a DECODE
+    step at whole float32 tiles on a TPU backend, ``use_pallas`` forces or
+    pins as everywhere (``paged_attention.pallas_dispatch``, whose kill
+    switch wins)."""
+    from .paged_attention import pallas_dispatch
+
+    if not decode:
+        return "xla"
+    _, n, d = state_shape
+    tileable = (n % 8 == 0 and d % 128 == 0
+                and jax.default_backend() == "tpu")
+    return pallas_dispatch(lambda: None, lambda: None, use_pallas,
+                           tileable)[1]
+
+
+def route_state_step(cache: StateCache, state_shape) -> str:
+    """:func:`state_step_path` of the launch ``cache`` is routed for,
+    published as :data:`last_path`."""
+    global last_path
+    last_path = state_step_path(state_shape, cache.use_pallas,
+                                decode=cache.n_valid is None)
+    return last_path
 
 
 def causal_conv(u, window, w, b):

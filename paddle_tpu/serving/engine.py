@@ -86,7 +86,7 @@ from ..ops.paged_attention import (
     shard_kv_pool,
 )
 from ..ops.decode_burst import run_burst
-from ..ops.selective_scan import StateCache
+from ..ops.selective_scan import StateCache, state_step_path
 from ..ops.sampling import sample_tokens
 from .burst import burst_eligible, clamp_burst
 from .burst import register_metrics as _register_burst_metrics
@@ -1171,7 +1171,7 @@ class EngineCore:
         # COMPILATIONS (bounded by the bucket sets), not calls
         self.metrics.count("decode_jit_traces")
         self.tracer.instant("decode_jit_trace", cat="jit",
-                            batch=int(ids.shape[0]),
+                            batch=int(ids.shape[0]), **self._state_stat(k_pools),
                             table_width=int(tables.shape[1]))
         def pages(c):
             c.route(tables, lens, slot_blocks, slot_offsets)
@@ -1372,6 +1372,20 @@ class EngineCore:
         return (tokens, last, self._launch_stats(last),
                 tuple(c.k_pool._value for c in caches),
                 tuple(c.v_pool._value for c in caches))
+
+    def _state_stat(self, k_pools) -> Dict[str, str]:
+        """``state_step`` on the ``decode_jit_trace`` instant: the path a
+        decode program's selective-scan layers step their state through
+        (``"pallas"`` in the slot, ``"xla"`` gathered), by the predicate
+        the layers' dispatch uses; nothing for a model without such
+        state.  It stands BELOW the step programs' functions: a line
+        shifted above a Pallas call site changes every program that holds
+        a kernel (ROADMAP S7 f)."""
+        for pool, spec in zip(k_pools, self.cache_specs):
+            if spec.state and spec.window is None:
+                return {"state_step": state_step_path(pool.shape,
+                                                      self._use_pallas)}
+        return {}
 
     # --- request lifecycle --------------------------------------------------
     def set_lifecycle(self, tracker: LifecycleTracker,

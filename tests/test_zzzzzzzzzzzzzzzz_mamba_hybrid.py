@@ -515,6 +515,10 @@ def test_planted_fault_fails_the_comparison(ref, builder, model, fault,
     """One request dirties the slots, then a 37-token prompt (27 pad
     positions in its bucket) and 24 decode steps through slots and pages,
     every launch compared."""
+    planted_fault(ref, builder, model, fault, monkeypatch)
+
+
+def planted_fault(ref, builder, model, fault, monkeypatch, **engine_kw):
     from paddle_tpu.models import llama, mamba_hybrid
 
     cfg = dict(TINY)
@@ -562,7 +566,7 @@ def test_planted_fault_fails_the_comparison(ref, builder, model, fault,
             if hasattr(layer, "self_attn"):
                 att = layer.self_attn
                 att._rope_cos, att._rope_sin = llama._rope_tables(16, 512, 1e4)
-    eng = make_engine(broken)
+    eng = make_engine(broken, **engine_kw)
     serve(eng, prompt_of(50, seed=40), 6)
     rows = capture(eng)
     prompt = prompt_of(37, seed=41)
@@ -570,3 +574,76 @@ def test_planted_fault_fails_the_comparison(ref, builder, model, fault,
     res = check(ref, builder, model, rows, req, prompt, 24)
     assert res["rows"] == 25
     assert res["ok"] == (fault == "none"), (fault, res)
+
+
+# --- the decode step forced through the in-place kernel (ops/pallas_ssm.py) --------
+# TINY's widths are whole float32 tiles (8 state indices, 128 channels): off the
+# chip only the force takes the kernel, in interpret mode.
+
+FORCED = dict(use_pallas_paged=True)
+
+
+def decode_traces(engine):
+    """The attributes of every ``decode_jit_trace`` instant the engine
+    emits from here on."""
+    seen, real = [], engine.tracer.instant
+
+    def instant(name, **kw):
+        if name == "decode_jit_trace":
+            seen.append(kw)
+        return real(name, **kw)
+
+    engine.tracer.instant = instant
+    return seen
+
+
+def test_forced_kernel_twenty_decode_steps_stay_on_the_reference(
+        ref, builder, model):
+    from paddle_tpu.ops import selective_scan
+
+    eng = make_engine(model, num_blocks=256, **FORCED)
+    seen = decode_traces(eng)
+    rows = capture(eng)
+    prompt = prompt_of(9, seed=9)       # 29 tokens: one table width
+    req = serve(eng, prompt, 20)
+    assert selective_scan.last_path == "pallas"
+    assert seen and all(kw["state_step"] == "pallas" for kw in seen)
+    assert [p for p, _ in rows].count("decode") == 20
+    res = check(ref, builder, model, rows, req, prompt, 20)
+    assert res["ok"] and res["rows"] == 21, res
+
+
+def test_forced_kernel_in_a_batch_of_eight_gives_the_xla_tokens(model):
+    """The tokens of a request through the kernel among seven others
+    (neighbours on other slots, padding rows on the null slot as the rows
+    grow from 1 to 8) are those of the XLA path alone."""
+    from paddle_tpu.serving.request import SamplingParams
+
+    prompt = prompt_of(10, seed=11)     # 31 tokens at most: one table width
+    want = serve(make_engine(model, num_blocks=256), prompt, 20).output_tokens
+
+    crowd = make_engine(model, num_blocks=256, **FORCED)
+    rows = capture(crowd)
+    greedy = SamplingParams(max_new_tokens=21, temperature=0.0)
+    for s in range(7):
+        crowd.add_request(prompt_of(4 + s, seed=20 + s), greedy)
+    mine = crowd.add_request(prompt, greedy)
+    for _ in range(120):
+        if mine.finished:
+            break
+        crowd.step()
+    assert mine.output_tokens == want
+    assert max(l.shape[0] for p, l in rows if p == "decode") == 8
+
+
+def test_the_xla_engine_says_so_on_its_trace_instant(model):
+    eng = make_engine(model)
+    seen = decode_traces(eng)
+    serve(eng, prompt_of(11), 2)
+    assert [kw["state_step"] for kw in seen] == ["xla"]
+
+
+@pytest.mark.parametrize("fault", ["stale_slot", "pad_advances_state"])
+def test_planted_fault_fails_the_comparison_through_the_kernel(
+        ref, builder, model, fault, monkeypatch):
+    planted_fault(ref, builder, model, fault, monkeypatch, **FORCED)

@@ -475,3 +475,32 @@ def test_paged_decode_kernel_compiles_at_the_cells_shapes(
         assert "tpu_custom_call" in compiled.as_text(), name
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
+
+
+@pytest.mark.parametrize("rows", [8, 256])
+def test_state_step_kernel_compiles_at_the_published_widths(
+        one_chip, monkeypatch, rows):
+    """The TPU compiler takes the selective-scan decode step in place on
+    the slot pool at AI21-Jamba2-3B's widths (``ops/pallas_ssm.py``; it
+    stands here because one file of a run may describe the chip), and the
+    donated pool is the kernel's output: nothing the size of the pool is
+    allocated beside it."""
+    from paddle_tpu.ops import pallas_ssm
+
+    monkeypatch.setattr(pallas_ssm, "_interpret", lambda: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        def s(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        n, d, slots = 16, 5120, 257
+        compiled = jax.jit(pallas_ssm.state_step, donate_argnums=5).lower(
+            s((rows, d)), s((rows, d)), s((n, d)), s((rows, n)), s((rows, n)),
+            s((slots, n, d)), s((rows,), jnp.int32)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        pool = slots * n * d * 4
+        assert mem.alias_size_in_bytes >= pool
+        assert mem.temp_size_in_bytes < pool // 8
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)

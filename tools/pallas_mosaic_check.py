@@ -3,9 +3,10 @@
 Off the chip the kernels only ever run in interpret mode, and Mosaic
 rejects kernels that interpret fine.  This tool compiles every kernel of
 ``paddle_tpu/ops`` (and the fused sampling epilogue, plain XLA) with the
-real backend at the shapes the Llama-3-8B serving path launches, compares
-each against its XLA reference, and records the compiler's own words where
-it refuses.  Times are host-clock information, not a benchmark.
+real backend at the shapes the Llama-3-8B serving path and the benchmark's
+cells launch, compares each against its XLA reference, and records the
+compiler's own words where it refuses.  Times are host-clock information,
+not a benchmark.
 
     chiprun -- python tools/pallas_mosaic_check.py [--beside FILE] [check ...]
 
@@ -211,6 +212,52 @@ def sampler_check(rows, vocab=128256):
     return run
 
 
+# --- selective-scan decode step, in place on the slot pool -------------------
+
+def ssm_state_step_check(rows=256, n=16, d=5120):
+    """``pallas_ssm.state_step`` at AI21-Jamba2-3B's widths (state 16 x
+    5,120 float32, 257 slots, every row on a scattered slot of its own)
+    against gather -> ``selective_step`` -> scatter, the pool donated to
+    both as a step program donates it; ``beside_ms`` is the XLA path's."""
+    def run():
+        from paddle_tpu.ops import pallas_ssm
+        from paddle_tpu.ops.selective_scan import selective_step
+
+        rng = np.random.default_rng(4)
+        f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+        x, Bm, Cm, A = f32(rows, d), f32(rows, n), f32(rows, n), -jnp.exp(
+            f32(n, d))
+        dt = jnp.asarray(rng.uniform(1e-3, 0.1, (rows, d)), jnp.float32)
+        slots = jnp.asarray(rng.permutation(np.arange(1, rows + 1)),
+                            jnp.int32)
+        pool0 = f32(rows + 1, n, d)
+
+        def kernel(pool):
+            return pallas_ssm.state_step(x, dt, A, Bm, Cm, pool, slots)
+
+        def xla(pool):
+            y, h = selective_step(x, dt, A, Bm, Cm, pool[slots])
+            return y, pool.at[slots].set(h)
+
+        def first_and_ms(step, iters=10):
+            f = jax.jit(step, donate_argnums=0)
+            y, pool = f(jnp.array(pool0))
+            first = (y, jnp.array(pool))    # kept: the pool is donated on
+            jax.block_until_ready(first)
+            t0 = time.perf_counter()
+            for _ in range(iters):
+                y, pool = f(pool)
+            jax.block_until_ready((y, pool))
+            return first, round((time.perf_counter() - t0) / iters * 1e3, 3)
+
+        out, ms = first_and_ms(kernel)
+        ref, xla_ms = first_and_ms(xla)
+        err = max(_err(a, b) for a, b in zip(out, ref))
+        return {"ok": err < 1e-5, "max_err": err, "pallas_ms": ms,
+                "beside_ms": xla_ms}
+    return run
+
+
 CHECKS = [
     ("flash_fwd_causal=False", flash_check(False)),
     ("flash_fwd_causal=True", flash_check(True)),
@@ -252,6 +299,8 @@ CHECKS = [
     ("sampler_rows8_vocab128256", sampler_check(8)),
     ("sampler_rows256_vocab128256", sampler_check(256)),
     ("sampler_rows2048_vocab128256", sampler_check(2048)),
+    # the hybrid cell's decode launch: 256 rows, every slot but the null one
+    ("ssm_state_step_256x16x5120", ssm_state_step_check()),
 ]
 
 

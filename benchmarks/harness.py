@@ -65,12 +65,16 @@ def load_reader(name: str, here: str = HERE):
 
 
 class Cell:
-    """One entry of ``workloads`` with everything it names resolved."""
+    """One entry of ``workloads`` with everything it names resolved.
+    ``bench`` stands in for ``<root>/BENCHMARK.json`` where a test holds
+    the benchmark as a ``dict`` (a copy with a later PR's entries)."""
 
-    def __init__(self, name: str, root: str = ROOT):
+    def __init__(self, name: str, root: str = ROOT,
+                 bench: Optional[Dict] = None):
         self.root = root
         self.here = os.path.join(root, "benchmarks")
-        self.bench = load_json(root, "BENCHMARK.json")
+        self.bench = load_json(root, "BENCHMARK.json") if bench is None \
+            else bench
         rows = [w for w in self.bench["workloads"] if w["name"] == name]
         if not rows:
             raise KeyError(f"no workload {name!r} in BENCHMARK.json; have "

@@ -14,20 +14,37 @@ program itself wrote into the same ``.xplane.pb`` (PERF.md section 3):
   the operation's *metadata*, where ``jax.profiler.ProfileData`` does not
   look: :func:`op_scopes` reads those few records from the file's bytes.
 
-Every device idle gap between two consecutive step programs -- the same
-gaps ``trace_reduce.gaps_between_modules`` sums into
-``engine.host_ms_per_step`` -- is put down to the phases the host was in
-meanwhile (:func:`attribute`).  The two planes are aligned by the profiler
-only to within a millisecond or so, so the host plane is first shifted by
-an offset that causality pins from both sides (:func:`offset_bounds`): a
-program cannot start before its ``engine.dispatch`` began, and
-``engine.device_wait`` (blocked until the program's outputs are ready)
-cannot end before its program ended.  The dispatch alone takes milliseconds before it reaches
-the device, so where the runtime's own host events carry the program's
-``run_id`` they pin the offset more closely: a program cannot start before
-its ``DoEnqueueProgram`` began nor end after its ``CompleteCallbacks``
-ended.  The width of what is left is ``engine.gap_offset_width_ms``: a
-phase shorter than it is not resolved.
+Every device idle gap between two consecutive programs -- the same gaps
+``trace_reduce.gaps_between_modules`` sums into ``engine.host_ms_per_step``
+-- is put down to the phases the engine thread was in while the device
+stood idle (:func:`attribute`).  Since the serving loop runs one decode
+launch ahead of the read (PERF.md section 3) that is whichever phase the
+thread is in when the device runs dry, not the phases of "the launch
+before": ``engine.dispatch`` and ``engine.device_wait`` carry the launch's
+number (``launch=<n>``), a dispatch ``ahead=1`` where it went out before
+the launch before it was read, and :func:`by_ahead` keeps the idle time
+under such a dispatch (and under the wait for its program) apart as
+``engine.dispatch.ahead`` / ``engine.device_wait.ahead``: the launch ran
+ahead and still came late, which is no round trip the step waited for.
+
+The two planes are aligned by the profiler only to within a millisecond or
+so, so the host plane is first shifted by an offset that causality pins
+from both sides (:func:`offset_bounds`): a program cannot start before its
+``engine.dispatch`` began, and ``engine.device_wait`` (blocked until the
+program's outputs are ready) cannot end before ITS program ended -- the
+wait that carries the dispatch's ``launch`` number, which in a step that
+ran ahead comes a step later (:func:`launches_by_number`); a trace from
+before the numbers (PR 35's parent, the recorded traces of
+``tests/bench_suite``) pairs a dispatch with the next wait, which then was
+its own (:func:`match_launches`).  The dispatch alone takes milliseconds
+before it reaches the device, so where the runtime's own host events carry
+the program's ``run_id`` they pin the offset more closely: a program
+cannot start before its ``DoEnqueueProgram`` began nor end after its
+``CompleteCallbacks`` ended.  The width of what is left
+(:func:`pin_offset`, which ``thread_spans`` uses too) is
+``engine.gap_offset_width_ms``: a phase shorter than it is not resolved,
+and where a numbered trace leaves it negative or over :data:`MAX_WIDTH_S`
+the metric is not reported and standard error says so.
 
 A reader calls :func:`analysis`, which finds the launcher's trace under
 ``<checkout>/.bench_trace``, parses it once a process and returns ``None``
@@ -49,15 +66,20 @@ from benchmarks import harness, trace_reduce    # noqa: E402
 
 Interval = Tuple[float, float]              # start, end; seconds
 Phase = Tuple[str, float, float, Dict]      # name, start, end, stats
+Program = Tuple[str, float, float, int]     # name, start, end, run id
+Pair = Tuple[Phase, Program]                # a dispatch and its program
 
 DISPATCH = "engine.dispatch"
 DEVICE_WAIT = "engine.device_wait"
 FETCH = "engine.fetch"
+AHEAD = ".ahead"        # suffix of a dispatch / wait of a launch that ran ahead
 UNATTRIBUTED = "unattributed"
 PHASE_PREFIXES = ("engine.", "sched.")
 SCOPES = ("sampler", "lm_head", "mlp", "attn", "embed", "logit_stats")
 UNSCOPED = "unscoped"
 HOST_PLANE = "/host:CPU"
+MAX_WIDTH_S = 0.002     # an offset left wider open than this resolves
+                        # no span of the loop thread (tens of microseconds)
 # the TPU runtime's host events that carry a program's ``run_id``: the
 # one that hands the program to the device, the one that hears it ended
 ENQUEUE = "DoEnqueueProgram"
@@ -188,16 +210,18 @@ def op_scopes(path: str) -> Dict[str, Dict[str, str]]:
     return out
 
 
+@trace_reduce.once_a_file
 def load_host(path: str) -> Tuple[List[Phase], Dict[int, List], Dict]:
     """One pass over the file: the program's phases in the host plane, by
     start; the runtime's anchors ``{run_id: [enqueue start, complete
     end]}`` (``None`` for a side the trace does not hold); and ``{device
-    plane: [(program start, program end, run_id)]}``."""
+    plane: [program]}`` for every executed program that carries a
+    ``run_id``, names normalised."""
     from jax.profiler import ProfileData
 
     phases: List[Phase] = []
     anchors: Dict[int, List] = {}
-    runs: Dict[str, List[Tuple]] = {}
+    runs: Dict[str, List[Program]] = {}
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/device:"):
             for line in plane.lines:
@@ -208,8 +232,8 @@ def load_host(path: str) -> Tuple[List[Phase], Dict[int, List], Dict]:
                     rid = dict(ev.stats).get("run_id")
                     if rid is not None:
                         start = ev.start_ns * 1e-9
-                        rows.append((start, start + ev.duration_ns * 1e-9,
-                                     rid))
+                        rows.append((trace_reduce.norm(ev.name), start,
+                                     start + ev.duration_ns * 1e-9, rid))
             continue
         if plane.name != HOST_PLANE:
             continue
@@ -272,26 +296,85 @@ def attribute(gaps: Iterable[Interval], phases: Iterable[Phase],
     return out
 
 
+def is_numbered(phases: Iterable[Phase]) -> bool:
+    """Whether the dispatches carry their launch's number (PR 35 on)."""
+    return any(p[0] == DISPATCH and "launch" in p[3] for p in phases)
+
+
+def pair_programs(dispatches: List[Phase], programs: Iterable[Program],
+                  anchors: Dict[int, List]) -> List[Pair]:
+    """Every ``engine.dispatch`` with the step program it launched: the
+    one whose ``DoEnqueueProgram`` (``anchors``, by ``run_id``) began at
+    or after that dispatch began and before the next one did, both on the
+    host's clock.  The small programs a build runs (``jit__ids_program``)
+    are no step programs and pair with nothing.  A dispatch with no step
+    program or more than one in its slot (the trace's edges) is left
+    out."""
+    disp = sorted(dispatches, key=lambda p: p[1])
+    progs = sorted(
+        ((anchors[p[3]][0], p) for p in programs
+         if p[0] in trace_reduce.STEP_PROGRAMS
+         and anchors.get(p[3], (None,))[0] is not None),
+        key=lambda ep: ep[0])
+    out: List[Pair] = []
+    m = 0
+    for i, d in enumerate(disp):
+        nxt = disp[i + 1][1] if i + 1 < len(disp) else float("inf")
+        while m < len(progs) and progs[m][0] < d[1]:
+            m += 1
+        mine = []
+        while m < len(progs) and progs[m][0] < nxt:
+            mine.append(progs[m][1])
+            m += 1
+        if len(mine) == 1:
+            out.append((d, mine[0]))
+    return out
+
+
+def launches_by_number(pairs: Iterable[Pair], waits: Iterable[Phase]
+                       ) -> List[Tuple[float, float, float, float]]:
+    """``(dispatch start, wait end, program start, program end)`` for
+    every paired dispatch whose OWN ``engine.device_wait`` is in the
+    trace: the one that carries the same ``launch`` number.  In a step
+    that ran ahead that wait comes a step later, after the next
+    dispatch."""
+    by_launch = {int(w[3]["launch"]): w for w in waits if "launch" in w[3]}
+    out = []
+    for d, prog in pairs:
+        w = by_launch.get(int(d[3].get("launch", -1)))
+        if w is not None:
+            out.append((d[1], w[2], prog[1], prog[2]))
+    return out
+
+
 def match_launches(modules: List[trace_reduce.Event], phases: List[Phase],
-                   runs: Iterable[Tuple] = (),
+                   runs: Iterable[Program] = (),
                    anchors: Optional[Dict[int, List]] = None) -> List[Tuple]:
     """Pair every ``engine.dispatch`` with the program it launched and the
     ``engine.device_wait`` that waited for it (one engine thread, one
     program a dispatch).  Returns ``(dispatch start, wait end, program
     start, program end)``, each in its own plane's clock.
 
-    Where the runtime's enqueue events are in the trace the pairing needs
-    no clock at all: the program of a dispatch is the one (``runs``:
-    program start, end, run id) whose enqueue (``anchors``) began between
-    that dispatch's start and the next one's, both on the host's clock.
-    Otherwise it goes by time, trusting the profiler's alignment to 5 ms:
-    the program that starts between a dispatch and the next.  A dispatch
-    with no program or more than one in its slot (the trace's edges) is
-    left out."""
+    Where the phases carry the launch's number (``launch=<n>``, PR 35 on)
+    a dispatch's wait is the one with ITS number, and its program the step
+    program of its slot (:func:`pair_programs`, :func:`launches_by_number`).
+
+    A trace from before the numbers pairs as it always did, and reads what
+    it read: the wait is the first one after the dispatch.  Where the
+    runtime's enqueue events are in the trace that pairing needs no clock
+    at all: the program of a dispatch is the one (``runs``) whose enqueue
+    (``anchors``) began between that dispatch's start and the next one's,
+    both on the host's clock.  Otherwise it goes by time, trusting the
+    profiler's alignment to 5 ms: the program that starts between a
+    dispatch and the next.  A dispatch with no program or more than one in
+    its slot (the trace's edges) is left out."""
     disp = [p for p in phases if p[0] == DISPATCH]
     waits = [p for p in phases if p[0] == DEVICE_WAIT]
+    if is_numbered(phases):
+        return launches_by_number(pair_programs(disp, runs, anchors or {}),
+                                  waits)
     # (what orders a program against the dispatches, its start, its end)
-    progs = sorted((anchors[rid][0], start, end) for start, end, rid in runs
+    progs = sorted((anchors[rid][0], start, end) for _, start, end, rid in runs
                    if anchors and anchors.get(rid, (None,))[0] is not None)
     slack = 0.0
     if not progs:
@@ -342,6 +425,50 @@ def offset_bounds(launches: List[Tuple], runs: Iterable[Tuple] = (),
     return max(los), min(his)
 
 
+def pin_launches(launches: List[Tuple], programs: Iterable[Program],
+                 anchors: Optional[Dict[int, List]]
+                 ) -> Optional[Tuple[float, float]]:
+    """``(offset, width)``: the middle of :func:`offset_bounds` over the
+    matched ``launches`` and every program's runtime anchors, and how far
+    causality leaves it open.  ``None`` where nothing bounds it from both
+    sides."""
+    bounds = offset_bounds(
+        launches, [(s, e, rid) for _, s, e, rid in programs
+                   if rid is not None], anchors)
+    if bounds is None:
+        return None
+    lo, hi = bounds
+    return (lo + hi) / 2.0, hi - lo
+
+
+def pin_offset(pairs: Iterable[Pair], waits: Iterable[Phase],
+               programs: Iterable[Program], anchors: Dict[int, List]
+               ) -> Optional[Tuple[float, float]]:
+    """``(offset, width)``: what to ADD to host times to get device
+    times, and how far causality leaves it open, from the launches
+    matched BY NUMBER and every program's runtime anchors."""
+    return pin_launches(launches_by_number(pairs, waits), programs, anchors)
+
+
+def by_ahead(phases: Iterable[Phase]) -> List[Phase]:
+    """The phases with ``engine.dispatch`` renamed ``engine.dispatch.ahead``
+    where it carries ``ahead=1``, and ``engine.device_wait`` renamed the
+    same way where the dispatch of ITS launch (same ``launch`` number) did:
+    idle time under those is a launch that ran ahead and still came late,
+    not the round trip of a launch the step waited for.  A trace without
+    the integers comes back as it is."""
+    phases = list(phases)
+    ahead = {int(p[3]["launch"]) for p in phases
+             if p[0] == DISPATCH and "launch" in p[3]
+             and int(p[3].get("ahead", 0))}
+    if not ahead:
+        return phases
+    return [(p[0] + AHEAD,) + tuple(p[1:])
+            if p[0] in (DISPATCH, DEVICE_WAIT)
+            and int(p[3].get("launch", -1)) in ahead else p
+            for p in phases]
+
+
 def scope_seconds(ops: Iterable[trace_reduce.Event],
                   scopes: Dict[str, str]) -> Dict[str, float]:
     """Device seconds by scope.  ``scopes`` names the operations whose
@@ -383,25 +510,30 @@ def analyse(planes: Dict, phases: List[Phase],
     n = len(planes)
     out = {"gaps": {}, "scope_s": {}, "launches": 0.0, "gap_s": 0.0,
            "ops_s": 0.0, "offset_s": 0.0, "offset_width_s": 0.0,
-           "matched": 0}
+           "matched": 0, "numbered": is_numbered(phases)}
+    split = by_ahead(phases)
     for name, rows in planes.items():
         mine = (runs or {}).get(name, ())
         launches = match_launches(rows["modules"], phases, mine, anchors)
-        bounds = offset_bounds(launches, mine, anchors)
-        if bounds is None:
+        pinned = pin_launches(launches, mine, anchors)
+        if pinned is None:
             return None         # no launch whole inside the trace
-        lo, hi = bounds
-        offset = (lo + hi) / 2.0
+        offset, width = pinned
         gaps = module_gaps(rows["modules"])
-        for k, v in attribute(gaps, phases, offset).items():
+        for k, v in attribute(gaps, split, offset).items():
             out["gaps"][k] = out["gaps"].get(k, 0.0) + v / n
         for k, v in scope_seconds(rows["ops"], scopes.get(name, {})).items():
             out["scope_s"][k] = out["scope_s"].get(k, 0.0) + v / n
-        out["launches"] += len(rows["modules"]) / n
+        out["launches"] += sum(
+            1 for m in rows["modules"]
+            if trace_reduce.norm(m[0]) in trace_reduce.STEP_PROGRAMS) / n
         out["gap_s"] += sum(b - a for a, b in gaps) / n
         out["ops_s"] += sum(e[2] for e in rows["ops"]) / n
         out["offset_s"] += offset / n
-        out["offset_width_s"] += (hi - lo) / n
+        # over the chips the width farthest from nought: one chip's
+        # broken pin must not hide in a mean
+        if abs(width) > abs(out["offset_width_s"]):
+            out["offset_width_s"] = width
         out["matched"] += len(launches)
     fetches = [p for p in phases if p[0] == FETCH]
     out["fetch_bytes"] = sum(int(p[3].get("bytes", 0)) for p in fetches)
@@ -445,7 +577,25 @@ def analysis(trace: Optional[Dict], root: str = harness.ROOT
             print("benchmark: host_spans could not read the trace:\n"
                   + traceback.format_exc(), file=sys.stderr)
             _CACHE[key] = None
+        if _CACHE[key] is not None and offset_width_ms(_CACHE[key]) is None:
+            print("benchmark: host_spans: engine.gap_offset_width_ms is not "
+                  "reported, and the engine.gap_* of this run are put down "
+                  "to the wrong phases by as much: the host plane's offset "
+                  f"is left {1e3 * _CACHE[key]['offset_width_s']:.3f} ms "
+                  f"open (negative, or over {1e3 * MAX_WIDTH_S:g} ms)",
+                  file=sys.stderr)
     return _CACHE[key]
+
+
+def offset_width_ms(a: Dict) -> Optional[float]:
+    """What causality leaves open for the host plane's offset, in ms.  In
+    a trace whose launches are paired by number the pin is sound only
+    where that is positive and at most :data:`MAX_WIDTH_S`: ``None``
+    otherwise.  A trace from before the numbers reads what it read."""
+    width = a["offset_width_s"]
+    if a.get("numbered") and not 0.0 <= width <= MAX_WIDTH_S:
+        return None
+    return 1e3 * width
 
 
 def gap_ms(trace: Optional[Dict], phase: str) -> Optional[float]:

@@ -12,10 +12,12 @@ Commands arrive as JSON lines on stdin, answers leave as lines starting
 
 ``{"cmd": "mark", "name": "open" | "close" | "trace"}``
     snapshot the program's counters now; ``trace`` runs the JAX profiler
-    for ``--trace-seconds`` (on an accelerator), snapshots before and
-    after, and reduces the ``.xplane.pb`` it leaves;
+    for ``--trace-seconds`` (on an accelerator) and snapshots before and
+    after; the ``.xplane.pb`` it leaves is read by the client, once this
+    process has stopped;
 ``{"cmd": "report"}``
-    everything gathered, as one JSON object; ``{"cmd": "quit"}``.
+    waits for the profiler, then everything gathered, as one JSON object;
+    ``{"cmd": "quit"}``.
 
 What is taken from the program: the system under test, its counters
 (``StepProfiler``, ``CacheStatTracker``'s pool, the serving registry) and
@@ -338,12 +340,19 @@ class Session:
         """The profiler, started and stopped from this thread.  Not through
         ``StepProfiler.arm_capture``: that holds the profiler's lock, which
         every engine step takes, across ``start_trace`` and ``stop_trace``,
-        and stalled serving for ~20 s (my chip run, PR 23)."""
+        and stalled serving for ~20 s (my chip run, PR 23).  The Python
+        tracer is off: no reader reads its events, and under it a host
+        chain of 256 rows is two to seven times too long, so the idle
+        share and every ``engine.gap_*`` ranked work an untraced server
+        does not have (PERF.md section 6, PR 42)."""
         import jax
 
         shutil.rmtree(self.trace_dir, ignore_errors=True)
         if self.device_trace:
-            jax.profiler.start_trace(self.trace_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.trace_dir,
+                                     profiler_options=options)
         # counters between the profiler's start and the call that stops it
         self.marks["trace0"] = self.probe.snapshot()
         time.sleep(self.trace_seconds)
@@ -359,15 +368,10 @@ class Session:
         if "open" in m and "close" in m:
             out["window"] = diff(m["open"], m["close"])
         if "trace0" in m and "trace1" in m:
+            # the trace itself is read by the client once this process has
+            # stopped: read here it was parsed beside an engine and a loop
+            # thread still serving the backlog (PERF.md section 6, PR 42)
             out["traced"] = diff(m["trace0"], m["trace1"])
-            from benchmarks import trace_reduce
-
-            path = trace_reduce.find_xplane(self.trace_dir)
-            if path is not None:
-                red = trace_reduce.reduce(trace_reduce.load(path))
-                if red is not None:
-                    out["trace"] = red
-                    out["breakdown"] = trace_reduce.breakdown(red)
         return out
 
 

@@ -1,4 +1,4 @@
-"""The ``bytes`` of ``engine.fetch`` (the logits a launch brings to the host), in 1e6
+"""The ``bytes`` of ``engine.fetch`` (what a launch copies to the host: its tokens since PR 30), in 1e6
 bytes per launch of the traced window."""
 from benchmarks import host_spans
 

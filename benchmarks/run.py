@@ -31,7 +31,8 @@ import threading    # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
-from benchmarks import harness, records, roofline, stats   # noqa: E402
+from benchmarks import (harness, records, roofline, stats,   # noqa: E402
+                        trace_reduce)
 
 READY_TIMEOUT_S = 1150.0    # a first run compiles; the contract allows 1200
 LATE_WARN_MS = 250.0        # every run but one sent within 22 ms (PERF.md)
@@ -120,7 +121,7 @@ def client_counters(run: dict) -> dict:
     gaps = [g for t in win for g in stats.token_gaps_ms(t)]
     tpot = [v for v in (stats.tpot_ms(t) for t in win) if v is not None]
     shape = {f"itl_p{q}_ms": stats.percentile(gaps, q)
-             for q in (50, 90, 95, 97, 98, 99, 99.5, 99.9)}
+             for q in (50, 90, 95, 97, 98, 99, 99.5, 99.8, 99.9)}
     shape.update(gaps=len(gaps), tpot_p50_ms=stats.percentile(tpot, 50),
                  tpot_p90_ms=stats.percentile(tpot, 90),
                  ttft_p75_ms=stats.percentile(ttft, 75),
@@ -154,6 +155,15 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         report = child.ask({"cmd": "report"}, "report", 300.0)
     finally:
         child.stop()
+    t_stopped = time.perf_counter()
+    if trace:
+        # read once, here, after the child has gone: every reader below
+        # is given the same parse (``trace_reduce.once_a_file``)
+        path = trace_reduce.find_xplane(os.path.join(root, ".bench_trace"))
+        red = trace_reduce.reduce(trace_reduce.load(path)) if path else None
+        if red is not None:
+            report["trace"] = red
+            report["breakdown"] = trace_reduce.breakdown(red)
 
     if records_path:
         records.write(records_path, run, {"workload": workload, "seed": seed,
@@ -198,6 +208,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         if red is not None:
             device["busy_s"], device["window_s"] = red["busy_s"], red["window_s"]
+        print(f"benchmark: traced run: {t_stopped - run['t_close']:.1f} s from "
+              f"the window's close until the server had stopped, "
+              f"{time.perf_counter() - t_stopped:.1f} s reading the trace",
+              file=sys.stderr)
     line = {"correct": bool(ready["check"]["ok"]) and not failed
             and bool(run["complete"]),
             "attempted": len(ended), "failed": len(failed),

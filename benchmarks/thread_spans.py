@@ -17,15 +17,16 @@ things on the profiler's clock (``paddle_tpu/observability/tracer.py``
 The profiler keeps one line a thread, so this module keeps the lines apart
 (:func:`load_lines`): the engine thread's line is the one that holds
 ``engine.dispatch``, the loop thread's the one that holds ``server.wake``.
-A step program (:data:`STEP_PROGRAMS`) is paired with its
+A step program (``trace_reduce.STEP_PROGRAMS``) is paired with its
 ``engine.dispatch`` by the runtime's ``DoEnqueueProgram`` of the same
 ``run_id`` beginning inside that dispatch's slot on the HOST's clock, which
-needs no offset between the planes (:func:`pair_programs`); the idle gaps
+needs no offset between the planes (``host_spans.pair_programs``); the idle gaps
 are ``host_spans.module_gaps`` over ALL programs, the ones
 ``engine.host_ms_per_step`` sums.  Where host spans are laid over device
 gaps the host plane is shifted by an offset pinned from the runtime's
 ``run_id`` anchors and from dispatch / wait pairs matched BY ``launch``
-(:func:`pin_offset`) -- never by "the next wait", which since the loop
+(``host_spans.pin_offset``, which pins the ``engine.gap_*`` too) -- never
+by "the next wait", which since the loop
 runs ahead is another launch's; where what causality leaves open is
 negative or wider than :data:`MAX_WIDTH_S` the overlap is not reported,
 and standard error says so.
@@ -49,10 +50,12 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))       # when run as a script
 
 from benchmarks import harness, host_spans, trace_reduce    # noqa: E402
-from benchmarks.host_spans import Interval, Phase           # noqa: E402
-
-Program = Tuple[str, float, float, int]     # name, start, end, run id
-Pair = Tuple[Phase, Program]                # a dispatch and its program
+# the pairing and the pin are ``host_spans``'s: one implementation for the
+# ``engine.gap_*`` and for the overlap with the loop thread's spans
+from benchmarks.host_spans import (                          # noqa: E402
+    MAX_WIDTH_S, Interval, Pair, Phase, Program, launches_by_number,
+    pair_programs, pin_offset)
+from benchmarks.trace_reduce import STEP_PROGRAMS           # noqa: E402
 
 SETTLE = "ahead.settle"
 ACCEPT, WAKE, WRITE = "server.accept", "server.wake", "server.write"
@@ -66,12 +69,7 @@ SETTLE_REASONS = ("prefill", "admit", "preempt", "finish", "audit", "fault",
                   "task", "bare", "family")
 NO_SETTLE = "none"      # not ahead, and no settle before it: nothing flew
 FRONT_DOOR = (ACCEPT, WAKE, WRITE)
-STEP_PROGRAMS = ("jit__decode_fn", "jit__prefill_fn",
-                 "jit__chunk_prefill_fn", "jit__unified_fn",
-                 "jit__burst_fn")
 DECODE = "jit__decode_fn"
-MAX_WIDTH_S = 0.002     # an offset left wider open than this resolves
-                        # no span of the loop thread (tens of microseconds)
 
 
 def load_lines(path: str) -> Tuple[List[List[Phase]], Dict[int, List],
@@ -138,69 +136,6 @@ def line_of(lines: Iterable[List[Phase]], name: str) -> List[Phase]:
         if n > most:
             best, most = line, n
     return best
-
-
-def pair_programs(dispatches: List[Phase], programs: Iterable[Program],
-                  anchors: Dict[int, List]) -> List[Pair]:
-    """Every ``engine.dispatch`` with the step program it launched: the
-    one whose ``DoEnqueueProgram`` (``anchors``, by ``run_id``) began at
-    or after that dispatch began and before the next one did, both on the
-    host's clock.  The small programs a build runs (``jit__ids_program``)
-    are no step programs and pair with nothing.  A dispatch with no step
-    program or more than one in its slot (the trace's edges) is left
-    out."""
-    disp = sorted(dispatches, key=lambda p: p[1])
-    progs = sorted(
-        ((anchors[p[3]][0], p) for p in programs
-         if p[0] in STEP_PROGRAMS and anchors.get(p[3], (None,))[0]
-         is not None), key=lambda ep: ep[0])
-    out: List[Pair] = []
-    m = 0
-    for i, d in enumerate(disp):
-        nxt = disp[i + 1][1] if i + 1 < len(disp) else float("inf")
-        while m < len(progs) and progs[m][0] < d[1]:
-            m += 1
-        mine = []
-        while m < len(progs) and progs[m][0] < nxt:
-            mine.append(progs[m][1])
-            m += 1
-        if len(mine) == 1:
-            out.append((d, mine[0]))
-    return out
-
-
-def launches_by_number(pairs: Iterable[Pair], waits: Iterable[Phase]
-                       ) -> List[Tuple[float, float, float, float]]:
-    """``(dispatch start, wait end, program start, program end)`` for
-    every paired dispatch whose OWN ``engine.device_wait`` is in the
-    trace: the one that carries the same ``launch`` number.  In a step
-    that ran ahead that wait comes a step later, after the next
-    dispatch."""
-    by_launch = {int(w[3]["launch"]): w for w in waits if "launch" in w[3]}
-    out = []
-    for d, prog in pairs:
-        w = by_launch.get(int(d[3].get("launch", -1)))
-        if w is not None:
-            out.append((d[1], w[2], prog[1], prog[2]))
-    return out
-
-
-def pin_offset(pairs: Iterable[Pair], waits: Iterable[Phase],
-               programs: Iterable[Program], anchors: Dict[int, List]
-               ) -> Optional[Tuple[float, float]]:
-    """``(offset, width)``: what to ADD to host times to get device
-    times, and how far causality leaves it open
-    (``host_spans.offset_bounds`` over the launches matched by number and
-    every program's runtime anchors).  ``None`` where nothing bounds it
-    from both sides."""
-    bounds = host_spans.offset_bounds(
-        launches_by_number(pairs, waits),
-        [(s, e, rid) for _, s, e, rid in programs if rid is not None],
-        anchors)
-    if bounds is None:
-        return None
-    lo, hi = bounds
-    return (lo + hi) / 2.0, hi - lo
 
 
 def ahead_share(pairs: Iterable[Pair]) -> Optional[float]:
@@ -282,10 +217,13 @@ def window_of(lines: Iterable[List[Phase]]) -> float:
 def handoffs_s(engine_line: Iterable[Phase], loop_line: Iterable[Phase]
                ) -> List[float]:
     """For every ``server.wake`` that has token-bearing writes before the
-    next wake: the end of the LAST of those ``server.write`` minus the end
-    of the engine thread's stream hand-off that posted the wake (the last
-    ``engine.emit`` carrying ``streams=`` that began before the wake
-    did): how long a token the engine has waits for the socket.  A write
+    next wake: the end of the LAST of those ``server.write`` minus the
+    START of the engine thread's stream hand-off that posted the wake (the
+    last ``engine.emit`` carrying ``streams=`` that began before the wake
+    did): how long a token the engine has waits for the socket.  From the
+    span's start, not its end: the wake is posted INSIDE the span, and on a
+    busy machine the loop thread has written the chunks before the engine
+    thread gets to close it, which read a hand-off below nought (PR 42).  A write
     with ``tokens=0`` (a new stream's header and id chunk, a final chunk)
     is no token's and is not counted: a header follows an accept, not a
     wake."""
@@ -310,7 +248,7 @@ def handoffs_s(engine_line: Iterable[Phase], loop_line: Iterable[Phase]
             last = writes[w]
             w += 1
         if last is not None and emits and emits[e][1] <= wake[1]:
-            out.append(last[2] - emits[e][2])
+            out.append(last[2] - emits[e][1])
     return out
 
 

@@ -1,7 +1,25 @@
 """The harness is driven by data: every name in BENCHMARK.json resolves to
 a file of its own, a later PR adds a cell by adding files only, the
 reference agrees with the model, and the command line measures on a TPU or
-not at all."""
+not at all.
+
+**The append contract, stated once for every test in this directory.**  A
+later PR grows the benchmark by APPENDING and edits nothing that is there:
+a configuration goes to the end of ``configs``; a cell to the end of
+``workloads`` and to the END of every shared list it reports (an
+end-to-end metric's ``workloads``, a per-layer entry's); per-layer entries
+to the END of ``per_layer``.  So a test written for one PR's additions may
+pin their order among themselves and against what was accepted BEFORE them
+(the names that stand before them, by name), and membership; it never
+asserts that they are last (``[-1]``, ``[-n:]``) nor a count of names over
+``configs``, ``workloads``, ``per_layer`` or a metric's ``workloads``: the
+next PR's entries would fail it, and that PR may not edit this directory.
+Each file's checks of the benchmark's entries are functions of a benchmark
+given as a ``dict``, named ``check_*`` and taking ``bench`` alone: a later
+PR's test file writes its own so.  :func:`test_the_tests_take_an_append`
+finds them in every ``test_bm_*.py`` here and runs them all on
+``BENCHMARK.json`` as it is, on a copy a later PR could have made, and on
+copies with a fault planted."""
 
 import io
 import json
@@ -28,29 +46,26 @@ TINY = {"source": "test", "hidden_size": 64, "intermediate_size": 128,
                   "atol": 0.05, "rms_rel": 0.05}}
 
 
-@pytest.mark.parametrize("m", BENCH["per_layer"], ids=lambda m: m["name"])
-def test_per_layer_metric_is_a_file_that_agrees_with_its_entry(m):
+def check_per_layer_entry(bench, m):
     mod = harness.load_reader(m["name"])
     assert (mod.UNIT, mod.LAYER, mod.SOURCE) == \
         (m["unit"], m["layer"], m["source"])
-    moved = [e for e in BENCH["end_to_end"] if e["name"] == m["moves"]][0]
-    cells = set(m.get("workloads") or [w["name"] for w in BENCH["workloads"]])
+    moved = [e for e in bench["end_to_end"] if e["name"] == m["moves"]][0]
+    cells = set(m.get("workloads") or [w["name"] for w in bench["workloads"]])
     assert cells <= set(moved.get("workloads")
-                        or [w["name"] for w in BENCH["workloads"]])
+                        or [w["name"] for w in bench["workloads"]])
     assert callable(mod.read)
 
 
-@pytest.mark.parametrize("m", BENCH["end_to_end"], ids=lambda m: m["name"])
-def test_end_to_end_metric_is_a_file(m):
+def check_end_to_end_entry(bench, m):
     assert callable(harness.load_module("e2e_metrics", m["name"]).compute)
     assert 0.01 <= m["bound"] <= 0.1 and m["source"] == "host_clock"
 
 
-@pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
-def test_cell_resolves_every_piece(w):
-    cell = harness.Cell(w["name"])
+def check_cell(bench, w):
+    cell = harness.Cell(w["name"], bench=bench)
     assert cell.config["reduced"].keys() == set(
-        [c for c in BENCH["configs"] if c["name"] == w["config"]][0]["reduced"])
+        [c for c in bench["configs"] if c["name"] == w["config"]][0]["reduced"])
     for kind, name in (("traffic_kinds", cell.traffic["kind"]),
                        ("models", cell.config["builder"]),
                        ("reference", cell.config["reference"])):
@@ -60,6 +75,174 @@ def test_cell_resolves_every_piece(w):
     lim = harness.traffic_limits(cell.traffic)
     pool = (cell.config["engine"]["num_blocks"] - 1) * 16
     assert lim["max_total"] <= min(pool, cell.config["max_position_embeddings"])
+
+
+def check_every_reader_has_an_entry(bench):
+    """Every ``layer_metrics/*.py`` reads some entry: the entry of its
+    name, or entries of its name with a group of cells for a suffix."""
+    files = {f[:-3] for f in os.listdir(os.path.join(harness.HERE,
+                                                     "layer_metrics"))
+             if f.endswith(".py")}
+    names = {m["name"] for m in bench["per_layer"]}
+    stems = names | {n.rpartition(".")[0] for n in names}
+    assert files <= stems, sorted(files - stems)
+
+
+@pytest.mark.parametrize("m", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_per_layer_metric_is_a_file_that_agrees_with_its_entry(m):
+    check_per_layer_entry(BENCH, m)
+
+
+@pytest.mark.parametrize("m", BENCH["end_to_end"], ids=lambda m: m["name"])
+def test_end_to_end_metric_is_a_file(m):
+    check_end_to_end_entry(BENCH, m)
+
+
+@pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
+def test_cell_resolves_every_piece(w):
+    check_cell(BENCH, w)
+
+
+def test_no_reader_is_left_without_its_entry():
+    check_every_reader_has_an_entry(BENCH)
+
+
+# --- the tests of this directory take an append -------------------------------
+
+LATER = "later-model.batch-decode"
+
+
+def appended(bench):
+    """``bench`` as a later ``model_config`` PR would leave it: one more
+    configuration (an existing file under another name), its cell last in
+    ``workloads``, in ``tokens_per_s`` and in every list the batch cells
+    share, and one more per-layer entry at the end."""
+    out = json.loads(json.dumps(bench))
+    batch = [m for m in out["end_to_end"]
+             if m["name"] == "tokens_per_s"][0]["workloads"]
+    like = [w for w in out["workloads"] if w["name"] == batch[0]][0]
+    cfg = [c for c in out["configs"] if c["name"] == like["config"]][0]
+    out["configs"].append(dict(cfg, name="later-model"))
+    out["workloads"].append(dict(like, name=LATER, config="later-model"))
+    every = set(batch)
+    for m in out["end_to_end"] + out["per_layer"]:
+        if every <= set(m.get("workloads", ())):
+            m["workloads"].append(LATER)
+    out["per_layer"].append({
+        "name": "device.idle_share.later", "unit": "%", "better": "lower",
+        "source": "device_trace", "layer": "device", "moves": "tokens_per_s",
+        "workloads": [LATER]})
+    return out
+
+
+def moved_before(bench, name, before, rows=True, lists=True):
+    """``bench`` with the cell ``name`` taken out and put back BEFORE the
+    cell ``before``: in ``workloads`` (``rows``) and in every list that
+    holds both (``lists``)."""
+    out = json.loads(json.dumps(bench))
+
+    def move(names, items):
+        if name in names and before in names:
+            item = items.pop(names.index(name))
+            names.remove(name)
+            items.insert(names.index(before), item)
+
+    if rows:
+        move([w["name"] for w in out["workloads"]], out["workloads"])
+    for m in (out["end_to_end"] + out["per_layer"]) if lists else ():
+        move(list(m.get("workloads", ())), m.get("workloads"))
+    return out
+
+
+def hold_to_the_contract(bench):
+    """Every file's checks of the benchmark's entries, on ``bench``: each
+    ``test_bm_*.py`` of this directory, a later PR's too, is held to the
+    contract through its functions named ``check_*`` that take the
+    benchmark alone; this file's checks of one entry run over them all."""
+    import glob
+    import importlib
+    import inspect
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    found = []
+    for path in sorted(glob.glob(os.path.join(here, "test_bm_*.py"))):
+        mod = importlib.import_module(os.path.basename(path)[:-3])
+        for name, fn in sorted(vars(mod).items()):
+            if name.startswith("check_") and inspect.isfunction(fn) \
+                    and fn.__module__ == mod.__name__ \
+                    and list(inspect.signature(fn).parameters) == ["bench"]:
+                fn(bench)
+                found.append(f"{mod.__name__}.{name}")
+    assert {"test_bm_window_moe.check_cell_entries",
+            "test_bm_thread_spans.check_the_twelve",
+            "test_bm_harness.check_every_reader_has_an_entry"} <= set(found)
+    for m in bench["per_layer"]:
+        check_per_layer_entry(bench, m)
+    for m in bench["end_to_end"]:
+        check_end_to_end_entry(bench, m)
+    for w in bench["workloads"]:
+        check_cell(bench, w)
+
+
+def _swap_two_of_the_seven(bench):
+    out = json.loads(json.dumps(bench))
+    names = [m["name"] for m in out["per_layer"]]
+    i = names.index("programs.window_attn_share")
+    out["per_layer"].insert(i - 2, out["per_layer"].pop(i))
+    return out
+
+
+def _later_entry_among_the_twelve(bench):
+    out = json.loads(json.dumps(bench))
+    names = [m["name"] for m in out["per_layer"]]
+    out["per_layer"].insert(names.index("frontdoor.handoff_ms.chat"),
+                            out["per_layer"].pop())
+    return out
+
+
+def _later_config_before_pr36s(bench):
+    out = json.loads(json.dumps(bench))
+    out["configs"].insert(4, out["configs"].pop())
+    return out
+
+
+FAULTS = {
+    # the later cell put BEFORE PR 36's, in ``workloads`` and in the lists
+    "cell-inserted-before-an-accepted-one": lambda b: moved_before(
+        b, LATER, "command-a-plus-05-2026.doc-reasoning-decode"),
+    # ... in ``workloads`` alone: the shared lists then disagree with it
+    "cell-inserted-in-workloads-alone": lambda b: moved_before(
+        b, LATER, "command-a-plus-05-2026.doc-reasoning-decode", lists=False),
+    # ... in the shared lists alone
+    "cell-inserted-in-the-lists-alone": lambda b: moved_before(
+        b, LATER, "command-a-plus-05-2026.doc-reasoning-decode", rows=False),
+    "one-of-pr36s-seven-moved": _swap_two_of_the_seven,
+    "entry-inserted-among-pr39s-twelve": _later_entry_among_the_twelve,
+    "configuration-inserted-before-an-accepted-one": _later_config_before_pr36s,
+}
+
+
+def test_the_tests_take_an_append():
+    """Pure JSON: the structural checks of every file pass on the benchmark
+    as it is and on a copy with a later PR's configuration, cell and entry
+    appended; nothing that was there has moved in the copy."""
+    hold_to_the_contract(BENCH)
+    later = appended(BENCH)
+    hold_to_the_contract(later)
+    for group in ("configs", "workloads", "per_layer", "end_to_end"):
+        old = [m["name"] for m in BENCH[group]]
+        assert [m["name"] for m in later[group]][:len(old)] == old
+    for was, now in zip(BENCH["end_to_end"] + BENCH["per_layer"],
+                        later["end_to_end"] + later["per_layer"]):
+        ws = was.get("workloads", [])
+        assert now.get("workloads", [])[:len(ws)] == ws
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_a_planted_fault_in_the_appended_copy_fails(fault):
+    broken = FAULTS[fault](appended(BENCH))
+    with pytest.raises((AssertionError, ValueError)):
+        hold_to_the_contract(broken)
 
 
 def test_unknown_names_are_errors():
@@ -289,7 +472,7 @@ def test_a_later_pr_adds_a_cell_by_adding_files_only(tmp_path):
             m["workloads"].append(name)
     bench["per_layer"].append({"name": "dummy.prompt_tokens", "unit": "tokens",
                                "better": "higher", "source": "program_counter",
-                               "layer": "scheduler", "moves": "itl_p995_ms",
+                               "layer": "scheduler", "moves": "tpot_p50_ms",
                                "workloads": [name]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
@@ -304,14 +487,18 @@ def test_a_later_pr_adds_a_cell_by_adding_files_only(tmp_path):
         lines[trace] = json.loads(out.getvalue().strip().splitlines()[-1])
     e2e, layer = lines[False], lines[True]
     assert e2e["correct"] and e2e["failed"] == 0 and e2e["attempted"] == 24
-    assert set(e2e["metrics"]) == {"tpot_p50_ms", "itl_p995_ms", "setup_s"}
+    assert set(e2e["metrics"]) == {"tpot_p50_ms", "setup_s"}
     assert all(v["value"] > 0 for v in e2e["metrics"].values())
     assert e2e["device"]["platform"] == "cpu"      # never a device metric
     # the window's records, written on request, give the same metrics back
     rec = records.load(records.read(os.path.join(root, "rec", "r.json.gz")))
-    for m in ("tpot_p50_ms", "itl_p995_ms"):
+    for m in ("tpot_p50_ms",):
         again = harness.load_module("e2e_metrics", m).compute(rec)
         assert again == pytest.approx(e2e["metrics"][m]["value"], abs=2e-3)
+    # the tail of the gaps is per-layer since PR 42: in the traced line, as
+    # the client prints it
+    assert layer["metrics"]["frontdoor.itl_p995_ms"]["value"] == \
+        layer["detail"]["client"]["shape"]["itl_p99.5_ms"] > 0
     # the TTFT tail is per-layer since PR 27: in the traced line, and among
     # what the client prints in both
     assert layer["metrics"]["frontdoor.ttft_p90_ms"]["value"] == \

@@ -1,6 +1,8 @@
 """``benchmarks/host_spans.py`` and the readers on it: hand-made planes
 (every number below is counted by hand), a hand-encoded ``.xplane.pb`` for
-the metadata parser, and one small recorded v5e trace.  No TPU library."""
+the metadata parser, one small recorded v5e trace from before the loop ran
+ahead, and a hand-made trace of a loop that does (``run_of`` of
+``test_bm_thread_spans.py``).  No TPU library."""
 
 import json
 import os
@@ -351,3 +353,152 @@ def test_recorded_trace_reads_the_recorded_numbers():
     assert set(hs.SCOPES) <= set(a["scope_s"])
     assert a["scope_s"]["sampler"] >= want["sort_s"] > 0.0
     assert a["gaps"]["unattributed"] <= 0.05 * a["gap_s"]
+
+
+# --- a loop that runs ahead: launches paired by number, steps counted ---------
+
+def run_ahead(skew=0.0, numbered=True, slack=0.0):
+    """``test_bm_thread_spans.run_of`` as ``host_spans.analyse`` takes it:
+    five step programs and the small ids program on one device plane, the
+    engine thread's phases (``ahead.settle`` is no phase of the step), the
+    runtime's anchors.  ``numbered=False`` takes ``launch`` and ``ahead``
+    off the phases: the trace as a program from before PR 35 would have
+    written it, were its loop to run ahead.  ``slack``: every program
+    starts that much after the host handed it over and ends that much
+    before the host heard of it, as on a chip."""
+    from test_bm_thread_spans import run_of
+
+    engine, programs, anchors = run_of(skew)
+    programs = [(n, a + slack, b - slack, rid) for n, a, b, rid in programs]
+    phases = [p for p in engine if p[0].startswith(hs.PHASE_PREFIXES)]
+    if not numbered:
+        phases = [(n, a, b, {k: v for k, v in st.items()
+                             if k not in ("launch", "ahead")})
+                  for n, a, b, st in phases]
+    rows = {"modules": [(n, a, b - a) for n, a, b, _ in programs], "ops": []}
+    return ({"/device:TPU:0": rows}, phases, {},
+            {"/device:TPU:0": programs}, anchors)
+
+
+@pytest.mark.parametrize("skew", [0.0, 7.0, -3.5])
+def test_a_dispatch_is_paired_with_its_own_wait(skew):
+    planes, phases, scopes, runs, anchors = run_ahead(skew)
+    mods = planes["/device:TPU:0"]["modules"]
+    got = hs.match_launches(mods, phases, runs["/device:TPU:0"], anchors)
+    # launch 2 went out ahead: its own wait ends with its program at 2.25,
+    # the NEXT wait after its dispatch is launch 1's and ends at 1.0
+    assert (0.5 + skew, 2.25 + skew, 1.25, 2.25) in got
+    assert len(got) == 4            # launch 5's wait is past the trace's end
+    a = hs.analyse(planes, phases, scopes, runs, anchors)
+    assert a["numbered"] and a["matched"] == 4
+    assert a["offset_s"] == pytest.approx(-skew)
+    assert a["offset_width_s"] == pytest.approx(0.0, abs=1e-9)
+    assert hs.offset_width_ms(a) == pytest.approx(0.0, abs=1e-6)
+    # with 0.2 ms between the host's events and the device's on either
+    # side the width is what causality leaves: positive, 0.4 ms
+    planes, phases, scopes, runs, anchors = run_ahead(skew, slack=0.0002)
+    a = hs.analyse(planes, phases, scopes, runs, anchors)
+    assert a["offset_s"] == pytest.approx(-skew)
+    assert hs.offset_width_ms(a) == pytest.approx(0.4)
+    # the same trace paired with the FIRST wait: negative, by a program
+    old = hs.analyse(*run_ahead(skew, numbered=False, slack=0.0002))
+    assert hs.offset_width_ms(old) == pytest.approx(0.4 - 1250.0)
+
+
+def test_the_first_wait_pairing_reads_a_negative_width_on_the_same_trace():
+    planes, phases, scopes, runs, anchors = run_ahead(numbered=False)
+    a = hs.analyse(planes, phases, scopes, runs, anchors)
+    # launch 2 with launch 1's wait: "the wait ends after the program
+    # ended" asks 2.25 - 1.0 of the offset, the anchors allow 0 at most
+    assert not a["numbered"]
+    assert a["offset_width_s"] == pytest.approx(0.0 - 1.25)
+    # such a trace reads what it read: the width is reported as it is
+    assert hs.offset_width_ms(a) == pytest.approx(-1250.0)
+    # the same width on a NUMBERED trace is no reading
+    assert hs.offset_width_ms(dict(a, numbered=True)) is None
+    assert hs.offset_width_ms(dict(a, numbered=True,
+                                   offset_width_s=0.0021)) is None
+    assert hs.offset_width_ms(dict(a, numbered=True, offset_width_s=0.0004)) \
+        == pytest.approx(0.4)
+
+
+def test_a_launch_is_a_step_program_and_the_ids_program_is_none():
+    planes, phases, scopes, runs, anchors = run_ahead()
+    red = tr.reduce(planes)
+    a = hs.analyse(planes, phases, scopes, runs, anchors)
+    assert red["modules"]["jit__ids_program"]["count"] == 1
+    assert sum(m["count"] for m in red["modules"].values()) == 6
+    assert red["launches"] == a["launches"] == 5
+    # the small program stays in the gaps under its name, and the idle
+    # seconds are what they were: 0.25 + 0.75 + 0.5 + 0.5 + 0.15
+    assert red["gaps"]["jit__decode_fn_-__jit__ids_program"] == \
+        pytest.approx(0.5)
+    assert red["gaps"]["jit__ids_program_-__jit__decode_fn"] == \
+        pytest.approx(0.15)
+    assert red["gap_s"] == pytest.approx(a["gap_s"]) == pytest.approx(2.15)
+    host = harness.load_reader("engine.host_ms_per_step.batch")
+    assert host.read({}, red) == pytest.approx(1e3 * 2.15 / 5)
+    assert set(tr.STEP_PROGRAMS) == {
+        "jit__decode_fn", "jit__prefill_fn", "jit__chunk_prefill_fn",
+        "jit__unified_fn", "jit__burst_fn"}
+
+
+def test_idle_time_goes_to_the_phase_the_thread_is_in_split_by_ahead(
+        monkeypatch):
+    planes, phases, scopes, runs, anchors = run_ahead()
+    a = hs.analyse(planes, phases, scopes, runs, anchors)
+    # gap 1-1.25: emit 1.0-1.05, then the wait for launch 2 (ahead) from
+    # 1.1; gap 2.25-3: dispatch 3 (not ahead) 2.9-3.0; gap 3.5-4: dispatch
+    # 4 (not ahead) 3.9-4.0; gap 5-5.5: dispatch 5 (AHEAD, and late)
+    # 5.4-5.5; gap 5.6-5.75: emit 5.6-5.65
+    assert a["gaps"] == pytest.approx({
+        "engine.emit": 0.05 + 0.05, "engine.device_wait.ahead": 0.15,
+        "engine.dispatch": 0.1 + 0.1, "engine.dispatch.ahead": 0.1,
+        "unattributed": 2.15 - 0.55})
+    assert sum(a["gaps"].values()) == pytest.approx(a["gap_s"])
+    monkeypatch.setattr(hs, "analysis", lambda trace, root=None: a)
+    # a launch's round trip, for the launches the step waited for
+    read = harness.load_reader("engine.gap_dispatch_ms.batch").read
+    assert read({}, {"busy_s": 1.0}) == pytest.approx(1e3 * 0.2 / 5)
+    # without the integers nothing is split (the recorded traces)
+    old = run_ahead(numbered=False)[1]
+    assert hs.by_ahead(old) == old
+    assert {p[0] for p in hs.by_ahead(phases)} == {
+        "engine.dispatch", "engine.dispatch.ahead", "engine.device_wait",
+        "engine.device_wait.ahead", "engine.emit"}
+
+
+def test_a_width_that_is_no_reading_says_so_on_standard_error(
+        tmp_path, capsys, monkeypatch):
+    planes, phases, scopes, runs, anchors = run_ahead()
+    anchors[12][1] = 1.0    # a completion "heard" before its program ended
+    a = hs.analyse(planes, phases, scopes, runs, anchors)
+    assert a["offset_width_s"] < 0 and hs.offset_width_ms(a) is None
+    trace_dir = tmp_path / ".bench_trace"
+    trace_dir.mkdir()
+    (trace_dir / "x.xplane.pb").write_bytes(b"")
+    monkeypatch.setattr(hs, "load", lambda path: a)
+    for _ in range(2):
+        assert hs.analysis({"busy_s": 1.0}, root=str(tmp_path)) is a
+    err = capsys.readouterr().err
+    assert err.count("engine.gap_offset_width_ms is not reported") == 1
+    assert "negative" in err
+
+
+def test_a_trace_is_parsed_once_a_file(tmp_path):
+    calls = []
+
+    @tr.once_a_file
+    def parse(path):
+        calls.append(path)
+        return {"n": len(calls)}
+
+    f = tmp_path / "a.xplane.pb"
+    f.write_bytes(b"one")
+    assert parse(str(f)) is parse(str(f)) and len(calls) == 1
+    f.write_bytes(b"another trace")         # a later run's file
+    assert parse(str(f)) == {"n": 2} and len(calls) == 2
+    # the two loaders every reader shares
+    path = os.path.join(harness.HERE, "data", "phase_trace.xplane.pb")
+    assert tr.load(path) is tr.load(path)
+    assert hs.load_host(path) is hs.load_host(path)

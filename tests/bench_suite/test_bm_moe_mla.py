@@ -57,15 +57,19 @@ def test_published_widths_are_unchanged_and_the_cut_is_stated():
                                       "num_nextn_predict_layers"]
     assert GLM["source"].startswith("https://huggingface.co/zai-org/GLM-4.7-Flash")
     assert GLM["assumed"] and GLM["deployment"]
-    entry = [c for c in BENCH["configs"] if c["name"] == "glm-4.7-flash"][0]
-    assert sorted(entry["reduced"]) == sorted(GLM["reduced"])
+    check_config_entry(BENCH)
     eng = GLM["engine"]
     assert (eng["num_blocks"], eng["block_size"], eng["max_num_seqs"],
             eng["prefix_cache"]) == (19200, 16, 128, False)
 
 
-def test_the_cell_lists_the_shared_readers_and_not_the_dense_counts():
-    listed = {m["name"] for m in BENCH["per_layer"]
+def check_config_entry(bench):
+    entry = [c for c in bench["configs"] if c["name"] == "glm-4.7-flash"][0]
+    assert sorted(entry["reduced"]) == sorted(GLM["reduced"])
+
+
+def check_cell_entries(bench):
+    listed = {m["name"] for m in bench["per_layer"]
               if CELL in m.get("workloads", ())}
     assert {"kernels.mla_decode_roofline", "kernels.moe_experts_roofline",
             "programs.moe_overhead_share", "engine.moe_load_max_over_mean",
@@ -76,8 +80,12 @@ def test_the_cell_lists_the_shared_readers_and_not_the_dense_counts():
             "cache.pool_peak_share", "device.peak_hbm_gb"} <= listed
     assert not {"kernels.paged_decode_roofline",
                 "programs.prefill_flops_share"} & listed
-    e2e = {m["name"] for m in harness.Cell(CELL).end_to_end}
+    e2e = {m["name"] for m in harness.Cell(CELL, bench=bench).end_to_end}
     assert e2e == {"tokens_per_s", "setup_s"}
+
+
+def test_the_cell_lists_the_shared_readers_and_not_the_dense_counts():
+    check_cell_entries(BENCH)
 
 
 # --- bytes and operations against the arithmetic of ISSUE 29 ---------------------

@@ -20,6 +20,12 @@ BENCH = harness.load_json(harness.ROOT, "BENCHMARK.json")
 CELL = "ai21-jamba2-3b.reasoning-decode-256"
 NEW = ("kernels.ssm_decode_roofline", "kernels.ssm_scan_roofline",
        "programs.ssm_share", "cache.state_slots_peak_share")
+# the ten ``engine.gap_*`` / ``scheduler.gap_*`` entries of the batch cells
+GAPS = {f"{stem}.batch" for stem in (
+    "engine.gap_intake_ms", "scheduler.gap_plan_ms", "engine.gap_admit_ms",
+    "engine.gap_build_ms", "engine.gap_dispatch_ms", "engine.gap_fetch_ms",
+    "engine.gap_emit_ms", "engine.gap_trackers_ms",
+    "engine.gap_unattributed_share", "engine.gap_offset_width_ms")}
 PEAKS = {"bytes_per_s": 819e9, "flops_per_s": 197e12}
 TINY = {"source": "test", "vocab_size": 256, "hidden_size": 64,
         "intermediate_size": 128, "num_hidden_layers": 6,
@@ -53,9 +59,7 @@ def test_every_published_key_is_unchanged_and_nothing_is_cut():
         tie_word_embeddings=True, use_mamba_kernels=True, vocab_size=65536)
     assert {k: JAMBA[k] for k in catalog} == catalog
     assert JAMBA["reduced"] == {}
-    entry = [c for c in BENCH["configs"] if c["name"] == "ai21-jamba2-3b"][0]
-    assert entry["reduced"] == [] and entry["source"] == JAMBA["source"] == \
-        "https://huggingface.co/ai21labs/AI21-Jamba2-3B/blob/main/config.json"
+    check_config_entry(BENCH)
     assert JAMBA["deployment"] and {"head_dim", "layer_order", "state_dtype",
                                     "seeded_recurrence"} <= set(JAMBA["assumed"])
     eng = JAMBA["engine"]
@@ -68,8 +72,16 @@ def test_every_published_key_is_unchanged_and_nothing_is_cut():
         ("jamba_hybrid", "jamba_hybrid_decoder")
 
 
-def test_the_cell_lists_the_shared_readers_and_not_the_dense_counts():
-    listed = {m["name"] for m in BENCH["per_layer"]
+def check_config_entry(bench):
+    entry = [c for c in bench["configs"] if c["name"] == "ai21-jamba2-3b"][0]
+    assert entry["reduced"] == [] and entry["source"] == JAMBA["source"] == \
+        "https://huggingface.co/ai21labs/AI21-Jamba2-3B/blob/main/config.json"
+
+
+def check_cell_entries(bench):
+    """The cell's entries in ``bench``: membership, and no count of names
+    a later PR's entries would move."""
+    listed = {m["name"] for m in bench["per_layer"]
               if CELL in m.get("workloads", ())}
     assert set(NEW) <= listed
     assert {"scheduler.rows_per_step.batch", "scheduler.padding_share",
@@ -81,25 +93,27 @@ def test_the_cell_lists_the_shared_readers_and_not_the_dense_counts():
             "programs.mlp_share.batch", "programs.lm_head_share.batch",
             "kernels.sampler_scope_share.batch",
             "programs.warm_s_per_program"} <= listed
-    gaps = {m["name"] for m in BENCH["per_layer"]
-            if ".gap_" in m["name"] and m["name"].endswith(".batch")}
-    assert len(gaps) == 10 and gaps <= listed
+    assert GAPS <= listed
     assert not {"kernels.paged_decode_roofline", "programs.prefill_flops_share",
                 "kernels.mla_decode_roofline"} & listed
-    warm = [m for m in BENCH["per_layer"]
+    warm = [m for m in bench["per_layer"]
             if m["name"] == "programs.warm_s_per_program"][0]
-    assert warm["workloads"] == [w["name"] for w in BENCH["workloads"]]
-    cell = harness.Cell(CELL)
+    assert warm["workloads"] == [w["name"] for w in bench["workloads"]]
+    cell = harness.Cell(CELL, bench=bench)
     assert {m["name"] for m in cell.end_to_end} == {"tokens_per_s", "setup_s"}
     assert cell.chips == 1 and cell.traffic == MIX and cell.config == JAMBA
     for m in cell.per_layer:                # every entry has a reader
         assert callable(cell.reader(m["name"]).read), m["name"]
     for name in NEW:                        # the new ones only here
-        entry = [m for m in BENCH["per_layer"] if m["name"] == name][0]
+        entry = [m for m in bench["per_layer"] if m["name"] == name][0]
         assert entry["workloads"] == [CELL] and entry["moves"] == "tokens_per_s"
         reader = harness.load_reader(name)
         assert (reader.UNIT, reader.LAYER, reader.SOURCE) == \
             (entry["unit"], entry["layer"], entry["source"])
+
+
+def test_the_cell_lists_the_shared_readers_and_not_the_dense_counts():
+    check_cell_entries(BENCH)
 
 
 # --- parameters and bytes against the arithmetic of ISSUE 33 -------------------------
@@ -151,7 +165,7 @@ def test_reasoning_decode_256_backlog():
     items = backlog.sequence(MIX, 3_000_000_019)
     assert len(items) == 1024 and MIX["in_flight"] == 256
     assert (MIX["kind"], MIX["lead_in_s"], MIX["cycle"], MIX["layout_seed"],
-            MIX["trace_s"], MIX["stream"]) == ("backlog", 75, 32, 23, 3.0, True)
+            MIX["trace_s"], MIX["stream"]) == ("backlog", 75, 32, 23, 2.0, True)
     first, rest = items[:256], items[256:]
     assert all(i["section"] == "lead_in" for i in first)
     assert all(512 <= i["prompt_len"] <= 1024
@@ -165,10 +179,11 @@ def test_reasoning_decode_256_backlog():
     lim = harness.traffic_limits(MIX)
     assert (lim["min_prompt"], lim["max_prompt"], lim["max_total"],
             lim["in_flight"]) == (512, 1024 + 3071, 4096, 256)
-    # twice the latent cell's concurrency, its lengths otherwise
+    # twice the latent cell's concurrency, its lengths otherwise (and since
+    # PR 42 a traced slice of 2 s for its 3: the trace's size, PERF.md)
     half = harness.load_json(harness.HERE, "traffic", "reasoning-decode.json")
     same = ("kind", "prompt_len", "output_len", "sampling", "stream", "cycle",
-            "layout_seed", "trace_s", "prime_first_wave")
+            "layout_seed", "prime_first_wave")
     assert {k: MIX[k] for k in same} == {k: half[k] for k in same}
     assert (MIX["in_flight"], MIX["requests"]) == \
         (2 * half["in_flight"], 2 * half["requests"])

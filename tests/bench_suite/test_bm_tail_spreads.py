@@ -115,9 +115,12 @@ def test_records_round_trip_a_run(tmp_path, name):
     back = records.load(rec)
     assert len(back["timelines"]) == len(r["timelines"])
     assert back["timelines"][-1]["end"] is None
-    for m in ("tpot_p50_ms", "itl_p995_ms", "tokens_per_s"):
+    for m in ("tpot_p50_ms", "tokens_per_s"):
         compute = harness.load_module("e2e_metrics", m).compute
         assert compute(back) == pytest.approx(compute(r), abs=2e-3)
+    assert run.client_counters(back)["shape"]["itl_p99.5_ms"] == \
+        pytest.approx(run.client_counters(r)["shape"]["itl_p99.5_ms"],
+                      abs=2e-3)
     assert run.client_counters(back)["ttft_p90_ms"] == pytest.approx(
         run.client_counters(r)["ttft_p90_ms"], abs=2e-3)
 
@@ -218,3 +221,29 @@ def test_ttft_p90_is_read_per_layer_from_what_the_client_prints():
              if m["name"] == "frontdoor.ttft_p90_ms"]
     assert [m["workloads"] for m in entry] == [[CHAT]]
     assert not any("ttft" in m["name"] for m in BENCH["end_to_end"])
+
+
+@pytest.mark.parametrize("name, q", [("frontdoor.itl_p995_ms", 99.5),
+                                     ("frontdoor.itl_p998_ms", 99.8)])
+def test_the_gaps_tail_is_read_per_layer_from_what_the_client_prints(name, q):
+    """``itl_p995_ms`` was end to end until PR 42: the same arithmetic, a
+    percentile of all gaps of the requests due in the window; the 99.8th
+    stands beside it as the steadier rank."""
+    r = _chat_run([50.0 + 3.0 * k for k in range(117)])
+    for k, t in enumerate(r["timelines"]):      # a gap of its own a request
+        t["chunks"] = [(t["chunks"][0][0] + i * (0.02 + 0.001 * k), 1)
+                       for i in range(9)]
+    gaps = [g for t in stats.counted(r["timelines"])
+            for g in stats.token_gaps_ms(t)]
+    assert stats.percentile(gaps, 99.5) < stats.percentile(gaps, 99.8)
+    reader = harness.load_reader(name)
+    c = {"client": run.client_counters(r)}
+    assert reader.read(c, None) == pytest.approx(stats.percentile(gaps, q))
+    assert reader.read(c, None) > 0
+    assert (reader.UNIT, reader.LAYER, reader.SOURCE) == (
+        "ms", "front door", "host_clock")
+    assert reader.read({"client": {}}, None) is None
+    entry = [m for m in BENCH["per_layer"] if m["name"] == name]
+    assert [(m["workloads"], m["moves"]) for m in entry] == \
+        [([CHAT], "tpot_p50_ms")]
+    assert not any("itl" in m["name"] for m in BENCH["end_to_end"])

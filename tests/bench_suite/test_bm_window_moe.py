@@ -16,6 +16,7 @@ import pytest
 from benchmarks import harness, roofline_window_moe as rf
 from benchmarks import window_moe_spans as spans
 from benchmarks.traffic_kinds import backlog
+from test_bm_ssm import GAPS     # the ten ``.gap_`` entries of the batch cells
 
 CFG = harness.load_json(harness.HERE, "configs",
                         "command-a-plus-05-2026.json")
@@ -26,6 +27,13 @@ NEW = ("kernels.window_decode_roofline", "kernels.global_decode_roofline",
        "kernels.moe_held_experts_roofline", "programs.window_attn_share",
        "programs.moe_absent_pairs_share", "cache.window_ring_peak_share",
        "engine.moe_held_pair_share")
+# what the benchmark held when this cell was accepted (PR 36), in order
+CONFIGS_BEFORE = ("mistral-7b-v0.3", "deepseek-llm-7b", "glm-4.7-flash",
+                  "ai21-jamba2-3b")
+CELLS_BEFORE = ("mistral-7b-v0.3.chat-steady", "deepseek-llm-7b.batch-decode",
+                "mistral-7b-v0.3.batch-prefill",
+                "glm-4.7-flash.reasoning-decode",
+                "ai21-jamba2-3b.reasoning-decode-256")
 PEAKS = {"bytes_per_s": 819e9, "flops_per_s": 197e12}
 KINDS = ["sliding_attention"] * 3 + ["full_attention"]
 TINY = {"source": "test", "vocab_size": 256, "hidden_size": 64,
@@ -77,12 +85,7 @@ def test_every_published_key_is_unchanged_but_the_four_that_are_cut():
     assert CFG["published"] == {k: catalog[k] for k in cut}
     assert CFG["experts_held"] == list(range(16))
     assert CFG["n_routed_experts"] == 128       # the router's width
-    entry = [c for c in BENCH["configs"]
-             if c["name"] == "command-a-plus-05-2026"][0]
-    assert entry["reduced"] == list(cut) and entry["source"] == CFG["source"] \
-        == ("https://huggingface.co/CohereLabs/command-a-plus-05-2026/"
-            "blob/main/config.json")
-    assert BENCH["configs"][-1] is entry and len(entry["why"]) <= 200
+    check_config_entry(BENCH)
     assert "eight" in CFG["deployment"] and "chip 0" in CFG["deployment"]
     assert {"expert_width", "shared_experts", "selection_bias",
             "prefix_dense", "window", "rope_pairing", "norm", "vision_tower",
@@ -97,8 +100,26 @@ def test_every_published_key_is_unchanged_but_the_four_that_are_cut():
         ("window_moe", "window_moe_decoder")
 
 
-def test_the_cell_lists_the_shared_readers_and_not_the_other_models_counts():
-    listed = {m["name"] for m in BENCH["per_layer"]
+def check_config_entry(bench):
+    """The configuration's entry in ``bench``: what it says, and that the
+    configurations accepted before it, and no other, stand before it."""
+    entry = [c for c in bench["configs"]
+             if c["name"] == "command-a-plus-05-2026"][0]
+    assert entry["reduced"] == ["num_hidden_layers", "num_experts",
+                                "vocab_size", "max_position_embeddings"]
+    assert entry["source"] == CFG["source"] \
+        == ("https://huggingface.co/CohereLabs/command-a-plus-05-2026/"
+            "blob/main/config.json")
+    names = [c["name"] for c in bench["configs"]]
+    assert names[:names.index(entry["name"])] == list(CONFIGS_BEFORE)
+    assert len(entry["why"]) <= 200
+
+
+def check_cell_entries(bench):
+    """The cell's entries in ``bench``.  Order is held against what was
+    accepted BEFORE this cell, never against the end: a later PR appends
+    (the contract at the head of ``test_bm_harness.py``)."""
+    listed = {m["name"] for m in bench["per_layer"]
               if CELL in m.get("workloads", ())}
     assert set(NEW) <= listed
     assert {"scheduler.rows_per_step.batch", "scheduler.padding_share",
@@ -113,32 +134,39 @@ def test_the_cell_lists_the_shared_readers_and_not_the_other_models_counts():
     # an accepted reader that WOULD read this cell unedited, but whose own
     # accepted test (test_bm_ssm.py) holds its list to one cell
     assert "cache.state_slots_peak_share" not in listed
-    gaps = {m["name"] for m in BENCH["per_layer"]
-            if ".gap_" in m["name"] and m["name"].endswith(".batch")}
-    assert len(gaps) == 10 and gaps <= listed
+    assert GAPS <= listed
     assert not {"kernels.paged_decode_roofline", "programs.prefill_flops_share",
                 "kernels.mla_decode_roofline", "kernels.moe_experts_roofline",
                 "programs.moe_overhead_share", "kernels.ssm_decode_roofline",
                 "programs.ssm_share"} & listed
-    assert BENCH["workloads"][-1]["name"] == CELL
-    assert len(BENCH["workloads"][-1]["why"]) <= 200
-    for m in BENCH["per_layer"] + BENCH["end_to_end"]:
+    cells = [w["name"] for w in bench["workloads"]]
+    assert cells[:cells.index(CELL)] == list(CELLS_BEFORE)
+    assert len(bench["workloads"][cells.index(CELL)]["why"]) <= 200
+    for m in bench["per_layer"] + bench["end_to_end"]:
         if CELL in m.get("workloads", ()):      # appended, nothing reordered
-            assert m["workloads"][-1] == CELL, m["name"]
-    cell = harness.Cell(CELL)
+            before = m["workloads"][:m["workloads"].index(CELL)]
+            assert before == [c for c in CELLS_BEFORE if c in before], \
+                m["name"]
+    cell = harness.Cell(CELL, bench=bench)
     assert {m["name"] for m in cell.end_to_end} == {"tokens_per_s", "setup_s"}
     assert cell.chips == 1 and cell.traffic == MIX and cell.config == CFG
     for m in cell.per_layer:                # every entry has a reader
         assert callable(cell.reader(m["name"]).read), m["name"]
-    assert [m["name"] for m in BENCH["per_layer"][-7:]] == list(NEW)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW[0])             # the seven stand together
+    assert names[first:first + len(NEW)] == list(NEW)
     for name in NEW:                        # the new ones only here
-        entry = [m for m in BENCH["per_layer"] if m["name"] == name][0]
+        entry = [m for m in bench["per_layer"] if m["name"] == name][0]
         assert entry["workloads"] == [CELL] and entry["moves"] == "tokens_per_s"
         reader = harness.load_reader(name)
         assert (reader.UNIT, reader.LAYER, reader.SOURCE) == \
             (entry["unit"], entry["layer"], entry["source"])
         if "roofline" in name:
             assert entry["unit"] == "%" and name.endswith("_roofline")
+
+
+def test_the_cell_lists_the_shared_readers_and_not_the_other_models_counts():
+    check_cell_entries(BENCH)
 
 
 # --- parameters and bytes against the arithmetic of ISSUE 36 -------------------------

@@ -8,6 +8,7 @@ framework models so the capability rungs are runnable in-repo.
 from . import (  # noqa: F401
     bert,
     gpt,
+    hc_moe_mla,
     llama,
     mamba_hybrid,
     moe_mla,
@@ -29,6 +30,11 @@ from .gpt import (  # noqa: F401
     GPTForCausalLM,
     GPTModel,
     GPTPretrainingCriterion,
+)
+from .hc_moe_mla import (  # noqa: F401
+    HCMLAMoEDecoderLayer,
+    HCMoEMLAConfig,
+    HyperConnection,
 )
 from .llama import (  # noqa: F401
     LlamaConfig,

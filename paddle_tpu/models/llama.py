@@ -562,6 +562,11 @@ class LlamaModel(Layer):
                 caches=None, pos=None):
         with jax.named_scope("embed"):
             h = self.embed_tokens(input_ids)
+        # a residual path that is not one stream: the configuration brings
+        # its entry and its exit (``models/hc_moe_mla.py``)
+        enter = getattr(self.config, "enter_residual", None)
+        if enter is not None:
+            h = enter(h)
         if caches is not None:
             for layer, cache in zip(self.layers, caches):
                 h = layer(h, cache=cache, pos=pos)
@@ -579,6 +584,8 @@ class LlamaModel(Layer):
                     h = _recompute(layer, h)
                 else:
                     h = layer(h)
+        if enter is not None:
+            h = self.config.exit_residual(h)
         return self.norm(h)
 
     def _scan_stack(self, h):
@@ -742,6 +749,24 @@ class LlamaForCausalLM(Layer):
                 loads.append(load)
                 layer.mlp.load = None
         return jnp.stack(loads) if loads else None
+
+    def pop_hc_health(self):
+        """float32 ``[3]``: over the hyper-connections of the forward just
+        run (``models/hc_moe_mla.py``) the entries of the pre-``exp``
+        matrices that met the clamp, the entries computed, and the largest
+        ``|colsum - 1|`` a Sinkhorn step left; ``None`` for a model with
+        one residual stream.  Clears what the layers held."""
+        found = []
+        for layer in self.llama.layers:
+            for hc in (getattr(layer, "attn_hc", None),
+                       getattr(layer, "mlp_hc", None)):
+                if hc is not None and hc.health is not None:
+                    found.append(hc.health)
+                    hc.health = None
+        if not found:
+            return None
+        h = jnp.stack(found)
+        return jnp.stack([h[:, 0].sum(), h[:, 1].sum(), h[:, 2].max()])
 
     def train_batch_1f1b(self, input_ids, labels, n_microbatch: int,
                          criterion=None, recompute: bool = False):

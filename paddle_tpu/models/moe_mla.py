@@ -126,6 +126,64 @@ class MoEMLAConfig(LlamaConfig):
         return cls(**defaults)
 
 
+def yarn_range(dim: int, theta: float, original_max: int,
+               beta_fast: float, beta_slow: float) -> Tuple[int, int]:
+    """``(low, high)``: the frequency indices between which YaRN blends,
+    from the rotations a dimension makes over the ORIGINAL positions."""
+    def at(rotations):
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    return (max(math.floor(at(beta_fast)), 0),
+            min(math.ceil(at(beta_slow)), dim - 1))
+
+
+def yarn_mscale(factor: float, a: float) -> float:
+    return 0.1 * a * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def latent_rope_tables(config):
+    """THE place the latent layer's RoPE tables are made.  Without
+    ``rope_scaling`` they are ``_rope_tables``' own; with YaRN frequency
+    ``i`` is ``(1 - g_i) f_i + g_i f_i / factor``, ``g`` a ramp from 0 at
+    ``low`` to 1 at ``high`` (:func:`yarn_range`), and cos and sin are
+    scaled by ``m(factor, mscale) / m(factor, mscale_all_dim)``."""
+    import numpy as np
+
+    c = config
+    d, rs = c.qk_rope_head_dim, getattr(c, "rope_scaling", None)
+    if rs is None:
+        return _rope_tables(d, c.max_position_embeddings, c.rope_theta)
+    kind = rs.get("type", rs.get("rope_type"))
+    if kind != "yarn":
+        raise ValueError(f"rope_scaling type {kind!r} is not built (yarn is)")
+    factor = float(rs["factor"])
+    low, high = yarn_range(d, c.rope_theta,
+                           int(rs["original_max_position_embeddings"]),
+                           float(rs.get("beta_fast", 32)),
+                           float(rs.get("beta_slow", 1)))
+    inv = 1.0 / (c.rope_theta ** (np.arange(0, d, 2, dtype=np.float32) / d))
+    ramp = np.clip((np.arange(d // 2, dtype=np.float32) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    inv = (inv * (1.0 - ramp) + inv / factor * ramp).astype(np.float32)
+    freqs = np.outer(np.arange(c.max_position_embeddings, dtype=np.float32),
+                     inv)
+    m = np.float32(yarn_mscale(factor, float(rs.get("mscale", 1)))
+                   / yarn_mscale(factor, float(rs.get("mscale_all_dim", 0))))
+    return np.cos(freqs) * m, np.sin(freqs) * m
+
+
+def softmax_scale(config) -> float:
+    """``1 / sqrt(nope + rope)``, times ``m(factor, mscale_all_dim)^2``
+    under YaRN: on the expanded and the absorbed path alike."""
+    scale = 1.0 / math.sqrt(config.head_dim)
+    rs = getattr(config, "rope_scaling", None)
+    if rs is not None and rs.get("mscale_all_dim"):
+        scale *= yarn_mscale(float(rs["factor"]),
+                             float(rs["mscale_all_dim"])) ** 2
+    return scale
+
+
 class LatentAttention(Layer):
     """Multi-head latent attention with decoupled RoPE (module docstring)."""
 
@@ -147,8 +205,8 @@ class LatentAttention(Layer):
         self.kv_b_proj = lin(c.kv_lora_rank,                        # W_UKV
                              heads * (c.qk_nope_head_dim + c.v_head_dim))
         self.o_proj = lin(heads * c.v_head_dim, h)
-        self._rope_cos, self._rope_sin = _rope_tables(
-            c.qk_rope_head_dim, c.max_position_embeddings, c.rope_theta)
+        self._rope_cos, self._rope_sin = latent_rope_tables(c)
+        self._scale = softmax_scale(c)
 
     # --- pieces --------------------------------------------------------------
     def _positions(self, pos, B, S):
@@ -236,7 +294,7 @@ class LatentAttention(Layer):
         ``lat [B, M, 1, latent]``: the queries sit at ``q_start + [0, S)``
         and see columns up to their own (and under ``lens``)."""
         c = self.config
-        scale = 1.0 / math.sqrt(c.head_dim)
+        scale = self._scale
 
         def attend(qv, lv, w):
             with jax.named_scope("mla_prefill_core"):
@@ -262,7 +320,7 @@ class LatentAttention(Layer):
             return p.at[blocks, offs].set(new.astype(p.dtype))
 
         pool._rebind(run_op("paged_kv_write", write, pool, lat))
-        scale = 1.0 / math.sqrt(c.head_dim)
+        scale = self._scale
         if chunk:
             def attend(qv, pv, w):
                 with jax.named_scope("mla_prefill_core"):

@@ -65,7 +65,9 @@ STEP_PHASES = (
                             # and state_slots_held=; where that state is
                             # a window's ring, a decode launch also
                             # window_tokens= (the ring entries its rows
-                            # read: min(length, window) summed over them)
+                            # read: min(length, window) summed over them).
+                            # For a model whose residual path is several
+                            # streams also hc_streams= (how many)
     "engine.dispatch",      # the step call, until the jit call returns
                             # (rows=, bucket=, ahead= 1 where the launch
                             # went out before the tokens of the launch
@@ -84,7 +86,14 @@ STEP_PHASES = (
                             # over expert layers), moe_decode= 1 on
                             # decode; where the model holds a SHARE of
                             # its experts also moe_pairs_held= (pairs
-                            # routed to them) and moe_held_touched=
+                            # routed to them) and moe_held_touched=.
+                            # For a model with hyper-connections the
+                            # health of the launch's Sinkhorn steps:
+                            # hc_res_clamped= of hc_entries= entries of
+                            # the pre-exp matrices met the clamp (over
+                            # sublayers, padding tokens included),
+                            # hc_sinkhorn_residual_ppb= the largest
+                            # |column sum - 1| left, in parts per billion
     "engine.emit",          # commit, emission, retire; then the stream
                             # hand-off: one callback posted to the
                             # server's loop a step (streams= the

@@ -145,6 +145,23 @@ def _register_held_metrics(registry, labels: Dict[str, str]):
     }
 
 
+def _register_hc_metrics(registry, labels: Dict[str, str]):
+    """Health of the Sinkhorn step of a model whose residual path is
+    several streams mixed by hyper-connections."""
+    return {
+        "clamped": registry.counter(
+            "serving_hc_res_clamped_total",
+            help="entries of the pre-exp residual-mixing matrices that met "
+                 "the clamp, over sublayers and launches (padding tokens "
+                 "included)", **labels),
+        "residual": registry.gauge(
+            "serving_hc_sinkhorn_residual",
+            help="last launch: the largest |column sum - 1| a sublayer's "
+                 "residual-mixing matrix was left with after its Sinkhorn "
+                 "rounds", **labels),
+    }
+
+
 _AHEAD_SETTLES_HELP = (
     "steps of the serving loop that read the launch in flight before "
     "planning instead of running ahead of it, by the rule that made them "
@@ -201,6 +218,7 @@ class _Flight:
     logits: object
     stats: object
     load: object            # routed-expert load, or None
+    hc: object              # hyper-connection health [3], or None
     nbytes: int             # what the host copies asked for at dispatch hold
     audit: bool
     shadow: bool
@@ -585,6 +603,10 @@ class EngineCore:
         # them (the model's configuration says which), else None
         held = getattr(cfg, "experts_held", None)
         self._experts_held = None if held is None else np.asarray(held, int)
+        # a residual path of several streams: ``engine.build`` says how many
+        self._hc_counters = None
+        self._hc_ints = {"hc_streams": int(cfg.hc_mult)} \
+            if getattr(cfg, "enter_residual", None) is not None else {}
         self._params = list(model.parameters())
         # retrace counters: += 1 runs only while JAX traces the function,
         # so these count COMPILATIONS, not calls (the N31 acceptance hook)
@@ -959,9 +981,10 @@ class EngineCore:
                 self._step_call(program, bucket, jit_fn,
                                 self._param_vals(), self._k_pools,
                                 self._v_pools, *args)
-            load = None
-            if isinstance(stats, tuple):    # a model with routed experts
-                stats, load = stats
+            load = hc = None
+            if isinstance(stats, tuple):    # routed experts (, streams)
+                stats, load, *rest = stats
+                hc = rest[0] if rest else None
             fetched = [toks]
             if audit:
                 fetched.append(stats)
@@ -970,12 +993,13 @@ class EngineCore:
                 fetched.append(logits)
             for arr in fetched:
                 arr.copy_to_host_async()
-            if load is not None:
-                load.copy_to_host_async()
+            for arr in (load, hc):
+                if arr is not None:
+                    arr.copy_to_host_async()
         # a launch during which a trace counter moved IS that bucket's
         # trace+compile: its wall time goes to the compile table
         return _Flight(program, bucket, self._launch_seq, st, toks, logits,
-                       stats, load, sum(arr.nbytes for arr in fetched),
+                       stats, load, hc, sum(arr.nbytes for arr in fetched),
                        audit, shadow, self._traces() > traces0)
 
     def _traces(self) -> int:
@@ -1002,9 +1026,10 @@ class EngineCore:
         toks, logits, stats = fl.toks, fl.logits, fl.stats
         with phase("engine.device_wait", prof, launch=fl.seq):
             toks.block_until_ready()
-            moe = self._moe_load_ints(fl.program, fl.load)
+            ints = {**self._moe_load_ints(fl.program, fl.load),
+                    **self._hc_health_ints(fl.hc)}
         fl.timer.start_no_earlier_than(self._last_ready)
-        with phase("engine.fetch", prof, bytes=fl.nbytes, **moe):
+        with phase("engine.fetch", prof, bytes=fl.nbytes, **ints):
             toks = np.asarray(toks, np.int32)
             if fl.audit:
                 stats = np.asarray(stats, np.float32)
@@ -1073,6 +1098,23 @@ class EngineCore:
                 c["held_share"].set(c["pairs_held"].value
                                     / c["assignments"].value)
         return ints
+
+    def _hc_health_ints(self, hc) -> Dict[str, int]:
+        """The health of the launch's Sinkhorn steps (three floats that
+        rode the launch beside the tokens) as the integers ``engine.fetch``
+        carries and ``/metrics`` counts: ``hc_res_clamped`` of
+        ``hc_entries`` entries, and the largest residual in parts per
+        billion (a phase carries integers); ``{}`` for any other model."""
+        if hc is None:
+            return {}
+        if self._hc_counters is None:
+            self._hc_counters = _register_hc_metrics(
+                self.metrics.registry, self.metrics.labels)
+        clamped, entries, residual = (float(v) for v in np.asarray(hc))
+        self._hc_counters["clamped"].inc(int(clamped))
+        self._hc_counters["residual"].set(residual)
+        return {"hc_res_clamped": int(clamped), "hc_entries": int(entries),
+                "hc_sinkhorn_residual_ppb": int(round(residual * 1e9))}
 
     def _mesh_jit_shardings(self, mesh, cfg) -> Dict[str, dict]:
         """Explicit in/out shardings for the three mesh-spanning jitted
@@ -1153,10 +1195,16 @@ class EngineCore:
     def _launch_stats(self, last):
         """The ``stats`` output of a step program: the numerics audit's
         logit sentinel, and for a model with routed experts the tokens
-        each expert of each layer received in this forward beside it."""
+        each expert of each layer received in this forward beside it;
+        for one with hyper-connections a third part, the health of their
+        Sinkhorn steps (``LlamaForCausalLM.pop_hc_health``)."""
         stats = logit_stats(last)
         pop = getattr(self.model, "pop_expert_load", None)
         load = pop() if pop is not None else None
+        pop = getattr(self.model, "pop_hc_health", None)
+        hc = pop() if pop is not None else None
+        if hc is not None:
+            return stats, load, hc
         return stats if load is None else (stats, load)
 
     def _decode_fn(self, param_vals, k_pools, v_pools, ids, pos,
@@ -1668,7 +1716,8 @@ class EngineCore:
         phase, prof = self.tracer.phase, self.stepprof
         t_chunk0 = time.perf_counter()
         one_shot = False
-        with phase("engine.build", prof, **self._state_ints(1)):
+        with phase("engine.build", prof, **self._state_ints(1),
+                   **self._hc_ints):
             ids, target, start, n, recompute = \
                 self._begin_prefill_chunk(req, t_chunk0)
             table = self.kv.table(rid)
@@ -1784,7 +1833,7 @@ class EngineCore:
         phase, prof = self.tracer.phase, self.stepprof
         B = len(reqs)
         with phase("engine.build", prof, rows=B,
-                   **self._state_ints(B, reqs)):
+                   **self._state_ints(B, reqs), **self._hc_ints):
             Bb = bucket_size(B)
             width = max(len(self.kv.table(r.request_id)) for r in reqs)
             Wb = bucket_size(width)

@@ -850,8 +850,20 @@ def _block_queries(fn, q, block: int = 1024):
     return out.reshape(out.shape[0], S, *out.shape[3:])
 
 
+# Which form the most recent :func:`latent_expanded_attention` was traced
+# with: "pallas" (``pallas_flash.flash_prefill``) | "xla", and the query
+# rows a grid step of that kernel takes (0: the XLA form).
+last_latent_prefill_path: Optional[str] = None
+last_latent_prefill_block_q: int = 0
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def latent_expanded_attention(q, lat, w_ukv, rank: int, scale: float,
-                              q_start=0, lens=None):
+                              q_start=0, lens=None,
+                              use_pallas: Optional[bool] = None):
     """Latent attention with keys and values REBUILT from the cache rows.
 
     q: ``[B, S, heads, nope + rope]`` (rope part rotated), at positions
@@ -859,12 +871,56 @@ def latent_expanded_attention(q, lat, w_ukv, rank: int, scale: float,
     normalised latent ``c_kv`` beside the rotated shared key ``k_r``;
     ``w_ukv = (W_UK [heads, rank, nope], W_UV [heads, rank, v])``.  A query
     sees the columns up to its own position, and under ``lens`` (``[B]``)
-    where given.  Returns ``[B, S, heads * v]``."""
+    where given.  Returns ``[B, S, heads * v]``.
+
+    Keys and values are rebuilt by two einsums either way.  The core —
+    scores, mask, softmax, weighted sum — is ``pallas_flash.flash_prefill``
+    on a TPU where ``S`` and ``M`` are whole blocks of it
+    (``pallas_flash.prefill_blocks``: the engine's prefill buckets are),
+    and :func:`_latent_expanded_core` elsewhere, which is also the kernel's
+    oracle; :func:`pallas_dispatch` decides (``use_pallas`` forces or
+    pins, the operator's kill switch wins), and the form traced is
+    published as :data:`last_latent_prefill_path` (the kernel's query
+    block as :data:`last_latent_prefill_block_q`)."""
+    global last_latent_prefill_path, last_latent_prefill_block_q
+
+    from .pallas_flash import flash_prefill, prefill_blocks
+
     w_uk, w_uv = w_ukv
     B, S, heads, _ = q.shape
-    M, nope = lat.shape[1], w_uk.shape[-1]
+    M = lat.shape[1]
     c_kv = lat[..., :rank].astype(q.dtype)
     k_r = lat[..., rank:].astype(q.dtype)
+    blocks = prefill_blocks(S, M)
+
+    def kernel():
+        # heads before tokens: the layout the kernel's blocks want
+        k_nope = jnp.einsum("bmr,hrn->bhmn", c_kv, w_uk)
+        v = jnp.einsum("bmr,hrv->bhmv", c_kv, w_uv)
+        starts = jnp.broadcast_to(jnp.asarray(q_start, jnp.int32), (B,))
+        n = jnp.full((B,), M, jnp.int32) if lens is None else lens
+        # forced past the rule, the whole launch is one block
+        return flash_prefill(q, k_nope, k_r, v, scale, starts, n,
+                             blocks or (S, M))
+
+    def oracle():
+        return _latent_expanded_core(q, c_kv, k_r, w_ukv, scale, q_start,
+                                     lens)
+
+    out, last_latent_prefill_path = pallas_dispatch(
+        kernel, oracle, use_pallas, _on_tpu() and blocks is not None)
+    last_latent_prefill_block_q = (blocks or (S, M))[0] \
+        if last_latent_prefill_path == "pallas" else 0
+    return out.reshape(B, S, heads * w_uv.shape[-1]).astype(q.dtype)
+
+
+def _latent_expanded_core(q, c_kv, k_r, w_ukv, scale, q_start, lens):
+    """The XLA form of :func:`latent_expanded_attention`'s core, and the
+    oracle of ``pallas_flash.flash_prefill``: float32 scores written whole,
+    a block of query rows at a time where there are many.  Returns
+    ``[B, S, heads, v]``."""
+    w_uk, w_uv = w_ukv
+    M, nope = c_kv.shape[1], w_uk.shape[-1]
     k_nope = jnp.einsum("bmr,hrn->bmhn", c_kv, w_uk)
     v = jnp.einsum("bmr,hrv->bmhv", c_kv, w_uv)
     col = jnp.arange(M)[None, None, :]
@@ -882,8 +938,7 @@ def latent_expanded_attention(q, lat, w_ukv, rank: int, scale: float,
         probs = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1)
         return jnp.einsum("bhqk,bkhv->bqhv", probs.astype(v.dtype), v)
 
-    out = _block_queries(block, q)
-    return out.reshape(B, S, heads * w_uv.shape[-1]).astype(q.dtype)
+    return _block_queries(block, q)
 
 
 def latent_paged_prefill_attention(q, pool, w_ukv, block_tables, seq_lens,

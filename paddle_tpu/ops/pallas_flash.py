@@ -343,3 +343,178 @@ def _vjp_bwd(causal, block_q, block_k, res, g):
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# serving prefill: the expanded core of latent attention (forward only)
+# ---------------------------------------------------------------------------
+
+#: query rows and keys of a grid step, the largest that divide the launch:
+#: a step's fixed cost is a few tenths of a microsecond, and a 128 x 128
+#: step's arithmetic is less than that
+PREFILL_BLOCKS_Q = (1024, 512, 256, 128)
+PREFILL_BLOCKS_K = (1024, 512, 256, 128)
+#: the float32 scores and probabilities of one step beside two buffers of
+#: each operand's block: more than Mosaic's default scope
+_PREFILL_VMEM_BYTES = 64 << 20
+
+
+def prefill_blocks(S: int, M: int):
+    """``(query rows, keys)`` of a grid step of :func:`flash_prefill` for
+    ``S`` queries over ``M`` keys, ``None`` where either is no whole number
+    of the smallest block."""
+    bq = next((b for b in PREFILL_BLOCKS_Q if S % b == 0), None)
+    bk = next((b for b in PREFILL_BLOCKS_K if M % b == 0), None)
+    return (bq, bk) if bq and bk else None
+
+
+def _prefill_kernel(start_ref, lens_ref, qn_ref, qr_ref, kn_ref, kr_ref,
+                    v_ref, o_ref, acc_ref, m_ref, l_ref, *, scale, block_q,
+                    block_k, n_k):
+    b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    first = start_ref[b] + qi * block_q     # position of the block's row 0
+    n = lens_ref[b]
+    k0 = ki * block_k
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def step(masked):
+        s = (jax.lax.dot_general(
+                qn_ref[0, 0], kn_ref[0, 0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(
+                qr_ref[0, 0], kr_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)) * scale   # [BQ, BK]
+        if masked:
+            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + first
+            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + k0
+            s = jnp.where((cols <= rows) & (cols < n), s, _NEG_INF)
+        v = v_ref[0, 0]
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    # a key block that begins past the block's last row, or at or past the
+    # sequence's length, is all mask: no arithmetic (and, by the index
+    # maps, no copy); one that ends at or before the block's first row and
+    # inside the length needs no mask.  Key block 0 of a sequence that has
+    # a token always runs and shows every row column 0, so a row's running
+    # max is real before any block that masks the whole of it.
+    run = (k0 <= first + (block_q - 1)) & (k0 < n)
+    inside = (k0 + (block_k - 1) <= first) & (k0 + block_k <= n)
+
+    @pl.when(run & inside)
+    def _whole():
+        step(masked=False)
+
+    @pl.when(run & jnp.logical_not(inside))
+    def _edge():
+        step(masked=True)
+
+    @pl.when(ki == n_k - 1)
+    def _finish():
+        l = l_ref[:, :1]
+        safe_l = jnp.where(l == 0.0, jnp.float32(1.0), l)
+        o_ref[0, 0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
+
+
+def flash_prefill(q, k_nope, k_r, v, scale, q_start, lens, blocks):
+    """Causal attention of a prefill launch whose keys come in two parts,
+    the scores never leaving VMEM (online softmax; float32 scores, running
+    max, sum and accumulator; probabilities cast to ``v``'s dtype for the
+    weighted sum).
+
+    q: ``[B, S, H, nope + rope]``, the rows at positions ``q_start[b] +
+    [0, S)``; k_nope: ``[B, H, M, nope]``; k_r: ``[B, M, rope]``, ONE key
+    part shared by every head; v: ``[B, H, M, vd]``; ``q_start``, ``lens``:
+    ``[B]`` int32 (scalar prefetch).  Score of row ``i``, column ``j``, head
+    ``h``: ``(q[.., :nope] . k_nope[h, j] + q[.., nope:] . k_r[j]) * scale``
+    where ``j <= q_start + i`` and ``j < lens``.  Returns ``[B, S, H, vd]``.
+
+    Rows at or past ``lens`` are padding: they hold the weighted sum over
+    the columns under ``lens`` (what the XLA form gives them), and all zeros
+    where ``lens`` is 0 (the XLA form: the mean of ``v``).
+
+    Grid ``(B, H, S / block_q, M / block_k)``, keys innermost.  A key block
+    wholly past a query block's last row or past ``lens`` costs a grid step
+    and nothing else: its index is clamped to the last block that query
+    block needs, so it is not copied, and the step skips the arithmetic.
+    ``blocks``: ``(block_q, block_k)``, divisors of ``S`` and ``M``
+    (:func:`prefill_blocks` gives the ones measured best)."""
+    return _prefill(q, k_nope, k_r, v, q_start.astype(jnp.int32),
+                    lens.astype(jnp.int32), scale=float(scale),
+                    blocks=tuple(blocks), interpret=_interpret())
+
+
+# A jit of its own: a prefill program calls the kernel once a layer at one
+# shape, and this way traces and lowers it ONCE (``pallas_ssm._step``); each
+# inlined copy's ``op_name`` keeps the scope path of its own call site.
+@functools.partial(jax.jit, static_argnames=("scale", "blocks", "interpret"))
+def _prefill(q, k_nope, k_r, v, q_start, lens, *, scale, blocks, interpret):
+    B, S, H, dq = q.shape
+    M, nope = k_nope.shape[2], k_nope.shape[3]
+    rope, vd = dq - nope, v.shape[3]
+    block_q, block_k = blocks
+    n_q, n_k = S // block_q, M // block_k
+    # blocked dims minor-most (Mosaic); the two parts of q apart, so that
+    # no block is sliced at a lane that is no multiple of 128
+    qt = q.transpose(0, 2, 1, 3)
+    q_nope, q_rope = qt[..., :nope], qt[..., nope:]
+
+    def last(b, i, start, lens):    # last key block query block i needs
+        diag = (start[b] + (i + 1) * block_q - 1) // block_k
+        return jnp.minimum(diag, (jnp.maximum(lens[b], 1) - 1) // block_k)
+
+    def q_map(b, h, i, j, start, lens):
+        return b, h, i, 0
+
+    def k_map(b, h, i, j, start, lens):
+        return b, h, jnp.minimum(j, last(b, i, start, lens)), 0
+
+    def kr_map(b, h, i, j, start, lens):
+        return b, jnp.minimum(j, last(b, i, start, lens)), 0
+
+    kernel = functools.partial(
+        _prefill_kernel, scale=np.float32(scale), block_q=block_q,
+        block_k=block_k, n_k=n_k)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,      # q_start, lens
+        grid=(B, H, n_q, n_k),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, nope), q_map),
+            pl.BlockSpec((1, 1, block_q, rope), q_map),
+            pl.BlockSpec((1, 1, block_k, nope), k_map),
+            pl.BlockSpec((1, block_k, rope), kr_map),
+            pl.BlockSpec((1, 1, block_k, vd), k_map),
+        ],
+        out_specs=pl.BlockSpec((1, 1, block_q, vd), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, vd), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+        ],
+    )
+    with no_x64():
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, H, S, vd), q.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary"),
+                vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+            interpret=interpret,
+            name="flash_prefill",       # its name in a device trace
+        )(q_start, lens, q_nope, q_rope, k_nope, k_r, v)
+    return out.transpose(0, 2, 1, 3)

@@ -624,6 +624,11 @@ class EngineCore:
         # (``ops.pallas_paged.kernel_pages``), written where the program
         # is traced; rides ``engine.dispatch`` as ``pages_per_step``
         self._kernel_pages: Dict[tuple, int] = {}
+        # (program, *bucket) of a prefill or chunk program -> query rows a
+        # grid step of its ``pallas_flash.flash_prefill`` takes, written
+        # where the program is traced (:meth:`_note_flash_prefill`); rides
+        # ``engine.dispatch`` as ``flash_block_q``
+        self._flash_rows: Dict[tuple, int] = {}
         self.decode_buckets = set()
         self.prefill_buckets = set()
         self.ragged_buckets = set()
@@ -959,7 +964,10 @@ class EngineCore:
         decode launch also carries ``pages_per_step``: the pages of a row
         the paged decode kernel of its program moves a step over the paged
         pool, as written where the program was traced (0: the gather path,
-        an AOT-served program, a bucket's first call).  What comes back is
+        an AOT-served program, a bucket's first call), and a prefill or
+        chunk launch ``flash_block_q``: the query rows a grid step of its
+        program's ``flash_prefill`` takes (0: the program holds the XLA
+        form of the expanded latent attention, or none).  What comes back is
         the launch
         in flight: the device arrays, and what :meth:`_collect` needs to
         finish it, now or a step later."""
@@ -976,7 +984,9 @@ class EngineCore:
                                launch=self._launch_seq,
                                pages_per_step=self._kernel_pages.get(
                                    tuple(bucket), 0)
-                               if program == "decode" else 0):
+                               if program == "decode" else 0,
+                               flash_block_q=self._flash_rows.get(
+                                   (program, *bucket), 0)):
             toks, logits, stats, self._k_pools, self._v_pools = \
                 self._step_call(program, bucket, jit_fn,
                                 self._param_vals(), self._k_pools,
@@ -1311,6 +1321,17 @@ class EngineCore:
             top_ks, top_ps, keys, k_pools, v_pools)
         return buf, last, self._launch_stats(last), k_out, v_out
 
+    def _note_flash_prefill(self, program: str, bucket) -> None:
+        """Run where a prefill or chunk program is traced, after the
+        model: which form of the expanded latent attention the program
+        holds (``attention_paths``; nothing where the model has no such
+        layer), and the query rows a grid step of its kernel takes."""
+        path = _paged_ops.last_latent_prefill_path
+        if path is not None:
+            self.attention_paths[program] = path
+        self._flash_rows[(program, *bucket)] = \
+            _paged_ops.last_latent_prefill_block_q if path == "pallas" else 0
+
     def _prefill_fn(self, param_vals, k_pools, v_pools, ids, last_pos,
                     blocks, offs, temps, top_ks, top_ps, keys):
         """Bucketed prefill: dense-cache forward over the (padded) prompt,
@@ -1337,7 +1358,9 @@ class EngineCore:
                 dense.append(c)
             else:
                 dense.append((buffer(spec.k), buffer(spec.v)))
+        _paged_ops.last_latent_prefill_path = None
         logits = self._call_model(ids, dense, jnp.int32(0), param_vals)
+        self._note_flash_prefill("prefill", (Tb,))
         last = jnp.take(logits[0], last_pos, axis=0).astype(jnp.float32)
         tokens = sample_tokens(last[None], temps, top_ks, top_ps, keys)
         # every k side, then every v side: the order of the parent's program
@@ -1374,7 +1397,9 @@ class EngineCore:
             # state carried in from the slot when the chunk starts past 0
             lambda c: c.route(tables[:, 0], start=start,
                               n_valid=last_pos + 1))
+        _paged_ops.last_latent_prefill_path = None
         logits = self._call_model(ids, caches, start, param_vals)
+        self._note_flash_prefill("chunk", (ids.shape[1], tables.shape[1]))
         last = jnp.take(logits[0], last_pos, axis=0).astype(jnp.float32)
         tokens = sample_tokens(last[None], temps, top_ks, top_ps, keys)
         return (tokens, last, self._launch_stats(last),

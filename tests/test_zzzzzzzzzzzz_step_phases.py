@@ -156,7 +156,8 @@ class TestUnarmedStep:
             for name, kwargs in made:
                 # integers the step already holds, nothing formatted
                 assert set(kwargs) <= {"rows", "bucket", "bytes", "ahead",
-                                       "launch", "pages_per_step"}
+                                       "launch", "pages_per_step",
+                                       "flash_block_q"}
                 assert all(type(v) is int for v in kwargs.values()), \
                     (name, kwargs)
             for name in PER_STEP:

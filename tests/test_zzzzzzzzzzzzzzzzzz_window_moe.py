@@ -504,3 +504,41 @@ def test_state_step_kernel_compiles_at_the_published_widths(
         assert mem.temp_size_in_bytes < pool // 8
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
+
+
+@pytest.mark.parametrize("name,tokens,heads,nope,vd,dtype", [
+    ("xing4.0-29b-a4b, the 4,096 bucket", 4096, 32, 128, 128, "bfloat16"),
+    ("xing4.0-29b-a4b, the 2,048 bucket", 2048, 32, 128, 128, "bfloat16"),
+    ("glm-4.7-flash, the 1,024 bucket", 1024, 20, 192, 256, "bfloat16"),
+    ("one block of 128, float32", 128, 4, 192, 256, "float32"),
+])
+def test_flash_prefill_compiles_at_the_latent_cells_shapes(
+        one_chip, monkeypatch, name, tokens, heads, nope, vd, dtype):
+    """The TPU compiler takes the expanded latent prefill with its kernel
+    (``ops/pallas_flash.py`` ``flash_prefill``, here for the reason above)
+    at both latent cells' head sizes — 192 and 64 are no multiples of 128
+    lanes — with ``q_start`` a traced scalar, as the prefill program
+    passes it, and the program holds no scores: its temporaries are the
+    rebuilt keys and values and the layout copies, not ``[heads, S, M]``."""
+    from paddle_tpu.ops import paged_attention as ops
+    from paddle_tpu.ops import pallas_flash
+
+    monkeypatch.setattr(pallas_flash, "_interpret", lambda: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        def s(shape, dt=jnp.dtype(dtype)):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+        rank, rope = 512, 64
+        compiled = jax.jit(
+            lambda q, lat, wk, wv, start: ops.latent_expanded_attention(
+                q, lat, (wk, wv), rank, 0.0722, start, use_pallas=True)
+        ).lower(s((1, tokens, heads, nope + rope)),
+                s((1, tokens, rank + rope)), s((heads, rank, nope)),
+                s((heads, rank, vd)), s((), jnp.int32)).compile()
+        assert "flash_prefill" in compiled.as_text(), name
+        scores = heads * tokens * tokens * 4
+        assert compiled.memory_analysis().temp_size_in_bytes < max(
+            scores // 4, 8 << 20), name
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)

@@ -7,8 +7,6 @@ the faults the comparison must catch, the health integers, and that a
 configuration WITHOUT the hooks runs the parent's program.  float32 on the
 CPU, tiny widths."""
 
-import hashlib
-
 import numpy as np
 import pytest
 
@@ -224,14 +222,28 @@ def test_the_latent_tables_are_yarns_and_a_factor_of_1_is_plain_rope(ref):
 
 # --- a configuration without the hooks runs the parent's program -----------------------
 
-# sha256 of ``str(jax.make_jaxpr(forward))`` of ``MoEMLAConfig.tiny()`` over
-# 24 tokens, recorded from the parent commit (8a4aae5) with the function
-# below; the RoPE tables are constants the text does not show, and the test
-# above holds them to the parent's
-PARENT_JAXPR = "1761084214af5238001042ba9d80f3bf6d56cc48ccf791c4bc1ac9568fe7b2ac"
+def parents_decoder_forward(self, input_ids, pp_microbatches=None,
+                            caches=None, pos=None):
+    """``LlamaModel.forward`` as the parent commit (8a4aae5) had it on the
+    paths a latent model takes: the hooks REMOVED, not looked for."""
+    with jax.named_scope("embed"):
+        h = self.embed_tokens(input_ids)
+    if caches is not None:
+        for layer, cache in zip(self.layers, caches):
+            h = layer(h, cache=cache, pos=pos)
+    else:
+        for layer in self.layers:
+            h = layer(h)
+    return self.norm(h)
 
 
-def test_the_single_stream_latent_model_traces_to_the_parents_program():
+def test_the_single_stream_latent_model_traces_to_the_parents_program(
+        monkeypatch):
+    """Two traces made in THIS process, whatever earlier tests left in it
+    (the text of a jaxpr is no constant of the program: a digest recorded
+    in one process failed in the driver's six-worker run and passed
+    alone): the model as it is, its configuration bringing no hooks,
+    against the same model under the parent's forward."""
     import paddle_tpu as paddle
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.models import LlamaForCausalLM, MoEMLAConfig
@@ -253,8 +265,14 @@ def test_the_single_stream_latent_model_traces_to_the_parents_program():
             for p, v in zip(params, saved):
                 p._value = v
 
-    text = str(jax.make_jaxpr(f)([p._value for p in params], ids))
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_JAXPR
+    def trace():
+        return str(jax.make_jaxpr(f)([p._value for p in params], ids))
+
+    text = trace()
+    assert not hasattr(m.config, "enter_residual")      # hooks absent
+    with monkeypatch.context() as mp:                   # hooks removed
+        mp.setattr(type(m.llama), "forward", parents_decoder_forward)
+        assert trace() == text
     assert "mhc" not in text and m.pop_hc_health() is None
     eng = make_engine(m)
     assert eng._hc_ints == {} and eng._hc_health_ints(None) == {}

@@ -258,6 +258,36 @@ def ssm_state_step_check(rows=256, n=16, d=5120):
     return run
 
 
+# --- the expanded latent prefill (one-shot prefill of a latent-cache model) --
+
+def flash_prefill_check(tokens, heads, nope, vd, rope=64, rank=512):
+    """``latent_expanded_attention`` of one prompt of ``tokens`` tokens at
+    a latent cell's head sizes, keys and values rebuilt from the latents
+    and the kernel for the core, against its XLA form (float32 scores
+    written whole): ``beside_ms`` is the XLA form's."""
+    def run():
+        from paddle_tpu.ops import paged_attention as ops
+
+        rng = np.random.default_rng(5)
+        q = _rand(rng, (1, tokens, heads, nope + rope))
+        lat = _rand(rng, (1, tokens, rank + rope))
+        w = tuple((_rand(rng, (heads, rank, d), jnp.float32)
+                   / np.sqrt(rank)).astype(jnp.bfloat16) for d in (nope, vd))
+        scale = 1.0 / np.sqrt(nope + rope)
+
+        def form(use_pallas):
+            return jax.jit(lambda q, lat, w, start: (
+                ops.latent_expanded_attention(q, lat, w, rank, scale, start,
+                                              use_pallas=use_pallas)))
+
+        f, ref, start = form(True), form(False), jnp.int32(0)
+        err = _err(f(q, lat, w, start), ref(q, lat, w, start))
+        return {"ok": err < 0.05, "max_err": err,
+                "pallas_ms": _bench(f, q, lat, w, start),
+                "beside_ms": _bench(ref, q, lat, w, start)}
+    return run
+
+
 CHECKS = [
     ("flash_fwd_causal=False", flash_check(False)),
     ("flash_fwd_causal=True", flash_check(True)),
@@ -301,6 +331,17 @@ CHECKS = [
     ("sampler_rows2048_vocab128256", sampler_check(2048)),
     # the hybrid cell's decode launch: 256 rows, every slot but the null one
     ("ssm_state_step_256x16x5120", ssm_state_step_check()),
+    # the latent cells' one-shot prefill launches: xing4.0-29b-a4b's three
+    # buckets (32 heads, 128 + 64 / 128) and glm-4.7-flash's widest (20
+    # heads, 192 + 64 / 256)
+    ("flash_prefill_cell_xing_32h_s4096_192_128",
+     flash_prefill_check(4096, 32, 128, 128)),
+    ("flash_prefill_cell_xing_32h_s2048_192_128",
+     flash_prefill_check(2048, 32, 128, 128)),
+    ("flash_prefill_cell_xing_32h_s1024_192_128",
+     flash_prefill_check(1024, 32, 128, 128)),
+    ("flash_prefill_cell_glm_20h_s1024_256_256",
+     flash_prefill_check(1024, 20, 192, 256)),
 ]
 
 

@@ -7,6 +7,7 @@ framework models so the capability rungs are runnable in-repo.
 
 from . import (  # noqa: F401
     bert,
+    eva,
     gpt,
     hc_moe_mla,
     llama,
@@ -24,6 +25,10 @@ from .ernie import (  # noqa: F401
     ErnieConfig,
     ErnieForSequenceClassification,
     ErnieModel,
+)
+from .eva import (  # noqa: F401
+    EvaConfig,
+    EvaDecoderLayer,
 )
 from .gpt import (  # noqa: F401
     GPTConfig,

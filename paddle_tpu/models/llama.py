@@ -710,7 +710,12 @@ class LlamaForCausalLM(Layer):
         super().__init__()
         self.config = config
         self.llama = LlamaModel(config)
-        if config.tie_word_embeddings:
+        # a head that is not one [hidden, vocab] matrix: the configuration
+        # brings it (``make_lm_head``, models/eva.py)
+        make_head = getattr(config, "make_lm_head", None)
+        if make_head is not None:
+            self.lm_head = make_head()
+        elif config.tie_word_embeddings:
             self.lm_head = None
         else:
             self.lm_head = ColumnParallelLinear(

@@ -795,8 +795,7 @@ class CacheSpec:
     argument for it.  Such state cannot be rebuilt from a block prefix of
     pages nor rolled back a token, so ``EngineCore`` refuses, by name,
     every path that would need to (prefix cache, speculative verify,
-    bursts, the ragged program, hand-off, ``mp > 1``).  A layer declares
-    pages OR slots, not both.
+    bursts, the ragged program, hand-off, ``mp > 1``).
 
     The two are two LIFETIMES of one model's layers: a layer that needs
     every token of a sequence declares pages, which grow with it; a
@@ -805,16 +804,37 @@ class CacheSpec:
     ``[window, heads, dim]`` arrays: ``ops/window_attention.py``), so what
     it holds a sequence never grows past the window.  ``window`` says what
     the state IS to whoever counts its use (the engine's ``window_tokens``
-    on ``engine.build``); the allocation goes by ``state`` alone."""
+    on ``engine.build``); the allocation goes by ``state`` alone.
+
+    **Both at once, and a third rate** (``tokens_per_row`` set): a layer of
+    chunk-summarised attention (``ops/eva_attention.py``) keeps a ring of
+    its OPEN window a sequence AND rows that grow with the sequence at one
+    row every ``tokens_per_row`` tokens (a closed window's chunk
+    summaries).  It declares ``state`` (the two rings, ``window`` set) and
+    ``k`` / ``v`` (a row's ``(heads, dim)``) together.  The rows live in
+    the sequence's OWN blocks -- ``block_size / tokens_per_row`` rows a
+    block, pools ``[num_blocks, rows a block, heads, dim]`` -- so the
+    block table ``KVCacheManager`` keeps already is the rows' table and
+    there is no second manager; the engine refuses a block size the rate
+    does not divide.  Such a layer's entry of ``k_pools`` / ``v_pools`` is
+    the pair ``(ring slots, rows)``.  Without ``tokens_per_row`` a layer
+    declares pages OR slots, not both, as before."""
 
     k: Optional[Tuple[int, int]] = None
     v: Optional[Tuple[int, int]] = None
     kind: str = "kv"
     state: Optional[Tuple[Tuple[Tuple[int, ...], Optional[str]], ...]] = None
     window: Optional[int] = None
+    tokens_per_row: Optional[int] = None
 
     def __post_init__(self):
-        if self.state is not None and (self.k or self.v):
+        if self.tokens_per_row is not None:
+            if not (self.state and self.window and self.k and self.v) \
+                    or self.tokens_per_row < 1:
+                raise ValueError(
+                    "rows at one every tokens_per_row tokens stand beside "
+                    "a window's ring: declare state, window, k and v")
+        elif self.state is not None and (self.k or self.v):
             raise ValueError("a layer declares per-token rows or "
                              "per-sequence state, not both")
         if self.window is not None and self.state is None:
@@ -826,8 +846,23 @@ class CacheSpec:
         if self.state is None and self.k is None:
             raise ValueError("a layer that keeps nothing declares no cache")
 
+    @property
+    def ring_and_rows(self) -> bool:
+        """A ring a sequence AND rows that grow with it, in one layer."""
+        return self.tokens_per_row is not None
+
+    def rows_per_block(self, block_size: int) -> int:
+        """Rows a block of ``block_size`` tokens holds in this layer."""
+        per = self.tokens_per_row or 1
+        if block_size % per:
+            raise ValueError(
+                f"block_size {block_size} is no multiple of the "
+                f"{per} tokens a row of this layer stands for")
+        return block_size // per
+
     def values_per_token(self) -> int:
-        return sum(r[0] * r[1] for r in (self.k, self.v) if r)
+        return sum(r[0] * r[1] for r in (self.k, self.v) if r) \
+            // (self.tokens_per_row or 1)
 
     def state_bytes_per_sequence(self, pool_dtype) -> int:
         """Bytes one live sequence holds in this layer's slots."""

@@ -32,6 +32,15 @@ Three paths, one mathematics:
 128 heads over 8,192 keys never hold more than ``SCORE_BYTES`` of float32
 scores (``paged_attention._block_queries``' fixed 1,024 rows would hold
 4.3 GB).
+
+The ring WRITES (:func:`ring_write_token`, :func:`ring_write_span`) also
+serve a second kind of window: chunk-summarised attention
+(``ops/eva_attention.py``) keeps the same ring, token ``p`` at ``p mod W``,
+but its window is ALIGNED to multiples of ``W``, so a row there reads the
+ring's first ``(p mod W) + 1`` entries (not ``min(p + 1, W)``) beside
+summary rows of the closed windows, under one softmax; its reads are that
+file's, not :func:`ring_decode_attention`'s, which returns no
+log-sum-exp to merge two partial attentions by.
 """
 
 from __future__ import annotations

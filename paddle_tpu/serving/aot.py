@@ -190,8 +190,11 @@ def enumerate_buckets(engine, max_seq_len: Optional[int] = None,
     for c in _pow2_upto(oneshot):
         for w in widths:
             out.append(("chunk", (c, w)))
+    # a model whose decode tables have ONE width: its decode step reads
+    # the same whatever the width (engine.decode_table_width)
+    pinned = engine.decode_table_width
     for b in _pow2_upto(sched.max_num_seqs):
-        for w in widths:
+        for w in [pinned] if pinned else widths:
             out.append(("decode", (b, w)))
     return out
 

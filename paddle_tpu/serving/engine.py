@@ -86,6 +86,7 @@ from ..ops.paged_attention import (
     shard_kv_pool,
 )
 from ..ops.decode_burst import run_burst
+from ..ops.eva_attention import EvaCache
 from ..ops.selective_scan import StateCache, state_step_path
 from ..ops.sampling import sample_tokens
 from .burst import burst_eligible, clamp_burst
@@ -428,6 +429,11 @@ class EngineCore:
         # the window whose ring some layers declare as their state, if any
         self._window = max((spec.window or 0 for spec in self.cache_specs),
                            default=0)
+        # layers that keep a ring AND rows at one a chunk of tokens
+        # (``CacheSpec.tokens_per_row``): their window and chunk, else None
+        self._ring_rows = next(
+            ((spec.window, spec.tokens_per_row) for spec in self.cache_specs
+             if spec.ring_and_rows), None)
         self._refuse_state_paths(config)
         # a slot a running sequence: the running set is capped at
         # max_num_seqs, so admission never waits on a slot it cannot get
@@ -438,6 +444,21 @@ class EngineCore:
         self.block_size = block_size
         self.num_blocks = num_blocks
         self.scheduler = ContinuousBatchingScheduler(sched_cfg, self.kv)
+        # the table width of a decode launch: its rows' widest table in
+        # power-of-two buckets -- but ONE width for a model with
+        # ring-and-rows layers, the one its positions reach.  Their decode
+        # step reads the whole pool of rows where it lies and the tables
+        # only say who holds what (``ops/eva_attention.py``), so a
+        # narrower table saves no read and every width is one more
+        # program to compile.  No sequence outgrows that width.  Public:
+        # ``aot.enumerate_buckets`` lists the decode programs by it
+        self.decode_table_width = None
+        if self._ring_rows:
+            reach = min(int(cfg.max_position_embeddings),
+                        (num_blocks - 1) * block_size)
+            self._cap_seq_len(reach, "max_position_embeddings of a model "
+                              "whose decode tables have one width")
+            self.decode_table_width = bucket_size(-(-reach // block_size))
         # registry=None keeps counts per-engine; pass
         # observability.get_registry() to publish serving series on the
         # process-wide Prometheus page next to the jit compile counters.
@@ -564,12 +585,20 @@ class EngineCore:
             return jnp.zeros((self.state_slots + 1,) + tuple(shape),
                              jnp.dtype(slot_dtype or dtype))
 
-        self._k_pools = tuple(
-            slots(spec.state[0]) if spec.state else pool(spec.k)
-            for spec in self.cache_specs)
-        self._v_pools = tuple(
-            slots(spec.state[1]) if spec.state else pool(spec.v)
-            for spec in self.cache_specs)
+        def rows(spec, row):
+            # a row every ``tokens_per_row`` tokens, in the sequence's blocks
+            return jnp.zeros((num_blocks, spec.rows_per_block(block_size))
+                             + tuple(row), dtype)
+
+        def side(spec, i):
+            # a layer's entry of one side of the pools, by what it declared
+            row = (spec.k, spec.v)[i]
+            if spec.ring_and_rows:
+                return slots(spec.state[i]), rows(spec, row)
+            return slots(spec.state[i]) if spec.state else pool(row)
+
+        self._k_pools = tuple(side(spec, 0) for spec in self.cache_specs)
+        self._v_pools = tuple(side(spec, 1) for spec in self.cache_specs)
         self.metrics.registry.gauge(
             "serving_kv_bytes_per_token",
             help="bytes one cached token holds over all layers, as the "
@@ -596,6 +625,21 @@ class EngineCore:
                       **labels).set(sum(
                           spec.state_bytes_per_sequence(dtype)
                           for spec in self.cache_specs))
+        # chunk-summary series: only a model with ring-and-rows layers has them
+        self._eva_counters = None
+        if self._ring_rows:
+            reg, labels = self.metrics.registry, self.metrics.labels
+            self._eva_counters = {
+                "rows_held": reg.gauge(
+                    "serving_eva_summary_rows_held",
+                    help="chunk-summary rows a layer holds for the rows of "
+                         "the last decode launch (one a whole chunk of "
+                         "each sequence)", **labels),
+                "windows_closed": reg.counter(
+                    "serving_eva_windows_closed_total",
+                    help="windows of chunk-summarised attention that "
+                         "closed (their summaries became visible), over "
+                         "sequences", **labels)}
         # routing-load series: made when a launch first brings a load, so a
         # model without routed experts never has them on /metrics
         self._moe_counters = None
@@ -859,19 +903,44 @@ class EngineCore:
             return {}
         ints = {"state_rows": rows,
                 "state_slots_held": self.kv.state_slots_held}
-        if self._window and reqs:
+        if self._ring_rows and reqs:
+            ints.update(self._eva_ints(reqs))
+        elif self._window and reqs:
             ints["window_tokens"] = sum(
                 min(self.kv.seq_len(r.request_id) + 1, self._window)
                 for r in reqs)
         return ints
 
-    def _layer_caches(self, k_pools, v_pools, route_pages, route_state):
+    def _eva_ints(self, reqs) -> Dict[str, int]:
+        """For the decode rows ``reqs`` of a model with ring-and-rows
+        layers (window ``W``, a row a chunk of ``C`` tokens), a row at
+        position ``p``: the ring entries its step reads (``(p mod W) +
+        1``), the summary rows it reads (``(W / C) (p // W)``), the rows
+        whose window this token closes, and the summary rows held once
+        it is written (``(p + 1) // C``).  The last two also go to
+        ``/metrics``."""
+        W, C = self._ring_rows
+        ps = [self.kv.seq_len(r.request_id) for r in reqs]
+        closed = sum((p + 1) % W == 0 for p in ps)
+        held = sum((p + 1) // C for p in ps)
+        self._eva_counters["windows_closed"].inc(closed)
+        self._eva_counters["rows_held"].set(held)
+        return {"eva_ring_tokens": sum(p % W + 1 for p in ps),
+                "eva_summary_rows": sum((W // C) * (p // W) for p in ps),
+                "eva_windows_closed": closed, "eva_rows_held": held}
+
+    def _layer_caches(self, k_pools, v_pools, route_pages, route_state,
+                      route_ring_rows):
         """One cache object a layer for a step program, by what the layer
         declared: ``route_pages(PagedCache)`` for per-token rows,
-        ``route_state(StateCache)`` for per-sequence state."""
+        ``route_state(StateCache)`` for per-sequence state,
+        ``route_ring_rows(EvaCache)`` for a ring and rows at once."""
         caches = []
         for spec, k, v in zip(self.cache_specs, k_pools, v_pools):
-            if spec.state:
+            if spec.ring_and_rows:
+                c = EvaCache(k, v)
+                route_ring_rows(c)
+            elif spec.state:
                 c = StateCache(Tensor(k), Tensor(v))
                 c.use_pallas = self._use_pallas
                 route_state(c)
@@ -1238,7 +1307,8 @@ class EngineCore:
         # a row's state slot is the id of its first block (kv_manager.py);
         # padding rows have table 0, the null slot
         caches = self._layer_caches(
-            k_pools, v_pools, pages, lambda c: c.route(tables[:, 0]))
+            k_pools, v_pools, pages, lambda c: c.route(tables[:, 0]),
+            lambda c: c.route(tables[:, 0], tables))
         logits = self._call_model(ids, caches, pos, param_vals)
         self.attention_paths["decode"] = _paged_ops.last_path
         pages = 0           # the gather path moves no pages a step
@@ -1350,7 +1420,14 @@ class EngineCore:
 
         dense = []
         for spec, kp, vp in zip(self.cache_specs, k_pools, v_pools):
-            if spec.state:
+            if spec.ring_and_rows:
+                # ring and rows are written where they lie; the blocks of
+                # the prompt's tokens give the sequence's table
+                c = EvaCache(kp, vp)
+                c.route(blocks[:1], blocks[None, ::self.block_size],
+                        n_valid=last_pos + 1)
+                dense.append(c)
+            elif spec.state:
                 # the state after the last REAL token goes to the slot of
                 # the sequence's first block; it starts from zero
                 c = StateCache(Tensor(kp), Tensor(vp))
@@ -1365,11 +1442,11 @@ class EngineCore:
         tokens = sample_tokens(last[None], temps, top_ks, top_ps, keys)
         # every k side, then every v side: the order of the parent's program
         new_k = tuple(
-            c.state_pool._value if spec.state else
+            c.k_pool._value if spec.state else
             kp.at[blocks, offs].set(c[0]._value[0].astype(kp.dtype))
             for spec, kp, c in zip(self.cache_specs, k_pools, dense))
         new_v = tuple(
-            c.conv_pool._value if spec.state else
+            c.v_pool._value if spec.state else
             vp if c[1] is None else
             vp.at[blocks, offs].set(c[1]._value[0].astype(vp.dtype))
             for spec, vp, c in zip(self.cache_specs, v_pools, dense))
@@ -1396,7 +1473,10 @@ class EngineCore:
                               q_start=start),
             # state carried in from the slot when the chunk starts past 0
             lambda c: c.route(tables[:, 0], start=start,
-                              n_valid=last_pos + 1))
+                              n_valid=last_pos + 1),
+            # ring and rows hold the sequence's earlier part likewise
+            lambda c: c.route(tables[:, 0], tables, start=start,
+                              n_valid=last_pos + 1, carried=True))
         _paged_ops.last_latent_prefill_path = None
         logits = self._call_model(ids, caches, start, param_vals)
         self._note_flash_prefill("chunk", (ids.shape[1], tables.shape[1]))
@@ -1754,6 +1834,10 @@ class EngineCore:
             pack = SamplingPack(1)
             pack.set_request(0, req)
             self._count_launch(pack)
+            if self._ring_rows:     # the windows this launch closes
+                W = self._ring_rows[0]
+                self._eva_counters["windows_closed"].inc(
+                    (start + n) // W - start // W)
             if start == 0 and n == target:
                 # cold one-shot: dense-cache forward + scatter (the
                 # cheapest program when nothing is cached and no budget
@@ -1861,7 +1945,7 @@ class EngineCore:
                    **self._state_ints(B, reqs), **self._hc_ints):
             Bb = bucket_size(B)
             width = max(len(self.kv.table(r.request_id)) for r in reqs)
-            Wb = bucket_size(width)
+            Wb = self.decode_table_width or bucket_size(width)
             ids = np.zeros((Bb, 1), np.int64)
             poss = np.zeros((Bb,), np.int32)
             tables = np.zeros((Bb, Wb), np.int32)

@@ -20,11 +20,13 @@ makes the stack build :class:`MLAMoEDecoderLayer` instead of
 The cache holds ``(c_kv, k_r)``: ONE row of ``kv_lora_rank + rope`` values
 a token a layer, shared by all heads, and no separate V — the layer says
 so in :meth:`MLAMoEDecoderLayer.cache_spec` and the engine allocates by
-that.  Two paths compute the same mathematics: *expanded* (prefill, chunks,
+that (the row in whole lane tiles, ``ops.paged_attention
+.latent_pool_shape``, so that a page lies contiguous).  Two paths compute the same mathematics: *expanded* (prefill, chunks,
 the cache-less forward: keys and values of every cached token are rebuilt
 from its latent row) and *absorbed* (decode through the pages: ``W_UK`` is
 folded into the query and ``W_UV`` applied after the weighted sum of latent
-rows, so a step reads 576 values a token and never builds a key).
+rows, so a step reads 576 values a token and never builds a key; on a TPU
+its core is the page walk ``ops.pallas_paged.latent_decode_attention``).
 
 **Routed experts** are :func:`paddle_tpu.parallel.moe.dropless_experts`
 behind :func:`~paddle_tpu.parallel.moe.sigmoid_topk_route`: sigmoid scores
@@ -58,6 +60,7 @@ from ..ops.paged_attention import (
     latent_expanded_attention,
     latent_paged_decode_attention,
     latent_paged_prefill_attention,
+    pool_rows,
 )
 from ..parallel.moe import dropless_experts, sigmoid_topk_route
 from .llama import LlamaConfig, LlamaMLP, _apply_rope, _rope_tables
@@ -317,7 +320,7 @@ class LatentAttention(Layer):
 
         def write(p, new):
             new = new if chunk else new[:, 0]
-            return p.at[blocks, offs].set(new.astype(p.dtype))
+            return p.at[blocks, offs].set(pool_rows(new, p))
 
         pool._rebind(run_op("paged_kv_write", write, pool, lat))
         scale = self._scale
@@ -336,7 +339,8 @@ class LatentAttention(Layer):
                     return latent_paged_decode_attention(
                         qv[:, 0], pv, self._w_ukv(w.astype(qv.dtype)),
                         cache.block_tables, cache.seq_lens,
-                        c.kv_lora_rank, scale)[:, None]
+                        c.kv_lora_rank, scale,
+                        use_pallas=cache.use_pallas)[:, None]
 
         o = run_op("mla_paged_attention", attend, q, pool,
                    self.kv_b_proj.weight)

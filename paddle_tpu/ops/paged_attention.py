@@ -781,7 +781,10 @@ class CacheSpec:
     separate values).  ``kind`` names that layout for the paths that can
     take only one: ``"kv"`` shards its head dimension over ``mp`` and is
     what the ragged, burst and hand-off paths move; ``"latent"`` is refused
-    by them, by name, when the engine is built.  A layer with no per-token
+    by them, by name, when the engine is built, and its pool is HELD as
+    ``[num_blocks, block_size, lanes]``, the row in whole lane tiles
+    (:func:`latent_pool_shape`: the array a TPU lays row-major, a page
+    contiguous; :func:`pool_rows` writes a row into either form).  A layer with no per-token
     row (a state-space mixer) leaves ``k`` and ``v`` ``None``.
 
     **Per SEQUENCE** (slots): ``state`` is ``None`` or two ``(shape,
@@ -896,6 +899,16 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _mesh_mp() -> int:
+    """Shards of the ``mp`` axis of the global mesh (1: none): a kernel of
+    one shard does not go into a program laid over more."""
+    from ..distributed import topology
+
+    mesh = topology.get_mesh()
+    return 1 if mesh is None or "mp" not in mesh.axis_names \
+        else int(mesh.shape["mp"])
+
+
 def latent_expanded_attention(q, lat, w_ukv, rank: int, scale: float,
                               q_start=0, lens=None,
                               use_pallas: Optional[bool] = None):
@@ -979,45 +992,104 @@ def _latent_expanded_core(q, c_kv, k_r, w_ukv, scale, q_start, lens):
 def latent_paged_prefill_attention(q, pool, w_ukv, block_tables, seq_lens,
                                    q_start, rank: int, scale: float):
     """A prefill chunk over a paged LATENT cache: gather the rows of each
-    sequence's pages (``pool [num_blocks, block_size, 1, rank + rope]``,
-    the chunk's own rows already written) and attend expanded
+    sequence's pages (``pool [num_blocks, block_size, 1, rank + rope]`` or
+    the resident form of :func:`latent_pool_shape`, the chunk's own rows
+    already written) and attend expanded
     (:func:`latent_expanded_attention`).  XLA gather path, like
     :func:`paged_prefill_attention`."""
-    B, W = block_tables.shape
-    lat = pool[block_tables].reshape(B, W * pool.shape[1], pool.shape[-1])
-    return latent_expanded_attention(q, lat, w_ukv, rank, scale, q_start,
-                                     seq_lens)
+    latent = rank + q.shape[-1] - w_ukv[0].shape[-1]
+    return latent_expanded_attention(
+        q, _latent_context(pool, block_tables, latent), w_ukv, rank, scale,
+        q_start, seq_lens)
 
 
-# the gathered context of one decode launch is held to this size by
+# the gathered context of one decode launch of the XLA form (the kernel's
+# oracle, the CPU's and the kill switch's path; a TPU walks the pages and
+# gathers nothing) is held to this size by
 # splitting the ROWS of the batch into groups run one after another: above
 # it (128 rows of 4,096 tokens are 604 MB) the TPU compiler gave every
 # layer's context a buffer of its own, 6.2 GB of temporaries in a 7-layer
 # step program against 0.8 GB at half the width (compiled for a v5e, PR 29)
 _LATENT_CONTEXT_BYTES = 320 * 2 ** 20
 
+#: lanes a latent pool's rows are a whole number of, as the engine holds it
+LATENT_LANES = 128
+
+
+def latent_pool_shape(num_blocks: int, block_size: int, row) -> tuple:
+    """The RESIDENT shape of a ``kind="latent"`` layer's pool, whose
+    ``CacheSpec.k`` is ``row = (1, latent)``: ``[num_blocks, block_size,
+    lanes]``, ``lanes`` the row's values rounded up to whole tiles of 128
+    (576 -> 640, the padding zeros that nothing reads).  The TPU compiler
+    lays an array out by its shape to pad least: ``[19200, 16, 1, 576]`` and
+    ``[19200, 16, 576]`` both get the BLOCK dimension minor-most, so every
+    program that writes a token's row or reads a page first copied the
+    whole pool into row-major tiles and copied it back after (two copies
+    of 354 MB a layer a step; compiled for a v5e, PR 46).  A last dimension
+    of whole tiles is laid out row-major: the scatter writes in place, and
+    a page ``[bs, lanes]`` is contiguous tiles the decode kernel's copies
+    take as they lie."""
+    heads, dim = row
+    return (num_blocks, block_size,
+            -(-heads * dim // LATENT_LANES) * LATENT_LANES)
+
+
+def pool_rows(rows, pool):
+    """Token rows ``[..., heads, dim]`` as ``pool`` holds them, in its type:
+    as they are for ``[blocks, bs, heads, dim]``; for a latent layer's
+    resident ``[blocks, bs, lanes]`` (:func:`latent_pool_shape`) flat and
+    zero-padded to its lanes."""
+    rows = rows.astype(pool.dtype)
+    if pool.ndim == 4:
+        return rows
+    rows = rows.reshape(rows.shape[:-2] + (-1,))
+    pad = pool.shape[-1] - rows.shape[-1]
+    return jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, pad),))
+
+
+def _latent_context(pool, tables, latent: int):
+    """The rows of each sequence's pages, gathered: ``[B, W * bs, latent]``
+    out of a pool ``[blocks, bs, 1, latent]`` or the resident
+    ``[blocks, bs, lanes]``."""
+    ctx = pool[tables].reshape(tables.shape[0], -1, pool.shape[-1])
+    return ctx if ctx.shape[-1] == latent else ctx[..., :latent]
+
 
 def latent_paged_decode_attention(q, pool, w_ukv, block_tables, seq_lens,
-                                  rank: int, scale: float):
+                                  rank: int, scale: float,
+                                  use_pallas: Optional[bool] = None):
     """One decode token a row over a paged latent cache, ABSORBED: ``W_UK``
     is folded into the query (``q~_h = W_UK,h^T q_nope_h``), scores and the
     weighted sum run on the 576-wide rows themselves, and ``W_UV`` comes
     after — the mathematics of :func:`latent_expanded_attention` with no
-    key or value ever built.  q: ``[B, heads, nope + rope]``; returns
-    ``[B, heads * v]``.  XLA gather path: the padded ``[rows, W *
-    block_size, 576]`` context is materialised, a group of rows at a time
-    (a kernel that walks the pages is ROADMAP's)."""
+    key or value ever built.  q: ``[B, heads, nope + rope]``; pool:
+    ``[blocks, bs, 1, rank + rope]`` or the engine's resident ``[blocks,
+    bs, lanes]`` (:func:`latent_pool_shape`); returns ``[B, heads * v]``.
+
+    The core between the two foldings is ``pallas_paged
+    .latent_decode_attention`` on a TPU — ONE kernel that walks the pages a
+    row holds where they lie and stops at the row's length, at every batch
+    size — where the shapes allow (a resident pool of 16- or 32-bit values
+    in whole sublane tiles a page, ``rank`` whole lane tiles, a rope part
+    of one tile at most), and the XLA gather form elsewhere (the CPU, the
+    operator's kill switch, a mesh with ``mp > 1``), which materialises the
+    padded ``[rows, W * block_size, 576]`` context a group of rows at a time
+    and is the kernel's oracle.  :func:`pallas_dispatch` decides
+    (``use_pallas`` forces or pins) and the form traced is published as
+    the module's :data:`last_path`, as :func:`paged_attention` does."""
+    global last_path
+
     w_uk, w_uv = w_ukv
     B, W = block_tables.shape
     nope = w_uk.shape[-1]
+    latent = rank + q.shape[-1] - nope
     q_lat = jnp.einsum("bhn,hrn->bhr", q[..., :nope], w_uk,
                        preferred_element_type=jnp.float32).astype(q.dtype)
     qc = jnp.concatenate([q_lat, q[..., nope:]], axis=-1)
 
     def rows(args):
         qg, tables, lens = args
-        ctx = pool[tables].reshape(tables.shape[0], -1,
-                                   pool.shape[-1]).astype(q.dtype)
+        ctx = _latent_context(pool, tables, latent).astype(q.dtype)
         s = jnp.einsum("bhd,bmd->bhm", qg, ctx,
                        preferred_element_type=jnp.float32) * scale
         mask = jnp.arange(ctx.shape[1])[None, None, :] < lens[:, None, None]
@@ -1027,17 +1099,35 @@ def latent_paged_decode_attention(q, pool, w_ukv, block_tables, seq_lens,
         return jnp.einsum("bhm,bmd->bhd", probs.astype(ctx.dtype), ctx,
                           preferred_element_type=jnp.float32)[..., :rank]
 
-    per_row = W * (pool[0].size // pool.shape[-1]) * pool.shape[-1] \
-        * jnp.dtype(q.dtype).itemsize
-    groups = 1
-    while B * per_row > groups * _LATENT_CONTEXT_BYTES and B % (2 * groups) == 0:
-        groups *= 2
-    # unrolled, not a loop: two groups at most in practice, and a ``while``
-    # in the program is an event the accepted scope readers count twice
-    n = B // groups
-    u = jnp.concatenate([rows((qc[i:i + n], block_tables[i:i + n],
-                               seq_lens[i:i + n]))
-                         for i in range(0, B, n)], axis=0)
+    def oracle():
+        per_row = W * pool.shape[1] * latent * jnp.dtype(q.dtype).itemsize
+        groups = 1
+        while B * per_row > groups * _LATENT_CONTEXT_BYTES \
+                and B % (2 * groups) == 0:
+            groups *= 2
+        # unrolled, not a loop: two groups at most in practice, and a
+        # ``while`` in the program is an event the accepted scope readers
+        # count twice
+        n = B // groups
+        return jnp.concatenate([rows((qc[i:i + n], block_tables[i:i + n],
+                                      seq_lens[i:i + n]))
+                                for i in range(0, B, n)], axis=0)
+
+    def kernel():
+        from .pallas_paged import latent_decode_attention
+
+        flat = pool if pool.ndim == 3 else pool.reshape(pool.shape[:2] + (-1,))
+        return latent_decode_attention(qc, flat, block_tables, seq_lens,
+                                       rank, scale)
+
+    itemsize = pool.dtype.itemsize
+    tileable = (_on_tpu() and _mesh_mp() == 1 and pool.ndim == 3
+                and pool.shape[-1] % LATENT_LANES == 0
+                and rank % LATENT_LANES == 0
+                and latent - rank <= LATENT_LANES
+                and itemsize in (2, 4)
+                and pool.shape[1] % (32 // itemsize) == 0)
+    u, last_path = pallas_dispatch(kernel, oracle, use_pallas, tileable)
     o = jnp.einsum("bhr,hrv->bhv", u.astype(q.dtype), w_uv)
     return o.reshape(B, -1)
 

@@ -11,8 +11,9 @@ statistics live in VMEM scratch across a row's pages, exactly like the
 flash kernel (``pallas_flash.py``); GQA/MQA is native (query heads grouped
 per KV head, KV pages are read once).
 
-There are TWO kernels behind :func:`paged_attention_decode`, and the pool's
-SHAPE chooses (:func:`pages_per_step`, ``P``: about 128 tokens of a row a
+There are THREE kernels here.  Two stand behind
+:func:`paged_attention_decode` (keys and values, ``[blocks, bs, Hkv, D]``
+pools), and the pool's SHAPE chooses (:func:`pages_per_step`, ``P``: about 128 tokens of a row a
 step, inside a VMEM budget):
 
 * ``P == 1`` — a page already holds a step's worth of tokens (a window
@@ -36,13 +37,23 @@ step, inside a VMEM budget):
   code grows with heads x tokens, and every program that holds the kernel
   loads that code at every warm start.
 
-The launch is a ``jax.jit`` of its own (:func:`_decode`): a step program
+The third, :func:`latent_decode_attention`, is the group walk over a
+LATENT pool (``[blocks, bs, lanes]``: one row a token that all query heads
+share, the absorbed decode of ``paged_attention
+.latent_paged_decode_attention``): the same copies, two buffers and end at
+the row's length, groups of :func:`latent_pages_per_step` pages, and the
+whole of a group's heads in ONE pair of products, since they all read the
+same rows (:func:`_latent_walk_kernel`).
+
+Each launch is a ``jax.jit`` of its own (:func:`_decode`,
+:func:`_latent_decode`): a step program
 calls it once a layer at the same shapes and so traces and lowers each
 kernel once, not once a layer.  And because the group walk reads nothing of
 a table's width, tables narrower than ``TABLE_WIDTH`` entries go in padded
 to it, and launches of fewer than ``ROWS_MIN`` rows get empty rows
-appended: the programs of a process that differ in their table width alone,
-or in a row bucket under 8, share one trace of the kernel.
+appended (:func:`_one_trace_shapes`): the programs of a process that differ
+in their table width alone, or in a row bucket under 8, share one trace of
+the kernel.
 
 q: [B, H, D] (one decode token per sequence)
 k/v_cache: [num_blocks, block_size, Hkv, D]
@@ -369,6 +380,27 @@ def decode_oracle(q, k_cache, v_cache, block_tables, seq_lens):
                                 seq_lens)
 
 
+def _one_trace_shapes(q, block_tables, seq_lens, block_size):
+    """A launch of a group walk at the shapes its trace is shared at.  The
+    walk reads the first ``ceil(len / bs)`` entries of a row's table and
+    nothing of its width, so every narrower table goes in at ONE width: a
+    process then traces the kernel once a row bucket, not once a (rows,
+    width) program.  Lengths are held to what the table holds."""
+    rows, width = block_tables.shape
+    seq_lens = jnp.minimum(seq_lens, width * block_size)
+    if width < TABLE_WIDTH and rows <= TABLE_WIDTH_ROWS:
+        block_tables = jnp.pad(block_tables,
+                               ((0, 0), (0, TABLE_WIDTH - width)))
+    if rows < ROWS_MIN:
+        # and a row of length 0 costs a grid step and no copy: the
+        # smallest row buckets share one trace too
+        more = ROWS_MIN - rows
+        q = jnp.pad(q, ((0, more), (0, 0), (0, 0)))
+        block_tables = jnp.pad(block_tables, ((0, more), (0, 0)))
+        seq_lens = jnp.pad(seq_lens, (0, more))
+    return q, block_tables, seq_lens
+
+
 def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens):
     """Fused paged decode attention; returns [B, H, D]."""
     # Mosaic has no i64: scalar-prefetch operands must be 32-bit
@@ -377,21 +409,8 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, seq_lens):
     rows, width = block_tables.shape
     pages = kernel_pages(k_cache, width)
     if pages > 1:
-        # the group walk reads the first ceil(len / bs) entries of a row's
-        # table and nothing of its width, so every narrower table goes in
-        # at ONE width: a process then traces the kernel once a row bucket,
-        # not once a (rows, width) program
-        seq_lens = jnp.minimum(seq_lens, width * k_cache.shape[1])
-        if width < TABLE_WIDTH and rows <= TABLE_WIDTH_ROWS:
-            block_tables = jnp.pad(block_tables,
-                                   ((0, 0), (0, TABLE_WIDTH - width)))
-        if rows < ROWS_MIN:
-            # and a row of length 0 costs a grid step and no copy: the
-            # smallest row buckets share one trace too
-            more = ROWS_MIN - rows
-            q = jnp.pad(q, ((0, more), (0, 0), (0, 0)))
-            block_tables = jnp.pad(block_tables, ((0, more), (0, 0)))
-            seq_lens = jnp.pad(seq_lens, (0, more))
+        q, block_tables, seq_lens = _one_trace_shapes(
+            q, block_tables, seq_lens, k_cache.shape[1])
     check_scalar_prefetch("paged_attention_decode", block_tables, seq_lens)
     return _decode(q, k_cache, v_cache, block_tables, seq_lens,
                    pages=pages, interpret=_interpret())[:rows]
@@ -491,3 +510,197 @@ def _group_walk(q, k_cache, v_cache, block_tables, seq_lens, *, pages,
             interpret=interpret,
             name="paged_decode_attention",   # its name in a device trace
         )(block_tables, seq_lens, q, k_cache, v_cache)
+
+
+# --- the absorbed latent decode: one row a token, shared by every head --------
+
+#: tokens of a row the latent walk copies and computes on a step.  A latent
+#: page of 16 tokens is 20 KB, so the step's fixed cost wants many of them
+#: under it, and a row's last group is computed on whole, so not too many:
+#: 128 rows of ~1,950 tokens under 20 heads took 1.259 / 1.063 / 0.980 /
+#: 0.976 ms at 256 / 512 / 1,024 / 2,048 (kernel alone; my chip run, PR 46)
+LATENT_STEP_TOKENS = 1024
+
+
+def latent_pages_per_step(block_size: int, lanes: int, itemsize: int,
+                          table_width: int) -> int:
+    """Pages of ONE row :func:`latent_decode_attention` copies and computes
+    on a step: ``LATENT_STEP_TOKENS`` tokens' worth (one page at least),
+    never more than the table is wide or than lets two buffers of them fit
+    ``PAGE_BUFFER_BYTES``."""
+    page = block_size * lanes * itemsize
+    return max(1, min(LATENT_STEP_TOKENS // block_size, table_width,
+                      PAGE_BUFFER_BYTES // (2 * page)))
+
+
+def latent_kernel_pages(pool, table_width: int) -> int:
+    """:func:`latent_pages_per_step` of a latent ``pool``
+    (``[blocks, bs, lanes]``) at this table width."""
+    _, bs, lanes = pool.shape
+    return latent_pages_per_step(bs, lanes, pool.dtype.itemsize, table_width)
+
+
+def _latent_scores(q, lat, k_r):
+    """``[H, T]`` float32 scores of a row's ``H`` queries ``q [H, rank +
+    rope]`` against a group's ``T`` cache rows, given as their latent part
+    ``lat [T, rank]`` and their rope part ``k_r [T, rope]``: two products
+    into one tile, the queries the small side of both."""
+    rank = lat.shape[1]
+    contract = (((1,), (1,)), ((), ()))
+    return (jax.lax.dot_general(q[:, :rank], lat, contract,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(q[:, rank:], k_r, contract,
+                                  preferred_element_type=jnp.float32))
+
+
+def _latent_walk_kernel(bt_ref, len_ref, q_ref, pool_hbm, o_ref,
+                        buf, sems, slot_ref, acc_ref, m_ref, l_ref,
+                        *, scale, block_size, pages, rank):
+    """:func:`_group_walk_kernel` over a latent pool: one grid step a ROW,
+    its pages walked in groups of ``pages`` through two VMEM buffers, the
+    walk ended at the row's length.  All ``H`` heads read the same rows, so
+    a group is one pair of score products ``[H, rank + rope] x [T, ...]``
+    and one weighted sum over the ``rank`` latent lanes."""
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    bs = block_size
+    step_tokens = pages * bs
+
+    seq_len = len_ref[b]    # no more than the table holds: the launch's clamp
+    n_groups = pl.cdiv(seq_len, step_tokens)
+
+    def group_copies(row, g, slot, wait):
+        """Start (or wait for) the copies of group ``g`` of ``row``."""
+        first = g * pages
+
+        def one(i, _):
+            copy = pltpu.make_async_copy(
+                pool_hbm.at[bt_ref[row, first + i]],
+                buf.at[slot, pl.ds(i * bs, bs)], sems.at[slot])
+            copy.wait() if wait else copy.start()
+            return 0
+
+        live = jnp.minimum(pages, pl.cdiv(len_ref[row], bs) - first)
+        jax.lax.fori_loop(0, live, one, 0)
+
+    @pl.when(b == 0)
+    def _first_row():
+        # what a row's last group leaves unwritten keeps an EARLIER
+        # group's tokens (masked, weight 0): never uninitialised memory
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
+
+    prev_len = len_ref[jnp.maximum(b - 1, 0)]
+    next_len = len_ref[jnp.minimum(b + 1, n_rows - 1)]
+    # the row before started this row's first group, unless it was empty
+    @pl.when((seq_len > 0) & ((b == 0) | (prev_len == 0)))
+    def _own_first_group():
+        group_copies(b, 0, slot_ref[0], wait=False)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    def group(g, slot):
+        # before computing on this group, start the copies of the next:
+        # this row's, or after its last the first of the next row
+        more = g + 1 < n_groups
+
+        @pl.when(more | ((b + 1 < n_rows) & (next_len > 0)))
+        def _next():
+            group_copies(jnp.where(more, b, b + 1), jnp.where(more, g + 1, 0),
+                         1 - slot, wait=False)
+
+        group_copies(b, g, slot, wait=True)
+        q = q_ref[0]                                     # [H, rank + rope]
+        # a row's latent part and its rope part: lane-aligned slices of the
+        # buffer where ``rank`` is a multiple of 128; operands in q's type
+        lat = buf[slot, :, :rank].astype(q.dtype)        # [P*bs, rank]
+        k_r = buf[slot, :, rank:q.shape[1]].astype(q.dtype)
+        s2 = _latent_scores(q, lat, k_r) * scale         # [H, P*bs]
+        pos = (jax.lax.broadcasted_iota(jnp.int32, s2.shape, 1)
+               + g * step_tokens)
+        s2 = jnp.where(pos < seq_len, s2, _NEG_INF)
+
+        m_prev = m_ref[...]                              # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s2, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s2 - m_new)                          # [H, P*bs]
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
+        m_ref[...] = m_new
+        # the weighted sum runs over the latent lanes alone: the rope part
+        # of a row is no value
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(lat.dtype), lat, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return 1 - slot
+
+    slot_ref[0] = jax.lax.fori_loop(0, n_groups, group, slot_ref[0])
+    # a row of length 0 (bucket padding) copied nothing and yields zeros
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], np.float32(1e-9))
+                ).astype(o_ref.dtype)
+
+
+def latent_decode_attention(qc, pool, block_tables, seq_lens, rank: int,
+                            scale: float):
+    """The core of the absorbed latent decode, pages read where they lie:
+    ``u[b, h] = softmax_m(scale * qc[b, h] . row[b, m]) . row[b, m, :rank]``
+    over the row's own ``m < seq_lens[b]``.  qc: ``[B, H, rank + rope]``
+    (``W_UK`` already folded into its first ``rank`` lanes); pool:
+    ``[blocks, bs, lanes]`` with a token's ``rank + rope`` values first in
+    its lanes (the rest, a resident pool's padding to whole tiles, is never
+    read); returns ``[B, H, rank]`` in ``qc``'s type."""
+    block_tables = block_tables.astype(jnp.int32)
+    seq_lens = seq_lens.astype(jnp.int32)
+    rows, width = block_tables.shape
+    pages = latent_kernel_pages(pool, width)
+    qc, block_tables, seq_lens = _one_trace_shapes(
+        qc, block_tables, seq_lens, pool.shape[1])
+    check_scalar_prefetch("latent_decode_attention", block_tables, seq_lens)
+    return _latent_decode(qc, pool, block_tables, seq_lens, pages=pages,
+                          rank=rank, scale=float(scale),
+                          interpret=_interpret())[:rows]
+
+
+# A jit of its own, as :func:`_decode` is: traced and lowered once a row
+# bucket, and each layer's copy keeps the scope path of its call site
+# (``.../attn/mla_decode_core/...``: the benchmark's readers)
+@functools.partial(jax.jit,
+                   static_argnames=("pages", "rank", "scale", "interpret"))
+def _latent_decode(qc, pool, block_tables, seq_lens, *, pages, rank, scale,
+                   interpret):
+    B, H, _ = qc.shape
+    _, bs, lanes = pool.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,   # block_tables, seq_lens
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1,) + qc.shape[1:], lambda b, bt, ln: (b, 0, 0)),
+            # the pool stays in HBM: the kernel copies the pages the
+            # scalar-prefetched block table names, and no others
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, H, rank), lambda b, bt, ln: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages * bs, lanes), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),      # one a buffer
+            pltpu.SMEM((1,), jnp.int32),        # buffer the next copy fills
+            pltpu.VMEM((H, rank), jnp.float32),     # acc
+            pltpu.VMEM((H, 1), jnp.float32),        # running max
+            pltpu.VMEM((H, 1), jnp.float32),        # running sum
+        ],
+    )
+    kernel = functools.partial(
+        _latent_walk_kernel, scale=np.float32(scale), block_size=bs,
+        pages=pages, rank=rank)
+    with no_x64():
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((B, H, rank), qc.dtype),
+            # rows run in order: each starts the next one's first copies
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+            name="latent_decode_attention",   # its name in a device trace
+        )(block_tables, seq_lens, qc, pool)

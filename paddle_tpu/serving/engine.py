@@ -571,10 +571,15 @@ class EngineCore:
             # specs from parallel/mp_layers.py) onto the mesh shard-wise
             apply_param_shardings(model, mesh)
         self.metrics.set_mp_shards(self.mp)
-        def pool(row):
+        def pool(row, kind):
             # a layer that keeps nothing on this side holds an empty array
             if row is None:
                 return jnp.zeros((0,), dtype)
+            if kind == "latent":
+                # rows in whole lane tiles, so that the array lies
+                # row-major and a page is read and written where it lies
+                return jnp.zeros(_paged_ops.latent_pool_shape(
+                    num_blocks, block_size, row), dtype)
             return shard_kv_pool(
                 jnp.zeros((num_blocks, block_size) + tuple(row), dtype))
 
@@ -595,7 +600,8 @@ class EngineCore:
             row = (spec.k, spec.v)[i]
             if spec.ring_and_rows:
                 return slots(spec.state[i]), rows(spec, row)
-            return slots(spec.state[i]) if spec.state else pool(row)
+            return slots(spec.state[i]) if spec.state \
+                else pool(row, spec.kind)
 
         self._k_pools = tuple(side(spec, 0) for spec in self.cache_specs)
         self._v_pools = tuple(side(spec, 1) for spec in self.cache_specs)
@@ -1313,11 +1319,12 @@ class EngineCore:
         self.attention_paths["decode"] = _paged_ops.last_path
         pages = 0           # the gather path moves no pages a step
         if _paged_ops.last_path == "pallas":
-            from ..ops.pallas_paged import kernel_pages
-            pages = kernel_pages(
-                next(p for p, spec in zip(k_pools, self.cache_specs)
-                     if spec.k is not None and not spec.state),
-                tables.shape[1])
+            from ..ops.pallas_paged import kernel_pages, latent_kernel_pages
+            pool, kind = next(
+                (p, spec.kind) for p, spec in zip(k_pools, self.cache_specs)
+                if spec.k is not None and not spec.state)
+            pages = (latent_kernel_pages if kind == "latent"
+                     else kernel_pages)(pool, tables.shape[1])
         self._kernel_pages[tuple(tables.shape)] = pages
         last = logits[:, -1, :].astype(jnp.float32)
         # in-trace sampling epilogue (ISSUE 18): greedy rows (temp 0,
@@ -1443,7 +1450,8 @@ class EngineCore:
         # every k side, then every v side: the order of the parent's program
         new_k = tuple(
             c.k_pool._value if spec.state else
-            kp.at[blocks, offs].set(c[0]._value[0].astype(kp.dtype))
+            kp.at[blocks, offs].set(
+                _paged_ops.pool_rows(c[0]._value[0], kp))
             for spec, kp, c in zip(self.cache_specs, k_pools, dense))
         new_v = tuple(
             c.v_pool._value if spec.state else

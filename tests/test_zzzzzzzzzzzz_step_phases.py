@@ -202,6 +202,39 @@ class TestUnarmedStep:
         assert carried <= {0} | pages
         assert (max(carried) > 1) == use_pallas
 
+    def test_a_latent_decode_dispatch_carries_the_walks_pages(
+            self, recorded, monkeypatch):
+        """A latent engine's decode launches ride the same integer: the
+        pages a step of ``latent_decode_attention`` where the program holds
+        that kernel (``latent_kernel_pages`` of the resident pool), so the
+        counter that says a page walk engaged reads > 0 there too."""
+        import importlib
+
+        from benchmarks import harness
+        from paddle_tpu.models import moe_mla
+        from paddle_tpu.ops import paged_attention as ops
+        from paddle_tpu.ops import pallas_paged
+
+        t = importlib.import_module("tests.test_zzzzzzzzzzzzzz_moe_mla")
+        model = harness.load_module("models", "glm_moe_mla").build(
+            t.TINY, 7, dtype="float32")
+        monkeypatch.setattr(pallas_paged, "LATENT_STEP_TOKENS", 8)
+        monkeypatch.setattr(
+            moe_mla, "latent_paged_decode_attention",
+            lambda *a, use_pallas=None, **kw:
+            ops.latent_paged_decode_attention(*a, use_pallas=True, **kw))
+        eng = t.make_engine(model)
+        t.serve(eng, t.prompt_of(21), 4)
+        assert eng.attention_paths["decode"] == "pallas"
+        pool = eng._k_pools[0]
+        pages = {pallas_paged.latent_kernel_pages(pool, width)
+                 for _, _, width in eng.decode_buckets}
+        assert pages == {2} == set(eng._kernel_pages.values())
+        decodes = [kw["pages_per_step"] for n, kw in recorded
+                   if n == "engine.dispatch" and kw["bucket"] < 16]
+        # a bucket's first call is traced inside its dispatch: 0, then 2
+        assert decodes[0] == 0 and set(decodes[1:]) == {2}
+
     @pytest.mark.parametrize("family", sorted(FAMILIES))
     def test_fetch_bytes_with_the_audit_on(self, family, recorded):
         """Audit on: the stats ride every launch the auditor observes,

@@ -107,7 +107,9 @@ def test_dense_layers_declare_keys_and_values():
 
 def test_engine_allocates_by_the_declaration(model):
     eng = make_engine(model)
-    assert [p.shape for p in eng._k_pools] == [(64, 4, 1, 32)] * 3
+    # a token's 32 values in whole lane tiles: the array the TPU lays
+    # row-major, a page contiguous (``latent_pool_shape``, PR 46)
+    assert [p.shape for p in eng._k_pools] == [(64, 4, 128)] * 3
     assert [p.size for p in eng._v_pools] == [0, 0, 0]
     text = eng.metrics.registry.prometheus_text()
     assert "serving_kv_bytes_per_token 384" in text     # 32 values x 4 B x 3

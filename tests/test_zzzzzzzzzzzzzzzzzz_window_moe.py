@@ -542,3 +542,64 @@ def test_flash_prefill_compiles_at_the_latent_cells_shapes(
             scores // 4, 8 << 20), name
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
+
+
+@pytest.mark.parametrize("name,rows,heads,nope,vd,width,dtype", [
+    ("glm-4.7-flash, 128 rows behind tables of 4,096 tokens", 128, 20, 192,
+     256, 256, "bfloat16"),
+    ("glm-4.7-flash, the set-up check's one row", 1, 20, 192, 256, 64,
+     "bfloat16"),
+    ("xing4.0-29b-a4b, 8 rows behind tables of 8,192 tokens", 8, 32, 128,
+     128, 512, "bfloat16"),
+    ("float32 pools", 8, 20, 192, 256, 64, "float32"),
+])
+def test_latent_decode_walk_compiles_at_the_latent_cells_shapes(
+        one_chip, monkeypatch, name, rows, heads, nope, vd, width, dtype):
+    """The TPU compiler takes a token's write and the absorbed latent
+    decode with its kernel (``ops/pallas_paged.py``
+    ``latent_decode_attention``, here for the reason above) on the pool as
+    the engine holds it, at both latent cells' head counts: the resident
+    array lies row-major, so the program holds NO copy of it and nothing
+    the size of a gathered context, and the custom call keeps the scope its
+    readers look for."""
+    from paddle_tpu.ops import paged_attention as ops
+    from paddle_tpu.ops import pallas_paged
+
+    monkeypatch.setattr(pallas_paged, "_interpret", lambda: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        def s(shape, dt=jnp.dtype(dtype)):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+        rank, rope, blocks, bs = 512, 64, 19200, 16
+        shape = ops.latent_pool_shape(blocks, bs, (1, rank + rope))
+        assert shape == (blocks, bs, 640)
+
+        def step(pool, new, slot_blocks, offs, q, wk, wv, tables, lens):
+            pool = pool.at[slot_blocks, offs].set(ops.pool_rows(new, pool))
+            with jax.named_scope("attn"), jax.named_scope("mla_decode_core"):
+                return pool, ops.latent_paged_decode_attention(
+                    q, pool, (wk, wv), tables, lens, rank, 0.0625,
+                    use_pallas=True)
+
+        ints = lambda *sh: s(sh, jnp.int32)
+        compiled = jax.jit(step, donate_argnums=0).lower(
+            s(shape), s((rows, 1, rank + rope)), ints(rows), ints(rows),
+            s((rows, heads, nope + rope)), s((heads, rank, nope)),
+            s((heads, rank, vd)), ints(rows, width), ints(rows)).compile()
+        text = compiled.as_text()
+        call = [l for l in text.splitlines()
+                if "custom-call(" in l and "latent_decode_attention" in l]
+        assert len(call) == 1, name
+        assert "attn/mla_decode_core/" in call[0], name
+        entry = next(l for l in text.splitlines()
+                     if "parameter(0), sharding" in l)
+        assert "640]{2,1,0:" in entry, entry        # row-major, as declared
+        assert not [l for l in text.splitlines()
+                    if " copy(" in l and f"[{blocks},{bs}," in l], name
+        mem = compiled.memory_analysis()
+        pool_bytes = blocks * bs * 640 * jnp.dtype(dtype).itemsize
+        assert mem.alias_size_in_bytes >= pool_bytes
+        assert mem.temp_size_in_bytes < pool_bytes // 64, name
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)

@@ -315,7 +315,7 @@ def test_the_cacheless_forward_agrees_with_the_reference(ref, builder, model):
 
 def test_prefill_then_decode_through_the_pages(ref, builder, model):
     eng = make_engine(model)
-    assert [p.shape for p in eng._k_pools] == [(64, 4, 1, 32)] * 2
+    assert [p.shape for p in eng._k_pools] == [(64, 4, 128)] * 2   # whole lane tiles
     rows = capture(eng)
     prompt = prompt_of(37)
     req = serve(eng, prompt, 6)

@@ -288,6 +288,43 @@ def flash_prefill_check(tokens, heads, nope, vd, rope=64, rank=512):
     return run
 
 
+# --- the absorbed latent decode (one token a row over a latent pool) ---------
+
+def latent_decode_check(rows, heads, nope, vd, lo, hi, width=512, rope=64,
+                        rank=512, blocks=19200):
+    """``latent_paged_decode_attention`` whole (both foldings and the core)
+    at a latent cell's decode launch — the resident pool of 19,200 blocks
+    of 16 tokens in whole lane tiles, ``rows`` rows of ``lo``..``hi``
+    tokens behind tables of ``width`` entries — with the kernel that walks
+    the pages, against its XLA form (the padded context gathered):
+    ``beside_ms`` is the XLA form's."""
+    def run():
+        from paddle_tpu.ops import paged_attention as ops
+
+        rng = np.random.default_rng(6)
+        shape = ops.latent_pool_shape(blocks, BS, (1, rank + rope))
+        pool = jnp.zeros(shape, jnp.bfloat16).at[..., :rank + rope].set(
+            _rand(rng, shape[:2] + (rank + rope,)))
+        q = _rand(rng, (rows, heads, nope + rope))
+        w = tuple((_rand(rng, (heads, rank, d), jnp.float32)
+                   / np.sqrt(rank)).astype(jnp.bfloat16) for d in (nope, vd))
+        bt = jnp.asarray(rng.integers(1, blocks, (rows, width)), jnp.int32)
+        sl = jnp.asarray(rng.integers(lo, hi, (rows,)), jnp.int32)
+        scale = 1.0 / np.sqrt(nope + rope)
+
+        def form(use_pallas):
+            return jax.jit(lambda q, pool, w, bt, sl: (
+                ops.latent_paged_decode_attention(
+                    q, pool, w, bt, sl, rank, scale, use_pallas=use_pallas)))
+
+        f, ref = form(True), form(False)
+        err = _err(f(q, pool, w, bt, sl), ref(q, pool, w, bt, sl))
+        return {"ok": err < 0.05, "max_err": err,
+                "pallas_ms": _bench(f, q, pool, w, bt, sl),
+                "beside_ms": _bench(ref, q, pool, w, bt, sl)}
+    return run
+
+
 CHECKS = [
     ("flash_fwd_causal=False", flash_check(False)),
     ("flash_fwd_causal=True", flash_check(True)),
@@ -342,6 +379,13 @@ CHECKS = [
      flash_prefill_check(1024, 32, 128, 128)),
     ("flash_prefill_cell_glm_20h_s1024_256_256",
      flash_prefill_check(1024, 20, 192, 256)),
+    # the latent cells' decode launches: glm-4.7-flash's 128 rows of 0.5k-4k
+    # tokens under 20 heads (192 + 64 / 256), xing4.0-29b-a4b's 8 rows of
+    # 1k-4.1k under 32 heads (128 + 64 / 128)
+    ("latent_decode_cell_glm_20h_rows128_w512",
+     latent_decode_check(128, 20, 192, 256, 512, 4096)),
+    ("latent_decode_cell_xing_32h_rows8_w512",
+     latent_decode_check(8, 32, 128, 128, 1024, 4200)),
 ]
 
 

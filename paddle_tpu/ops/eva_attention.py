@@ -32,20 +32,23 @@ A chunk's row is written in the launch that completes the chunk and is
 VISIBLE once its window has closed: ``(W / C) (p // W)`` rows at position
 ``p``.
 
-Three paths, one mathematics, all XLA (no kernel: ``pallas_paged.py``
-returns no log-sum-exp to merge two partial attentions by):
+Three paths, one mathematics:
 
-* **decode** (:func:`decode_attention`): the ring is read where it lies,
-  with the queries put in SLOT order (no gathered copy of the rings); the
-  rows where THEY lie too, every row of the launch against the whole pool
-  under a mask of who holds what (one matmul a head; the block tables
-  only say who holds what, so their width changes no read); both score
-  sets under one float32 softmax.  A launch so reads every ring and the
-  pool WHOLE, whatever its rows' lengths: a gathered copy of just a row's
-  blocks costs 7.1 times the read on a v5e (6.91 ms against 0.97 ms a
-  layer at 16 rows x 2,048 blocks of a pool of 34,816; PERF.md section 6,
-  PR 45), so reading no more than a row holds waits for a kernel that
-  walks ring and rows in place (ROADMAP R11a).
+* **decode** (:func:`decode_attention`): ring and rows are read WHERE
+  THEY LIE (no gathered copy of either: a gathered copy of just a row's
+  blocks costs 7.1 times the read on a v5e, PERF.md section 6, PR 45), as
+  two partial attentions.  On a TPU ``ops/pallas_eva.py``'s two kernels,
+  at every number of rows: a row's ring slot up to its position, and the
+  tiles of the pool in which some row of the launch sees a row (every row
+  of the launch against a tile, under a mask of who sees what; the block
+  tables only say who holds what, so their width changes no read), each
+  carrying ``(weighted sum, max, sum)`` in float32 and merged by their
+  log-sum-exp, so no score array over ring and pool is written and a
+  launch's cost follows what its rows hold.  Elsewhere (the CPU, shapes
+  that do not tile) the XLA form, the kernels' oracle: the queries put in
+  SLOT order against every ring whole, every row of the launch against
+  the WHOLE pool under the same mask (one matmul a head), both score sets
+  under one float32 softmax.
 * **a prompt or a chunk of one** (:func:`span_attention`): explicit local
   keys with their positions and remote rows with their chunk ids under
   the masks above, the queries in blocks so that the float32 scores stay
@@ -65,6 +68,7 @@ summarising of chunks.
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -211,18 +215,73 @@ def _chunk_of_row(tables, num_blocks: int, R: int):
 
 
 def decode_attention(q, k_ring, v_ring, k_rows, v_rows, slots, tables, pos,
-                     window: int, chunk: int):
+                     window: int, chunk: int,
+                     use_pallas: Optional[bool] = None):
     """One decode token a row (already written to its ring) over the
     ring's first ``(pos mod window) + 1`` entries and the ``(window /
     chunk) (pos // window)`` rows of the closed windows, one softmax.
     ``q`` ``[B, H, D]``; rings ``[S, W, H, D]``; rows ``[num_blocks, R, H,
     D]``; ``slots`` / ``pos`` ``[B]``, ``tables`` ``[B, width]``.  Returns
-    ``[B, H * D]`` in ``q``'s type."""
+    ``[B, H * D]`` in ``q``'s type.
+
+    On a TPU, at every number of rows, ``ops/pallas_eva.py``'s two kernels
+    where the shapes allow (``pallas_eva.takes``): a row's ring slot up to
+    its position and the tiles of the pool in which some row of the launch
+    sees a row, both where they lie, as two partial attentions merged by
+    their log-sum-exp.  Elsewhere (the CPU, the operator's kill switch,
+    shapes that do not tile) the XLA form below, which reads every ring
+    and the pool whole and is the kernels' oracle.
+    :func:`~paddle_tpu.ops.paged_attention.pallas_dispatch` decides
+    (``use_pallas`` forces or pins, a test's) and the form traced is
+    published as ``paged_attention.last_path``."""
+    from . import pallas_eva as _pk
+
+    B, H, D = q.shape
+    W = k_ring.shape[1]
+
+    def kernel():
+        with jax.named_scope("eva_attn"):
+            with jax.named_scope("eva_local"):
+                # padding rows share the null slot 0 and read nothing
+                loc = _pk.ring_partials(
+                    q, k_ring, v_ring, slots,
+                    jnp.where(slots > 0, jnp.mod(pos, W) + 1, 0))
+            with jax.named_scope("eva_remote"):
+                rem = _pk.pool_partials(
+                    q, k_rows, v_rows,
+                    _seen_rows(tables, pos, k_rows.shape, W, chunk))
+            with jax.named_scope("eva_merge"):
+                return _pk.merge(loc, rem).astype(q.dtype).reshape(B, H * D)
+
+    def oracle():
+        return _decode_attention_xla(q, k_ring, v_ring, k_rows, v_rows, slots,
+                                     tables, pos, chunk)
+
+    out, _paged.last_path = _paged.pallas_dispatch(
+        kernel, oracle, use_pallas,
+        jax.default_backend() == "tpu" and _pk.takes(q, k_ring, k_rows))
+    return out
+
+
+def _seen_rows(tables, pos, pool_shape, window: int, chunk: int):
+    """``[B, num_blocks x R]`` bool: who sees what of the pool ``[num_blocks,
+    R, ...]``.  A freed block's stale rows are nobody's, the open window's
+    rows are held and not yet seen.  The same for every layer of a step
+    (XLA keeps one), and part of the remote half's work: both forms make it
+    under ``eva_attn/eva_remote``, where the benchmark's readers time it."""
+    n_rem = (window // chunk) * (pos // window)
+    return _chunk_of_row(tables, *pool_shape[:2]) < n_rem[:, None]
+
+
+def _decode_attention_xla(q, k_ring, v_ring, k_rows, v_rows, slots, tables,
+                          pos, chunk: int):
+    """:func:`decode_attention` in XLA: every ring read whole in slot
+    order under its row's ``(pos mod W) + 1`` visible entries, every row
+    of the launch against the WHOLE pool under :func:`_seen_rows`, both
+    score sets under one float32 softmax."""
     B, H, D = q.shape
     S, W = k_ring.shape[0], k_ring.shape[1]
-    R = k_rows.shape[1]
     scale = 1.0 / math.sqrt(D)
-    _paged.last_path = "xla"
     with jax.named_scope("eva_attn"):
         with jax.named_scope("eva_local"):
             # the queries in SLOT order: the rings are read where they lie.
@@ -234,14 +293,11 @@ def decode_attention(q, k_ring, v_ring, k_rows, v_rows, slots, tables, pos,
             seen = jnp.arange(W, dtype=jnp.int32)[None] < n_loc[:, None]
             s_loc = jnp.where(seen[:, None, :], s_loc * scale, NEG)
         with jax.named_scope("eva_remote"):
-            # every row of the launch against the WHOLE pool where it lies;
-            # a freed block's stale rows are nobody's and masked with the rest
-            n_rem = (W // chunk) * (pos // W)
             kr, vr = (a.reshape(-1, H, D) for a in (k_rows, v_rows))
             s_rem = jnp.einsum("bhd,nhd->bhn", q, kr.astype(q.dtype),
                                preferred_element_type=jnp.float32)
-            seen = _chunk_of_row(tables, k_rows.shape[0], R) < n_rem[:, None]
-            s_rem = jnp.where(seen[:, None, :], s_rem * scale, NEG)
+            seen_rows = _seen_rows(tables, pos, k_rows.shape, W, chunk)
+            s_rem = jnp.where(seen_rows[:, None, :], s_rem * scale, NEG)
         with jax.named_scope("eva_merge"):
             probs = jax.nn.softmax(jnp.concatenate([s_loc, s_rem], -1), -1)
             p_loc, p_rem = probs[..., :W], probs[..., W:]

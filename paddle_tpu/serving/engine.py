@@ -431,9 +431,20 @@ class EngineCore:
                            default=0)
         # layers that keep a ring AND rows at one a chunk of tokens
         # (``CacheSpec.tokens_per_row``): their window and chunk, else None
-        self._ring_rows = next(
-            ((spec.window, spec.tokens_per_row) for spec in self.cache_specs
-             if spec.ring_and_rows), None)
+        spec = next((s for s in self.cache_specs if s.ring_and_rows), None)
+        self._ring_rows = None if spec is None else (
+            spec.window, spec.tokens_per_row)
+        # such a layer's decode kernel reads its pool of rows in tiles and
+        # skips a tile in which no row of the launch sees a row
+        # (``ops/pallas_eva.py``): the rows of a tile, for the count of
+        # tiles seen, and each running row's (windows closed, first block,
+        # tiles) as last counted
+        self._eva_tiles: Dict[object, tuple] = {}
+        if spec is not None:
+            from ..ops.pallas_eva import pool_tile_rows
+
+            self._eva_tile_rows = pool_tile_rows(
+                num_blocks * spec.rows_per_block(block_size), *spec.k)
         self._refuse_state_paths(config)
         # a slot a running sequence: the running set is capped at
         # max_num_seqs, so admission never waits on a slot it cannot get
@@ -645,7 +656,17 @@ class EngineCore:
                     "serving_eva_windows_closed_total",
                     help="windows of chunk-summarised attention that "
                          "closed (their summaries became visible), over "
-                         "sequences", **labels)}
+                         "sequences", **labels),
+                "tiles_seen": reg.counter(
+                    "serving_eva_pool_tiles_seen_total",
+                    help="tiles of a layer's pool of chunk-summary rows in "
+                         "which some row of a decode launch saw a row (what "
+                         "the decode kernel reads), over launches",
+                    **labels),
+                "tiles": reg.counter(
+                    "serving_eva_pool_tiles_total",
+                    help="tiles of a layer's pool of chunk-summary rows, "
+                         "over decode launches", **labels)}
         # routing-load series: made when a launch first brings a load, so a
         # model without routed experts never has them on /metrics
         self._moe_counters = None
@@ -924,16 +945,42 @@ class EngineCore:
         1``), the summary rows it reads (``(W / C) (p // W)``), the rows
         whose window this token closes, and the summary rows held once
         it is written (``(p + 1) // C``).  The last two also go to
+        ``/metrics``.  And the tiles of a layer's pool of rows in which
+        some row of the launch SEES a row, of the tiles there are: what
+        the decode kernel reads of the pool (``ops/pallas_eva.py``; counted
+        from the tables whichever form runs, so where the XLA form reads
+        the whole pool it is what the kernel WOULD read), also on
         ``/metrics``."""
         W, C = self._ring_rows
         ps = [self.kv.seq_len(r.request_id) for r in reqs]
         closed = sum((p + 1) % W == 0 for p in ps)
         held = sum((p + 1) // C for p in ps)
+        # a row sees the rows of its closed windows: its tiles change when a
+        # window closes, or its blocks do (a preemption: ``_admit`` drops the
+        # row's entry, since a last-in-first-out free list may hand it the
+        # same first block again before other later ones)
+        T, R = self._eva_tile_rows, self.block_size // C
+        tiles, was = {}, self._eva_tiles
+        for r, p in zip(reqs, ps):
+            table = self.kv.table(r.request_id)
+            key = (p // W, table[0] if table else 0)
+            old = was.get(r.request_id)
+            tiles[r.request_id] = old if old and old[0] == key else (
+                key, frozenset((table[c // R] * R + c % R) // T
+                               for c in range((W // C) * (p // W))))
+        self._eva_tiles = tiles
+        # the rows past the pool's last whole tile are read by every launch
+        rest = {self.num_blocks * R // T} if self.num_blocks * R % T else ()
+        seen = len(frozenset(rest).union(*(t for _, t in tiles.values())))
+        total = -(-self.num_blocks * R // T)
         self._eva_counters["windows_closed"].inc(closed)
         self._eva_counters["rows_held"].set(held)
+        self._eva_counters["tiles_seen"].inc(seen)
+        self._eva_counters["tiles"].inc(total)
         return {"eva_ring_tokens": sum(p % W + 1 for p in ps),
                 "eva_summary_rows": sum((W // C) * (p // W) for p in ps),
-                "eva_windows_closed": closed, "eva_rows_held": held}
+                "eva_windows_closed": closed, "eva_rows_held": held,
+                "eva_pool_tiles_seen": seen, "eva_pool_tiles": total}
 
     def _layer_caches(self, k_pools, v_pools, route_pages, route_state,
                       route_ring_rows):
@@ -1320,11 +1367,13 @@ class EngineCore:
         pages = 0           # the gather path moves no pages a step
         if _paged_ops.last_path == "pallas":
             from ..ops.pallas_paged import kernel_pages, latent_kernel_pages
-            pool, kind = next(
-                (p, spec.kind) for p, spec in zip(k_pools, self.cache_specs)
-                if spec.k is not None and not spec.state)
-            pages = (latent_kernel_pages if kind == "latent"
-                     else kernel_pages)(pool, tables.shape[1])
+            # (a ring-and-rows layer's kernels walk no pages: tiles of
+            # the pool where it lies, ``ops/pallas_eva.py``)
+            for pool, spec in zip(k_pools, self.cache_specs):
+                if spec.k is not None and not spec.state:
+                    pages = (latent_kernel_pages if spec.kind == "latent"
+                             else kernel_pages)(pool, tables.shape[1])
+                    break
         self._kernel_pages[tuple(tables.shape)] = pages
         last = logits[:, -1, :].astype(jnp.float32)
         # in-trace sampling epilogue (ISSUE 18): greedy rows (temp 0,
@@ -2463,6 +2512,7 @@ class EngineCore:
                     generated=len(req.output_tokens))
                 self._lc(req.request_id, _lc.EV_PREEMPTED,
                          generated=len(req.output_tokens))
+                self._eva_tiles.pop(req.request_id, None)
             for req in plan.aborted:
                 # unservable at admission: scheduler set state/reason,
                 # the engine owns finish bookkeeping (timestamp +

@@ -603,3 +603,66 @@ def test_latent_decode_walk_compiles_at_the_latent_cells_shapes(
         assert mem.temp_size_in_bytes < pool_bytes // 64, name
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
+
+
+@pytest.mark.parametrize("name,rows,blocks,per_block", [
+    ("evabyte-6.5b, 16 rows over a pool of 136 whole tiles", 16, 34816, 1),
+    ("evabyte-6.5b, the set-up check's one row", 1, 34816, 1),
+    ("a pool of 136 tiles and 100 rows", 16, 34916, 1),
+    ("two rows a block, a pool of 39 tiles and 16 rows", 8, 5000, 2),
+    ("a pool of one tile of 128 and 72 rows", 16, 200, 1),
+])
+def test_chunk_summarised_decode_compiles_at_the_byte_cells_shapes(
+        one_chip, monkeypatch, name, rows, blocks, per_block):
+    """The TPU compiler takes the chunk-summarised decode's two kernels
+    (``ops/pallas_eva.py``, here for the reason above) on the rings and the
+    pool of rows as the engine holds them, at EvaByte's 32 heads of 128 --
+    among them pools whose rows are no multiple of the tile (a deployment's
+    ``num_blocks`` comes from its memory and rarely is one: the rows past
+    the last whole tile are XLA's).  The program holds NO copy of a ring or of
+    the pool and nothing their size, and both custom calls and the mask of
+    who sees what keep the scopes the benchmark's readers time them by."""
+    from paddle_tpu.ops import eva_attention, pallas_eva
+
+    monkeypatch.setattr(pallas_eva, "_interpret", lambda: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        def s(shape, dt=jnp.bfloat16):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+        slots, window, heads, dim, width = 17, 2048, 32, 128, 2048
+        tile = pallas_eva.pool_tile_rows(blocks * per_block, heads, dim)
+        assert tile == min(256, blocks * per_block // 128 * 128)
+
+        def step(q, k_ring, v_ring, k_rows, v_rows, slot, tables, pos):
+            with jax.named_scope("attn"):
+                return eva_attention.decode_attention(
+                    q, k_ring, v_ring, k_rows, v_rows, slot, tables, pos,
+                    window, 16, use_pallas=True)
+
+        ints = lambda *sh: s(sh, jnp.int32)
+        ring = s((slots, window, heads, dim))
+        pool = s((blocks, per_block, heads, dim))
+        assert pallas_eva.takes(s((rows, heads, dim)), ring, pool)
+        compiled = jax.jit(step).lower(
+            s((rows, heads, dim)), ring, ring, pool, pool, ints(rows),
+            ints(rows, width), ints(rows)).compile()
+        lines = compiled.as_text().splitlines()
+        calls = [l for l in lines
+                 if "custom-call(" in l and "tpu_custom_call" in l]
+        assert len(calls) == 2, name
+        assert ["attn/eva_attn/eva_remote/" in l and "eva_pool_attention" in l
+                for l in calls] == [True, False], name
+        assert ["attn/eva_attn/eva_local/" in l and "eva_ring_attention" in l
+                for l in calls] == [False, True], name
+        # the serial scatter that says who holds what is the remote half's
+        scatters = [l for l in lines if "jit(step)" in l and "scatter" in l]
+        assert scatters and all("attn/eva_attn/eva_remote/" in l
+                                for l in scatters), name
+        assert not [l for l in lines if " copy(" in l and (
+            f"[{blocks}," in l or f"[{slots},{window}," in l)], name
+        pool_bytes = blocks * per_block * heads * dim * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < max(
+            pool_bytes // 8, 4 << 20), name
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)

@@ -265,21 +265,39 @@ def test_a_prompt_in_chunks_agrees_with_the_reference(ref, weights, model,
     assert res["ok"] and res["rows"] == 9, res
 
 
-@pytest.mark.parametrize("form,kw", [
+@pytest.mark.parametrize("form,kw,kernels", [
     ("one row a block, a pool of many times what the one row's table "
      "reaches (the cell's check: one request at a time)",
-     dict(num_blocks=512)),
-    ("two rows a block", dict(block_size=32, num_blocks=24)),
+     dict(num_blocks=512), False),
+    ("two rows a block", dict(block_size=32, num_blocks=24), False),
+    ("the kernels: one row a block, a pool of eight tiles of which the one "
+     "row sees one", dict(num_blocks=512), True),
+    ("the kernels: two rows a block, a pool that ends inside its one tile",
+     dict(block_size=32, num_blocks=24), True),
 ])
 def test_the_rows_read_where_they_lie_agree_with_the_reference(
-        ref, weights, model, form, kw):
-    """A decode launch reads the WHOLE pool under a mask of who holds
-    what, whatever the launch's rows and the pool's size: one form, so
-    what a check of one request compares is what a full launch runs."""
+        ref, weights, model, monkeypatch, form, kw, kernels):
+    """A decode launch reads ring and rows WHERE THEY LIE, in one form
+    whatever the launch's rows and the pool's size, so what a check of one
+    request compares is what a full launch runs.  On the CPU that form is
+    XLA's (every ring whole, the whole pool under a mask of who holds
+    what); on a TPU it is ``ops/pallas_eva.py``'s two kernels (the row's
+    ring slot up to its position, the tiles of the pool somebody sees,
+    merged by their log-sum-exp), forced here in interpret mode through
+    the engine.  Both against the float32 reference."""
+    from paddle_tpu.ops import eva_attention as eva
+    from paddle_tpu.ops import paged_attention as paged
+
+    if kernels:
+        real = eva.decode_attention
+        monkeypatch.setattr(
+            eva, "decode_attention",
+            lambda *a, **k: real(*a, **dict(k, use_pallas=True)))
     eng = make_engine(model, **kw)
     rows = capture(eng)
     prompt = prompt_of(70, seed=7)
     req = serve(eng, prompt, 30)
+    assert paged.last_path == ("pallas" if kernels else "xla")
     res = check(ref, weights, rows, req, prompt, 30)
     assert res["ok"] and res["rows"] == 31, (form, res)
 

@@ -129,6 +129,102 @@ def test_the_integers_of_a_decode_launch_and_the_metrics_series(model):
     assert "serving_eva_" not in dense.metrics.registry.prometheus_text()
 
 
+@pytest.mark.parametrize("blocks", [40, 41])
+def test_the_tiles_a_launch_sees_are_counted_from_the_tables(
+        model, monkeypatch, blocks):
+    """``engine.build`` of a decode launch carries ``eva_pool_tiles_seen``
+    / ``eva_pool_tiles``: the tiles of a layer's pool of rows in which
+    some row of the launch sees a row (a closed window's), of the tiles
+    there are -- counted here by hand from the tables --, and ``/metrics``
+    sums both over launches; the integers the roofline reads are as they
+    were.  The rows past a pool's last whole tile (41 blocks: two of them)
+    are read by every launch and count as a tile seen."""
+    from paddle_tpu.ops import pallas_eva
+    from paddle_tpu.serving.request import SamplingParams
+
+    # tiles of 4 rows, so that three sequences' blocks spread over several
+    monkeypatch.setattr(pallas_eva, "pool_tile_rows", lambda *a: 4)
+    eng = make_engine(model, block_size=32, num_blocks=blocks)
+    W, C, R, T = 32, 16, 2, 4
+    assert eng._eva_tile_rows == T
+    total, rest = -(-blocks * R // T), {blocks * R // T} if blocks % 2 else set()
+    launches, built = [], []
+    ints, phase = eng._eva_ints, eng.tracer.phase
+
+    def counted(reqs):
+        rows = [(eng.kv.seq_len(r.request_id), list(eng.kv.table(
+            r.request_id))) for r in reqs]
+        launches.append((ints(reqs), rows))
+        return launches[-1][0]
+
+    def traced(name, prof=None, **stats):
+        if name == "engine.build" and "eva_ring_tokens" in stats:
+            built.append(stats)
+        return phase(name, prof, **stats)
+
+    eng._eva_ints, eng.tracer.phase = counted, traced
+    reqs = [eng.add_request(prompt_of(n, seed=n), SamplingParams(
+        max_new_tokens=40, temperature=0.0)) for n in (20, 70, 130)]
+    for _ in range(200):
+        if all(r.finished for r in reqs):
+            break
+        eng.step()
+    assert all(r.finished for r in reqs)
+    assert max(len(rows) for _, rows in launches) == 3
+    for got, rows in launches:
+        tiles = rest | {(table[c // R] * R + c % R) // T for p, table in rows
+                        for c in range((W // C) * (p // W))}
+        ps = [p for p, _ in rows]
+        assert got == {
+            "eva_pool_tiles_seen": len(tiles), "eva_pool_tiles": total,
+            "eva_ring_tokens": sum(p % W + 1 for p in ps),
+            "eva_summary_rows": sum((W // C) * (p // W) for p in ps),
+            "eva_windows_closed": sum((p + 1) % W == 0 for p in ps),
+            "eva_rows_held": sum((p + 1) // C for p in ps)}, (got, rows)
+    # the rows' windows close as they decode: the tiles seen grow
+    seen = [got["eva_pool_tiles_seen"] for got, _ in launches]
+    assert len(rest) == min(seen) < max(seen) <= total
+    assert [{k: s[k] for k in launches[0][0]} for s in built] \
+        == [got for got, _ in launches]
+    reg, labels = eng.metrics.registry, eng.metrics.labels
+    assert reg.counter("serving_eva_pool_tiles_seen_total",
+                       **labels).value == sum(seen)
+    assert reg.counter("serving_eva_pool_tiles_total",
+                       **labels).value == total * len(launches)
+
+
+def test_a_preempted_rows_tiles_are_counted_from_its_new_blocks(model,
+                                                               monkeypatch):
+    """A row's tiles are kept from launch to launch under (windows closed,
+    first block).  The free list is last-in-first-out: a row preempted and
+    prefilled again between two decode launches may get its FIRST block
+    back and other later ones, so the admission that preempts it drops
+    what was kept and the next launch counts from the new table."""
+    import types
+
+    from paddle_tpu.ops import pallas_eva
+
+    monkeypatch.setattr(pallas_eva, "pool_tile_rows", lambda *a: 4)
+    eng = make_engine(model, block_size=32, num_blocks=40)
+    W, C, R, T = 32, 16, 2, 4
+    req = types.SimpleNamespace(request_id="r", trace_id=None,
+                                output_tokens=[1, 2])
+    tables = {"r": [3, 4, 5, 6, 7]}
+    monkeypatch.setattr(eng.kv, "table", lambda rid: tables[rid])
+    monkeypatch.setattr(eng.kv, "seq_len", lambda rid: 4 * W + 5)
+
+    def by_hand():
+        return len({(tables["r"][c // R] * R + c % R) // T
+                    for c in range((W // C) * 4)})
+
+    assert eng._eva_ints([req])["eva_pool_tiles_seen"] == by_hand() == 3
+    tables["r"] = [3, 20, 9, 30, 31]
+    assert by_hand() == 4
+    eng._admit(types.SimpleNamespace(preempted=[req], aborted=[],
+                                     admitted=[]))
+    assert eng._eva_ints([req])["eva_pool_tiles_seen"] == 4
+
+
 def test_the_metrics_are_documented_where_the_checker_looks():
     import os
     import subprocess
@@ -140,7 +236,9 @@ def test_the_metrics_are_documented_where_the_checker_looks():
     assert out.returncode == 0, out.stdout + out.stderr
     readme = open(os.path.join(root, "README.md")).read()
     for name in ("serving_eva_summary_rows_held",
-                 "serving_eva_windows_closed_total"):
+                 "serving_eva_windows_closed_total",
+                 "serving_eva_pool_tiles_seen_total",
+                 "serving_eva_pool_tiles_total"):
         assert name in readme
 
 
@@ -170,17 +268,17 @@ PLANTS = {
     "none": lambda mp: None,
     "mu_and_phi_swapped": swap_pooling,
     "decode_reads_a_sliding_window": lambda mp: planted(
-        mp, "decode_attention", "n_loc = jnp.mod(pos, W) + 1",
+        mp, "_decode_attention_xla", "n_loc = jnp.mod(pos, W) + 1",
         "n_loc = jnp.minimum(pos + 1, W)"),
     "decode_sees_summaries_a_window_early": lambda mp: planted(
-        mp, "decode_attention", "n_rem = (W // chunk) * (pos // W)",
-        "n_rem = (W // chunk) * (pos // W + 1)"),
+        mp, "_seen_rows", "n_rem = (window // chunk) * (pos // window)",
+        "n_rem = (window // chunk) * (pos // window + 1)"),
     "a_prompts_windows_see_no_summaries": lambda mp: planted(
         mp, "span_attention", "< qw[:, None] // window)",
         "< qw[:, None] // window - 1)"),
     "stale_summary_rows_visible": lambda mp: planted(
-        mp, "decode_attention", "n_rem = (W // chunk) * (pos // W)",
-        "n_rem = (W // chunk) * (pos // W) + 2 * (pos >= 64)"),
+        mp, "_seen_rows", "n_rem = (window // chunk) * (pos // window)",
+        "n_rem = (window // chunk) * (pos // window) + 2 * (pos >= 64)"),
 }
 
 

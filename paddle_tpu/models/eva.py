@@ -63,6 +63,7 @@ from ..nn.layers import Layer
 from ..ops import eva_attention as _eva
 from ..ops import window_attention as _win
 from ..ops.paged_attention import CacheSpec
+from ..ops.selective_scan import StateSlots
 from .llama import LlamaConfig, LlamaMLP
 from .window_moe import _positions
 
@@ -288,6 +289,8 @@ class EvaDecoderLayer(Layer):
     """``x + Attn(norm1(x))``, then ``+ MLP(norm2(.))``, the sums in
     float32; a prompt longer than a window a window at a time."""
 
+    telemetry = (StateSlots, _eva.SummaryRows)
+
     def __init__(self, config: EvaConfig, layer_idx: int = 0):
         super().__init__()
         self.config = config
@@ -306,7 +309,8 @@ class EvaDecoderLayer(Layer):
         row = (c.num_attention_heads, c.head_dim)
         ring = ((c.window_size,) + row, None)
         return CacheSpec(k=row, v=row, state=(ring, ring),
-                         window=c.window_size, tokens_per_row=c.chunk_size)
+                         window=c.window_size, tokens_per_row=c.chunk_size,
+                         cache=_eva.EvaCache)
 
     def _block(self, x, cache, pos):
         def add(a, b):
@@ -339,8 +343,9 @@ class EvaDecoderLayer(Layer):
                 xw, i = xs
                 part = _eva.EvaCache((carry[0], carry[2]),
                                      (carry[1], carry[3]))
-                part.route(slots, tables, start=i * W,
-                           n_valid=jnp.clip(n_valid - i * W, 0, W))
+                # a window of a whole prompt: nothing of it is carried
+                part.slots, part.tables, part.start = slots, tables, i * W
+                part.n_valid = jnp.clip(n_valid - i * W, 0, W)
                 y = self._block(Tensor(xw[None]), part, Tensor(i * W))
                 return tuple(t._value for t in part.tensors), y._value[0]
 

@@ -32,8 +32,9 @@ Device scopes: an outer ``mhc`` that is NOT inside ``attn`` or ``mlp`` (as
 ``ssm`` is not), with ``mhc_coeffs``, ``mhc_sinkhorn``, ``mhc_pre``,
 ``mhc_post`` inside it.  After a forward every :class:`HyperConnection`
 holds the health of its Sinkhorn step (``health``);
-``LlamaForCausalLM.pop_hc_health`` sums them for the engine, which carries
-them on ``engine.fetch`` and ``/metrics``.
+``ops.hyper_connections.Health``, which the layer names under
+``telemetry``, sums them and carries them on ``engine.fetch`` and
+``/metrics``.
 """
 
 from __future__ import annotations
@@ -51,6 +52,7 @@ from ..nn.norm import RMSNorm
 from ..ops import hyper_connections as _hc
 from ..ops.paged_attention import CacheSpec
 from .llama import LlamaMLP
+from ..parallel.moe import ExpertLoad
 from .moe_mla import LatentAttention, MoEMLAConfig, RoutedExperts
 
 
@@ -176,6 +178,8 @@ class HCMLAMoEDecoderLayer(Layer):
     """Latent attention, then the dense SwiGLU (the first
     ``first_k_dense_replace`` layers) or the routed experts, each behind
     its own :class:`HyperConnection` and its own pre-norm."""
+
+    telemetry = (ExpertLoad, _hc.Health)    # the order they ride a launch in
 
     def __init__(self, config: HCMoEMLAConfig, layer_idx: int = 0):
         super().__init__()

@@ -42,6 +42,8 @@ from ..nn.container import LayerList
 from ..nn.initializer import Constant, Normal
 from ..nn.layers import Layer
 from ..nn.norm import RMSNorm
+from ..ops.hyper_connections import pop_health
+from ..parallel.moe import pop_load
 from ..parallel.mp_layers import (
     ColumnParallelLinear,
     RowParallelLinear,
@@ -742,36 +744,21 @@ class LlamaForCausalLM(Layer):
         pools and its prefill buffers by this and by nothing else."""
         return [layer.cache_spec() for layer in self.llama.layers]
 
-    def pop_expert_load(self):
-        """``[expert layers, experts]`` int32: the tokens each routed
-        expert received in the forward just run (``RoutedExperts.load``),
-        or ``None`` for a model without such layers.  Clears what the
-        layers held, so a traced value never outlives its trace."""
-        loads = []
+    def launch_telemetry(self, view):
+        """``ops.paged_attention.LaunchTelemetry``: one object a class the
+        layers name under ``telemetry`` (a dense layer names none), over
+        the layers that name it, in the order they first do."""
+        kinds = {}
         for layer in self.llama.layers:
-            load = getattr(layer.mlp, "load", None)
-            if load is not None:
-                loads.append(load)
-                layer.mlp.load = None
-        return jnp.stack(loads) if loads else None
+            for kind in getattr(layer, "telemetry", ()):
+                kinds.setdefault(kind, []).append(layer)
+        return [kind(layers, view) for kind, layers in kinds.items()]
+
+    def pop_expert_load(self):      # ``tests/bench_suite`` clears by name
+        return pop_load(self.llama.layers)
 
     def pop_hc_health(self):
-        """float32 ``[3]``: over the hyper-connections of the forward just
-        run (``models/hc_moe_mla.py``) the entries of the pre-``exp``
-        matrices that met the clamp, the entries computed, and the largest
-        ``|colsum - 1|`` a Sinkhorn step left; ``None`` for a model with
-        one residual stream.  Clears what the layers held."""
-        found = []
-        for layer in self.llama.layers:
-            for hc in (getattr(layer, "attn_hc", None),
-                       getattr(layer, "mlp_hc", None)):
-                if hc is not None and hc.health is not None:
-                    found.append(hc.health)
-                    hc.health = None
-        if not found:
-            return None
-        h = jnp.stack(found)
-        return jnp.stack([h[:, 0].sum(), h[:, 1].sum(), h[:, 2].max()])
+        return pop_health(self.llama.layers)
 
     def train_batch_1f1b(self, input_ids, labels, n_microbatch: int,
                          criterion=None, recompute: bool = False):

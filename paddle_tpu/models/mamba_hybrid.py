@@ -57,6 +57,7 @@ from ..nn.norm import RMSNorm
 from ..ops.paged_attention import CacheSpec
 from ..ops.selective_scan import (
     StateCache,
+    StateSlots,
     causal_conv,
     conv_window,
     route_state_step,
@@ -263,6 +264,8 @@ class MambaMixer(Layer):
 class MambaDecoderLayer(Layer):
     """Pre-norm block: the mixer, then the dense SwiGLU."""
 
+    telemetry = (StateSlots,)
+
     def __init__(self, config: HybridMambaConfig, layer_idx: int = 0):
         super().__init__()
         self.config = config
@@ -278,7 +281,8 @@ class MambaDecoderLayer(Layer):
         c = self.config
         return CacheSpec(state=(
             ((c.mamba_d_state, c.mamba_d_inner), "float32"),
-            (((c.mamba_d_conv - 1) * c.mamba_d_inner,), None)))
+            (((c.mamba_d_conv - 1) * c.mamba_d_inner,), None)),
+            cache=StateCache)
 
     def forward(self, x, cache=None, pos=None):
         with jax.named_scope("ssm"):

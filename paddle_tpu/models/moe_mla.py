@@ -62,7 +62,7 @@ from ..ops.paged_attention import (
     latent_paged_prefill_attention,
     pool_rows,
 )
-from ..parallel.moe import dropless_experts, sigmoid_topk_route
+from ..parallel.moe import ExpertLoad, dropless_experts, sigmoid_topk_route
 from .llama import LlamaConfig, LlamaMLP, _apply_rope, _rope_tables
 
 
@@ -409,6 +409,8 @@ class RoutedExperts(Layer):
 class MLAMoEDecoderLayer(Layer):
     """Pre-norm block: latent attention, then the dense SwiGLU (the first
     ``first_k_dense_replace`` layers) or the routed experts."""
+
+    telemetry = (ExpertLoad,)
 
     def __init__(self, config: MoEMLAConfig, layer_idx: int = 0):
         super().__init__()

@@ -64,8 +64,8 @@ from ..nn.norm import LayerNorm
 from ..ops import paged_attention as _paged
 from ..ops import window_attention as _win
 from ..ops.paged_attention import CacheSpec, PagedCache
-from ..ops.selective_scan import StateCache
-from ..parallel.moe import dropless_experts, sigmoid_topk_route
+from ..ops.selective_scan import StateCache, StateSlots
+from ..parallel.moe import ExpertLoad, dropless_experts, sigmoid_topk_route
 from .llama import LlamaConfig, LlamaMLP, _apply_rope, _rope_tables
 
 
@@ -359,6 +359,8 @@ class ParallelWindowMoELayer(Layer):
                                          bias_attr=False)
         self.self_attn = WindowedAttention(config, self.window)
         self.mlp = HeldExperts(config)
+        self.telemetry = (ExpertLoad,) if self.window is None else (
+            StateSlots, _win.WindowTokens, ExpertLoad)
 
     def cache_spec(self) -> CacheSpec:
         """A global layer: keys and values a token, in pages.  A window
@@ -369,7 +371,8 @@ class ParallelWindowMoELayer(Layer):
         if self.window is None:
             return CacheSpec(k=row, v=row)
         ring = ((self.window,) + row, None)
-        return CacheSpec(state=(ring, ring), window=self.window)
+        return CacheSpec(state=(ring, ring), window=self.window,
+                         cache=StateCache)
 
     def forward(self, x, cache=None, pos=None):
         u = self.input_layernorm(x)

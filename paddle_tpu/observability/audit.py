@@ -486,7 +486,7 @@ class NumericsAuditor:
                 for k, v in zip(k_pools, v_pools):
                     c = PagedCache(Tensor(k), Tensor(v))
                     c.route(tables, lens, slot_blocks, slot_offsets,
-                            q_start=pos[0], seg_ids=seg_ids)
+                            start=pos[0], seg_ids=seg_ids)
                     c.use_pallas = False  # the XLA ragged oracle
                     caches.append(c)
                 logits = eng._call_model(ids, caches, pos, param_vals)

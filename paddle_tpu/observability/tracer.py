@@ -53,21 +53,15 @@ from jax.profiler import TraceAnnotation
 # fetch and emit of launch N, the one dispatched a step earlier.
 # The names are a contract: the benchmark's readers
 # (benchmarks/host_spans.py) and PERF.md key on them.
+# What a model's kinds of layer add to ``engine.build`` / ``engine.fetch``:
+# ``ops.paged_attention.LaunchTelemetry`` (a kind's docstring lists them).
 STEP_PHASES = (
     "engine.wait",          # engine thread blocked with no work
     "engine.intake",        # server queue -> scheduler (submits, aborts)
     "sched.plan",           # scheduler.schedule()
     "engine.admit",         # per-request admission bookkeeping
     "engine.build",         # numpy routing arrays + the SamplingPack
-                            # (rows= on a decode launch).  For a model
-                            # with per-sequence state also state_rows=
-                            # (real rows whose state the launch advances)
-                            # and state_slots_held=; where that state is
-                            # a window's ring, a decode launch also
-                            # window_tokens= (the ring entries its rows
-                            # read: min(length, window) summed over them).
-                            # For a model whose residual path is several
-                            # streams also hc_streams= (how many)
+                            # (rows= on a decode launch)
     "engine.dispatch",      # the step call, until the jit call returns
                             # (rows=, bucket=, ahead= 1 where the launch
                             # went out before the tokens of the launch
@@ -79,21 +73,7 @@ STEP_PHASES = (
     "engine.fetch",         # host arrays of what the step reads (bytes=):
                             # the int32 tokens; with the audit on its
                             # stats, and a sampled decode / ragged
-                            # launch's real rows of logits.  For a
-                            # model with routed experts also the
-                            # launch's routing load: moe_assignments=,
-                            # moe_experts_touched=, moe_max_load= (summed
-                            # over expert layers), moe_decode= 1 on
-                            # decode; where the model holds a SHARE of
-                            # its experts also moe_pairs_held= (pairs
-                            # routed to them) and moe_held_touched=.
-                            # For a model with hyper-connections the
-                            # health of the launch's Sinkhorn steps:
-                            # hc_res_clamped= of hc_entries= entries of
-                            # the pre-exp matrices met the clamp (over
-                            # sublayers, padding tokens included),
-                            # hc_sinkhorn_residual_ppb= the largest
-                            # |column sum - 1| left, in parts per billion
+                            # launch's real rows of logits
     "engine.emit",          # commit, emission, retire; then the stream
                             # hand-off: one callback posted to the
                             # server's loop a step (streams= the

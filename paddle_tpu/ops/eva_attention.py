@@ -106,7 +106,8 @@ class EvaCache:
     and ``start`` the absolute position of its first token (``None``: 0);
     ``carried`` says the ring and the rows hold an earlier part of THIS
     sequence's open window that the launch's queries may see (a chunk
-    that does not start a window).  ``n_valid`` is ``None`` in decode."""
+    that does not start a window: :meth:`route` sets it where it is given
+    a ``start``).  ``n_valid`` is ``None`` in decode."""
 
     def __init__(self, k_side, v_side):
         from ..core.tensor import Tensor
@@ -129,14 +130,109 @@ class EvaCache:
         for t, a in zip(self.tensors, arrays):
             t._rebind(a)
 
-    def route(self, slots, tables, start=None, n_valid=None,
-              carried: bool = False):
-        self.slots = jnp.asarray(slots, jnp.int32)
+    over = classmethod(lambda cls, k, v: cls(k, v))
+
+    def route(self, tables, seq_lens=None, slot_blocks=None,
+              slot_offsets=None, start=None, n_valid=None):
+        """``PagedCache.route``'s arrays: a row's ring slot is the id of
+        its first block; given a ``start``, ring and rows hold the
+        sequence's earlier part."""
+        self.slots = jnp.asarray(tables[:, 0], jnp.int32)
         self.tables = jnp.asarray(tables, jnp.int32)
         self.start = None if start is None else jnp.asarray(start, jnp.int32)
         self.n_valid = None if n_valid is None \
             else jnp.asarray(n_valid, jnp.int32)
-        self.carried = carried
+        self.carried = start is not None
+
+
+class SummaryRows(_paged.LaunchTelemetry):
+    """What ring-and-rows layers (window ``W``, chunk ``C``) bring to a
+    launch.  On ``engine.build`` of a DECODE launch, summed over its rows
+    at positions ``p``: ``eva_ring_tokens`` (ring entries read, ``(p mod
+    W) + 1``), ``eva_summary_rows`` (summary rows read, ``(W / C) (p //
+    W)``), ``eva_windows_closed`` (this token closes the row's window),
+    ``eva_rows_held`` (rows held once it is written, ``(p + 1) // C``);
+    and ``eva_pool_tiles_seen`` of ``eva_pool_tiles``: the tiles of a
+    layer's pool in which some row of the launch SEES a row -- what the
+    decode kernel reads (``ops/pallas_eva.py``; counted from the tables, so
+    under the XLA form what the kernel WOULD read).  Also the
+    ``serving_eva_*`` series below (a prompt's windows too)."""
+
+    def __init__(self, layers, view):
+        super().__init__(layers, view)
+        from .pallas_eva import pool_tile_rows
+
+        spec, kv = layers[0].cache_spec(), view.kv
+        self.window, self.chunk = spec.window, spec.tokens_per_row
+        # the rows of a tile of the pool, and each running row's (windows
+        # closed, first block, tiles) as last counted
+        self.tile_rows = pool_tile_rows(
+            kv.num_blocks * spec.rows_per_block(kv.block_size), *spec.k)
+        self.tiles: dict = {}
+        reg, labels = view.registry, view.labels
+        self.counters = {
+            "rows_held": reg.gauge(
+                "serving_eva_summary_rows_held", **labels,
+                help="chunk-summary rows a layer holds for the rows of the "
+                     "last decode launch (one a whole chunk of each "
+                     "sequence)"),
+            "windows_closed": reg.counter(
+                "serving_eva_windows_closed_total", **labels,
+                help="windows of chunk-summarised attention that closed "
+                     "(their summaries became visible), over sequences"),
+            "tiles_seen": reg.counter(
+                "serving_eva_pool_tiles_seen_total", **labels,
+                help="tiles of a layer's pool of chunk-summary rows in "
+                     "which some row of a decode launch saw a row (what "
+                     "the decode kernel reads), over launches"),
+            "tiles": reg.counter(
+                "serving_eva_pool_tiles_total", **labels,
+                help="tiles of a layer's pool of chunk-summary rows, over "
+                     "decode launches")}
+
+    def build_ints(self, view, rows, reqs):
+        if view.program != "decode":
+            return {}
+        W, C = self.window, self.chunk
+        ps = [view.kv.seq_len(r.request_id) for r in reqs]
+        closed = sum((p + 1) % W == 0 for p in ps)
+        held = sum((p + 1) // C for p in ps)
+        # a row sees the rows of its closed windows: its tiles change when a
+        # window closes, or its blocks do (a preemption: ``forget`` drops the
+        # row's entry, since a last-in-first-out free list may hand it the
+        # same first block again before other later ones)
+        T, R = self.tile_rows, view.kv.block_size // C
+        blocks = view.kv.num_blocks
+        tiles, was = {}, self.tiles
+        for r, p in zip(reqs, ps):
+            table = view.kv.table(r.request_id)
+            key = (p // W, table[0] if table else 0)
+            old = was.get(r.request_id)
+            tiles[r.request_id] = old if old and old[0] == key else (
+                key, frozenset((table[c // R] * R + c % R) // T
+                               for c in range((W // C) * (p // W))))
+        self.tiles = tiles
+        # the rows past the pool's last whole tile are read by every launch
+        rest = {blocks * R // T} if blocks * R % T else ()
+        seen = len(frozenset(rest).union(*(t for _, t in tiles.values())))
+        total = -(-blocks * R // T)
+        self.counters["windows_closed"].inc(closed)
+        self.counters["rows_held"].set(held)
+        self.counters["tiles_seen"].inc(seen)
+        self.counters["tiles"].inc(total)
+        return {"eva_ring_tokens": sum(p % W + 1 for p in ps),
+                "eva_summary_rows": sum((W // C) * (p // W) for p in ps),
+                "eva_windows_closed": closed, "eva_rows_held": held,
+                "eva_pool_tiles_seen": seen, "eva_pool_tiles": total}
+
+    def fetch_ints(self, program, host_array):
+        if program != "decode":         # the windows a prompt's launch closes
+            start, n, W = *self.view.span, self.window
+            self.counters["windows_closed"].inc((start + n) // W - start // W)
+        return {}
+
+    def forget(self, request_id):
+        self.tiles.pop(request_id, None)
 
 
 def inv_freq(dim: int, theta: float) -> np.ndarray:

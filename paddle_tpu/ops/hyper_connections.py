@@ -37,6 +37,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from .paged_attention import LaunchTelemetry
 
 
 class Coefficients(NamedTuple):
@@ -52,6 +55,64 @@ class Coefficients(NamedTuple):
     h_res: jax.Array
     clamped: jax.Array
     residual: jax.Array
+
+
+def pop_health(layers):
+    """float32 ``[3]``: over the hyper-connections of the forward just run
+    (``models/hc_moe_mla.py``) the entries of the pre-``exp`` matrices that
+    met the clamp, the entries computed, and the largest ``|colsum - 1|`` a
+    Sinkhorn step left; ``None`` without any.  Clears what the layers held."""
+    found = []
+    for layer in layers:
+        for hc in (getattr(layer, "attn_hc", None),
+                   getattr(layer, "mlp_hc", None)):
+            if hc is not None and hc.health is not None:
+                found.append(hc.health)
+                hc.health = None
+    if not found:
+        return None
+    h = jnp.stack(found)
+    return jnp.stack([h[:, 0].sum(), h[:, 1].sum(), h[:, 2].max()])
+
+
+class Health(LaunchTelemetry):
+    """What layers on a residual path of several streams bring to a
+    launch.  On ``engine.build``: ``hc_streams`` (how many).  Three floats
+    ride the launch (:func:`pop_health`) and become on ``engine.fetch``:
+    ``hc_res_clamped`` of ``hc_entries`` entries of the pre-exp
+    residual-mixing matrices met the clamp (over sublayers, padding tokens
+    included), ``hc_sinkhorn_residual_ppb`` the largest ``|column sum - 1|``
+    left, in parts per billion (a phase carries integers); and two series."""
+
+    def __init__(self, layers, view):
+        super().__init__(layers, view)
+        self.streams = int(layers[0].config.hc_mult)
+        reg, labels = view.registry, view.labels
+        self.clamped = reg.counter(
+            "serving_hc_res_clamped_total", **labels,
+            help="entries of the pre-exp residual-mixing matrices that met "
+                 "the clamp, over sublayers and launches (padding tokens "
+                 "included)")
+        self.residual = reg.gauge(
+            "serving_hc_sinkhorn_residual", **labels,
+            help="last launch: the largest |column sum - 1| a sublayer's "
+                 "residual-mixing matrix was left with after its Sinkhorn "
+                 "rounds")
+
+    def traced(self):
+        return pop_health(self.layers)
+
+    def build_ints(self, view, rows, reqs):
+        return {"hc_streams": self.streams}
+
+    def fetch_ints(self, program, hc):
+        if hc is None:
+            return {}
+        clamped, entries, residual = (float(v) for v in np.asarray(hc))
+        self.clamped.inc(int(clamped))
+        self.residual.set(residual)
+        return {"hc_res_clamped": int(clamped), "hc_entries": int(entries),
+                "hc_sinkhorn_residual_ppb": int(round(residual * 1e9))}
 
 
 def sinkhorn(m, iters: int, eps: float):

@@ -25,12 +25,14 @@ from __future__ import annotations
 import hashlib
 import math
 from collections import OrderedDict
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..core.tensor import Tensor
 
 
 class PoolExhausted(RuntimeError):
@@ -681,14 +683,22 @@ class PagedCache:
                                    # False forces the XLA gather path,
                                    # None keeps the auto dispatch
 
+    @classmethod
+    def over(cls, k, v):
+        """The cache over a layer's entry of each side of the pools, raw
+        as a step program holds them (every cache class has this)."""
+        return cls(Tensor(k), Tensor(v))
+
     def route(self, block_tables, seq_lens, slot_blocks, slot_offsets,
-              q_start=None, seg_ids=None):
+              start=None, n_valid=None, seg_ids=None):
+        """A launch's routing arrays: the ONE call every cache class takes,
+        each reading what it needs (``start`` is kept as ``q_start``)."""
         self.block_tables = jnp.asarray(block_tables, jnp.int32)
         self.seq_lens = jnp.asarray(seq_lens, jnp.int32)
         self.slot_blocks = jnp.asarray(slot_blocks, jnp.int32)
         self.slot_offsets = jnp.asarray(slot_offsets, jnp.int32)
-        if q_start is not None:
-            self.q_start = jnp.asarray(q_start, jnp.int32)
+        if start is not None:
+            self.q_start = jnp.asarray(start, jnp.int32)
         if seg_ids is not None:
             self.seg_ids = jnp.asarray(seg_ids, jnp.int32)
 
@@ -806,8 +816,8 @@ class CacheSpec:
     older, and declares as its state a RING of them (``window`` set, two
     ``[window, heads, dim]`` arrays: ``ops/window_attention.py``), so what
     it holds a sequence never grows past the window.  ``window`` says what
-    the state IS to whoever counts its use (the engine's ``window_tokens``
-    on ``engine.build``); the allocation goes by ``state`` alone.
+    the state IS to whoever counts its use (``window_attention
+    .WindowTokens``); the allocation goes by ``state`` alone.
 
     **Both at once, and a third rate** (``tokens_per_row`` set): a layer of
     chunk-summarised attention (``ops/eva_attention.py``) keeps a ring of
@@ -829,6 +839,9 @@ class CacheSpec:
     state: Optional[Tuple[Tuple[Tuple[int, ...], Optional[str]], ...]] = None
     window: Optional[int] = None
     tokens_per_row: Optional[int] = None
+    # the class of the ``cache`` object a step program hands the layer
+    # (``cls.over(k, v)``, then one ``route`` call with the launch's arrays)
+    cache: type = field(default=PagedCache, compare=False)
 
     def __post_init__(self):
         if self.tokens_per_row is not None:
@@ -871,6 +884,52 @@ class CacheSpec:
         """Bytes one live sequence holds in this layer's slots."""
         return sum(math.prod(shape) * jnp.dtype(dtype or pool_dtype).itemsize
                    for shape, dtype in self.state or ())
+
+
+@dataclass(slots=True)
+class LaunchView:
+    """What a kind's telemetry may read of the engine: given when it is
+    built and with every launch, whose ``program`` it names; ``span`` is
+    the first position and the tokens of the prompt chunk last BUILT (set
+    inside its ``engine.build``: read it from ``fetch_ints``); ``kv`` is
+    the KV manager (block size and count are its)."""
+
+    registry: object
+    labels: Dict[str, str]
+    kv: object
+    pool_dtype: object
+    program: str = ""
+    span: Optional[Tuple[int, int]] = None
+
+
+class LaunchTelemetry:
+    """What a decoder-layer kind brings to a launch beside its cache:
+    four methods ``EngineCore`` calls, knowing no kind, on one object a
+    class the model's layers name (``layer.telemetry``; ``LlamaForCausalLM
+    .launch_telemetry`` makes it over the ``layers`` that do).  A kind
+    registers its own series on ``view.registry`` and lists its integers
+    in its docstring: their names are ``benchmarks/*_spans.py``'s."""
+
+    def __init__(self, layers, view: LaunchView):
+        self.layers, self.view = layers, view
+
+    def traced(self):
+        """Inside a step program, after the forward: the array that rides
+        the launch beside the logit sentinel (or ``None``), popped off the
+        layers: a traced value does not outlive its trace."""
+        return None
+
+    def build_ints(self, view: LaunchView, rows: int, reqs) -> Dict[str, int]:
+        """``engine.build``'s integers for ``rows`` real rows, ``reqs``."""
+        return {}
+
+    def fetch_ints(self, program: str, host_array) -> Dict[str, int]:
+        """``engine.fetch``'s integers, from what :meth:`traced` sent (or
+        ``None``); runs under ``engine.device_wait``."""
+        return {}
+
+    def forget(self, request_id) -> None:
+        """A row retired or preempted."""
 
 
 def _block_queries(fn, q, block: int = 1024):

@@ -108,7 +108,7 @@ def _tile_rows(budget: int, heads: int, head_dim: int, rows: int) -> int:
 
 def pool_tile_rows(pool_rows: int, heads: int, head_dim: int) -> int:
     """Rows of the summary pool (``num_blocks x R`` of them) a grid step of
-    :func:`pool_partials` reads; ``serving/engine.py`` counts the tiles a
+    :func:`pool_partials` reads; ``eva_attention.SummaryRows`` counts the tiles a
     launch sees by it."""
     return _tile_rows(POOL_TILE_BYTES, heads, head_dim, pool_rows)
 

@@ -40,6 +40,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ..core.tensor import Tensor
+from .paged_attention import LaunchTelemetry
+
 #: positions a compiled loop iteration advances (``lax.scan(unroll=)``):
 #: the loop's fixed cost an iteration is what bounds a prefill scan in XLA
 SCAN_UNROLL = 8
@@ -75,11 +78,52 @@ class StateCache:
     k_pool = property(lambda self: self.state_pool)
     v_pool = property(lambda self: self.conv_pool)
 
-    def route(self, slots, start=None, n_valid=None):
-        self.slots = jnp.asarray(slots, jnp.int32)
+    @classmethod
+    def over(cls, k, v):
+        return cls(Tensor(k), Tensor(v))
+
+    def route(self, tables, seq_lens=None, slot_blocks=None,
+              slot_offsets=None, start=None, n_valid=None):
+        """``PagedCache.route``'s arrays: a row's slot is the id of its
+        first block (``kv_manager.py``; a padding row's the null slot 0)."""
+        self.slots = jnp.asarray(tables[:, 0], jnp.int32)
         self.start = None if start is None else jnp.asarray(start, jnp.int32)
         self.n_valid = None if n_valid is None \
             else jnp.asarray(n_valid, jnp.int32)
+
+
+class StateSlots(LaunchTelemetry):
+    """What layers with per-sequence state bring to a launch.  On
+    ``engine.build``: ``state_rows`` (the real rows whose state the launch
+    advances) and ``state_slots_held``, also a gauge (as of the last launch
+    read or row let go) beside the two that are set once."""
+
+    def __init__(self, layers, view):
+        super().__init__(layers, view)
+        reg, labels = view.registry, view.labels
+        self.held = reg.gauge(
+            "serving_state_slots_held", **labels,
+            help="per-sequence state slots held by running sequences")
+        reg.gauge("serving_state_slots_capacity", **labels,
+                  help="per-sequence state slots (max_num_seqs; the null "
+                       "slot is not counted)").set(view.kv.state_slots)
+        reg.gauge("serving_state_bytes_per_sequence", **labels,
+                  help="bytes one live sequence holds in slots over all "
+                       "layers, whatever its length, as the model declares "
+                       "its state").set(sum(
+                      layer.cache_spec().state_bytes_per_sequence(
+                          view.pool_dtype) for layer in layers))
+
+    def build_ints(self, view, rows, reqs):
+        return {"state_rows": rows,
+                "state_slots_held": view.kv.state_slots_held}
+
+    def fetch_ints(self, program, host_array):
+        self.held.set(self.view.kv.state_slots_held)
+        return {}
+
+    def forget(self, request_id):
+        self.held.set(self.view.kv.state_slots_held)
 
 
 # Which path the most recent launch's state update was traced through:

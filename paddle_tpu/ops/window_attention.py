@@ -65,6 +65,24 @@ RING_PAGE = 256
 SCORE_BYTES = 256 * 2 ** 20
 
 
+class WindowTokens(_paged.LaunchTelemetry):
+    """What sliding-window layers bring to a launch: on ``engine.build``
+    of a DECODE launch ``window_tokens``, the ring entries its rows read
+    (a row of length ``n`` after its token reads ``min(n, window)`` of
+    them in every window layer)."""
+
+    def __init__(self, layers, view):
+        super().__init__(layers, view)
+        self.window = max(layer.cache_spec().window for layer in layers)
+
+    def build_ints(self, view, rows, reqs):
+        if view.program != "decode":
+            return {}
+        return {"window_tokens": sum(
+            min(view.kv.seq_len(r.request_id) + 1, self.window)
+            for r in reqs)}
+
+
 def ring_positions(last, window: int):
     """The position each ring index holds once token ``last`` (``[...]``
     int32) is written: ``[..., window]``, negative where nothing is."""

@@ -28,6 +28,7 @@ from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..nn.initializer import Constant, XavierNormal
 from ..nn.layers import Layer
+from ..ops.paged_attention import LaunchTelemetry
 from .utils import annotate_param, axis_size, sharding_constraint
 
 EP_AXIS = "sep"  # expert parallelism rides the sep axis of the 5-axis mesh
@@ -421,3 +422,88 @@ def global_gather(x: Tensor, local_count, global_count, group=None) -> Tensor:
         lambda v: jax.lax.all_to_all(v, EP_AXIS, split_axis=0, concat_axis=0),
         x,
     )
+
+
+def pop_load(layers):
+    """``[expert layers, experts]`` int32: the tokens each routed expert
+    received in the forward just run (``mlp.load`` of the layers that have
+    one), or ``None``.  Clears what the layers held."""
+    loads = []
+    for layer in layers:
+        load = getattr(layer.mlp, "load", None)
+        if load is not None:
+            loads.append(load)
+            layer.mlp.load = None
+    return jnp.stack(loads) if loads else None
+
+
+class ExpertLoad(LaunchTelemetry):
+    """What layers with routed experts bring to a launch: the routing load
+    (:func:`pop_load`, a few hundred integers) rides it and becomes on
+    ``engine.fetch`` ``moe_assignments`` ((token, expert) pairs routed,
+    padding rows included), ``moe_experts_touched``, ``moe_max_load`` (the
+    fullest expert's tokens, summed over expert layers), ``moe_decode`` (1
+    on a decode launch); where this process holds a SHARE of the experts
+    (the configuration's ``experts_held``) also ``moe_pairs_held`` (pairs
+    routed to them) and ``moe_held_touched``; and the ``serving_moe_*``
+    series below."""
+
+    def __init__(self, layers, view):
+        super().__init__(layers, view)
+        held = getattr(layers[0].config, "experts_held", None)
+        self.held = None if held is None else np.asarray(held, int)
+        reg, labels = view.registry, view.labels
+        self.counters = {
+            "assignments": reg.counter(
+                "serving_moe_assignments_total", **labels,
+                help="(token, expert) pairs routed, over expert layers and "
+                     "launches (padding rows included)"),
+            "touched": reg.counter(
+                "serving_moe_experts_touched_total", **labels,
+                help="experts that received a token, summed over expert "
+                     "layers and launches"),
+            "max_over_mean": reg.gauge(
+                "serving_moe_load_max_over_mean", **labels,
+                help="last launch: the fullest expert's tokens over the mean "
+                     "expert's, averaged over expert layers (1.0 = balanced)")}
+        if self.held is not None:
+            self.counters.update(
+                pairs_held=reg.counter(
+                    "serving_moe_pairs_held_total", **labels,
+                    help="(token, expert) pairs routed to an expert this "
+                         "process holds, over expert layers and launches "
+                         "(the rest are another chip's)"),
+                held_share=reg.gauge(
+                    "serving_moe_held_pair_share", **labels,
+                    help="pairs routed to experts held here over all pairs "
+                         "routed, since the start"))
+
+    def traced(self):
+        return pop_load(self.layers)
+
+    def fetch_ints(self, program, load):
+        if load is None:
+            return {}
+        load = np.asarray(load)
+        assignments = int(load.sum())
+        touched = int(np.count_nonzero(load))
+        max_load = int(load.max(axis=1).sum())
+        c = self.counters
+        c["assignments"].inc(assignments)
+        c["touched"].inc(touched)
+        if assignments:
+            c["max_over_mean"].set(max_load * load.shape[1] / assignments)
+        ints = {"moe_assignments": assignments,
+                "moe_experts_touched": touched, "moe_max_load": max_load,
+                "moe_decode": int(program == "decode")}
+        if self.held is not None:
+            # a share of the experts: the pairs that are this chip's, and
+            # how many of its experts a pair reached
+            mine = load[:, self.held]
+            ints["moe_pairs_held"] = int(mine.sum())
+            ints["moe_held_touched"] = int(np.count_nonzero(mine))
+            c["pairs_held"].inc(ints["moe_pairs_held"])
+            if c["assignments"].value:
+                c["held_share"].set(c["pairs_held"].value
+                                    / c["assignments"].value)
+        return ints

@@ -81,13 +81,12 @@ from ..ops import paged_attention as _paged_ops
 from ..ops import ragged_paged as _ragged_ops
 from ..ops.paged_attention import (
     KV_POOL_SPEC,
-    PagedCache,
+    LaunchView,
     PoolExhausted,
     shard_kv_pool,
 )
 from ..ops.decode_burst import run_burst
-from ..ops.eva_attention import EvaCache
-from ..ops.selective_scan import StateCache, state_step_path
+from ..ops.selective_scan import state_step_path
 from ..ops.sampling import sample_tokens
 from .burst import burst_eligible, clamp_burst
 from .burst import register_metrics as _register_burst_metrics
@@ -110,57 +109,6 @@ from .scheduler import (
 # the request-lifecycle events a post-mortem needs — evictions past the
 # cap collapse into one prefix_cache_eviction_burst summary event.
 _EVICT_EVENTS_PER_STEP = 8
-
-
-def _register_moe_metrics(registry, labels: Dict[str, str]):
-    """Routing-load series of a model with routed experts."""
-    return {
-        "assignments": registry.counter(
-            "serving_moe_assignments_total",
-            help="(token, expert) pairs routed, over expert layers and "
-                 "launches (padding rows included)", **labels),
-        "touched": registry.counter(
-            "serving_moe_experts_touched_total",
-            help="experts that received a token, summed over expert "
-                 "layers and launches", **labels),
-        "max_over_mean": registry.gauge(
-            "serving_moe_load_max_over_mean",
-            help="last launch: the fullest expert's tokens over the mean "
-                 "expert's, averaged over expert layers (1.0 = balanced)",
-            **labels),
-    }
-
-
-def _register_held_metrics(registry, labels: Dict[str, str]):
-    """Series of a model that holds a SHARE of its routed experts."""
-    return {
-        "pairs_held": registry.counter(
-            "serving_moe_pairs_held_total",
-            help="(token, expert) pairs routed to an expert this process "
-                 "holds, over expert layers and launches (the rest are "
-                 "another chip's)", **labels),
-        "held_share": registry.gauge(
-            "serving_moe_held_pair_share",
-            help="pairs routed to experts held here over all pairs routed, "
-                 "since the start", **labels),
-    }
-
-
-def _register_hc_metrics(registry, labels: Dict[str, str]):
-    """Health of the Sinkhorn step of a model whose residual path is
-    several streams mixed by hyper-connections."""
-    return {
-        "clamped": registry.counter(
-            "serving_hc_res_clamped_total",
-            help="entries of the pre-exp residual-mixing matrices that met "
-                 "the clamp, over sublayers and launches (padding tokens "
-                 "included)", **labels),
-        "residual": registry.gauge(
-            "serving_hc_sinkhorn_residual",
-            help="last launch: the largest |column sum - 1| a sublayer's "
-                 "residual-mixing matrix was left with after its Sinkhorn "
-                 "rounds", **labels),
-    }
 
 
 _AHEAD_SETTLES_HELP = (
@@ -218,8 +166,7 @@ class _Flight:
     toks: object
     logits: object
     stats: object
-    load: object            # routed-expert load, or None
-    hc: object              # hyper-connection health [3], or None
+    sent: tuple             # what each of the model's telemetry sent
     nbytes: int             # what the host copies asked for at dispatch hold
     audit: bool
     shadow: bool
@@ -426,25 +373,6 @@ class EngineCore:
         self.cache_specs = list(model.cache_specs())
         sched_cfg = config.scheduler or SchedulerConfig()
         self._has_state = any(spec.state for spec in self.cache_specs)
-        # the window whose ring some layers declare as their state, if any
-        self._window = max((spec.window or 0 for spec in self.cache_specs),
-                           default=0)
-        # layers that keep a ring AND rows at one a chunk of tokens
-        # (``CacheSpec.tokens_per_row``): their window and chunk, else None
-        spec = next((s for s in self.cache_specs if s.ring_and_rows), None)
-        self._ring_rows = None if spec is None else (
-            spec.window, spec.tokens_per_row)
-        # such a layer's decode kernel reads its pool of rows in tiles and
-        # skips a tile in which no row of the launch sees a row
-        # (``ops/pallas_eva.py``): the rows of a tile, for the count of
-        # tiles seen, and each running row's (windows closed, first block,
-        # tiles) as last counted
-        self._eva_tiles: Dict[object, tuple] = {}
-        if spec is not None:
-            from ..ops.pallas_eva import pool_tile_rows
-
-            self._eva_tile_rows = pool_tile_rows(
-                num_blocks * spec.rows_per_block(block_size), *spec.k)
         self._refuse_state_paths(config)
         # a slot a running sequence: the running set is capped at
         # max_num_seqs, so admission never waits on a slot it cannot get
@@ -457,14 +385,14 @@ class EngineCore:
         self.scheduler = ContinuousBatchingScheduler(sched_cfg, self.kv)
         # the table width of a decode launch: its rows' widest table in
         # power-of-two buckets -- but ONE width for a model with
-        # ring-and-rows layers, the one its positions reach.  Their decode
-        # step reads the whole pool of rows where it lies and the tables
-        # only say who holds what (``ops/eva_attention.py``), so a
+        # ring-and-rows layers (``CacheSpec.tokens_per_row``), the one its
+        # positions reach.  Their decode step reads the whole pool of rows
+        # where it lies and the tables only say who holds what, so a
         # narrower table saves no read and every width is one more
         # program to compile.  No sequence outgrows that width.  Public:
         # ``aot.enumerate_buckets`` lists the decode programs by it
         self.decode_table_width = None
-        if self._ring_rows:
+        if any(spec.ring_and_rows for spec in self.cache_specs):
             reach = min(int(cfg.max_position_embeddings),
                         (num_blocks - 1) * block_size)
             self._cap_seq_len(reach, "max_position_embeddings of a model "
@@ -623,61 +551,11 @@ class EngineCore:
             **self.metrics.labels).set(
             sum(spec.values_per_token() for spec in self.cache_specs)
             * jnp.dtype(dtype).itemsize)
-        # slot series: only a model that declares per-sequence state has them
-        self._state_gauge = None
-        if self._has_state:
-            reg, labels = self.metrics.registry, self.metrics.labels
-            self._state_gauge = reg.gauge(
-                "serving_state_slots_held",
-                help="per-sequence state slots held by running sequences",
-                **labels)
-            reg.gauge("serving_state_slots_capacity",
-                      help="per-sequence state slots (max_num_seqs; the "
-                           "null slot is not counted)",
-                      **labels).set(self.state_slots)
-            reg.gauge("serving_state_bytes_per_sequence",
-                      help="bytes one live sequence holds in slots over all "
-                           "layers, whatever its length, as the model "
-                           "declares its state",
-                      **labels).set(sum(
-                          spec.state_bytes_per_sequence(dtype)
-                          for spec in self.cache_specs))
-        # chunk-summary series: only a model with ring-and-rows layers has them
-        self._eva_counters = None
-        if self._ring_rows:
-            reg, labels = self.metrics.registry, self.metrics.labels
-            self._eva_counters = {
-                "rows_held": reg.gauge(
-                    "serving_eva_summary_rows_held",
-                    help="chunk-summary rows a layer holds for the rows of "
-                         "the last decode launch (one a whole chunk of "
-                         "each sequence)", **labels),
-                "windows_closed": reg.counter(
-                    "serving_eva_windows_closed_total",
-                    help="windows of chunk-summarised attention that "
-                         "closed (their summaries became visible), over "
-                         "sequences", **labels),
-                "tiles_seen": reg.counter(
-                    "serving_eva_pool_tiles_seen_total",
-                    help="tiles of a layer's pool of chunk-summary rows in "
-                         "which some row of a decode launch saw a row (what "
-                         "the decode kernel reads), over launches",
-                    **labels),
-                "tiles": reg.counter(
-                    "serving_eva_pool_tiles_total",
-                    help="tiles of a layer's pool of chunk-summary rows, "
-                         "over decode launches", **labels)}
-        # routing-load series: made when a launch first brings a load, so a
-        # model without routed experts never has them on /metrics
-        self._moe_counters = None
-        # the routed experts this process holds where that is a share of
-        # them (the model's configuration says which), else None
-        held = getattr(cfg, "experts_held", None)
-        self._experts_held = None if held is None else np.asarray(held, int)
-        # a residual path of several streams: ``engine.build`` says how many
-        self._hc_counters = None
-        self._hc_ints = {"hc_streams": int(cfg.hc_mult)} \
-            if getattr(cfg, "enter_residual", None) is not None else {}
+        # what the model's layers bring to a launch beside their caches
+        # (``ops.paged_attention.LaunchTelemetry``), and what it may read
+        self._view = LaunchView(self.metrics.registry, self.metrics.labels,
+                                self.kv, jnp.dtype(dtype))
+        self._telemetry = model.launch_telemetry(self._view)
         self._params = list(model.parameters())
         # retrace counters: += 1 runs only while JAX traces the function,
         # so these count COMPILATIONS, not calls (the N31 acceptance hook)
@@ -918,88 +796,24 @@ class EngineCore:
                 "EngineCore has no path with such state for: "
                 + "; ".join(refused))
 
-    def _state_ints(self, rows: int, reqs=()) -> Dict[str, int]:
-        """What ``engine.build`` carries for a model with per-sequence
-        state: the real rows whose state the launch advances, and the
-        slots held; where that state is a window's ring, for the decode
-        rows ``reqs`` also the ring entries their step reads
-        (``window_tokens``: a row of length ``n`` after its token reads
-        ``min(n, window)`` of them in every window layer); nothing for any
-        other model."""
-        if not self._has_state:
-            return {}
-        ints = {"state_rows": rows,
-                "state_slots_held": self.kv.state_slots_held}
-        if self._ring_rows and reqs:
-            ints.update(self._eva_ints(reqs))
-        elif self._window and reqs:
-            ints["window_tokens"] = sum(
-                min(self.kv.seq_len(r.request_id) + 1, self._window)
-                for r in reqs)
+    def _build_ints(self, program: str, rows: int, reqs) -> Dict[str, int]:
+        """What ``engine.build`` of a ``program`` launch of ``rows`` real
+        rows carries for the model's telemetry."""
+        self._view.program = program
+        ints = {}
+        for t in self._telemetry:
+            ints.update(t.build_ints(self._view, rows, reqs))
         return ints
 
-    def _eva_ints(self, reqs) -> Dict[str, int]:
-        """For the decode rows ``reqs`` of a model with ring-and-rows
-        layers (window ``W``, a row a chunk of ``C`` tokens), a row at
-        position ``p``: the ring entries its step reads (``(p mod W) +
-        1``), the summary rows it reads (``(W / C) (p // W)``), the rows
-        whose window this token closes, and the summary rows held once
-        it is written (``(p + 1) // C``).  The last two also go to
-        ``/metrics``.  And the tiles of a layer's pool of rows in which
-        some row of the launch SEES a row, of the tiles there are: what
-        the decode kernel reads of the pool (``ops/pallas_eva.py``; counted
-        from the tables whichever form runs, so where the XLA form reads
-        the whole pool it is what the kernel WOULD read), also on
-        ``/metrics``."""
-        W, C = self._ring_rows
-        ps = [self.kv.seq_len(r.request_id) for r in reqs]
-        closed = sum((p + 1) % W == 0 for p in ps)
-        held = sum((p + 1) // C for p in ps)
-        # a row sees the rows of its closed windows: its tiles change when a
-        # window closes, or its blocks do (a preemption: ``_admit`` drops the
-        # row's entry, since a last-in-first-out free list may hand it the
-        # same first block again before other later ones)
-        T, R = self._eva_tile_rows, self.block_size // C
-        tiles, was = {}, self._eva_tiles
-        for r, p in zip(reqs, ps):
-            table = self.kv.table(r.request_id)
-            key = (p // W, table[0] if table else 0)
-            old = was.get(r.request_id)
-            tiles[r.request_id] = old if old and old[0] == key else (
-                key, frozenset((table[c // R] * R + c % R) // T
-                               for c in range((W // C) * (p // W))))
-        self._eva_tiles = tiles
-        # the rows past the pool's last whole tile are read by every launch
-        rest = {self.num_blocks * R // T} if self.num_blocks * R % T else ()
-        seen = len(frozenset(rest).union(*(t for _, t in tiles.values())))
-        total = -(-self.num_blocks * R // T)
-        self._eva_counters["windows_closed"].inc(closed)
-        self._eva_counters["rows_held"].set(held)
-        self._eva_counters["tiles_seen"].inc(seen)
-        self._eva_counters["tiles"].inc(total)
-        return {"eva_ring_tokens": sum(p % W + 1 for p in ps),
-                "eva_summary_rows": sum((W // C) * (p // W) for p in ps),
-                "eva_windows_closed": closed, "eva_rows_held": held,
-                "eva_pool_tiles_seen": seen, "eva_pool_tiles": total}
-
-    def _layer_caches(self, k_pools, v_pools, route_pages, route_state,
-                      route_ring_rows):
-        """One cache object a layer for a step program, by what the layer
-        declared: ``route_pages(PagedCache)`` for per-token rows,
-        ``route_state(StateCache)`` for per-sequence state,
-        ``route_ring_rows(EvaCache)`` for a ring and rows at once."""
+    def _layer_caches(self, k_pools, v_pools, *routing, **spans):
+        """One cache object a layer for a step program, of the class the
+        layer declared (``CacheSpec.cache``), routed by the launch's
+        ``tables, lens, slot_blocks, slot_offsets`` (, ``start, n_valid``)."""
         caches = []
         for spec, k, v in zip(self.cache_specs, k_pools, v_pools):
-            if spec.ring_and_rows:
-                c = EvaCache(k, v)
-                route_ring_rows(c)
-            elif spec.state:
-                c = StateCache(Tensor(k), Tensor(v))
-                c.use_pallas = self._use_pallas
-                route_state(c)
-            else:
-                c = PagedCache(Tensor(k), Tensor(v))
-                route_pages(c)
+            c = spec.cache.over(k, v)
+            c.use_pallas = self._use_pallas  # EngineConfig.use_pallas_paged
+            c.route(*routing, **spans)
             caches.append(c)
         return caches
 
@@ -1113,10 +927,9 @@ class EngineCore:
                 self._step_call(program, bucket, jit_fn,
                                 self._param_vals(), self._k_pools,
                                 self._v_pools, *args)
-            load = hc = None
-            if isinstance(stats, tuple):    # routed experts (, streams)
-                stats, load, *rest = stats
-                hc = rest[0] if rest else None
+            sent = ()
+            if self._telemetry:     # the sentinel, then what each sent
+                stats, *sent = stats
             fetched = [toks]
             if audit:
                 fetched.append(stats)
@@ -1125,13 +938,13 @@ class EngineCore:
                 fetched.append(logits)
             for arr in fetched:
                 arr.copy_to_host_async()
-            for arr in (load, hc):
+            for arr in sent:
                 if arr is not None:
                     arr.copy_to_host_async()
         # a launch during which a trace counter moved IS that bucket's
         # trace+compile: its wall time goes to the compile table
         return _Flight(program, bucket, self._launch_seq, st, toks, logits,
-                       stats, load, hc, sum(arr.nbytes for arr in fetched),
+                       stats, tuple(sent), sum(arr.nbytes for arr in fetched),
                        audit, shadow, self._traces() > traces0)
 
     def _traces(self) -> int:
@@ -1158,8 +971,9 @@ class EngineCore:
         toks, logits, stats = fl.toks, fl.logits, fl.stats
         with phase("engine.device_wait", prof, launch=fl.seq):
             toks.block_until_ready()
-            ints = {**self._moe_load_ints(fl.program, fl.load),
-                    **self._hc_health_ints(fl.hc)}
+            ints = {}
+            for t, arr in zip(self._telemetry, fl.sent):
+                ints.update(t.fetch_ints(fl.program, arr))
         fl.timer.start_no_earlier_than(self._last_ready)
         with phase("engine.fetch", prof, bytes=fl.nbytes, **ints):
             toks = np.asarray(toks, np.int32)
@@ -1193,60 +1007,6 @@ class EngineCore:
         self._burst_counters["logits_fetches"].inc()
         self._burst_counters["logits_fetch_bytes"].inc(host.nbytes)
         return host
-
-    def _moe_load_ints(self, program: str, load) -> Dict[str, int]:
-        """The routing load of the launch just run (``[expert layers,
-        experts]`` int32, a few hundred integers that rode the launch
-        beside the tokens) as the integers ``engine.fetch`` carries and
-        ``/metrics`` counts; ``{}`` for a model without routed experts."""
-        if load is None:
-            return {}
-        if self._moe_counters is None:
-            self._moe_counters = _register_moe_metrics(
-                self.metrics.registry, self.metrics.labels)
-            if self._experts_held is not None:
-                self._moe_counters.update(_register_held_metrics(
-                    self.metrics.registry, self.metrics.labels))
-        load = np.asarray(load)
-        assignments = int(load.sum())
-        touched = int(np.count_nonzero(load))
-        max_load = int(load.max(axis=1).sum())
-        c = self._moe_counters
-        c["assignments"].inc(assignments)
-        c["touched"].inc(touched)
-        if assignments:
-            c["max_over_mean"].set(max_load * load.shape[1] / assignments)
-        ints = {"moe_assignments": assignments,
-                "moe_experts_touched": touched, "moe_max_load": max_load,
-                "moe_decode": int(program == "decode")}
-        if self._experts_held is not None:
-            # a share of the experts: the pairs that are this chip's, and
-            # how many of its experts a pair reached
-            mine = load[:, self._experts_held]
-            ints["moe_pairs_held"] = int(mine.sum())
-            ints["moe_held_touched"] = int(np.count_nonzero(mine))
-            c["pairs_held"].inc(ints["moe_pairs_held"])
-            if c["assignments"].value:
-                c["held_share"].set(c["pairs_held"].value
-                                    / c["assignments"].value)
-        return ints
-
-    def _hc_health_ints(self, hc) -> Dict[str, int]:
-        """The health of the launch's Sinkhorn steps (three floats that
-        rode the launch beside the tokens) as the integers ``engine.fetch``
-        carries and ``/metrics`` counts: ``hc_res_clamped`` of
-        ``hc_entries`` entries, and the largest residual in parts per
-        billion (a phase carries integers); ``{}`` for any other model."""
-        if hc is None:
-            return {}
-        if self._hc_counters is None:
-            self._hc_counters = _register_hc_metrics(
-                self.metrics.registry, self.metrics.labels)
-        clamped, entries, residual = (float(v) for v in np.asarray(hc))
-        self._hc_counters["clamped"].inc(int(clamped))
-        self._hc_counters["residual"].set(residual)
-        return {"hc_res_clamped": int(clamped), "hc_entries": int(entries),
-                "hc_sinkhorn_residual_ppb": int(round(residual * 1e9))}
 
     def _mesh_jit_shardings(self, mesh, cfg) -> Dict[str, dict]:
         """Explicit in/out shardings for the three mesh-spanning jitted
@@ -1326,18 +1086,11 @@ class EngineCore:
 
     def _launch_stats(self, last):
         """The ``stats`` output of a step program: the numerics audit's
-        logit sentinel, and for a model with routed experts the tokens
-        each expert of each layer received in this forward beside it;
-        for one with hyper-connections a third part, the health of their
-        Sinkhorn steps (``LlamaForCausalLM.pop_hc_health``)."""
+        logit sentinel, and what each telemetry sent to ride beside it."""
         stats = logit_stats(last)
-        pop = getattr(self.model, "pop_expert_load", None)
-        load = pop() if pop is not None else None
-        pop = getattr(self.model, "pop_hc_health", None)
-        hc = pop() if pop is not None else None
-        if hc is not None:
-            return stats, load, hc
-        return stats if load is None else (stats, load)
+        if not self._telemetry:
+            return stats
+        return (stats, *(t.traced() for t in self._telemetry))
 
     def _decode_fn(self, param_vals, k_pools, v_pools, ids, pos,
                    tables, lens, slot_blocks, slot_offsets,
@@ -1353,22 +1106,15 @@ class EngineCore:
         self.tracer.instant("decode_jit_trace", cat="jit",
                             batch=int(ids.shape[0]), **self._state_stat(k_pools),
                             table_width=int(tables.shape[1]))
-        def pages(c):
-            c.route(tables, lens, slot_blocks, slot_offsets)
-            c.use_pallas = self._use_pallas  # EngineConfig.use_pallas_paged
-
-        # a row's state slot is the id of its first block (kv_manager.py);
-        # padding rows have table 0, the null slot
-        caches = self._layer_caches(
-            k_pools, v_pools, pages, lambda c: c.route(tables[:, 0]),
-            lambda c: c.route(tables[:, 0], tables))
+        caches = self._layer_caches(k_pools, v_pools, tables, lens,
+                                    slot_blocks, slot_offsets)
         logits = self._call_model(ids, caches, pos, param_vals)
         self.attention_paths["decode"] = _paged_ops.last_path
         pages = 0           # the gather path moves no pages a step
         if _paged_ops.last_path == "pallas":
             from ..ops.pallas_paged import kernel_pages, latent_kernel_pages
             # (a ring-and-rows layer's kernels walk no pages: tiles of
-            # the pool where it lies, ``ops/pallas_eva.py``)
+            # the pool where it lies, ``ops/pallas_eva.py``; ROADMAP D15)
             for pool, spec in zip(k_pools, self.cache_specs):
                 if spec.k is not None and not spec.state:
                     pages = (latent_kernel_pages if spec.kind == "latent"
@@ -1429,12 +1175,7 @@ class EngineCore:
                             burst_bucket=int(slot_blocks.shape[1]))
 
         def model_step(ids_j, pos_j, lens_j, sb, so, kp, vp):
-            caches = []
-            for k, v in zip(kp, vp):
-                c = PagedCache(Tensor(k), Tensor(v))
-                c.route(tables, lens_j, sb, so)
-                c.use_pallas = self._use_pallas
-                caches.append(c)
+            caches = self._layer_caches(kp, vp, tables, lens_j, sb, so)
             logits = self._call_model(ids_j, caches, pos_j, param_vals)
             self.attention_paths["burst"] = _paged_ops.last_path
             return (logits[:, -1, :].astype(jnp.float32),
@@ -1474,20 +1215,15 @@ class EngineCore:
             return None if row is None else Tensor(
                 jnp.zeros((1, Tb) + tuple(row), self._pool_dtype))
 
+        # a layer with per-sequence state writes it where it lies (the
+        # state after the last REAL token, from zero); the blocks of the
+        # prompt's tokens give the sequence's table
+        table, n_valid = blocks[None, ::self.block_size], last_pos + 1
         dense = []
         for spec, kp, vp in zip(self.cache_specs, k_pools, v_pools):
-            if spec.ring_and_rows:
-                # ring and rows are written where they lie; the blocks of
-                # the prompt's tokens give the sequence's table
-                c = EvaCache(kp, vp)
-                c.route(blocks[:1], blocks[None, ::self.block_size],
-                        n_valid=last_pos + 1)
-                dense.append(c)
-            elif spec.state:
-                # the state after the last REAL token goes to the slot of
-                # the sequence's first block; it starts from zero
-                c = StateCache(Tensor(kp), Tensor(vp))
-                c.route(blocks[:1], n_valid=last_pos + 1)
+            if spec.state:
+                c = spec.cache.over(kp, vp)
+                c.route(table, None, blocks, offs, n_valid=n_valid)
                 dense.append(c)
             else:
                 dense.append((buffer(spec.k), buffer(spec.v)))
@@ -1524,16 +1260,11 @@ class EngineCore:
         self.tracer.instant("prefill_jit_trace", cat="jit",
                             chunk_bucket=int(ids.shape[1]),
                             table_bucket=int(tables.shape[1]))
+        # state (a ring, rows) is carried in from where it lies when the
+        # chunk starts past 0
         caches = self._layer_caches(
-            k_pools, v_pools,
-            lambda c: c.route(tables, lens, slot_blocks, slot_offsets,
-                              q_start=start),
-            # state carried in from the slot when the chunk starts past 0
-            lambda c: c.route(tables[:, 0], start=start,
-                              n_valid=last_pos + 1),
-            # ring and rows hold the sequence's earlier part likewise
-            lambda c: c.route(tables[:, 0], tables, start=start,
-                              n_valid=last_pos + 1, carried=True))
+            k_pools, v_pools, tables, lens, slot_blocks, slot_offsets,
+            start=start, n_valid=last_pos + 1)
         _paged_ops.last_latent_prefill_path = None
         logits = self._call_model(ids, caches, start, param_vals)
         self._note_flash_prefill("chunk", (ids.shape[1], tables.shape[1]))
@@ -1562,14 +1293,11 @@ class EngineCore:
         self.tracer.instant("ragged_jit_trace", cat="jit",
                             token_bucket=int(ids.shape[1]),
                             table_bucket=int(tables.shape[1]))
-        caches = []
-        for k, v in zip(k_pools, v_pools):
-            c = PagedCache(Tensor(k), Tensor(v))
-            c.route(tables, lens, slot_blocks, slot_offsets,
-                    q_start=pos[0], seg_ids=seg_ids)
-            c.use_pallas = self._use_pallas_ragged  # shard_map kernel —
-            # the mp>1 auto-pin does NOT apply to the ragged program
-            caches.append(c)
+        caches = self._layer_caches(
+            k_pools, v_pools, tables, lens, slot_blocks, slot_offsets,
+            start=pos[0], seg_ids=seg_ids)
+        for c in caches:    # the shard_map kernel: the mp>1 auto-pin does
+            c.use_pallas = self._use_pallas_ragged  # NOT apply to it
         logits = self._call_model(ids, caches, pos, param_vals)
         self.attention_paths["ragged"] = _ragged_ops.last_path
         last = jnp.take(logits[0], last_idx, axis=0).astype(jnp.float32)
@@ -1724,10 +1452,8 @@ class EngineCore:
         req = self.requests.get(request_id)
         if req is None or req.finished:
             return False
-        self.scheduler.remove(req)
-        self.kv.free(req.request_id)
+        self._retire(req)
         self._finish(req, reason)
-        self.requests.pop(request_id, None)
         self._let_go_if_ended()
         return True
 
@@ -1804,6 +1530,8 @@ class EngineCore:
     def _retire(self, req: Request) -> None:
         self.scheduler.remove(req)
         self.kv.free(req.request_id)
+        for t in self._telemetry:
+            t.forget(req.request_id)
         # drop the engine's handle so a long-lived server never accumulates
         # finished Requests; the caller keeps the object from add_request
         self.requests.pop(req.request_id, None)
@@ -1832,6 +1560,7 @@ class EngineCore:
         start = self.kv.seq_len(rid)  # cached fork + earlier chunks
         n = req._chunk_tokens if req._chunk_tokens else target - start
         req._chunk_tokens = None
+        self._view.span = (start, n)
         recompute = bool(req.output_tokens
                          and start == req.num_cached_tokens)
         if req.prefill_start_time is None:
@@ -1878,8 +1607,8 @@ class EngineCore:
         phase, prof = self.tracer.phase, self.stepprof
         t_chunk0 = time.perf_counter()
         one_shot = False
-        with phase("engine.build", prof, **self._state_ints(1),
-                   **self._hc_ints):
+        with phase("engine.build", prof,
+                   **self._build_ints("prefill", 1, (req,))):
             ids, target, start, n, recompute = \
                 self._begin_prefill_chunk(req, t_chunk0)
             table = self.kv.table(rid)
@@ -1891,10 +1620,6 @@ class EngineCore:
             pack = SamplingPack(1)
             pack.set_request(0, req)
             self._count_launch(pack)
-            if self._ring_rows:     # the windows this launch closes
-                W = self._ring_rows[0]
-                self._eva_counters["windows_closed"].inc(
-                    (start + n) // W - start // W)
             if start == 0 and n == target:
                 # cold one-shot: dense-cache forward + scatter (the
                 # cheapest program when nothing is cached and no budget
@@ -1999,7 +1724,7 @@ class EngineCore:
         phase, prof = self.tracer.phase, self.stepprof
         B = len(reqs)
         with phase("engine.build", prof, rows=B,
-                   **self._state_ints(B, reqs), **self._hc_ints):
+                   **self._build_ints("decode", B, reqs)):
             Bb = bucket_size(B)
             width = max(len(self.kv.table(r.request_id)) for r in reqs)
             Wb = self.decode_table_width or bucket_size(width)
@@ -2512,7 +2237,8 @@ class EngineCore:
                     generated=len(req.output_tokens))
                 self._lc(req.request_id, _lc.EV_PREEMPTED,
                          generated=len(req.output_tokens))
-                self._eva_tiles.pop(req.request_id, None)
+                for t in self._telemetry:
+                    t.forget(req.request_id)
             for req in plan.aborted:
                 # unservable at admission: scheduler set state/reason,
                 # the engine owns finish bookkeeping (timestamp +
@@ -2671,8 +2397,6 @@ class EngineCore:
                 self.metrics.sample_gauges(self.scheduler.queue_depth,
                                            self.scheduler.num_running,
                                            self.kv.occupancy())
-                if self._state_gauge is not None:
-                    self._state_gauge.set(self.kv.state_slots_held)
                 if self.history is not None:
                     # metrics history + alert evaluation (ISSUE 14):
                     # deterministic engine-step cadence, host-side only

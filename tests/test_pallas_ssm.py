@@ -124,7 +124,7 @@ def test_inside_an_outer_jit_with_the_pool_donated():
 
 def routed(n_valid=None, use_pallas=None):
     c = ss.StateCache(None, None)
-    c.route(np.arange(4), start=None if n_valid is None else 0,
+    c.route(np.arange(4)[:, None], start=None if n_valid is None else 0,
             n_valid=n_valid)
     c.use_pallas = use_pallas
     return c
@@ -177,7 +177,7 @@ def mixer_step(use_pallas):
         Tensor(jnp.asarray(rng.standard_normal((7, n, d)), jnp.float32)),
         Tensor(jnp.asarray(rng.standard_normal((7, (k - 1) * d)),
                            jnp.float32)))
-    cache.route(np.asarray([5, 2, 0]))
+    cache.route(np.asarray([[5], [2], [0]]))    # a row's slot: its first block
     cache.use_pallas = use_pallas
     x = Tensor(jnp.asarray(rng.standard_normal((3, 1, cfg.hidden_size)),
                            jnp.float32))

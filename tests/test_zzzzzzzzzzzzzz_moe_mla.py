@@ -102,7 +102,8 @@ def test_dense_layers_declare_keys_and_values():
     assert [p.shape for p in eng._k_pools] == [(64, 4, 2, 16)] * 2
     assert [p.shape for p in eng._v_pools] == [(64, 4, 2, 16)] * 2
     serve(eng, prompt_of(6), 1)
-    assert eng._moe_counters is None      # no routing series for a dense model
+    assert eng._telemetry == []           # no routing series for a dense model
+    assert "serving_moe_" not in eng.metrics.registry.prometheus_text()
 
 
 def test_engine_allocates_by_the_declaration(model):
@@ -392,10 +393,11 @@ def test_stats_carry_the_load_and_metrics_count_it(model):
                  "serving_moe_experts_touched_total",
                  "serving_moe_load_max_over_mean"):
         assert name in text
-    ints = eng._moe_load_ints("decode", np.array([[3, 1, 0, 0], [0, 2, 2, 0]]))
+    (load,) = eng._telemetry            # parallel.moe.ExpertLoad
+    ints = load.fetch_ints("decode", np.array([[3, 1, 0, 0], [0, 2, 2, 0]]))
     assert ints == {"moe_assignments": 8, "moe_experts_touched": 4,
                     "moe_max_load": 5, "moe_decode": 1}
-    assert eng._moe_load_ints("prefill", None) == {}
+    assert load.fetch_ints("prefill", None) == {}
 
 
 def test_no_tracer_outlives_its_trace(model):

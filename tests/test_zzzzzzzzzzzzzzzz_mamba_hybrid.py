@@ -139,7 +139,7 @@ def test_a_dense_model_has_no_slots_and_no_slot_series():
     eng = make_engine(LlamaForCausalLM(LlamaConfig.tiny(num_hidden_layers=2)),
                       prefix_cache=True)
     assert eng.state_slots == 0 and eng.kv.state_slots == 0
-    assert eng._state_ints(3) == {}
+    assert eng._telemetry == [] and eng._build_ints("decode", 3, ()) == {}
     assert "serving_state" not in eng.metrics.registry.prometheus_text()
     assert eng.kv.num_free == 127 and eng.kv.can_start_sequence()
 

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks import harness
+from paddle_tpu.ops.hyper_connections import Health
 
 YARN = {"type": "yarn", "factor": 8.0, "beta_fast": 32, "beta_slow": 1,
         "mscale": 1, "mscale_all_dim": 1,
@@ -275,7 +276,8 @@ def test_the_single_stream_latent_model_traces_to_the_parents_program(
         assert trace() == text
     assert "mhc" not in text and m.pop_hc_health() is None
     eng = make_engine(m)
-    assert eng._hc_ints == {} and eng._hc_health_ints(None) == {}
+    assert not any(isinstance(t, Health) for t in eng._telemetry)
+    assert "hc_streams" not in eng._build_ints("decode", 1, ())
     rows = capture(eng)
     serve(eng, list(range(1, 8)), 2)
     # the step programs' ``stats`` keep the parent's two parts
@@ -425,7 +427,8 @@ def test_a_mean_at_the_collapse_shows_only_through_the_norms_eps(
 
 def test_stats_carry_the_health_and_the_phases_and_metrics_count_it(model):
     eng = make_engine(model)
-    assert eng._hc_ints == {"hc_streams": 4}
+    (health,) = (t for t in eng._telemetry if isinstance(t, Health))
+    assert health.build_ints(eng._view, 1, ()) == {"hc_streams": 4}
     rows = capture(eng)
     seen = {"engine.build": [], "engine.fetch": []}
     real = eng.tracer.phase
@@ -454,7 +457,9 @@ def test_stats_carry_the_health_and_the_phases_and_metrics_count_it(model):
     text = eng.metrics.registry.prometheus_text()
     assert "serving_hc_res_clamped_total" in text
     assert "serving_hc_sinkhorn_residual" in text
-    ints = eng._hc_health_ints(np.array([7.0, 96.0, 2.5e-6], np.float32))
+    assert health.fetch_ints("decode", None) == {}
+    ints = health.fetch_ints("decode",
+                             np.array([7.0, 96.0, 2.5e-6], np.float32))
     assert ints == {"hc_res_clamped": 7, "hc_entries": 96,
                     "hc_sinkhorn_residual_ppb": 2500}
     assert all(l.attn_hc.health is None and l.mlp_hc.health is None

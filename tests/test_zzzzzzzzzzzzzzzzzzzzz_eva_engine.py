@@ -30,6 +30,14 @@ from eva_common import (  # noqa: F401  (fixtures among them)
 ROWS = [(90, 30), (10, 40), (40, 30), (3, 70)]     # prompt, new tokens
 
 
+def summary_rows(eng):
+    """The engine's telemetry object of the ring-and-rows kind."""
+    from paddle_tpu.ops.eva_attention import SummaryRows
+
+    (kind,) = (t for t in eng._telemetry if isinstance(t, SummaryRows))
+    return kind
+
+
 @pytest.fixture(scope="module")
 def alone(model):
     """Each request of ROWS served alone, on one engine, one at a time."""
@@ -125,7 +133,7 @@ def test_the_integers_of_a_decode_launch_and_the_metrics_series(model):
     # a model without such layers has neither
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
     dense = make_engine(LlamaForCausalLM(LlamaConfig.tiny()))
-    assert dense._ring_rows is None and dense._eva_counters is None
+    assert dense._telemetry == []       # a dense layer brings none
     assert "serving_eva_" not in dense.metrics.registry.prometheus_text()
 
 
@@ -146,15 +154,18 @@ def test_the_tiles_a_launch_sees_are_counted_from_the_tables(
     monkeypatch.setattr(pallas_eva, "pool_tile_rows", lambda *a: 4)
     eng = make_engine(model, block_size=32, num_blocks=blocks)
     W, C, R, T = 32, 16, 2, 4
-    assert eng._eva_tile_rows == T
+    kind = summary_rows(eng)
+    assert kind.tile_rows == T
     total, rest = -(-blocks * R // T), {blocks * R // T} if blocks % 2 else set()
     launches, built = [], []
-    ints, phase = eng._eva_ints, eng.tracer.phase
+    ints, phase = kind.build_ints, eng.tracer.phase
 
-    def counted(reqs):
+    def counted(view, n, reqs):
+        if view.program != "decode":
+            return ints(view, n, reqs)
         rows = [(eng.kv.seq_len(r.request_id), list(eng.kv.table(
             r.request_id))) for r in reqs]
-        launches.append((ints(reqs), rows))
+        launches.append((ints(view, n, reqs), rows))
         return launches[-1][0]
 
     def traced(name, prof=None, **stats):
@@ -162,7 +173,7 @@ def test_the_tiles_a_launch_sees_are_counted_from_the_tables(
             built.append(stats)
         return phase(name, prof, **stats)
 
-    eng._eva_ints, eng.tracer.phase = counted, traced
+    kind.build_ints, eng.tracer.phase = counted, traced
     reqs = [eng.add_request(prompt_of(n, seed=n), SamplingParams(
         max_new_tokens=40, temperature=0.0)) for n in (20, 70, 130)]
     for _ in range(200):
@@ -217,12 +228,15 @@ def test_a_preempted_rows_tiles_are_counted_from_its_new_blocks(model,
         return len({(tables["r"][c // R] * R + c % R) // T
                     for c in range((W // C) * 4)})
 
-    assert eng._eva_ints([req])["eva_pool_tiles_seen"] == by_hand() == 3
+    def seen():
+        return eng._build_ints("decode", 1, [req])["eva_pool_tiles_seen"]
+
+    assert seen() == by_hand() == 3
     tables["r"] = [3, 20, 9, 30, 31]
     assert by_hand() == 4
     eng._admit(types.SimpleNamespace(preempted=[req], aborted=[],
                                      admitted=[]))
-    assert eng._eva_ints([req])["eva_pool_tiles_seen"] == 4
+    assert seen() == 4
 
 
 def test_the_metrics_are_documented_where_the_checker_looks():

@@ -8,6 +8,7 @@ framework models so the capability rungs are runnable in-repo.
 from . import (  # noqa: F401
     bert,
     eva,
+    gated_delta_moe_mla,
     gpt,
     hc_moe_mla,
     llama,
@@ -29,6 +30,12 @@ from .ernie import (  # noqa: F401
 from .eva import (  # noqa: F401
     EvaConfig,
     EvaDecoderLayer,
+)
+from .gated_delta_moe_mla import (  # noqa: F401
+    GatedDeltaDecoderLayer,
+    GatedDeltaMixer,
+    GatedDeltaMoEMLAConfig,
+    ZeroCenteredGatedNorm,
 )
 from .gpt import (  # noqa: F401
     GPTConfig,

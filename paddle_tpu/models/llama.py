@@ -43,7 +43,7 @@ from ..nn.initializer import Constant, Normal
 from ..nn.layers import Layer
 from ..nn.norm import RMSNorm
 from ..ops.hyper_connections import pop_health
-from ..parallel.moe import pop_load
+from ..parallel.moe import clamped_swiglu, pop_load
 from ..parallel.mp_layers import (
     ColumnParallelLinear,
     RowParallelLinear,
@@ -85,6 +85,8 @@ class LlamaConfig:
     use_rope: bool = True               # False: no positional rotation (a
                                         # hybrid whose recurrent layers carry
                                         # the order, models/mamba_hybrid.py)
+    swiglu_limit: Optional[float] = None    # clamp of the SwiGLU's branches
+                                        # (parallel.moe.clamped_swiglu)
     # MoE knobs (0 experts = dense; DeepSeek/Qwen2-MoE style otherwise)
     num_experts: int = 0
     num_experts_per_tok: int = 2
@@ -415,7 +417,9 @@ class LlamaAttention(Layer):
 
 
 class LlamaMLP(Layer):
-    """SwiGLU feed-forward, column→row TP pairing."""
+    """SwiGLU feed-forward, column→row TP pairing.  Under ``swiglu_limit``
+    the two branches are clamped before their product
+    (``parallel.moe.clamped_swiglu``)."""
 
     def __init__(self, config: LlamaConfig):
         super().__init__()
@@ -427,8 +431,14 @@ class LlamaMLP(Layer):
                                             gather_output=False, weight_attr=init)
         self.down_proj = RowParallelLinear(ff, h, has_bias=False,
                                            input_is_parallel=True, weight_attr=init)
+        self.limit = config.swiglu_limit
 
     def forward(self, x):
+        if self.limit is not None:
+            return self.down_proj(run_op(
+                "swiglu_clamped",
+                lambda g, u: clamped_swiglu(g, u, self.limit).astype(g.dtype),
+                self.gate_proj(x), self.up_proj(x)))
         return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 

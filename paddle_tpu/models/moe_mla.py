@@ -34,10 +34,17 @@ in float32, a selection bias that selects and does not weigh, weights
 renormalised over the chosen and scaled, every routed token computed.  A
 row's output does not depend on who shares its batch.
 
+Two keys more, each off by default so that a model without it traces to
+the same program: ``gated_attention`` (an
+OUTPUT GATE, ``o <- o * sigmoid(W_g x)`` elementwise on the heads' values,
+after ``W_UV`` and before ``W_O``, on the expanded and the absorbed path
+alike) and ``LlamaConfig.swiglu_limit`` (the clamp of the experts'
+SwiGLU, ``parallel.moe.clamped_swiglu``).
+
 Device scopes, nested in the ``attn`` / ``mlp`` scopes of the skeleton:
 ``mla_q``, ``mla_kv_down``, ``mla_decode_core``, ``mla_prefill_core``,
-``mla_out``; ``moe_router``, ``moe_dispatch``, ``moe_experts``,
-``moe_combine``, ``moe_shared``.
+``mla_gate`` (a gated layer only), ``mla_out``; ``moe_router``,
+``moe_dispatch``, ``moe_experts``, ``moe_combine``, ``moe_shared``.
 """
 
 from __future__ import annotations
@@ -96,6 +103,7 @@ class MoEMLAConfig(LlamaConfig):
     # None = all: the router always scores all of them, the layer computes
     # what its own give (a chip's share of an expert-parallel deployment)
     experts_held: Optional[Tuple[int, ...]] = None
+    gated_attention: bool = False           # an output gate on the heads' values
 
     def __post_init__(self):
         self.num_experts = self.n_routed_experts
@@ -208,6 +216,8 @@ class LatentAttention(Layer):
         self.kv_b_proj = lin(c.kv_lora_rank,                        # W_UKV
                              heads * (c.qk_nope_head_dim + c.v_head_dim))
         self.o_proj = lin(heads * c.v_head_dim, h)
+        self.g_proj = lin(h, heads * c.v_head_dim) \
+            if c.gated_attention else None
         self._rope_cos, self._rope_sin = latent_rope_tables(c)
         self._scale = softmax_scale(c)
 
@@ -265,7 +275,12 @@ class LatentAttention(Layer):
         w = jnp.transpose(w, (1, 0, 2))
         return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
 
-    def _out(self, o):
+    def _out(self, o, x):
+        if self.g_proj is not None:
+            with jax.named_scope("mla_gate"):
+                o = run_op("mla_gate", lambda ov, gv: ov * jax.nn.sigmoid(
+                    gv.astype(jnp.float32)).astype(ov.dtype),
+                    o, self.g_proj(x))
         with jax.named_scope("mla_out"):
             return self.o_proj(o)
 
@@ -276,7 +291,7 @@ class LatentAttention(Layer):
         q = self._queries(x, idx)
         lat = self._latents(x, idx)
         if isinstance(cache, PagedCache):
-            return self._paged(q, lat, cache, B, S)
+            return self._paged(x, q, lat, cache, B, S)
         if cache is not None:           # dense buffer (one-shot prefill)
             buf = cache[0]
             start = pos._value if hasattr(pos, "_value") else pos
@@ -290,7 +305,7 @@ class LatentAttention(Layer):
             lat, q_start = buf, start
         else:
             q_start = 0
-        return self._out(self._expanded(q, lat, q_start))
+        return self._out(self._expanded(q, lat, q_start), x)
 
     def _expanded(self, q, lat, q_start, lens=None):
         """Attention with keys and values rebuilt from the latent rows
@@ -308,7 +323,7 @@ class LatentAttention(Layer):
         return run_op("mla_expanded_attention", attend, q, lat,
                       self.kv_b_proj.weight)
 
-    def _paged(self, q, lat, cache, B, S):
+    def _paged(self, x, q, lat, cache, B, S):
         c = self.config
         if cache.seg_ids is not None:
             raise NotImplementedError(
@@ -344,7 +359,7 @@ class LatentAttention(Layer):
 
         o = run_op("mla_paged_attention", attend, q, pool,
                    self.kv_b_proj.weight)
-        return self._out(o)
+        return self._out(o, x)
 
 
 class RoutedExperts(Layer):
@@ -393,7 +408,8 @@ class RoutedExperts(Layer):
                     normalize=c.norm_topk_prob)
             out, load = dropless_experts(
                 flat, ids, weights, w_gu.astype(xv.dtype),
-                w_d.astype(xv.dtype), c.n_routed_experts, self.held)
+                w_d.astype(xv.dtype), c.n_routed_experts, self.held,
+                limit=c.swiglu_limit)
             return out.reshape(B, S, H), load
 
         out, load = run_op("moe_routed_experts", routed, x, self.gate.weight,

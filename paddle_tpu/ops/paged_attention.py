@@ -799,8 +799,9 @@ class CacheSpec:
 
     **Per SEQUENCE** (slots): ``state`` is ``None`` or two ``(shape,
     dtype)`` pairs, arrays of FIXED shape one live sequence holds in this
-    layer whatever its length (a selective scan's recurrent state and the
-    last inputs of its causal convolution).  The engine allocates
+    layer whatever its length (a selective scan's recurrent state, or a
+    gated delta rule's matrix a value head, and the last inputs of its
+    causal convolution).  The engine allocates
     ``[max_num_seqs + 1, *shape]`` of each (slot 0 is the null slot that
     padding rows use) in the layer's ``k_pools`` / ``v_pools`` entry; a
     dtype of ``None`` is the pool's.  The slot of a sequence is the id of

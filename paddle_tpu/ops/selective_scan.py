@@ -49,9 +49,11 @@ SCAN_UNROLL = 8
 
 
 class StateCache:
-    """Per-layer view of the per-sequence slot pools, handed to a
-    state-space mixer as its ``cache``: ``state_pool`` ``[slots, N, D]``
-    float32 and ``conv_pool`` ``[slots, (K-1) * D]`` are framework Tensors
+    """Per-layer view of the per-sequence slot pools, handed to a mixer
+    that keeps a recurrent state as its ``cache``: ``state_pool``
+    (float32: ``[slots, N, D]`` of a selective scan, ``[slots, heads, d_k,
+    d_v]`` of a gated delta rule, ``ops/gated_delta.py``) and ``conv_pool``
+    ``[slots, (K-1) * D]`` are framework Tensors
     so the in-place update threads as jit state like a page write.
     ``slots`` ``[B]`` is each row's slot (0 = the null slot of padding
     rows).  In a prefill or chunk launch ``start`` is the absolute position
@@ -135,12 +137,13 @@ def state_step_path(state_shape, use_pallas, decode: bool = True) -> str:
     """``"pallas"`` where a launch over a ``[slots, N, D]`` state pool
     steps the state in its slot (``pallas_ssm.state_step``), ``"xla"``
     where it gathers, steps (or scans) and scatters: the kernel is a DECODE
-    step at whole float32 tiles on a TPU backend, ``use_pallas`` forces or
-    pins as everywhere (``paged_attention.pallas_dispatch``, whose kill
-    switch wins)."""
+    step of the selective scan at whole float32 tiles on a TPU backend,
+    ``use_pallas`` forces or pins as everywhere (``paged_attention
+    .pallas_dispatch``, whose kill switch wins).  A state pool of another
+    rank (a matrix a head, ``ops/gated_delta.py``) has no kernel."""
     from .paged_attention import pallas_dispatch
 
-    if not decode:
+    if not decode or len(state_shape) != 3:
         return "xla"
     _, n, d = state_shape
     tileable = (n % 8 == 0 and d % 128 == 0

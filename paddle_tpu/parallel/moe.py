@@ -297,21 +297,32 @@ def held_pair_chunk(pairs: int, n_held: int, num_experts: int) -> int:
     return min(1 << (want - 1).bit_length(), MAX_PAIR_CHUNK)
 
 
-def _grouped_swiglu(rows, w_gate_up, w_down, sizes):
+def clamped_swiglu(gate, up, limit):
+    """``silu(min(gate, limit)) * clip(up, -limit, limit)``: the SwiGLU of
+    a model that publishes ``swiglu_limit`` (the gate is bounded above, the
+    linear branch on both sides)."""
+    return jax.nn.silu(jnp.minimum(gate, limit)) * jnp.clip(up, -limit, limit)
+
+
+def _grouped_swiglu(rows, w_gate_up, w_down, sizes, limit=None):
     """``E_g(row)`` for rows sorted by group, ``sizes`` rows a group; rows
-    past the last group are whatever the grouped matmul leaves there."""
+    past the last group are whatever the grouped matmul leaves there.
+    ``limit``: :func:`clamped_swiglu`'s (``None``: no clamp)."""
     f = w_down.shape[1]
     with jax.named_scope("moe_experts"):
         h = jax.lax.ragged_dot(rows, w_gate_up, sizes)
-        h = jax.nn.silu(h[:, :f]) * h[:, f:]
+        if limit is None:
+            h = jax.nn.silu(h[:, :f]) * h[:, f:]
+        else:
+            h = clamped_swiglu(h[:, :f], h[:, f:], limit)
         return jax.lax.ragged_dot(h, w_down, sizes)
 
 
 def dropless_experts(x, ids, weights, w_gate_up, w_down, num_experts: int,
-                     held=None):
+                     held=None, limit=None):
     """``out[t] = sum_j weights[t, j] * E_{ids[t, j]}(x[t])`` over the
     experts THIS process holds, with ``E(x) = W_down(silu(W_gate x) * W_up
-    x)``: the step's (token, expert) pairs are sorted by expert and each
+    x)`` (under ``limit`` :func:`clamped_swiglu`'s product): the step's (token, expert) pairs are sorted by expert and each
     expert multiplies only the rows routed to it (``jax.lax.ragged_dot``
     over the stacked weights).  No capacity: every routed token is
     computed, so a token's result does not depend on the rest of the batch.
@@ -356,9 +367,9 @@ def dropless_experts(x, ids, weights, w_gate_up, w_down, num_experts: int,
             .astype(jnp.int32)
     if in_chunks:
         out = _held_pairs_in_chunks(x, weights.reshape(-1), order, sizes,
-                                    w_gate_up, w_down, k, chunk)
+                                    w_gate_up, w_down, limit, k, chunk)
         return out.astype(x.dtype), load
-    y = _grouped_swiglu(rows, w_gate_up, w_down, sizes)
+    y = _grouped_swiglu(rows, w_gate_up, w_down, sizes, limit)
     with jax.named_scope("moe_combine"):
         w = jnp.where(local < n_held, weights.reshape(-1), 0.0)[order]
         # rows past the last group (pairs of experts not held) are whatever
@@ -368,14 +379,15 @@ def dropless_experts(x, ids, weights, w_gate_up, w_down, num_experts: int,
     return out.astype(x.dtype), load
 
 
-def _held_pairs_in_chunks(x, flat_w, order, sizes, w_gate_up, w_down, k: int,
-                          chunk: int):
+def _held_pairs_in_chunks(x, flat_w, order, sizes, w_gate_up, w_down, limit,
+                          k: int, chunk: int):
     """The held pairs alone, ``chunk`` of them a pass: ``order`` lists the
     pairs sorted by local expert id, the held ones first, so pass ``i``
     takes ``order[i chunk : (i + 1) chunk]``, gathers THOSE rows of ``x``,
     gives each expert the part of its group that falls in the pass, and
     adds the weighted results to their tokens.  As many passes as the held
     pairs need (a ``while`` in the program), none for the absent ones.
+    ``limit`` is :func:`_grouped_swiglu`'s.
     Returns ``[T, H]`` float32."""
     T, H = x.shape
     ends = jnp.cumsum(sizes)
@@ -393,7 +405,7 @@ def _held_pairs_in_chunks(x, flat_w, order, sizes, w_gate_up, w_down, k: int,
             rows = x[token]
             part = (jnp.clip(ends, lo, lo + chunk)
                     - jnp.clip(starts, lo, lo + chunk)).astype(jnp.int32)
-        y = _grouped_swiglu(rows, w_gate_up, w_down, part)
+        y = _grouped_swiglu(rows, w_gate_up, w_down, part, limit)
         with jax.named_scope("moe_combine"):
             w = jnp.where(lo + lane < n_pairs, flat_w[pair], 0.0)
             y = jnp.where(w[:, None] != 0,

@@ -426,13 +426,13 @@ def bias_weighs(real):
 
 
 def capacity_drops(real):
-    def experts(x, ids, weights, gu, down, n, held=None):
+    def experts(x, ids, weights, gu, down, n, held=None, limit=None):
         T, k = ids.shape
         cap = max(1, int(1.25 * k * T / n))
         onehot = jax.nn.one_hot(ids.reshape(-1), n, dtype=jnp.int32)
         place = (jnp.cumsum(onehot, 0) * onehot).sum(-1).reshape(T, k)
         return real(x, ids, jnp.where(place <= cap, weights, 0.0), gu, down,
-                    n, held)
+                    n, held, limit)
     return experts
 
 

@@ -42,14 +42,22 @@ pools from it and hands them in as a :class:`~paddle_tpu.ops
 .selective_scan.StateCache`, as it does for ``models/mamba_hybrid.py``.
 Three paths: the chunked rule over a (padded) bucket, whose padding is
 inert and which starts from zero or from the slot; one step a row for
-decode (gather by slot, step, scatter in place on the donated pools); and
-the cache-less forward over a whole sequence.
+decode; and the cache-less forward over a whole sequence.  The decode step
+runs IN PLACE on the state pool where ``selective_scan.route_state_step``
+says the kernel can take it (``ops/pallas_gated_delta.py``: a TPU backend,
+``d_k % 8 == 0`` and ``d_v % 128 == 0``, read off the pool's shape): each
+row's slot is copied into VMEM, stepped and copied back, and nothing the
+size of the rows' states is gathered or scattered.  Elsewhere (a CPU run,
+an untileable width, the kill switch) it is gather by slot, step, scatter
+on the donated pools in XLA, which stays the oracle.  The convolution's
+window is gathered and scattered in XLA on both.
 
 Device scopes, under an outer ``gdn`` that is NOT inside ``attn`` (as
 ``ssm`` is not): ``gdn_in_proj``, ``gdn_conv``, ``gdn_gates`` (the L2
 norms, beta, alpha), ``gdn_chunk`` (prefill and carried chunk: the solve,
 the products inside a chunk, the state's carry), ``gdn_step`` (decode:
-EVERY operation that reads or writes either slot pool), ``gdn_out`` (the
+EVERY operation that reads or writes either slot pool, the kernel
+``gdn_state_step`` among them), ``gdn_out`` (the
 gated norm and the out-projection).  The latent layer's and the experts'
 scopes are ``moe_mla.py``'s.
 """
@@ -81,6 +89,7 @@ from ..ops.selective_scan import (
     StateSlots,
     causal_conv,
     conv_window,
+    route_state_step,
 )
 from ..parallel.moe import ExpertLoad
 from .llama import LlamaMLP
@@ -250,15 +259,19 @@ def delta_mixer_core(c, cache, qkvz, ba, conv_w, a_log, dt_bias, o_w,
     B, S = qkvz.shape[0], qkvz.shape[1]
     u, z = qkvz[..., :conv_dim], qkvz[..., conv_dim:]
     f32 = jnp.float32
+    in_place = False
     if cache is None:
         window = jnp.zeros((B, k - 1, conv_dim), u.dtype)
         s0 = jnp.zeros((B, hv, dk, dv), f32)
     else:
         state_pool, conv_pool = pools
         slots = cache.slots
+        # a decode launch the kernel can take steps each row's state in
+        # its slot: nothing of the state is gathered
+        in_place = route_state_step(cache, state_pool.shape) == "pallas"
         with jax.named_scope(step_scope):
             s0, window = _carried_state(
-                cache, state_pool[slots],
+                cache, None if in_place else state_pool[slots],
                 conv_pool[slots].reshape(B, k - 1, conv_dim), decode)
     with jax.named_scope("gdn_conv"):
         xc, padded = causal_conv(u, window, conv_w, None)
@@ -269,15 +282,22 @@ def delta_mixer_core(c, cache, qkvz, ba, conv_w, a_log, dt_bias, o_w,
         q = l2_normalize(heads(xc[..., :hk * dk], hk, dk)) \
             * (1.0 / math.sqrt(dk))
         kk = l2_normalize(heads(xc[..., hk * dk:2 * hk * dk], hk, dk))
-        # key head j serves value heads j * rep .. (j + 1) * rep - 1
-        q = jnp.repeat(q, hv // hk, axis=2)
-        kk = jnp.repeat(kk, hv // hk, axis=2)
+        if not in_place:
+            # key head j serves value heads j * rep .. (j + 1) * rep - 1
+            # (the kernel indexes the key head itself)
+            q = jnp.repeat(q, hv // hk, axis=2)
+            kk = jnp.repeat(kk, hv // hk, axis=2)
         v = heads(xc[..., 2 * hk * dk:], hv, dv)
         beta, log_alpha = gates(ba[..., :hv], ba[..., hv:], a_log, dt_bias)
     with jax.named_scope(step_scope):
         if decode:
-            o, s = gated_delta_step(q[:, 0], kk[:, 0], v[:, 0],
-                                    log_alpha[:, 0], beta[:, 0], s0)
+            step = (q[:, 0], kk[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0])
+            if in_place:
+                from ..ops.pallas_gated_delta import state_step
+
+                o, state_pool = state_step(*step, state_pool, slots)
+            else:
+                o, s = gated_delta_step(*step, s0)
             o = o[:, None]
             keep = padded[:, 1:]
         else:
@@ -298,7 +318,8 @@ def delta_mixer_core(c, cache, qkvz, ba, conv_w, a_log, dt_bias, o_w,
     with jax.named_scope(step_scope):
         # in place on the donated pools; padding rows all write the null
         # slot 0, which no sequence reads
-        state_pool = state_pool.at[slots].set(s)
+        if not in_place:
+            state_pool = state_pool.at[slots].set(s)
         conv_pool = conv_pool.at[slots].set(
             keep.reshape(B, -1).astype(conv_pool.dtype))
     return y, state_pool, conv_pool

@@ -20,11 +20,16 @@ in slot pools the engine allocates from the layer's declaration
 
 Three forms, one mathematics:
 
-* :func:`gated_delta_step` -- one token a row: the decode step, and under
+* :func:`gated_delta_step` -- one token a row, on states GATHERED from
+  their slots (the caller scatters the new ones back): the decode step of
+  a CPU run and of an untileable width, and under
   :func:`gated_delta_recurrence` (a ``lax.scan`` of it) the ORACLE.  The
   two products with the state are elementwise multiplies and sums in
   float32 (a step is bound by the state's bytes, not by operations), so the
-  state is never rounded.
+  state is never rounded.  ``pallas_gated_delta.state_step`` is the same
+  step IN PLACE on the slot pool, one Pallas kernel: a decode launch on the
+  chip at ``d_k % 8 == 0`` and ``d_v % 128 == 0``
+  (``selective_scan.state_step_path`` decides from the pool's shape).
 * :func:`gated_delta_chunked` -- a prompt, in chunks of ``chunk`` tokens.
   With ``g_i`` the running sum of ``log alpha`` inside a chunk and ``u_i =
   beta_i (v_i - alpha_i S_{i-1}^T k_i)`` the row a token WRITES, the rule

@@ -29,7 +29,9 @@ Four paths, one mathematics:
   Pallas kernel: a decode launch on the chip at ``N % 8 == 0`` and
   ``D % 128 == 0`` (:func:`state_step_path` decides, through the one
   dispatch policy ``paged_attention.pallas_dispatch``; :data:`last_path`
-  says which of the two a launch was traced through).
+  says which of the two a launch was traced through).  The gated delta
+  rule's matrix state a head (``ops/gated_delta.py``) takes the same route
+  to a kernel of its own, ``pallas_gated_delta.state_step``.
 * :func:`conv_window` — the conv inputs to keep, cut at ``n_valid``.
 """
 
@@ -74,7 +76,7 @@ class StateCache:
                                     # in-place Pallas step (interpret mode
                                     # off the chip), False pins the XLA
                                     # gather / step / scatter, None leaves
-                                    # it to shape and platform
+                                    # it to the pool's shape and platform
 
     # the names the engine's step programs read the pools back by
     k_pool = property(lambda self: self.state_pool)
@@ -134,19 +136,21 @@ last_path: Optional[str] = None
 
 
 def state_step_path(state_shape, use_pallas, decode: bool = True) -> str:
-    """``"pallas"`` where a launch over a ``[slots, N, D]`` state pool
-    steps the state in its slot (``pallas_ssm.state_step``), ``"xla"``
-    where it gathers, steps (or scans) and scatters: the kernel is a DECODE
-    step of the selective scan at whole float32 tiles on a TPU backend,
+    """``"pallas"`` where a launch over a state pool steps the state in its
+    slot, ``"xla"`` where it gathers, steps (or scans) and scatters.  The
+    kernels are DECODE steps at whole float32 tiles on a TPU backend: of
+    the selective scan over ``[slots, N, D]`` (``pallas_ssm.state_step``;
+    ``N % 8 == 0``, ``D % 128 == 0``) and of the gated delta rule over
+    ``[slots, H, d_k, d_v]`` (``pallas_gated_delta.state_step``; ``d_k % 8
+    == 0``, ``d_v % 128 == 0``).  The pool's SHAPE chooses;
     ``use_pallas`` forces or pins as everywhere (``paged_attention
-    .pallas_dispatch``, whose kill switch wins).  A state pool of another
-    rank (a matrix a head, ``ops/gated_delta.py``) has no kernel."""
+    .pallas_dispatch``, whose kill switch wins)."""
     from .paged_attention import pallas_dispatch
 
-    if not decode or len(state_shape) != 3:
+    if not decode or len(state_shape) not in (3, 4):
         return "xla"
-    _, n, d = state_shape
-    tileable = (n % 8 == 0 and d % 128 == 0
+    sublanes, lanes = state_shape[-2:]
+    tileable = (sublanes % 8 == 0 and lanes % 128 == 0
                 and jax.default_backend() == "tpu")
     return pallas_dispatch(lambda: None, lambda: None, use_pallas,
                            tileable)[1]

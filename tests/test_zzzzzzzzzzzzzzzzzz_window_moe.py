@@ -477,6 +477,15 @@ def test_paged_decode_kernel_compiles_at_the_cells_shapes(
         jax.config.update("jax_enable_compilation_cache", True)
 
 
+def _steps_the_donated_pool_in_place(compiled, pool_bytes):
+    """A state-step kernel is in the program and the donated pool is its
+    output: nothing the size of the pool is allocated beside it."""
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 8
+
+
 @pytest.mark.parametrize("rows", [8, 256])
 def test_state_step_kernel_compiles_at_the_published_widths(
         one_chip, monkeypatch, rows):
@@ -497,11 +506,36 @@ def test_state_step_kernel_compiles_at_the_published_widths(
         compiled = jax.jit(pallas_ssm.state_step, donate_argnums=5).lower(
             s((rows, d)), s((rows, d)), s((n, d)), s((rows, n)), s((rows, n)),
             s((slots, n, d)), s((rows,), jnp.int32)).compile()
-        assert "tpu_custom_call" in compiled.as_text()
-        mem = compiled.memory_analysis()
-        pool = slots * n * d * 4
-        assert mem.alias_size_in_bytes >= pool
-        assert mem.temp_size_in_bytes < pool // 8
+        _steps_the_donated_pool_in_place(compiled, slots * n * d * 4)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+
+
+@pytest.mark.parametrize("rows", [8, 128])
+def test_delta_state_step_kernel_compiles_at_the_published_widths(
+        one_chip, monkeypatch, rows):
+    """The TPU compiler takes the gated delta rule's decode step in place
+    on the slot pool at GigaChat3.5-432B-A28B's widths (64 value heads on
+    32 key heads, states of 128 x 128 float32, the cell's 128 slots and
+    the null one: ``ops/pallas_gated_delta.py``, here for the reason
+    above), and the donated pool is the kernel's output: nothing the size
+    of the pool, or of the rows' gathered states, is allocated beside it."""
+    from paddle_tpu.ops import pallas_gated_delta
+
+    monkeypatch.setattr(pallas_gated_delta, "_interpret", lambda: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        def s(shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        hk, hv, d, slots = 32, 64, 128, 129
+        compiled = jax.jit(pallas_gated_delta.state_step,
+                           donate_argnums=5).lower(
+            s((rows, hk, d)), s((rows, hk, d)), s((rows, hv, d)),
+            s((rows, hv)), s((rows, hv)), s((slots, hv, d, d)),
+            s((rows,), jnp.int32)).compile()
+        assert "gdn_state_step" in compiled.as_text()
+        _steps_the_donated_pool_in_place(compiled, slots * hv * d * d * 4)
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
 

@@ -40,9 +40,11 @@ def test_the_engine_allocates_what_the_layers_declare(model):
     half = make_engine(model, dtype=jnp.bfloat16)
     assert half._k_pools[0].dtype == jnp.float32
     assert half._v_pools[0].dtype == half._k_pools[3].dtype == jnp.bfloat16
-    # a matrix a head has no in-place kernel: the step is gathered
+    # heads of 16 x 16 are no whole float32 tiles: the step is gathered
+    # unless the in-place kernel is forced (``test_pallas_gated_delta.py``)
     assert state_step_path((5, 4, 16, 16), None) == "xla"
-    assert state_step_path((5, 4, 16, 16), True) == "xla"
+    assert state_step_path((5, 4, 16, 16), True) == "pallas"
+    assert state_step_path((5, 4, 16, 16), True, decode=False) == "xla"
     text = eng.metrics.registry.prometheus_text()
     assert "serving_kv_bytes_per_token 128" in text       # one layer x 32 x 4 B
     assert "serving_state_slots_capacity 4" in text

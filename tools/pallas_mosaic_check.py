@@ -212,6 +212,23 @@ def sampler_check(rows, vocab=128256):
     return run
 
 
+# --- decode steps in place on a slot pool ------------------------------------
+
+def _first_and_ms(step, pool0, iters=10):
+    """``step(pool) -> (y, pool)`` with the pool donated, as a step program
+    donates it: the first launch's results (kept: the pool is donated on)
+    and the milliseconds a launch of ``iters`` more in a row."""
+    f = jax.jit(step, donate_argnums=0)
+    y, pool = f(jnp.array(pool0))
+    first = (y, jnp.array(pool))
+    jax.block_until_ready(first)
+    t0 = time.perf_counter()
+    for _ in range(iters):
+        y, pool = f(pool)
+    jax.block_until_ready((y, pool))
+    return first, round((time.perf_counter() - t0) / iters * 1e3, 3)
+
+
 # --- selective-scan decode step, in place on the slot pool -------------------
 
 def ssm_state_step_check(rows=256, n=16, d=5120):
@@ -239,22 +256,54 @@ def ssm_state_step_check(rows=256, n=16, d=5120):
             y, h = selective_step(x, dt, A, Bm, Cm, pool[slots])
             return y, pool.at[slots].set(h)
 
-        def first_and_ms(step, iters=10):
-            f = jax.jit(step, donate_argnums=0)
-            y, pool = f(jnp.array(pool0))
-            first = (y, jnp.array(pool))    # kept: the pool is donated on
-            jax.block_until_ready(first)
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                y, pool = f(pool)
-            jax.block_until_ready((y, pool))
-            return first, round((time.perf_counter() - t0) / iters * 1e3, 3)
-
-        out, ms = first_and_ms(kernel)
-        ref, xla_ms = first_and_ms(xla)
+        out, ms = _first_and_ms(kernel, pool0)
+        ref, xla_ms = _first_and_ms(xla, pool0)
         err = max(_err(a, b) for a, b in zip(out, ref))
         return {"ok": err < 1e-5, "max_err": err, "pallas_ms": ms,
                 "beside_ms": xla_ms}
+    return run
+
+
+# --- gated delta-rule decode step, in place on the slot pool -----------------
+
+def gdn_state_step_check(rows=128, hk=32, hv=64, d=128):
+    """``pallas_gated_delta.state_step`` at GigaChat3.5-432B-A28B's widths
+    (64 value heads on 32 key heads, states of 128 x 128 float32: 4.2 MB a
+    row; the cell's 128 rows, every slot of 129 but the null one, scattered)
+    against gather -> ``gated_delta_step`` -> scatter, the pool donated to
+    both as a step program donates it; ``beside_ms`` is the XLA path's and
+    ``floor_ms`` the state's two crossings at 819 GB/s."""
+    def run():
+        from paddle_tpu.ops import pallas_gated_delta
+        from paddle_tpu.ops.gated_delta import gated_delta_step, l2_normalize
+
+        rng = np.random.default_rng(5)
+        f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+        q = l2_normalize(f32(rows, hk, d)) / np.sqrt(d)
+        k, v = l2_normalize(f32(rows, hk, d)), f32(rows, hv, d)
+        log_alpha = -jnp.abs(f32(rows, hv)) * 0.1
+        beta = jax.nn.sigmoid(f32(rows, hv))
+        slots = jnp.asarray(rng.permutation(np.arange(1, rows + 1)),
+                            jnp.int32)
+        pool0 = f32(rows + 1, hv, d, d)
+        rep = hv // hk
+
+        def kernel(pool):
+            return pallas_gated_delta.state_step(q, k, v, log_alpha, beta,
+                                                 pool, slots)
+
+        def xla(pool):
+            o, s = gated_delta_step(jnp.repeat(q, rep, 1),
+                                    jnp.repeat(k, rep, 1), v, log_alpha,
+                                    beta, pool[slots])
+            return o, pool.at[slots].set(s)
+
+        out, ms = _first_and_ms(kernel, pool0)
+        ref, xla_ms = _first_and_ms(xla, pool0)
+        err = max(_err(a, b) for a, b in zip(out, ref))
+        return {"ok": err < 1e-4, "max_err": err, "pallas_ms": ms,
+                "beside_ms": xla_ms,
+                "floor_ms": round(2 * rows * hv * d * d * 4 / 819e9 * 1e3, 3)}
     return run
 
 
@@ -368,6 +417,8 @@ CHECKS = [
     ("sampler_rows2048_vocab128256", sampler_check(2048)),
     # the hybrid cell's decode launch: 256 rows, every slot but the null one
     ("ssm_state_step_256x16x5120", ssm_state_step_check()),
+    # the delta-rule cell's decode launch: 128 rows, every slot but the null
+    ("gdn_state_step_128x64x128x128", gdn_state_step_check()),
     # the latent cells' one-shot prefill launches: xing4.0-29b-a4b's three
     # buckets (32 heads, 128 + 64 / 128) and glm-4.7-flash's widest (20
     # heads, 192 + 64 / 256)

@@ -28,7 +28,8 @@ from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..nn.initializer import Constant, XavierNormal
 from ..nn.layers import Layer
-from ..ops.paged_attention import LaunchTelemetry
+from ..ops import pallas_moe
+from ..ops.paged_attention import LaunchTelemetry, pallas_dispatch
 from .utils import annotate_param, axis_size, sharding_constraint
 
 EP_AXIS = "sep"  # expert parallelism rides the sep axis of the 5-axis mesh
@@ -304,18 +305,43 @@ def clamped_swiglu(gate, up, limit):
     return jax.nn.silu(jnp.minimum(gate, limit)) * jnp.clip(up, -limit, limit)
 
 
+# Which implementation the most recent :func:`_grouped_swiglu` was traced
+# with: "pallas" (``ops.pallas_moe.grouped_matmul``) | "xla" (``ragged_dot``),
+# and the (token, expert) pairs of the :func:`dropless_experts` around it.
+last_path: Optional[str] = None
+last_pairs: int = 0
+
+
 def _grouped_swiglu(rows, w_gate_up, w_down, sizes, limit=None):
     """``E_g(row)`` for rows sorted by group, ``sizes`` rows a group; rows
     past the last group are whatever the grouped matmul leaves there.
-    ``limit``: :func:`clamped_swiglu`'s (``None``: no clamp)."""
+    ``limit``: :func:`clamped_swiglu`'s (``None``: no clamp).
+
+    The two grouped products have two implementations, chosen by what the
+    program can see (``ops.pallas_moe.streams`` through ``paged_attention
+    .pallas_dispatch``, whose kill switch wins; published as
+    :data:`last_path`): on a TPU, at whole lane tiles and up to
+    ``pallas_moe.STREAM_ROWS_PER_EXPERT`` static rows a held expert (a
+    decode launch: a handful of rows a group, bound by reading the weights)
+    ``pallas_moe.grouped_matmul``, which reads each touched expert's weights
+    once; above that, and off the TPU, ``jax.lax.ragged_dot``."""
+    global last_path
     f = w_down.shape[1]
-    with jax.named_scope("moe_experts"):
-        h = jax.lax.ragged_dot(rows, w_gate_up, sizes)
+
+    def both(product):
+        h = product(rows, w_gate_up, sizes)
         if limit is None:
             h = jax.nn.silu(h[:, :f]) * h[:, f:]
         else:
             h = clamped_swiglu(h[:, :f], h[:, f:], limit)
-        return jax.lax.ragged_dot(h, w_down, sizes)
+        return product(h, w_down, sizes)
+
+    with jax.named_scope("moe_experts"):
+        out, last_path = pallas_dispatch(
+            lambda: both(pallas_moe.grouped_matmul),
+            lambda: both(jax.lax.ragged_dot), None,
+            pallas_moe.streams(rows, w_gate_up, w_down))
+    return out
 
 
 def dropless_experts(x, ids, weights, w_gate_up, w_down, num_experts: int,
@@ -342,8 +368,13 @@ def dropless_experts(x, ids, weights, w_gate_up, w_down, num_experts: int,
     whatever the routing.
 
     Scopes: ``moe_dispatch`` (sort and gather), ``moe_experts`` (the
-    grouped matmuls), ``moe_combine`` (weigh and sum per token)."""
+    grouped matmuls, :func:`_grouped_swiglu`: ``ops.pallas_moe``'s kernel
+    up to ``pallas_moe.STREAM_ROWS_PER_EXPERT`` static rows a held expert
+    on a TPU, ``ragged_dot`` above and elsewhere), ``moe_combine`` (weigh
+    and sum per token)."""
+    global last_pairs
     T, k = ids.shape
+    last_pairs = T * k
     n_held = w_gate_up.shape[0]
     all_held = held is None or tuple(held) == tuple(range(num_experts))
     chunk = T * k
@@ -455,7 +486,9 @@ class ExpertLoad(LaunchTelemetry):
     ``engine.fetch`` ``moe_assignments`` ((token, expert) pairs routed,
     padding rows included), ``moe_experts_touched``, ``moe_max_load`` (the
     fullest expert's tokens, summed over expert layers), ``moe_decode`` (1
-    on a decode launch); where this process holds a SHARE of the experts
+    on a decode launch), ``moe_streamed`` (1 where the program's expert
+    layers were traced through ``ops.pallas_moe``'s kernel: :data:`last_path`
+    as of that trace); where this process holds a SHARE of the experts
     (the configuration's ``experts_held``) also ``moe_pairs_held`` (pairs
     routed to them) and ``moe_held_touched``; and the ``serving_moe_*``
     series below."""
@@ -465,7 +498,15 @@ class ExpertLoad(LaunchTelemetry):
         held = getattr(layers[0].config, "experts_held", None)
         self.held = None if held is None else np.asarray(held, int)
         reg, labels = view.registry, view.labels
+        # pairs a layer -> the path a program of that many was traced
+        # through (the rule reads shapes: buckets fall on both its sides)
+        self._paths: dict = {}
         self.counters = {
+            "streamed": reg.counter(
+                "serving_moe_streamed_launches_total", **labels,
+                help="launches whose routed experts ran the kernel that "
+                     "reads each touched expert's weights once "
+                     "(ops.pallas_moe), not ragged_dot"),
             "assignments": reg.counter(
                 "serving_moe_assignments_total", **labels,
                 help="(token, expert) pairs routed, over expert layers and "
@@ -491,7 +532,10 @@ class ExpertLoad(LaunchTelemetry):
                          "routed, since the start"))
 
     def traced(self):
-        return pop_load(self.layers)
+        load = pop_load(self.layers)
+        if load is not None:
+            self._paths[last_pairs] = last_path
+        return load
 
     def fetch_ints(self, program, load):
         if load is None:
@@ -500,14 +544,17 @@ class ExpertLoad(LaunchTelemetry):
         assignments = int(load.sum())
         touched = int(np.count_nonzero(load))
         max_load = int(load.max(axis=1).sum())
+        streamed = int(self._paths.get(int(load[0].sum())) == "pallas")
         c = self.counters
+        c["streamed"].inc(streamed)
         c["assignments"].inc(assignments)
         c["touched"].inc(touched)
         if assignments:
             c["max_over_mean"].set(max_load * load.shape[1] / assignments)
         ints = {"moe_assignments": assignments,
                 "moe_experts_touched": touched, "moe_max_load": max_load,
-                "moe_decode": int(program == "decode")}
+                "moe_decode": int(program == "decode"),
+                "moe_streamed": streamed}
         if self.held is not None:
             # a share of the experts: the pairs that are this chip's, and
             # how many of its experts a pair reached

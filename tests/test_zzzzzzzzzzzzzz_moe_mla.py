@@ -396,7 +396,7 @@ def test_stats_carry_the_load_and_metrics_count_it(model):
     (load,) = eng._telemetry            # parallel.moe.ExpertLoad
     ints = load.fetch_ints("decode", np.array([[3, 1, 0, 0], [0, 2, 2, 0]]))
     assert ints == {"moe_assignments": 8, "moe_experts_touched": 4,
-                    "moe_max_load": 5, "moe_decode": 1}
+                    "moe_max_load": 5, "moe_decode": 1, "moe_streamed": 0}
     assert load.fetch_ints("prefill", None) == {}
 
 

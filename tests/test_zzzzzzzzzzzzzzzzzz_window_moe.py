@@ -700,3 +700,61 @@ def test_chunk_summarised_decode_compiles_at_the_byte_cells_shapes(
             pool_bytes // 8, 4 << 20), name
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
+
+
+@pytest.mark.parametrize("name,rows,experts,h,f,limit,dtype", [
+    ("glm-4.7-flash, 128 decode rows x 4", 512, 64, 2048, 1536, None,
+     "bfloat16"),
+    ("glm-4.7-flash, the set-up check's one row", 4, 64, 2048, 1536, None,
+     "bfloat16"),
+    ("glm-4.7-flash, the 4,096 prefill bucket", 16384, 64, 2048, 1536, None,
+     "bfloat16"),
+    ("gigachat3.5-432b-a28b, 128 decode rows x 8 on 16 held", 1024, 16, 7168,
+     2048, 10.0, "bfloat16"),
+    ("command-a-plus-05-2026, 32 decode rows x 8 on 16 held", 256, 16, 4096,
+     4096, None, "bfloat16"),
+    ("command-a-plus-05-2026, a pass of 8,192 held pairs", 8192, 16, 4096,
+     4096, None, "bfloat16"),
+    ("xing4.0-29b-a4b, the 4,096 prefill bucket", 16384, 64, 3584, 1024,
+     None, "bfloat16"),
+    ("float32 operands", 64, 8, 256, 128, None, "float32"),
+])
+def test_grouped_matmul_compiles_at_the_expert_cells_shapes(
+        one_chip, monkeypatch, name, rows, experts, h, f, limit, dtype):
+    """The TPU compiler takes both grouped products of a routed-expert layer
+    through ``ops/pallas_moe.py`` (here for the reason above) at the shapes
+    of the four configurations with routed experts, decode and prefill, as
+    ``parallel.moe._grouped_swiglu`` chooses them by its rule; both custom
+    calls keep the scope the benchmark's readers time them by, and
+    ``ragged-dot`` is not in the program."""
+    from paddle_tpu.ops import pallas_moe
+    from paddle_tpu.parallel import moe
+
+    monkeypatch.setattr(pallas_moe, "_interpret", lambda: False)
+    monkeypatch.setattr(pallas_moe, "_on_tpu", lambda: True)
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        def s(shape, dt=jnp.dtype(dtype)):
+            return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+        def layer(x, wgu, wd, sizes):
+            with jax.named_scope("mlp"):
+                return moe._grouped_swiglu(x, wgu, wd, sizes, limit)
+
+        compiled = jax.jit(layer).lower(
+            s((rows, h)), s((experts, h, 2 * f)), s((experts, f, h)),
+            s((experts,), jnp.int32)).compile()
+        assert moe.last_path == "pallas", name
+        lines = compiled.as_text().splitlines()
+        calls = [l for l in lines
+                 if "custom-call(" in l and "tpu_custom_call" in l]
+        assert len(calls) == 2, name
+        assert all("mlp/moe_experts/" in l and "moe_grouped_matmul" in l
+                   for l in calls), name
+        assert not [l for l in lines if "ragged" in l], name
+        # the weights are read where they lie: nothing their size beside them
+        # (its temporaries are the rows between the two products)
+        assert compiled.memory_analysis().temp_size_in_bytes < max(
+            experts * 3 * h * f // 8, 4 * rows * (h + 3 * f), 1 << 20), name
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)

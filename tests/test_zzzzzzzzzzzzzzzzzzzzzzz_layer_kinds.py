@@ -210,20 +210,20 @@ NAMES = {
         set()),
     "moe_mla": (
         set(),
-        {"moe_assignments", "moe_decode", "moe_experts_touched", "moe_max_load"},
-        {"serving_moe_assignments_total", "serving_moe_experts_touched_total", "serving_moe_load_max_over_mean"}),
+        {"moe_assignments", "moe_decode", "moe_experts_touched", "moe_max_load", "moe_streamed"},
+        {"serving_moe_assignments_total", "serving_moe_experts_touched_total", "serving_moe_load_max_over_mean", "serving_moe_streamed_launches_total"}),
     "mamba_hybrid": (
         {"state_rows", "state_slots_held"},
         set(),
         {"serving_state_bytes_per_sequence", "serving_state_slots_capacity", "serving_state_slots_held"}),
     "window_moe": (
         {"state_rows", "state_slots_held", "window_tokens"},
-        {"moe_assignments", "moe_decode", "moe_experts_touched", "moe_held_touched", "moe_max_load", "moe_pairs_held"},
-        {"serving_moe_assignments_total", "serving_moe_experts_touched_total", "serving_moe_held_pair_share", "serving_moe_load_max_over_mean", "serving_moe_pairs_held_total", "serving_state_bytes_per_sequence", "serving_state_slots_capacity", "serving_state_slots_held"}),
+        {"moe_assignments", "moe_decode", "moe_experts_touched", "moe_held_touched", "moe_max_load", "moe_pairs_held", "moe_streamed"},
+        {"serving_moe_assignments_total", "serving_moe_experts_touched_total", "serving_moe_held_pair_share", "serving_moe_load_max_over_mean", "serving_moe_pairs_held_total", "serving_moe_streamed_launches_total", "serving_state_bytes_per_sequence", "serving_state_slots_capacity", "serving_state_slots_held"}),
     "hc_moe_mla": (
         {"hc_streams"},
-        {"hc_entries", "hc_res_clamped", "hc_sinkhorn_residual_ppb", "moe_assignments", "moe_decode", "moe_experts_touched", "moe_max_load"},
-        {"serving_hc_res_clamped_total", "serving_hc_sinkhorn_residual", "serving_moe_assignments_total", "serving_moe_experts_touched_total", "serving_moe_load_max_over_mean"}),
+        {"hc_entries", "hc_res_clamped", "hc_sinkhorn_residual_ppb", "moe_assignments", "moe_decode", "moe_experts_touched", "moe_max_load", "moe_streamed"},
+        {"serving_hc_res_clamped_total", "serving_hc_sinkhorn_residual", "serving_moe_assignments_total", "serving_moe_experts_touched_total", "serving_moe_load_max_over_mean", "serving_moe_streamed_launches_total"}),
     "eva": (
         {"eva_pool_tiles", "eva_pool_tiles_seen", "eva_ring_tokens", "eva_rows_held", "eva_summary_rows", "eva_windows_closed", "state_rows", "state_slots_held"},
         set(),

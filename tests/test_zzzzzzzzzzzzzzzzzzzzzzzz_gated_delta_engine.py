@@ -73,7 +73,8 @@ def test_a_launch_carries_the_slots_and_the_held_experts_load(model):
     assert all(set(b) == {"rows", "state_rows", "state_slots_held"}
                and b["state_rows"] == 1 for b in seen["engine.build"][1:])
     assert all(set(f) == {"bytes", "moe_assignments", "moe_decode",
-                          "moe_experts_touched", "moe_max_load",
+                          "moe_streamed", "moe_experts_touched",
+                          "moe_max_load",
                           "moe_pairs_held", "moe_held_touched"}
                for f in seen["engine.fetch"])
     slots, load = eng._telemetry

@@ -264,6 +264,47 @@ def ssm_state_step_check(rows=256, n=16, d=5120):
     return run
 
 
+# --- the routed experts' grouped matmul -------------------------------------
+
+def moe_grouped_matmul_check(rows, experts, h, f, real=None, touched=None):
+    """``pallas_moe.grouped_matmul`` for both products of one routed-expert
+    layer (``[experts, h, 2 f]``, ``[experts, f, h]`` bf16, XLA's SwiGLU
+    between them) against ``jax.lax.ragged_dot``: ``real`` of the ``rows``
+    routed over ``touched`` experts (all of both by default), the rest a
+    tail no group owns; ``beside_ms`` is ``ragged_dot``'s and ``floor_ms``
+    the touched experts' weights once at 819 GB/s."""
+    def run():
+        from paddle_tpu.ops import pallas_moe
+
+        rng = np.random.default_rng(51)
+        n_real, n_touched = real or rows, touched or experts
+        sizes = np.zeros(experts, np.int32)
+        sizes[np.sort(rng.permutation(experts)[:n_touched])] = \
+            rng.multinomial(n_real, np.ones(n_touched) / n_touched)
+        n_touched = int(np.count_nonzero(sizes))
+        sizes = jnp.asarray(sizes)
+        x = _rand(rng, (rows, h))
+        k1, k2 = jax.random.split(jax.random.PRNGKey(51))
+        wgu = jax.random.normal(k1, (experts, h, 2 * f), jnp.bfloat16) \
+            * h ** -0.5
+        wd = jax.random.normal(k2, (experts, f, h), jnp.bfloat16) * f ** -0.5
+
+        def layer(product):     # the weights are arguments, not constants
+            def both(x, wgu, wd):
+                g = product(x, wgu, sizes)
+                return product(jax.nn.silu(g[:, :f]) * g[:, f:], wd, sizes)
+            return jax.jit(both)
+
+        kernel, xla = layer(pallas_moe.grouped_matmul), \
+            layer(jax.lax.ragged_dot)
+        err = _err(kernel(x, wgu, wd)[:n_real], xla(x, wgu, wd)[:n_real])
+        return {"ok": err < 1e-2, "max_err": err,
+                "pallas_ms": _bench(kernel, x, wgu, wd),
+                "beside_ms": _bench(xla, x, wgu, wd),
+                "floor_ms": round(n_touched * 3 * h * f * 2 / 819e9 * 1e3, 3)}
+    return run
+
+
 # --- gated delta-rule decode step, in place on the slot pool -----------------
 
 def gdn_state_step_check(rows=128, hk=32, hv=64, d=128):
@@ -419,6 +460,16 @@ CHECKS = [
     ("ssm_state_step_256x16x5120", ssm_state_step_check()),
     # the delta-rule cell's decode launch: 128 rows, every slot but the null
     ("gdn_state_step_128x64x128x128", gdn_state_step_check()),
+    # the routed experts of a decode launch: glm-4.7-flash's 128 rows x 4 over
+    # 64 experts, gigachat3.5-432b-a28b's 1,024 static rows of which a step
+    # routes about 54 to 9 of the 16 held experts, and xing4.0-29b-a4b's
+    # 4,096-token prefill bucket (256 rows an expert)
+    ("moe_grouped_matmul_cell_glm_rows512_64x2048x1536",
+     moe_grouped_matmul_check(512, 64, 2048, 1536)),
+    ("moe_grouped_matmul_cell_gigachat_rows1024_16x7168x2048_54real",
+     moe_grouped_matmul_check(1024, 16, 7168, 2048, real=54, touched=9)),
+    ("moe_grouped_matmul_cell_xing_prefill_rows16384_64x3584x1024",
+     moe_grouped_matmul_check(16384, 64, 3584, 1024)),
     # the latent cells' one-shot prefill launches: xing4.0-29b-a4b's three
     # buckets (32 heads, 128 + 64 / 128) and glm-4.7-flash's widest (20
     # heads, 192 + 64 / 256)
